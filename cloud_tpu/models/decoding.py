@@ -164,10 +164,8 @@ def paged_slot_rewind(cache_tree, delta, cache_len):
 # The load-bearing fragment of the warning jax emits when donated
 # buffers can't alias (a plain `warnings.warn`, so category
 # UserWarning; jax/_src/interpreters/mlir.py). Matching a FRAGMENT
-# rather than jax 0.4.37's exact text ("Some donated buffers were not
-# usable: ...") keeps the suppression armed across jax releases that
-# reword the sentence around it — prefix AND suffix are free to
-# change. Only if the core phrase itself disappears does the filter
+# rather than the whole sentence ("Some donated buffers were not
+# usable: ...") leaves the text around it free to change. Only if the core phrase itself disappears does the filter
 # degrade to a no-op: the warning becomes visible again (fail open),
 # never wrongly silenced.
 _DONATION_FRAGMENT = "donated buffers were not usable"

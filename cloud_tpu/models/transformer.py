@@ -126,7 +126,9 @@ class CausalSelfAttention(nn.Module):
     def _paged_decode_attention(self, q, k, v, mask=None):
         """Decode over the paged KV pool (continuous batching). The
         batch dimension is SLOTS, each at its own depth: physical K/V
-        live in a shared page pool `[num_pages, page_size, H, D]`, each
+        live in a shared page pool `[num_pages, page_size, H*D]` (a row
+        per token, heads folded into lanes — the device layout
+        ops/paged_attention.py's kernel reads without a copy), each
         slot's logical `[cache_len]` view is its page table's gather
         over the pool. Writes are per-slot scatters at `slot_steps[s]`;
         insertion/eviction are index updates on the page table and
@@ -172,14 +174,11 @@ class CausalSelfAttention(nn.Module):
         quantized = self.page_dtype == "int8"
         pages_per_slot = self.cache_len // self.page_size
         page_store = jnp.int8 if quantized else self.compute_dtype
-        key_pages = self.variable(
-            "cache", "key_pages", jnp.zeros,
-            (self.num_pages, self.page_size, heads, head_dim),
-            page_store)
-        value_pages = self.variable(
-            "cache", "value_pages", jnp.zeros,
-            (self.num_pages, self.page_size, heads, head_dim),
-            page_store)
+        pool_shape = (self.num_pages, self.page_size, heads * head_dim)
+        key_pages = self.variable("cache", "key_pages", jnp.zeros,
+                                  pool_shape, page_store)
+        value_pages = self.variable("cache", "value_pages", jnp.zeros,
+                                    pool_shape, page_store)
         page_table = self.variable(
             "cache", "page_table", jnp.zeros, (slots, pages_per_slot),
             jnp.int32)
@@ -220,10 +219,11 @@ class CausalSelfAttention(nn.Module):
             scales_kw = dict(key_scales=key_scales.value,
                              value_scales=value_scales.value)
         else:
-            key_pages.value = key_pages.value.at[phys, off].set(
-                k.astype(self.compute_dtype))
+            rows = lambda x: x.astype(self.compute_dtype).reshape(
+                slots, seq, heads * head_dim)
+            key_pages.value = key_pages.value.at[phys, off].set(rows(k))
             value_pages.value = value_pages.value.at[phys, off].set(
-                v.astype(self.compute_dtype))
+                rows(v))
             scales_kw = {}
 
         # Impl selection (ops/paged_attention.py): "auto" runs the
@@ -233,9 +233,9 @@ class CausalSelfAttention(nn.Module):
         # dense [slots, cache_len, H, D] gather — and the gathered-lax
         # reference elsewhere, which is bitwise the dense path's math
         # (engine-vs-solo bit-identity). CLOUD_TPU_PAGED_KERNEL=1/0
-        # force-overrides (kernel runs in interpret mode off-TPU).
-        # Every paged decode — engine tick, speculative verify window,
-        # solo paged decode — routes through this one call.
+        # force-overrides. Every paged decode — engine tick,
+        # speculative verify window, solo paged decode — routes
+        # through this one call.
         from cloud_tpu.ops import paged_attention
         return paged_attention(
             q, key_pages.value, value_pages.value, page_table.value,
@@ -247,7 +247,7 @@ def _quantized_page_write(pages, scales, x, phys, off):
     """Write [slots, seq, H, D] decode K/V into int8 pages with
     per-page per-head amax rescale.
 
-    pages: [N, P, H, D] int8; scales: [N, H] f32; phys/off: [slots,
+    pages: [N, P, H*D] int8; scales: [N, H] f32; phys/off: [slots,
     seq] physical page / in-page offset per token. Returns the updated
     (pages, scales).
 
@@ -265,8 +265,8 @@ def _quantized_page_write(pages, scales, x, phys, off):
     rewrites (the engine insert scatter / host-tier promote), which
     cover every recycled page before a decode write can touch it.
     """
-    slots = x.shape[0]
-    seq = x.shape[1]
+    slots, seq, heads, head_dim = x.shape
+    page_size = pages.shape[1]
     xf = x.astype(jnp.float32)
     rows = jnp.arange(slots)
     for j in range(seq):
@@ -278,11 +278,13 @@ def _quantized_page_write(pages, scales, x, phys, off):
         new = jnp.maximum(old, amax / 127.0)
         safe = jnp.where(new > 0, new, 1.0)
         factor = (old / safe)[:, None, :, None]
-        block = jnp.clip(jnp.round(pages[p].astype(jnp.float32)
-                                   * factor), -127, 127)
+        block = pages[p].astype(jnp.float32).reshape(
+            slots, page_size, heads, head_dim)
+        block = jnp.clip(jnp.round(block * factor), -127, 127)
         qx = jnp.clip(jnp.round(xj / safe[:, :, None]), -127, 127)
         block = block.at[rows, o].set(qx)
-        pages = pages.at[p].set(block.astype(jnp.int8))
+        pages = pages.at[p].set(block.astype(jnp.int8).reshape(
+            slots, page_size, heads * head_dim))
         scales = scales.at[p].set(new)
     return pages, scales
 

@@ -25,46 +25,17 @@ def start_server(port=9012):
     return jax.profiler.start_server(port)
 
 
-def _profile_options(host_tracer_level=None, python_tracer_level=None):
-    """A `jax.profiler.ProfileOptions` with the given tracer levels, or
-    None on jax versions that predate the class (feature-gated: the
-    options are a tuning knob, never a requirement)."""
-    options_cls = getattr(jax.profiler, "ProfileOptions", None)
-    if options_cls is None:
-        return None
-    options = options_cls()
-    if host_tracer_level is not None:
-        options.host_tracer_level = host_tracer_level
-    if python_tracer_level is not None:
-        options.python_tracer_level = python_tracer_level
-    return options
-
-
-def _start_trace(log_dir, options):
-    """start_trace with `options` when both the options object and the
-    `profiler_options` kwarg exist; plain start_trace otherwise. Some
-    jax versions ship ProfileOptions but not the kwarg (or vice versa),
-    so the TypeError fallback covers the half-feature case too."""
-    if options is not None:
-        try:
-            jax.profiler.start_trace(log_dir, profiler_options=options)
-            return
-        except TypeError:
-            pass
-    jax.profiler.start_trace(log_dir)
-
-
 @contextlib.contextmanager
 def trace(log_dir, host_tracer_level=2, python_tracer_level=1):
     """Context manager capturing a device+host trace into `log_dir`.
 
     The artifact lands under `<log_dir>/plugins/profile/<run>` in the
-    TensorBoard profile-plugin layout. Tracer levels apply only on jax
-    versions whose profiler exposes ProfileOptions; older/newer ones
-    fall back to a plain `start_trace` instead of raising.
+    TensorBoard profile-plugin layout.
     """
-    _start_trace(log_dir, _profile_options(host_tracer_level,
-                                           python_tracer_level))
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    options.python_tracer_level = python_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:
@@ -122,7 +93,7 @@ class ProfilerCallback(Callback):
 
     def on_epoch_begin(self, epoch):
         if epoch in self._run_epochs and jax.process_index() == 0:
-            _start_trace(self.log_dir, _profile_options())
+            jax.profiler.start_trace(self.log_dir)
             self._active = True
 
     def on_epoch_end(self, epoch, logs):

@@ -30,8 +30,11 @@ is a None/env check (the graftsan seam contract, unchanged).
 Env contract:
     CLOUD_TPU_TELEMETRY        1|on  -> Trainer entry points enable
     CLOUD_TPU_TELEMETRY_DIR    output directory (default ./telemetry)
-    CLOUD_TPU_PEAK_TFLOPS      chip peak for the MFU gauge (default
-                               197, the v5e bf16 peak bench.py uses)
+
+The MFU and kernel pct-of-peak gauges divide by the chip's published
+peak, looked up in `PEAK_TFLOPS` by the `device_kind` JAX reports. A
+CPU run has no such gauge; an accelerator missing from the table is an
+error, never a default.
 """
 
 import bisect
@@ -46,12 +49,30 @@ from cloud_tpu.parallel import runtime
 logger = logging.getLogger("cloud_tpu")
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "Telemetry",
-           "enable", "disable", "get", "enabled", "env_enabled",
-           "env_scope"]
+           "PEAK_TFLOPS", "peak_tflops", "enable", "disable", "get",
+           "enabled", "env_enabled", "env_scope"]
 
-#: v5e bf16 peak, TFLOPs — the same constant bench.py's pct_peak uses,
-#: so the MFU gauge and the bench census agree on the denominator.
-DEFAULT_PEAK_TFLOPS = 197.0
+#: Published bf16 peak of ONE chip in TFLOP/s, keyed by the
+#: `device_kind` JAX reports — the one denominator the MFU gauge and
+#: bench.py's pct_peak share. v5e: 197 (Google Cloud documentation,
+#: "TPU v5e"). Add a kind together with its source.
+PEAK_TFLOPS = {
+    "TPU v5 lite": 197.0,
+}
+
+
+def peak_tflops(device_kind):
+    """The published bf16 peak for `device_kind`; raises for a kind
+    that is not in `PEAK_TFLOPS` rather than measuring utilization
+    against another chip's peak."""
+    try:
+        return PEAK_TFLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            "No published peak for device_kind {!r}; add it to "
+            "cloud_tpu.monitoring.telemetry.PEAK_TFLOPS with its "
+            "source (known: {}).".format(
+                device_kind, sorted(PEAK_TFLOPS))) from None
 
 #: Span name -> histogram metric fed by the span listener.
 SPAN_HISTOGRAMS = {
@@ -223,7 +244,7 @@ class Histogram:
     Bucket upper bounds are `start * factor**i` for i in [0, buckets);
     observations above the last bound land in the +Inf bucket. The
     defaults (1 µs .. ~72 min at factor 2) cover every latency this
-    framework measures — a step dispatch, a tunnel round trip, a cold
+    framework measures — a step dispatch, a host round trip, a cold
     compile — at ≤2x relative bucket error, which is what a p99 read
     off bucket interpolation inherits.
     """
@@ -407,10 +428,8 @@ class Telemetry:
         self.out_dir = str(out_dir)
         self.registry = Registry()
         self.tracer = None
-        if peak_tflops is None:
-            peak_tflops = float(os.environ.get(
-                "CLOUD_TPU_PEAK_TFLOPS", DEFAULT_PEAK_TFLOPS))
-        self.peak_flops = peak_tflops * 1e12
+        # None = look the device up on first use (tests pass a value).
+        self._peak_tflops = peak_tflops
         self._observer = None
         self._worker = None
         self._exporters = ()
@@ -429,9 +448,9 @@ class Telemetry:
         self.tracer.add_listener(self._on_span)
         self._observer = _RuntimeObserver(self.registry)
         runtime.add_observer(self._observer)
-        # The headline series exist from t=0 (a textfile scrape between
-        # enable and the first epoch still sees them).
-        self.registry.gauge(MFU_GAUGE).set(0.0)
+        # The headline histograms exist from t=0 (a textfile scrape
+        # between enable and the first epoch still sees them); the MFU
+        # gauge appears with its first value, on a chip with a peak.
         self.registry.histogram("cloud_tpu_step_latency_seconds")
         self.registry.histogram(DECODE_TOKEN_HISTOGRAM)
         from cloud_tpu.monitoring import export
@@ -456,6 +475,19 @@ class Telemetry:
     @property
     def active(self):
         return self._active
+
+    @property
+    def peak_flops(self):
+        """The chip's peak FLOP/s, or None on the CPU (which has no
+        utilization gauges)."""
+        if self._peak_tflops is None:
+            import jax
+
+            device = jax.devices()[0]
+            if device.platform == "cpu":
+                return None
+            self._peak_tflops = peak_tflops(device.device_kind)
+        return self._peak_tflops * 1e12
 
     # -- adapters ------------------------------------------------------
 
@@ -484,10 +516,11 @@ class Telemetry:
             elapsed_secs = max(float(elapsed_secs), 1e-9)
             self.registry.gauge("cloud_tpu_steps_per_sec").set(
                 steps / elapsed_secs)
-            if self._step_flops:
+            peak = self.peak_flops if self._step_flops else None
+            if peak:
                 flops_per_sec = self._step_flops * steps / elapsed_secs
                 self.registry.gauge(MFU_GAUGE).set(
-                    100.0 * flops_per_sec / self.peak_flops)
+                    100.0 * flops_per_sec / peak)
         self.flush()
 
     def record_kernel_cost(self, kernel, flops, bytes_moved,
@@ -500,10 +533,11 @@ class Telemetry:
         ops.fused_norm.fused_norm_cost)."""
         self.registry.gauge(KERNEL_BYTES_GAUGE % kernel).set(
             float(bytes_moved))
-        if flops and elapsed_secs and elapsed_secs > 0:
+        peak = (self.peak_flops
+                if flops and elapsed_secs and elapsed_secs > 0 else None)
+        if peak:
             self.registry.gauge(KERNEL_PCT_PEAK_GAUGE % kernel).set(
-                100.0 * (float(flops) / float(elapsed_secs))
-                / self.peak_flops)
+                100.0 * (float(flops) / float(elapsed_secs)) / peak)
 
     def observe_decode(self, n_tokens, elapsed_secs):
         """Per-token decode latency: one observation per generated

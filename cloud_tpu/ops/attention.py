@@ -24,7 +24,8 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   the kernel, padded dO rows are zero so they contribute nothing.
 
 On non-TPU backends the kernels run in Pallas interpret mode (tests), so
-the same code path is exercised everywhere.
+the same code path is exercised everywhere. Under a device mesh the
+kernels run per shard (ops/partition.py).
 """
 
 import functools
@@ -36,6 +37,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from jax.sharding import PartitionSpec as P
+
+from cloud_tpu.ops import partition
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -271,6 +276,7 @@ def _flash_forward(config, q, k, v, kmask):
     GQA streams each kv head's blocks to its group of q-head programs
     via the index map (b // kv_group) — the H-wide expansion is never
     materialized in HBM."""
+    vma = partition.vma_of(q, k, v, kmask)
     bh, seq, head_dim = q.shape
     num_q = seq // config.block_q
     num_k = seq // config.block_k
@@ -301,8 +307,9 @@ def _flash_forward(config, q, k, v, kmask):
                          lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, head_dim), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, seq, head_dim), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, seq, _LANES), jnp.float32,
+                                 vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((config.block_q, head_dim), jnp.float32),
@@ -431,6 +438,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(config, q, k, v, kmask, out, lse, g):
+    vma = partition.vma_of(q, k, v, g)
     bh, seq, head_dim = q.shape
     bh_kv = k.shape[0]
     num_q = seq // config.block_q
@@ -459,7 +467,7 @@ def _flash_backward(config, q, k, v, kmask, out, lse, g):
         grid=(bh, num_q, num_k),
         in_specs=in_specs + [q_spec, row_spec, row_spec],
         out_specs=[q_spec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma)],
         scratch_shapes=[
             pltpu.VMEM((config.block_q, head_dim), jnp.float32)],
         interpret=config.interpret,
@@ -487,8 +495,8 @@ def _flash_backward(config, q, k, v, kmask, out, lse, g):
         in_specs=inT_specs + [qT_spec, rowT_spec, rowT_spec],
         out_specs=[kT_spec, kT_spec],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((config.block_k, head_dim), jnp.float32),
@@ -619,39 +627,54 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
     block_q = min(block_q, seq_pad)
     block_k = min(block_k, seq_pad)
 
-    config = _Config(causal=bool(causal), sm_scale=float(sm_scale),
-                     block_q=block_q, block_k=block_k, kv_len=seq,
-                     heads=heads, has_mask=mask is not None,
-                     interpret=bool(interpret),
-                     kv_group=heads // h_kv,
-                     window=int(window or 0),
-                     softcap=float(logit_softcap or 0.0))
+    if mask is not None and mask.shape != (batch, seq):
+        raise ValueError(
+            "mask must be [batch, seq] = {}; got {}.".format(
+                (batch, seq), mask.shape))
 
-    def fold(x):
-        n_heads = x.shape[2]
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(
-            batch * n_heads, seq, head_dim)
-        if seq_pad != seq:
-            x = jnp.pad(x, ((0, 0), (0, seq_pad - seq), (0, 0)))
-        return x
+    def kernel(q, k, v, *kmask):
+        """One device's [B', S, H', D] block."""
+        batch, _, heads, _ = q.shape
+        config = _Config(causal=bool(causal), sm_scale=float(sm_scale),
+                         block_q=block_q, block_k=block_k, kv_len=seq,
+                         heads=heads, has_mask=bool(kmask),
+                         interpret=bool(interpret),
+                         kv_group=heads // k.shape[2],
+                         window=int(window or 0),
+                         softcap=float(logit_softcap or 0.0))
 
-    if mask is None:
-        out = _flash_attention(config, fold(q), fold(k), fold(v))
-    else:
-        if mask.shape != (batch, seq):
-            raise ValueError(
-                "mask must be [batch, seq] = {}; got {}.".format(
-                    (batch, seq), mask.shape))
-        kmask = mask.astype(jnp.int32)
-        if seq_pad != seq:
-            kmask = jnp.pad(kmask, ((0, 0), (0, seq_pad - seq)))
-        # [B, 1, S_pad]: the singleton axis makes the (1, 1, block_k)
-        # mask blocks legal under Mosaic's sublane rule (_mask_spec).
-        kmask = kmask[:, None, :]
-        out = _flash_attention_masked(config, fold(q), fold(k), fold(v),
-                                      kmask)
-    out = out[:, :seq].reshape(batch, heads, seq, head_dim)
-    return jnp.transpose(out, (0, 2, 1, 3))
+        def fold(x):
+            n_heads = x.shape[2]
+            x = jnp.transpose(x, (0, 2, 1, 3)).reshape(
+                batch * n_heads, seq, head_dim)
+            if seq_pad != seq:
+                x = jnp.pad(x, ((0, 0), (0, seq_pad - seq), (0, 0)))
+            return x
+
+        operands = [fold(q), fold(k), fold(v)]
+        if kmask:
+            # [B, 1, S_pad]: the singleton axis makes the
+            # (1, 1, block_k) mask blocks legal under Mosaic's sublane
+            # rule (_mask_spec).
+            operands.append(jnp.pad(
+                kmask[0].astype(jnp.int32),
+                ((0, 0), (0, seq_pad - seq)))[:, None, :])
+        attend = _flash_attention_masked if kmask else _flash_attention
+        out = attend(config, *partition.common_vma(*operands))
+        out = out[:, :seq].reshape(batch, heads, seq, head_dim)
+        return jnp.transpose(out, (0, 2, 1, 3))
+
+    def plan(mesh):
+        """Batch over the data axis, heads over the model axis."""
+        dp = partition.data_axis(mesh, batch)
+        tp = partition.model_axis(mesh, heads, h_kv)
+        spec = P(dp, None, tp, None)
+        in_specs = (spec,) * 3 + ((P(dp, None),) if mask is not None
+                                  else ())
+        return in_specs, spec, None
+
+    args = (q, k, v) + ((mask,) if mask is not None else ())
+    return partition.per_shard(kernel, args, plan, interpret)
 
 
 def attention(q, k, v, causal=True, sm_scale=None, mask=None,

@@ -23,7 +23,8 @@ is dominated by the matmuls either way; the single-pass claim is for
 the forward serving/training hot path.
 
 On non-TPU backends a forced kernel runs in Pallas interpret mode, so
-parity tests exercise the same code path CPU-side.
+parity tests exercise the same code path CPU-side. Under a device mesh
+the kernel runs per shard (ops/partition.py).
 """
 
 import functools
@@ -33,6 +34,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from jax.sharding import PartitionSpec as P
+
+from cloud_tpu.ops import partition
 
 _BLOCK_ROWS = 128
 
@@ -83,6 +88,7 @@ def _fwd_kernel(x_ref, r_ref, w_ref, o_ref, h_ref, *, config):
 def _norm_forward(config, x, residual, scale):
     """x/residual: [rows, D] (row-padded); scale: [1, D] f32 ->
     (normed [rows, D] out_dtype, h [rows, D] x.dtype)."""
+    vma = partition.vma_of(x, residual, scale)
     rows, features = x.shape
     block = config.block_rows
     grid = (rows // block,)
@@ -99,7 +105,8 @@ def _norm_forward(config, x, residual, scale):
             grid=grid,
             in_specs=[row_spec, w_spec],
             out_specs=row_spec,
-            out_shape=jax.ShapeDtypeStruct((rows, features), out_dtype),
+            out_shape=jax.ShapeDtypeStruct((rows, features), out_dtype,
+                                           vma=vma),
             interpret=config.interpret,
         )(x, scale)
         return normed, x
@@ -110,8 +117,9 @@ def _norm_forward(config, x, residual, scale):
         in_specs=[row_spec, row_spec, w_spec],
         out_specs=[row_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, features), out_dtype),
-            jax.ShapeDtypeStruct((rows, features), x.dtype),
+            jax.ShapeDtypeStruct((rows, features), out_dtype,
+                                 vma=vma),
+            jax.ShapeDtypeStruct((rows, features), x.dtype, vma=vma),
         ],
         interpret=config.interpret,
     )(x, residual, scale)
@@ -233,36 +241,52 @@ def fused_rmsnorm(x, scale, residual=None, eps=1e-6, out_dtype=None,
         out_dtype = x.dtype if residual is None else jnp.promote_types(
             x.dtype, residual.dtype)
 
-    lead = x.shape[:-1]
-    rows = 1
-    for dim in lead:
-        rows *= dim
-    block_rows = min(block_rows, max(rows, 1))
-    rows_pad = -(-rows // block_rows) * block_rows
-    # eps stays as passed (a static Python scalar — the config is a
-    # hashable static kernel arg); a float(...) cast here would read
-    # as a host sync to graftlint's jit-chain analysis.
-    config = _NormConfig(eps=eps, block_rows=int(block_rows),
-                         out_dtype=jnp.dtype(out_dtype).name,
-                         interpret=bool(interpret))
+    def kernel(x, scale, *residual):
+        """One device's rows."""
+        lead = x.shape[:-1]
+        rows = 1
+        for dim in lead:
+            rows *= dim
+        block = min(block_rows, max(rows, 1))
+        rows_pad = -(-rows // block) * block
+        # eps stays as passed (a static Python scalar — the config is a
+        # hashable static kernel arg); a float(...) cast here would
+        # read as a host sync to graftlint's jit-chain analysis.
+        config = _NormConfig(eps=eps, block_rows=int(block),
+                             out_dtype=jnp.dtype(out_dtype).name,
+                             interpret=bool(interpret))
 
-    def fold(a):
-        a = a.reshape(rows, features)
-        if rows_pad != rows:
-            # Zero rows: var = 0, rsqrt(eps) finite, output rows 0 —
-            # sliced away below; pad/slice autodiff owns the edges.
-            a = jnp.pad(a, ((0, rows_pad - rows), (0, 0)))
-        return a
+        def fold(a):
+            a = a.reshape(rows, features)
+            if rows_pad != rows:
+                # Zero rows: var = 0, rsqrt(eps) finite, output rows 0
+                # — sliced away below; pad/slice autodiff owns the
+                # edges.
+                a = jnp.pad(a, ((0, rows_pad - rows), (0, 0)))
+            return a
 
-    w = scale.astype(jnp.float32)[None, :]
-    if residual is None:
-        normed, h = _fused_rmsnorm(config, fold(x), w)
-    else:
-        normed, h = _fused_rmsnorm_residual(config, fold(x),
-                                            fold(residual), w)
-    normed = normed[:rows].reshape(lead + (features,))
-    h = h[:rows].reshape(lead + (features,))
-    return normed, h
+        # One varying-axes type, cast out here: the cast's transpose is
+        # the psum that sums the replicated scale's gradient over the
+        # axes the rows are split on.
+        xf, w, *rf = partition.common_vma(
+            fold(x), scale.astype(jnp.float32)[None, :],
+            *(fold(r) for r in residual))
+        if rf:
+            normed, h = _fused_rmsnorm_residual(config, xf, rf[0], w)
+        else:
+            normed, h = _fused_rmsnorm(config, xf, w)
+        return (normed[:rows].reshape(lead + (features,)),
+                h[:rows].reshape(lead + (features,)))
+
+    def plan(mesh):
+        """Rows over the data axis, the scale everywhere."""
+        rows = partition.rows_spec(mesh, x)
+        in_specs = (rows, P()) + ((rows,) if residual is not None
+                                  else ())
+        return in_specs, (rows, rows), None
+
+    args = (x, scale) + ((residual,) if residual is not None else ())
+    return partition.per_shard(kernel, args, plan, interpret)
 
 
 def fused_norm_cost(shape, dtype=jnp.bfloat16, with_residual=True):
@@ -288,8 +312,6 @@ def fused_norm_cost(shape, dtype=jnp.bfloat16, with_residual=True):
         else:
             fn = rmsnorm_residual_reference
         analysis = jax.jit(fn).lower(*args).cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
         flops = float(analysis.get("flops", flops) or flops)
     except Exception:
         pass

@@ -3,8 +3,13 @@
 The serving tick's hot op: one (or `spec_k + 1`) query positions per
 slot attending over that slot's logical KV cache, which lives scattered
 across a physical page pool (`key_pages`/`value_pages`
-`[num_pages, page_size, H, D]`, serving/kvpool.py) and is addressed
-through a per-slot page table. The lax path materializes a dense
+`[num_pages, page_size, H*D]`, serving/kvpool.py) and is addressed
+through a per-slot page table. A page row is one token's K (or V) for
+every head, heads folded into the lane dim: the TPU stores an array
+whose minor dim is under 128 with the *page* index minor-most (its
+compact layout), and Mosaic would then need the whole pool copied
+row-major before every call — at H*D wide the device layout is the
+kernel's own. The lax path materializes a dense
 `[slots, cache_len, H, D]` view by gathering the pool through the page
 table every tick; this kernel never does — the page table rides as a
 scalar-prefetch operand, so each grid step's K/V block is *indexed*
@@ -12,15 +17,22 @@ straight out of the pool in HBM (the gather becomes block addressing)
 and streamed through VMEM with FlashAttention-style online softmax.
 
 Grid and masking contract (see /opt/skills/guides/pallas_guide.md):
-- Grid is (slots*heads, pages_per_slot) with the page dimension
-  innermost. Program (b, j) serves slot b // H, head b % H, and logical
-  page j; its K/V block is physical page `page_table[b // H, j]` —
-  `PrefetchScalarGridSpec` places the table in SMEM before the kernel
-  runs so the BlockSpec index maps can read it.
-- VMEM scratch (acc, m, l) carries the online-softmax state across page
-  steps; the output block is written on the last page step. m/l live in
-  (seq_pad, 128) lane-broadcast scratch (Mosaic has no cheap
-  (N,1)<->(1,N) transpose).
+- Grid is (slots, pages_per_slot) with the page dimension innermost.
+  Program (s, j) serves every head of slot s against logical page j;
+  its K/V block is the whole physical page `page_table[s, j]`
+  (`[P, H*D]`, the array's full trailing dims, which is what Mosaic's
+  block rule asks of a 16-row page) — `PrefetchScalarGridSpec` places
+  the table in SMEM before the kernel runs so the BlockSpec index maps
+  can read it.
+- Heads stay folded in lanes. Per query row the scores are
+  `(k * q_row) @ seg`, with `seg` the `[H*D, H]` 0/1 matrix that sums
+  each head's D lanes, giving `[P, H]` (keys on sublanes, heads on
+  lanes); `p @ seg.T` spreads the probabilities back over the lanes to
+  weight V. No per-head slicing or relayout; the matmuls are f32 at
+  HIGHEST precision, so the sums are the reference's f32 accumulation.
+- VMEM scratch (acc `[seq, H*D]`, m/l `[seq, H]`) carries the
+  online-softmax state across page steps; the output block is written
+  on the last page step.
 - Masking is purely the caller's `allowed [slots, seq, cache_len]`
   (from `decoding.paged_slot_update`): it already encodes per-query
   causality over *logical* key slots plus slot validity, so freed /
@@ -30,8 +42,7 @@ Grid and masking contract (see /opt/skills/guides/pallas_guide.md):
   padded query row or an evicted slot) outputs zeros, never a uniform
   average over pool garbage.
 - `seq` (1 for the plain tick, spec_k + 1 for the speculative verify
-  window) is padded to a sublane multiple; padded query rows are
-  all-masked and sliced away.
+  window) is a static unrolled loop over query rows.
 
 The gathered-lax reference below is bitwise the math
 `models/transformer.py::_paged_decode_attention` shipped before this
@@ -54,10 +65,11 @@ the dequant contract, identical across kernel/walk/reference:
 
 and both the QK and PV dots run in f32 (int8 quantization already
 costs ~0.4% relative error, so bf16 intermediate rounding would
-dominate it). In the kernel the scale is ONE SMEM scalar per grid
-step — it rides scalar prefetch next to the page table, the dequant
-folds into the dots as a scalar multiply, and nothing dequantized is
-ever materialized in HBM. The walk and reference grow the same math,
+dominate it). In the kernel the page's `[1, H]` scale row is one more
+VMEM block indexed through the page table (not SMEM, whose size would
+cap the pool); the dequant folds into the `[P, H]` scores and
+probabilities as a per-head multiply, and nothing dequantized is ever
+materialized in HBM. The walk and reference grow the same math,
 so the parity suite covers all three impls in int8 mode too. A zero
 scale means an all-zero (never-written) page and dequantizes to exact
 zeros.
@@ -75,21 +87,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from jax.sharding import PartitionSpec as P
+
+from cloud_tpu.ops import partition
+
 _NEG_INF = -1e30
-_LANES = 128
-_SUBLANES = 8
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class _PagedConfig(NamedTuple):
     sm_scale: float
     heads: int
-    seq_pad: int     # query rows after sublane padding
+    seq: int
     page_size: int
     interpret: bool
     quantized: bool = False
 
 
-def _check_scales(key_pages, key_scales, value_scales):
+def _check_scales(key_pages, key_scales, value_scales, heads):
     """Validates the int8-page calling convention: both scale arrays or
     neither; int8 pages; [num_pages, heads] f32 scales."""
     if (key_scales is None) != (value_scales is None):
@@ -97,7 +112,7 @@ def _check_scales(key_pages, key_scales, value_scales):
             "key_scales and value_scales must be given together.")
     if key_scales is None:
         return False
-    num_pages, _, heads, _ = key_pages.shape
+    num_pages = key_pages.shape[0]
     if key_pages.dtype != jnp.int8:
         raise ValueError(
             "scales imply int8 pages; got page dtype {}.".format(
@@ -116,7 +131,7 @@ def paged_attention_reference(q, key_pages, value_pages, page_table,
                               value_scales=None):
     """Gathered-lax paged decode attention (the correctness oracle).
 
-    q: [slots, seq, H, D]; key_pages/value_pages: [N, P, H, D];
+    q: [slots, seq, H, D]; key_pages/value_pages: [N, P, H*D];
     page_table: [slots, pages_per_slot] int32; allowed:
     [slots, seq, cache_len] bool (True = attend) ->
     [slots, seq, H, D] in the page dtype (q's dtype for int8 pages).
@@ -128,24 +143,26 @@ def paged_attention_reference(q, key_pages, value_pages, page_table,
     the gathered f32 view (the module-level dequant contract) and the
     whole computation stays f32.
     """
-    num_pages, page_size, heads, head_dim = key_pages.shape
+    page_size = key_pages.shape[1]
+    heads, head_dim = q.shape[2:]
     slots, pages_per_slot = page_table.shape
     cache_len = pages_per_slot * page_size
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
-    quantized = _check_scales(key_pages, key_scales, value_scales)
-    if quantized:
-        ks = key_scales[page_table][:, :, None, :, None]
-        vs = value_scales[page_table][:, :, None, :, None]
-        k_view = (key_pages[page_table].astype(jnp.float32) * ks
-                  ).reshape(slots, cache_len, heads, head_dim)
-        v_view = (value_pages[page_table].astype(jnp.float32) * vs
-                  ).reshape(slots, cache_len, heads, head_dim)
-    else:
-        k_view = key_pages[page_table].reshape(slots, cache_len, heads,
-                                               head_dim)
-        v_view = value_pages[page_table].reshape(slots, cache_len,
-                                                 heads, head_dim)
+    quantized = _check_scales(key_pages, key_scales, value_scales,
+                              heads)
+
+    def view(pages, scales):
+        """[slots, cache_len, H, D] logical view of the slots' pages."""
+        g = pages[page_table].reshape(slots, pages_per_slot, page_size,
+                                      heads, head_dim)
+        if quantized:
+            g = g.astype(jnp.float32) * scales[page_table][
+                :, :, None, :, None]
+        return g.reshape(slots, cache_len, heads, head_dim)
+
+    k_view = view(key_pages, key_scales)
+    v_view = view(value_pages, value_scales)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_view,
                         preferred_element_type=jnp.float32) * sm_scale
     logits = jnp.where(allowed[:, None], logits, _NEG_INF)
@@ -161,10 +178,21 @@ def paged_attention_reference(q, key_pages, value_pages, page_table,
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(pt_ref, q_ref, k_ref, v_ref, a_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, config, num_pages):
+def _paged_kernel(pt_ref, q_ref, k_ref, v_ref, a_ref, seg_ref,
+                  segt_ref, *rest, config, num_pages):
+    """One (slot, logical page) step for every head. Int8 pages bring
+    two more inputs, the page's `[1, H]` K and V scale rows:
+    `s = ((k_i8 * q) @ seg) * (ks * sm_scale)` and
+    `acc += sum_p (p * vs) @ seg.T * v_i8` are exactly the pre-dot
+    dequant contract because a scale is constant over its head's
+    lanes."""
     del pt_ref  # consumed by the BlockSpec index maps
+    if config.quantized:
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, acc_ref, m_ref, l_ref = rest
     ji = pl.program_id(1)
+    page = config.page_size
 
     @pl.when(ji == 0)
     def _init():
@@ -172,161 +200,116 @@ def _paged_kernel(pt_ref, q_ref, k_ref, v_ref, a_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]             # [seq_pad, D]
-    k = k_ref[0, :, 0, :]    # [P, D] — physical page pt[slot, ji]
-    v = v_ref[0, :, 0, :]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * config.sm_scale
-    mask = a_ref[0, :, 0, :] != 0
+    def dot(a, b):
+        return jnp.dot(a, b, precision=_HIGHEST,
+                       preferred_element_type=jnp.float32)
 
-    s = jnp.where(mask, s, _NEG_INF)
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_curr = jnp.max(s, axis=-1, keepdims=True)
-    m_next = jnp.maximum(m_prev, m_curr)
-    alpha = jnp.exp(m_prev - m_next)
-    # Explicit zero where masked: exp(s - m) underflows to 0 for normal
-    # rows, but a fully-masked row (padded query, evicted slot, scratch
-    # page) has m == s == -inf and exp(0) == 1 would leak pool garbage.
-    p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
-    l_next = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
-
-    @pl.when(ji == num_pages - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
-
-
-def _paged_kernel_quant(pt_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
-                        a_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                        config, num_pages):
-    """Int8-page variant: same online softmax, with each block's
-    per-page per-head f32 scale read as ONE SMEM scalar (it rides
-    scalar prefetch next to the page table) and folded into the dots —
-    `s = dot(q, k_i8) * (ks * sm_scale)` and `acc += dot(p, v_i8) * vs`
-    are exactly the pre-dot dequant contract because the scale is
-    constant over the block. Both dots run in f32 (module docstring)."""
-    b = pl.program_id(0)
-    ji = pl.program_id(1)
-    page = pt_ref[b // config.heads, ji]
-    ks = ks_ref[page, b % config.heads]
-    vs = vs_ref[page, b % config.heads]
-
-    @pl.when(ji == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)          # [seq_pad, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # [P, D] int8 -> f32
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * (ks * config.sm_scale)
-    mask = a_ref[0, :, 0, :] != 0
-
-    s = jnp.where(mask, s, _NEG_INF)
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_curr = jnp.max(s, axis=-1, keepdims=True)
-    m_next = jnp.maximum(m_prev, m_curr)
-    alpha = jnp.exp(m_prev - m_next)
-    p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
-    l_next = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * vs
-    m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
+    k = k_ref[0].astype(jnp.float32)     # [P, H*D], page pt[slot, ji]
+    v = v_ref[0].astype(jnp.float32)
+    seg = seg_ref[...]                   # [H*D, H]
+    segt = segt_ref[...]                 # [H, H*D]
+    scale = config.sm_scale
+    if config.quantized:
+        scale = ks_ref[0] * config.sm_scale          # [1, H]
+    for i in range(config.seq):
+        row = slice(i, i + 1)
+        q = q_ref[0, row, :].astype(jnp.float32)     # [1, H*D]
+        s = dot(k * q, seg) * scale                  # [P, H]
+        mask = a_ref[0, 0, i * page:(i + 1) * page, :] != 0  # [P, 1]
+        s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_ref[row, :]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)             # [1, H]
+        # Explicit zero where masked: exp(s - m) underflows to 0 for
+        # normal rows, but a fully-masked row (evicted slot, scratch
+        # page) has m == s == -inf and exp(0) == 1 would leak pool
+        # garbage.
+        p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
+        l_ref[row, :] = alpha * l_ref[row, :] + jnp.sum(
+            p, axis=0, keepdims=True)
+        if config.quantized:
+            p = p * vs_ref[0]
+        pv = jnp.sum(dot(p, segt) * v, axis=0, keepdims=True)
+        acc_ref[row, :] = acc_ref[row, :] * dot(alpha, segt) + pv
+        m_ref[row, :] = m_next
 
     @pl.when(ji == num_pages - 1)
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / dot(safe_l, segt)).astype(
+            o_ref.dtype)
 
 
 def _paged_forward(config, q, key_pages, value_pages, page_table,
                    allowed, key_scales=None, value_scales=None):
-    """q: [S*H, seq_pad, D] (head-folded); allowed:
-    [S, seq_pad, pages_per_slot, P] int32 -> out [S*H, seq_pad, D].
+    """q: [S, seq, H*D]; allowed: [S, pages_per_slot, seq*P, 1] int32;
+    scales (int8 mode): [N, 1, H] -> out [S, seq, H*D].
 
     The page table is the scalar-prefetch operand: index maps read
-    `pt[b // H, j]` to address each program's physical K/V page, so the
-    pool is only ever touched at the pages a slot actually owns. In
-    int8 mode the scale arrays join it in SMEM (num_scalar_prefetch=3)
-    and the kernel reads one scalar per grid step.
+    `pt[s, j]` to address each program's physical K/V page (and, in
+    int8 mode, its scale rows), so the pool is only ever touched at the
+    pages a slot actually owns.
     """
-    bh, seq_pad, head_dim = q.shape
+    slots, seq, width = q.shape
     heads = config.heads
     page_size = config.page_size
     pages_per_slot = page_table.shape[1]
-    grid = (bh, pages_per_slot)
-    n_scalar = 3 if config.quantized else 1
-    kern = _paged_kernel_quant if config.quantized else _paged_kernel
-    kernel = functools.partial(kern, config=config,
+    kernel = functools.partial(_paged_kernel, config=config,
                                num_pages=pages_per_slot)
+    # seg[c, h] = 1 where lane c belongs to head h.
+    seg = (jnp.arange(width)[:, None] // (width // heads)
+           == jnp.arange(heads)[None, :]).astype(jnp.float32)
+    operands = [page_table, q, key_pages, value_pages, allowed, seg,
+                seg.T]
+    if config.quantized:
+        operands += [key_scales, value_scales]
+    operands = partition.common_vma(*operands)
 
-    def _drop(index_map):
-        # Index maps receive every scalar-prefetch operand; only the
-        # page table is ever indexed.
-        return lambda b, j, pt, *_: index_map(b, j, pt)
-
+    whole = lambda shape: pl.BlockSpec(shape, lambda s, j, pt: (0, 0))
+    slot_block = pl.BlockSpec((1, seq, width),
+                              lambda s, j, pt: (s, 0, 0))
+    # K/V blocks are single physical pages, gathered by block
+    # *indexing* through the prefetched table — never an HBM
+    # materialization of the dense [S, cache_len, H, D] view.
+    page_block = pl.BlockSpec((1, page_size, width),
+                              lambda s, j, pt: (pt[s, j], 0, 0))
+    in_specs = [
+        slot_block, page_block, page_block,
+        pl.BlockSpec((1, 1, seq * page_size, 1),
+                     lambda s, j, pt: (s, j, 0, 0)),
+        whole((width, heads)), whole((heads, width)),
+    ]
+    if config.quantized:
+        scale_block = pl.BlockSpec((1, 1, heads),
+                                   lambda s, j, pt: (pt[s, j], 0, 0))
+        in_specs += [scale_block, scale_block]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_scalar,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, seq_pad, head_dim),
-                         _drop(lambda b, j, pt: (b, 0, 0))),
-            # K/V blocks are single physical pages, gathered by block
-            # *indexing* through the prefetched table — never an HBM
-            # materialization of the dense [S, cache_len, H, D] view.
-            pl.BlockSpec((1, page_size, 1, head_dim),
-                         _drop(lambda b, j, pt: (pt[b // heads, j], 0,
-                                                 b % heads, 0))),
-            pl.BlockSpec((1, page_size, 1, head_dim),
-                         _drop(lambda b, j, pt: (pt[b // heads, j], 0,
-                                                 b % heads, 0))),
-            # The singleton page axis keeps the mask block's last dim
-            # equal to the array dim (Mosaic's lane rule for P < 128).
-            pl.BlockSpec((1, seq_pad, 1, page_size),
-                         _drop(lambda b, j, pt: (b // heads, 0, j, 0))),
-        ],
-        out_specs=pl.BlockSpec((1, seq_pad, head_dim),
-                               _drop(lambda b, j, pt: (b, 0, 0))),
+        num_scalar_prefetch=1,
+        grid=(slots, pages_per_slot),
+        in_specs=in_specs,
+        out_specs=slot_block,
         scratch_shapes=[
-            pltpu.VMEM((seq_pad, head_dim), jnp.float32),
-            pltpu.VMEM((seq_pad, _LANES), jnp.float32),
-            pltpu.VMEM((seq_pad, _LANES), jnp.float32),
+            pltpu.VMEM((seq, width), jnp.float32),
+            pltpu.VMEM((seq, heads), jnp.float32),
+            pltpu.VMEM((seq, heads), jnp.float32),
         ],
     )
     out_dtype = q.dtype if config.quantized else value_pages.dtype
-    call = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, seq_pad, head_dim),
-                                       out_dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            q.shape, out_dtype, vma=partition.vma_of(*operands)),
         interpret=config.interpret,
-    )
-    if config.quantized:
-        return call(page_table, key_scales, value_scales, q,
-                    key_pages, value_pages, allowed)
-    return call(page_table, q, key_pages, value_pages, allowed)
+    )(*operands)
 
 
 def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
                     sm_scale, key_scales=None, value_scales=None):
     """The kernel's defining math as vectorized lax: walk the page
     blocks in grid order, gathering ONLY the slots' own pages (one
-    [slots, P, H, D] take per logical page — never the dense
+    [slots, P, H*D] take per logical page — never the dense
     [slots, cache_len] view), with the exact online-softmax update
     sequence `_paged_kernel` runs per step. This is the off-TPU
     execution of the kernel path: Mosaic can't compile there and
@@ -335,8 +318,8 @@ def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
     suite pins it against the true interpreted kernel
     (`interpret=True`) and the gathered reference. Int8 pages are
     dequantized per page block in f32 (the module dequant contract)."""
-    num_pages, page_size, heads, head_dim = key_pages.shape
-    slots, seq, q_heads, _ = q.shape
+    page_size = key_pages.shape[1]
+    slots, seq, heads, head_dim = q.shape
     pages_per_slot = page_table.shape[1]
     quantized = key_scales is not None
     am = allowed.reshape(slots, seq, pages_per_slot, page_size)
@@ -345,8 +328,9 @@ def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
     acc = jnp.zeros((slots, heads, seq, head_dim), jnp.float32)
     for j in range(pages_per_slot):
         pages = page_table[:, j]
-        k = key_pages[pages]                 # [slots, P, H, D]
-        v = value_pages[pages]
+        k = key_pages[pages].reshape(slots, page_size, heads,
+                                     head_dim)
+        v = value_pages[pages].reshape(k.shape)
         if quantized:
             k = k.astype(jnp.float32) * key_scales[pages][:, None, :,
                                                           None]
@@ -377,11 +361,10 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     """Pallas paged decode attention; layouts as the reference.
 
     Handles both the seq=1 plain tick and the seq=spec_k+1 speculative
-    verify window (query rows are sublane-padded; padded rows are
-    all-masked and sliced away). Output matches
+    verify window. Output matches
     `paged_attention_reference` to online-softmax accumulation order —
-    tolerance-level, not bitwise; fully-masked rows (evicted slots,
-    padded queries) output exact zeros. With scales given the pages
+    tolerance-level, not bitwise; fully-masked rows (evicted slots)
+    output exact zeros. With scales given the pages
     are int8 and the kernel dequantizes in its block loads (module
     docstring).
 
@@ -390,15 +373,15 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     interpret mode (the parity suite's same-code-path check — far too
     slow for a serving tick).
     """
-    num_pages, page_size, heads, head_dim = key_pages.shape
-    slots, seq, q_heads, _ = q.shape
+    page_size = key_pages.shape[1]
+    slots, seq, heads, head_dim = q.shape
     pages_per_slot = page_table.shape[1]
     cache_len = pages_per_slot * page_size
-    if q_heads != heads:
+    if key_pages.ndim != 3 or key_pages.shape[2] != heads * head_dim:
         raise ValueError(
-            "q heads ({}) must match page heads ({}) — the paged "
-            "decode cache stores full-width heads.".format(q_heads,
-                                                           heads))
+            "key_pages must be [num_pages, page_size, heads * head_dim"
+            " = {}] — the paged decode cache stores full-width heads; "
+            "got {}.".format(heads * head_dim, key_pages.shape))
     if value_pages.shape != key_pages.shape:
         raise ValueError(
             "key_pages and value_pages must have identical shapes; "
@@ -409,7 +392,8 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
             "{}.".format((slots, seq, cache_len), allowed.shape))
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
-    quantized = _check_scales(key_pages, key_scales, value_scales)
+    quantized = _check_scales(key_pages, key_scales, value_scales,
+                              heads)
     if interpret is None:
         if jax.default_backend() != "tpu":
             return _paged_walk_lax(q, key_pages, value_pages,
@@ -419,27 +403,45 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
                                    value_scales=value_scales)
         interpret = False
 
-    seq_pad = -(-seq // _SUBLANES) * _SUBLANES
-    config = _PagedConfig(sm_scale=float(sm_scale), heads=heads,
-                          seq_pad=seq_pad, page_size=page_size,
-                          interpret=bool(interpret),
-                          quantized=quantized)
+    args = [q, key_pages, value_pages, page_table.astype(jnp.int32),
+            allowed]
+    if quantized:
+        args += [key_scales, value_scales]
 
-    qf = jnp.transpose(q, (0, 2, 1, 3)).reshape(slots * heads, seq,
-                                                head_dim)
-    amask = allowed.astype(jnp.int32)
-    if seq_pad != seq:
-        qf = jnp.pad(qf, ((0, 0), (0, seq_pad - seq), (0, 0)))
-        # Padded query rows are fully masked -> zero output rows.
-        amask = jnp.pad(amask, ((0, 0), (0, seq_pad - seq), (0, 0)))
-    amask = amask.reshape(slots, seq_pad, pages_per_slot, page_size)
+    def kernel(q, key_pages, value_pages, page_table, allowed,
+               *scales):
+        """[slots, seq, H', D] over one device's H' heads."""
+        local_heads = q.shape[2]
+        config = _PagedConfig(sm_scale=float(sm_scale),
+                              heads=local_heads, seq=seq,
+                              page_size=page_size,
+                              interpret=bool(interpret),
+                              quantized=quantized)
+        # [slots, pages, seq * P, 1]: per page, each query row's P key
+        # flags as a sublane column.
+        amask = jnp.transpose(
+            allowed.astype(jnp.int32).reshape(
+                slots, seq, pages_per_slot, page_size),
+            (0, 2, 1, 3)).reshape(slots, pages_per_slot,
+                                  seq * page_size, 1)
+        out = _paged_forward(
+            config, q.reshape(slots, seq, local_heads * head_dim),
+            key_pages, value_pages, page_table, amask,
+            *(sc[:, None, :] for sc in scales))
+        return out.reshape(q.shape)
 
-    out = _paged_forward(config, qf, key_pages, value_pages,
-                         page_table.astype(jnp.int32), amask,
-                         key_scales=key_scales,
-                         value_scales=value_scales)
-    out = out[:, :seq].reshape(slots, heads, seq, head_dim)
-    return jnp.transpose(out, (0, 2, 1, 3))
+    def plan(mesh):
+        """Heads over the model axis; slots share the pool, so every
+        other axis sees the whole call."""
+        tp = partition.model_axis(mesh, heads)
+        by_head = P(None, None, tp, None)
+        pages = P(None, None, tp)
+        specs = [by_head, pages, pages, P(), P()]
+        if quantized:
+            specs += [P(None, tp)] * 2
+        return tuple(specs), by_head, None
+
+    return partition.per_shard(kernel, args, plan, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -494,7 +496,7 @@ def paged_attention_cost(slots, seq, heads, head_dim, page_size,
     """Per-call flops / bytes-moved row for the telemetry gauges.
 
     flops come from the jit cost-analysis hook (the PR 6 idiom —
-    `lower().cost_analysis()`, list-unwrapped, exception-swallowed) on
+    `lower().cost_analysis()`, exception-swallowed) on
     the gathered reference at these shapes; bytes_moved is the kernel's
     HBM traffic (q + out + the slot's own K/V pages + table + mask),
     i.e. what the fused path touches — NOT the dense gather the
@@ -514,17 +516,15 @@ def paged_attention_cost(slots, seq, heads, head_dim, page_size,
     try:
         shapes = (
             jax.ShapeDtypeStruct((slots, seq, heads, head_dim), dtype),
-            jax.ShapeDtypeStruct((num_pages, page_size, heads,
-                                  head_dim), dtype),
-            jax.ShapeDtypeStruct((num_pages, page_size, heads,
-                                  head_dim), dtype),
+            jax.ShapeDtypeStruct((num_pages, page_size,
+                                  heads * head_dim), dtype),
+            jax.ShapeDtypeStruct((num_pages, page_size,
+                                  heads * head_dim), dtype),
             jax.ShapeDtypeStruct((slots, pages_per_slot), jnp.int32),
             jax.ShapeDtypeStruct((slots, seq, cache_len), jnp.bool_),
         )
         analysis = jax.jit(paged_attention_reference).lower(
             *shapes).cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
         flops = float(analysis.get("flops", flops) or flops)
     except Exception:
         pass
@@ -534,6 +534,6 @@ def paged_attention_cost(slots, seq, heads, head_dim, page_size,
         + slots * pages_per_slot * 4                          # table
         + slots * seq * cache_len)                            # mask
     if quantized:
-        # Per-page per-head f32 K and V scales ride scalar prefetch.
+        # Per-page per-head f32 K and V scale rows.
         bytes_moved += float(2 * slots * pages_per_slot * heads * 4)
     return {"flops": flops, "bytes_moved": bytes_moved}

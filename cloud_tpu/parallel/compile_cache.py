@@ -4,11 +4,12 @@ The reference delegates compilation entirely to TF; a TPU-native stack
 pays trace + XLA compile on every cold start. This module makes that
 cost a managed resource in three pieces:
 
-1. `enable()` — framework-level persistent-compile-cache enablement
-   (previously hardcoded inside bench.py's worker). Entries are scoped
-   to a `jax-<ver>-jaxlib-<ver>` subdirectory, so upgrading either
-   package starts a fresh namespace instead of deserializing stale
-   executables — version invalidation by construction.
+1. `enable()` — turns the persistent compile cache on for the process.
+   WHERE it lives is decided outside the code: when
+   `JAX_COMPILATION_CACHE_DIR` is set, JAX already has its directory
+   and `enable()` sets none; otherwise it is `benchmarks/.jax_cache` in
+   the checkout (git-ignored). One fixed path either way — the path is
+   part of the cache key, so a directory that moves never hits.
 2. Cache hit/miss stats — a `jax.monitoring` listener feeds persistent
    cache hits into `runtime.compile_stats()["cache_hits"]` so tests and
    bench can assert "the second process compiled nothing" as a counted
@@ -16,153 +17,97 @@ cost a managed resource in three pieces:
 3. `serialize_executable` / `deserialize_executable` — thin wrappers
    over the JAX AOT serialization API for shipping a compiled step to
    another same-topology process (deploy-time warm start).
-
-Env contract:
-    CLOUD_TPU_COMPILE_CACHE   cache directory override. Beats the
-                              directory passed to `enable()`. The
-                              values "" / "0" / "off" / "none" /
-                              "false" disable the cache entirely.
 """
 
 import logging
 import os
+import pickle
 
 logger = logging.getLogger("cloud_tpu")
 
-ENV_VAR = "CLOUD_TPU_COMPILE_CACHE"
-_DISABLE_VALUES = ("", "0", "off", "none", "false", "disabled")
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), "benchmarks", ".jax_cache")
 
-_enabled_dir = None          # resolved, version-scoped directory
+_enabled = False             # also gates the hit/miss listener
 _listener_installed = False
-_counting = False            # listener no-ops unless enable() succeeded
 _event_stats = {"persistent_hits": 0, "persistent_misses": 0}
 
 
-def version_scope():
-    """The cache-invalidation namespace: jax + jaxlib versions."""
-    import jax
-    try:
-        import jaxlib
-        jaxlib_version = jaxlib.__version__
-    except Exception:  # pragma: no cover - jaxlib always ships with jax
-        jaxlib_version = "unknown"
-    return "jax-{}-jaxlib-{}".format(jax.__version__, jaxlib_version)
-
-
-def resolve_dir(cache_dir=None):
-    """Resolves the cache root: env override beats the argument.
-
-    Returns None when disabled (no directory anywhere, or an explicit
-    disable value in the env). The returned path includes the version
-    scope subdirectory.
-    """
-    env = os.environ.get(ENV_VAR)
-    if env is not None:
-        if env.strip().lower() in _DISABLE_VALUES:
-            return None
-        cache_dir = env
-    if not cache_dir:
-        return None
-    return os.path.join(os.path.expanduser(cache_dir), version_scope())
-
-
-def enable(cache_dir=None, min_compile_time_secs=0.0):
+def enable(min_compile_time_secs=0.0):
     """Turns on the persistent compilation cache for this process.
 
     Args:
-        cache_dir: Cache root. `CLOUD_TPU_COMPILE_CACHE` (when set)
-            overrides it; disable values there win over everything.
         min_compile_time_secs: Persist executables whose compile took at
-            least this long. The default 0.0 persists everything — on
-            the tunneled-CPU bench even sub-second compiles are worth
-            skipping, and the entry-size floor is lifted for the same
-            reason.
+            least this long. The default 0.0 persists everything, and
+            the entry-size floor is lifted with it, so a small (CPU,
+            test) executable still round-trips.
 
     Returns:
-        The resolved version-scoped directory, or None when disabled.
+        The cache directory in effect.
     """
-    global _enabled_dir, _counting
-    resolved = resolve_dir(cache_dir)
-    if resolved is None:
-        _counting = False
-        _enabled_dir = None
-        return None
-
+    global _enabled
     import jax
+    from jax._src import compilation_cache as jax_cc
 
-    os.makedirs(resolved, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", resolved)
+    if not os.environ.get(ENV_VAR):
+        os.makedirs(CHECKOUT_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_time_secs))
-    try:
-        # Without this, small (CPU/test) executables fall under the
-        # default size floor and never persist, which would make the
-        # hit-after-restart invariant silently untestable.
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # pragma: no cover - option absent on old jax
-        pass
-    try:
-        # jax memoizes the is-the-cache-used decision per process at
-        # the FIRST compile — enabling after anything has compiled
-        # would otherwise be a silent no-op (no writes, no events).
-        # Drop the memo so the new directory takes effect now.
-        from jax._src import compilation_cache as _jax_cc
-        _jax_cc.reset_cache()
-    except Exception:  # pragma: no cover - private API moved
-        logger.warning("could not reset jax's compilation-cache memo; "
-                       "cache may stay off if jit ran before enable().")
-    _enabled_dir = resolved
-    _counting = True
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax memoizes the is-the-cache-used decision per process at the
+    # FIRST compile — enabling after anything has compiled would
+    # otherwise be a silent no-op (no writes, no events).
+    jax_cc.reset_cache()
+    _enabled = True
     _install_listener()
-    logger.info("Persistent compile cache enabled at %s", resolved)
-    return resolved
+    logger.info("Persistent compile cache enabled at %s", cache_dir())
+    return cache_dir()
 
 
 def disable():
-    """Stops persisting and counting (test isolation)."""
-    global _enabled_dir, _counting
-    _counting = False
-    if _enabled_dir is None:
+    """Stops counting, and persisting where `enable()` chose the
+    directory (test isolation). A directory that came from
+    `JAX_COMPILATION_CACHE_DIR` is JAX's own and stays."""
+    global _enabled
+    if not _enabled:
         return
-    try:
+    _enabled = False
+    if not os.environ.get(ENV_VAR):
         import jax
+        from jax._src import compilation_cache as jax_cc
+
         jax.config.update("jax_compilation_cache_dir", None)
-        from jax._src import compilation_cache as _jax_cc
-        _jax_cc.reset_cache()
-    except Exception:  # pragma: no cover
-        pass
-    _enabled_dir = None
+        jax_cc.reset_cache()
 
 
 def is_enabled():
-    return _enabled_dir is not None
+    return _enabled
 
 
 def cache_dir():
-    """The active version-scoped cache directory, or None."""
-    return _enabled_dir
+    """The directory JAX persists to, or None before `enable()`."""
+    if not _enabled:
+        return None
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 def _install_listener():
     """Registers the (idempotent, irrevocable) jax.monitoring hook.
 
     jax has no unregister API, so the listener is installed once and
-    gated on `_counting`; `disable()` just flips the gate. The private
-    `jax._src.monitoring` import is deliberately failure-tolerant — on
-    a jax that moved it, cache_hits stays 0 instead of crashing.
+    gated on `_enabled`; `disable()` just flips the gate.
     """
     global _listener_installed
     if _listener_installed:
         return
-    try:
-        from jax._src import monitoring
-    except Exception:  # pragma: no cover - private API moved
-        logger.warning("jax monitoring API unavailable; persistent "
-                       "cache hit counting disabled.")
-        return
+    from jax import monitoring
 
     def _on_event(event, **kwargs):
-        if not _counting:
+        if not _enabled:
             return
         if event == "/jax/compilation_cache/cache_hits":
             _event_stats["persistent_hits"] += 1
@@ -200,26 +145,36 @@ def serialize_executable(compiled):
     """Serializes a `jax.stages.Compiled` to a portable triple.
 
     Returns `(payload_bytes, in_tree, out_tree)` — exactly what
-    `deserialize_executable` needs. Raises whatever the JAX AOT API
-    raises when the executable is not serializable on this backend.
+    `deserialize_executable` needs; the payload carries the ids of the
+    devices the executable was compiled for. Raises whatever the JAX
+    AOT API raises when the executable is not serializable on this
+    backend.
     """
     from jax.experimental import serialize_executable as se
-    return se.serialize(compiled)
+    payload, in_tree, out_tree = se.serialize(compiled)
+    device_ids = [d.id for d in
+                  compiled.runtime_executable().local_devices()]
+    return pickle.dumps((device_ids, payload)), in_tree, out_tree
 
 
 def deserialize_executable(triple):
     """Loads a `(payload, in_tree, out_tree)` triple back into a
-    callable Compiled. Only valid on a same-topology process with the
-    same jax/jaxlib versions (the same constraint the version-scoped
-    cache directory encodes)."""
+    callable Compiled on the devices (by id) it was compiled for. Only
+    valid on a same-topology process with the same jax/jaxlib."""
+    import jax
     from jax.experimental import serialize_executable as se
-    payload, in_tree, out_tree = triple
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+
+    blob, in_tree, out_tree = triple
+    # Only bytes this module wrote: `serialize_executable`'s payload.
+    device_ids, payload = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def save_executable(path, compiled):
     """Serializes `compiled` to `path` (pickle of the AOT triple)."""
-    import pickle
     triple = serialize_executable(compiled)
     with open(path, "wb") as f:
         pickle.dump(triple, f)
@@ -228,7 +183,6 @@ def save_executable(path, compiled):
 
 def load_executable(path):
     """Loads an executable previously written by `save_executable`."""
-    import pickle
     with open(path, "rb") as f:
         triple = pickle.load(f)
     return deserialize_executable(triple)

@@ -58,17 +58,9 @@ def pipeline(stage_fn, stage_params, microbatches, axis_name):
 
     # The scan carry must be typed device-varying over the pp axis from
     # tick 0 (stage outputs are varying), hence the pvary casts.
-    def _pvary(v):
-        try:
-            return jax.lax.pcast(v, (axis_name,), to="varying")
-        except AttributeError:
-            try:  # jax < 0.8
-                return jax.lax.pvary(v, (axis_name,))
-            except AttributeError:  # older jax: vma typing absent anyway
-                return v
-
-    carry0 = _pvary(jnp.zeros_like(microbatches[0]))
-    outputs0 = _pvary(jnp.zeros_like(microbatches))
+    vary = lambda v: jax.lax.pcast(v, (axis_name,), to="varying")
+    carry0 = vary(jnp.zeros_like(microbatches[0]))
+    outputs0 = vary(jnp.zeros_like(microbatches))
 
     @jax.checkpoint
     def tick(state, t):
@@ -122,10 +114,7 @@ def pipeline_apply(stage_fn, stacked_params, x, num_microbatches,
     Returns:
         [B, ...] output of the last stage.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from cloud_tpu.parallel import sharding as sharding_lib
 

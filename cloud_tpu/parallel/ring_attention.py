@@ -229,10 +229,7 @@ def sequence_parallel_attention(q, k, v, mesh=None, axis="sp", causal=True,
     ring (sp) with Megatron-style tensor parallelism (tp-sharded qkv
     heads stay resident; no cross-tp gather).
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from cloud_tpu.parallel import sharding as _sharding_resolve
 

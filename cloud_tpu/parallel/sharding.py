@@ -22,48 +22,27 @@ SEQUENCE_AXIS = "sp"
 
 
 def _active_context_mesh():
-    """The mesh of an enclosing `with Mesh(...)` block, if any.
+    """The mesh of an enclosing `with Mesh(...)` block, if any. jax
+    keeps it in a thread-local it does not export
+    (tests/unit/test_runtime.py pins the lookup)."""
+    from jax._src import mesh as mesh_lib
 
-    The legacy-but-idiomatic `with Mesh(devices, axes):` context sets a
-    thread-local physical mesh that `jax.sharding` doesn't expose
-    publicly. Two lookup paths, most-stable first: the internal module
-    (fast, no deprecation machinery), then the public-but-deprecated
-    `jax.interpreters.pxla` re-export — so a jax upgrade that moves the
-    internal doesn't silently disable `with Mesh(...)` resolution
-    (tests/unit/test_runtime.py pins this behavior)."""
-    m = None
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        import warnings
-        try:
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore", DeprecationWarning)
-                from jax.interpreters import pxla
-                m = pxla.thread_resources.env.physical_mesh
-        except (ImportError, AttributeError):
-            # Both paths gone: don't silently ignore the user's
-            # `with Mesh(...)` block — say why it can't be seen.
-            warnings.warn(
-                "cloud_tpu: this jax version does not expose the "
-                "active Mesh context (jax._src.mesh.thread_resources "
-                "or jax.interpreters.pxla); pass `mesh=` explicitly "
-                "or use runtime.initialize().",
-                RuntimeWarning, stacklevel=3)
-            return None
-    if m is not None and not m.empty:
-        return m
-    return None
+    m = mesh_lib.thread_resources.env.physical_mesh
+    return None if m.empty else m
+
+
+def ambient_mesh():
+    """Enclosing `with Mesh(...)` context > ambient runtime mesh, or
+    None when neither exists — most-local wins, like variable
+    scoping."""
+    mesh = _active_context_mesh()
+    return runtime.global_mesh() if mesh is None else mesh
 
 
 def _resolve_mesh(mesh=None):
-    """Explicit arg > enclosing `with Mesh(...)` context > ambient
-    runtime mesh — most-local wins, like variable scoping."""
+    """Explicit arg > `ambient_mesh()`; raises when there is none."""
     if mesh is None:
-        mesh = _active_context_mesh()
-    if mesh is None:
-        mesh = runtime.global_mesh()
+        mesh = ambient_mesh()
     if mesh is None:
         raise RuntimeError(
             "No mesh: pass `mesh=`, enter a `with Mesh(...)` block, or "

@@ -124,10 +124,7 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=True,
     knob: the sp all-to-all owns the head dim; combine tp with ring
     instead when heads must stay tp-sharded.)
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from cloud_tpu.parallel import sharding as _sharding
 

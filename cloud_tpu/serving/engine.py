@@ -863,8 +863,11 @@ class DecodeEngine:
 
         def seed(att, datt):
             out = dict(datt)
-            k = att["key_pages"][page_vec]   # [ppn, P, H, D]
-            v = att["value_pages"][page_vec]
+            # Pool rows are [H*D] wide; the dense cache is [.., H, D].
+            heads, head_dim = datt["cached_key"].shape[2:]
+            unfold = lambda a: a.reshape(*a.shape[:2], heads, head_dim)
+            k = unfold(att["key_pages"][page_vec])   # [ppn, P, H, D]
+            v = unfold(att["value_pages"][page_vec])
             if "key_scales" in att:
                 # Int8 pool -> dense compute-dtype cache: dequantize
                 # with the per-page per-head scales (never-written
@@ -943,10 +946,11 @@ class DecodeEngine:
             # Owned ids are unique and nonzero, so fresh chunks land
             # exactly; shared/overflow chunks collapse onto scratch,
             # whose content is never attended.
+            fold = lambda a: a.reshape(ppn, page, -1)  # [.., H*D] rows
             out["key_pages"] = att["key_pages"].at[scatter_vec].set(
-                chunks_k)
+                fold(chunks_k))
             out["value_pages"] = att["value_pages"].at[scatter_vec].set(
-                chunks_v)
+                fold(chunks_v))
             out["page_table"] = att["page_table"].at[slot].set(page_vec)
             out["slot_steps"] = att["slot_steps"].at[slot].set(
                 patt["token_count"][0])
@@ -1203,7 +1207,7 @@ class DecodeEngine:
     def snapshot_pages(self, page_ids):
         """Host numpy snapshot of `page_ids`' pool content (the demote
         D2H): a pytree mirroring the cache's attention subtrees, each
-        holding `[n, P, H, D]` K/V blocks (+ `[n, H]` scales in int8
+        holding `[n, P, H*D]` K/V blocks (+ `[n, H]` scales in int8
         mode) with n == len(page_ids), rows in logical page order.
         Tick thread only — reads the tick-donated cache."""
         n = len(page_ids)

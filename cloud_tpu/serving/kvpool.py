@@ -1,7 +1,7 @@
 """Paged KV-cache pool: host-side physical page accounting.
 
 The physical pages themselves live in HBM as flax cache variables of the
-paged decoder (`key_pages`/`value_pages` `[num_pages, page_size, H, D]`
+paged decoder (`key_pages`/`value_pages` `[num_pages, page_size, H*D]`
 per attention layer — models/transformer.py `_paged_decode_attention`).
 This module owns the other half of the design: WHICH physical pages each
 request holds. Reservation happens at admission (before any HBM is

@@ -12,9 +12,8 @@ transport (the library imports and unit-tests cleanly without them).
 def make_required_install_packages():
     return [
         "absl-py",
-        # Floor set by jax.shard_map + the jax_num_cpu_devices config
-        # (used by the driver dry-run's virtual-device fallback).
-        "jax>=0.6",
+        # jax.shard_map with varying-axes types, jax.lax.pcast.
+        "jax>=0.9",
         "flax",
         "optax",
         "numpy",

@@ -44,8 +44,12 @@ def _launch(process_id, port, num_processes=2, local_devices=None):
         env["CLOUD_TPU_TEST_LOCAL_DEVICES"] = str(local_devices)
     else:  # same leak-scrub as CLOUD_TPU_MESH below
         env.pop("CLOUD_TPU_TEST_LOCAL_DEVICES", None)
-    # The workers force the CPU backend themselves (config update);
-    # scrub mesh-layout leftovers so the pod defaults apply.
+    # Virtual CPU devices come from the launcher's environment; the
+    # worker sets its own device count, so the suite's XLA_FLAGS count
+    # must not ride along. Scrub mesh-layout leftovers so the pod
+    # defaults apply.
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
     env.pop("CLOUD_TPU_MESH", None)
     return subprocess.Popen(
         [sys.executable, WORKER], env=env, cwd=REPO_ROOT,
@@ -107,7 +111,7 @@ def _single_process_reference():
         runtime.reset()
 
     # Weighted (x, y, w) validation + weighted evaluate with a padded
-    # validation tail (90/32), the VERDICT r3 #4 parity surface.
+    # validation tail (90/32).
     runtime.reset()
     runtime.initialize(strategy="tpu_slice")
     try:
@@ -173,30 +177,10 @@ def _assert_pod_parity(outs, num_processes):
         assert rec["es_epochs"] >= 1
 
 
-def _gloo_transport_broken():
-    """jaxlib < 0.5 ships a gloo whose TCP pair aborts mid-collective
-    ("op.preamble.length <= op.nbytes") on the mixed-width psums these
-    workers issue; fixed upstream in later jaxlib bundles."""
-    import jaxlib
-    try:
-        parts = tuple(int(p) for p in jaxlib.__version__.split(".")[:3])
-    except ValueError:
-        return False
-    return parts < (0, 5, 0)
-
-
-_GLOO_XFAIL = pytest.mark.xfail(
-    _gloo_transport_broken(), reason="jaxlib<0.5 gloo TCP-pair abort "
-    "(op.preamble.length <= op.nbytes) on CPU cross-process collectives",
-    strict=False)
-
-
-@_GLOO_XFAIL
 def test_two_process_pod_matches_single_process():
     _assert_pod_parity(_run_pod(2), 2)
 
 
-@_GLOO_XFAIL
 def test_four_process_pod_matches_single_process():
     """The same parity surface over a 4-process grid (4 x 2 virtual
     devices = the same 8-device mesh): process_local_view quarters,

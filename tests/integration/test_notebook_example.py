@@ -18,6 +18,7 @@ import sys
 
 from cloud_tpu.core import preprocess
 from cloud_tpu.core.machine_config import COMMON_MACHINE_CONFIGS
+from cloud_tpu.parallel import compile_cache
 
 REPO_ROOT = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
@@ -31,17 +32,7 @@ LLM_NOTEBOOK = os.path.join(REPO_ROOT, "examples",
 def _collective_timeout_flags():
     """Raised collective-call timeouts: under full-suite parallel load
     the CPU all-reduce rendezvous threads can be starved past the 20s
-    default, SIGABRTing the subprocess (round-3 flake). The flags only
-    exist in newer XLA bundles — on older jaxlibs an unknown XLA flag
-    is itself a hard SIGABRT, so gate on the jaxlib version."""
-    import jaxlib
-    try:
-        major, minor, patch = (
-            int(p) for p in jaxlib.__version__.split(".")[:3])
-    except ValueError:
-        return ""
-    if (major, minor, patch) < (0, 5, 0):
-        return ""
+    default, SIGABRTing the subprocess (round-3 flake)."""
     return (
         " --xla_cpu_collective_call_warn_stuck_timeout_seconds=60"
         " --xla_cpu_collective_call_terminate_timeout_seconds=240"
@@ -62,8 +53,7 @@ def _mesh_env(**extra):
         # Persistent compile cache: repeated runs (CI retries, the 10x
         # flake loop) skip the multi-minute model compile, taking the
         # whole compile-starvation timeout class off the table.
-        JAX_COMPILATION_CACHE_DIR=os.path.join(
-            REPO_ROOT, "benchmarks", ".jax_cache"),
+        JAX_COMPILATION_CACHE_DIR=compile_cache.CHECKOUT_DIR,
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="2",
     )
     env.pop("CLOUD_TPU_EXAMPLE_LAUNCH", None)

@@ -208,8 +208,8 @@ class TestMetricFuture:
 
     def test_exception_propagates_to_result(self):
         f = MetricFuture()
-        f.set_exception(RuntimeError("tunnel died"))
-        with pytest.raises(RuntimeError, match="tunnel died"):
+        f.set_exception(RuntimeError("backend died"))
+        with pytest.raises(RuntimeError, match="backend died"):
             f.result()
 
     def test_timeout(self):

@@ -1,427 +1,143 @@
-"""Unit tests for bench.py's record-handling logic (no accelerator).
+"""bench.py's contract around the measurement (no accelerator).
 
-The measurement itself needs hardware; what's pinned here is the
-harness contract around it: stale fallbacks must fail safe for
-consumers that read `value` without checking provenance flags, a
-crashed worker must never be reported as parity-ok, and the tiered
-green cache (fully-green > annotated-harness-capture > hand seed)
-must keep annotations attached to anything it replays.
+The measurement itself needs the chip; what is pinned here is what
+surrounds it: the run happens in this one process, every record is
+stamped with the device JAX reports, a backend that is not a TPU is a
+non-zero exit and not a fallback, and utilization is a share of the
+peak published for that `device_kind` or nothing at all.
 """
 
 import importlib.util
-import json
 import os
+import subprocess
 import sys
-import types
 
 import pytest
 
-_BENCH_PATH = os.path.join(os.path.dirname(__file__), "..", "..",
-                           "bench.py")
+_BENCH_PATH = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "bench.py"))
 
 
 @pytest.fixture()
-def bench(tmp_path, monkeypatch):
-    """Fresh bench module per test (module state: _EMITTED, paths).
-
-    BENCH_IGNORE_PIN: the import-time best-pin application mutates
-    os.environ; a real benchmarks/best_pin.json on the dev box must
-    not leak BENCH_* values into the pytest process."""
-    monkeypatch.setenv("BENCH_IGNORE_PIN", "1")
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.abspath(_BENCH_PATH))
+def bench():
+    """Fresh bench module per test (import-time env expansion)."""
+    spec = importlib.util.spec_from_file_location("bench_under_test",
+                                                  _BENCH_PATH)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    mod.LAST_GREEN_PATH = str(tmp_path / "last_green.json")
     return mod
 
 
-def _emitted_record(capsys):
-    out = capsys.readouterr().out.strip().splitlines()
-    assert out, "no JSON line emitted"
-    return json.loads(out[-1])
+TPU = {"platform": "tpu", "device_kind": "TPU v5 lite",
+       "device_count": 1}
+CPU = {"platform": "cpu", "device_kind": "cpu", "device_count": 1}
 
 
-class TestStaleFallback:
-    def test_self_reported_green_zeroed(self, bench, capsys):
-        """A hand-reported cached green is served with value 0.0 and the
-        number moved to last_green_* keys (ADVICE r3: consumers reading
-        `value` must fail safe on non-harness numbers)."""
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump({"metric": bench.METRIC, "value": 2452.8,
-                       "unit": "images/sec", "vs_baseline": 7.0,
-                       "self_reported": True,
-                       "source": "hand measurement"}, f)
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert record["stale"] is True
-        assert record["value"] == 0.0
-        assert record["vs_baseline"] == 0.0
-        assert record["last_green_value"] == 2452.8
-        assert record["last_green_vs_baseline"] == 7.0
-        assert record["self_reported"] is True
+class TestDeviceGate:
+    def test_default_backend_not_a_tpu_exits_nonzero(self, bench,
+                                                     monkeypatch):
+        """No TPU and nobody asked for the CPU: refuse, with the
+        device in the message, before any worker starts."""
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(bench, "_device_stamp", lambda: dict(CPU))
+        monkeypatch.setattr(bench, "worker", lambda stamp: pytest.fail(
+            "worker ran without a TPU"))
+        with pytest.raises(SystemExit) as info:
+            bench.main()
+        assert info.value.code not in (0, None)
+        assert "'cpu'" in str(info.value.code)
 
-    def test_harness_green_served_at_face_value(self, bench, capsys):
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump({"metric": bench.METRIC, "value": 3000.0,
-                       "unit": "images/sec", "vs_baseline": 8.57,
-                       "platform": "tpu"}, f)
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert record["stale"] is True
-        assert record["value"] == 3000.0
-        assert "last_green_value" not in record
+    def test_explicit_cpu_run_is_stamped_cpu(self, bench, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        monkeypatch.setattr(bench, "_device_stamp", lambda: dict(CPU))
+        seen = []
+        monkeypatch.setattr(bench, "worker", seen.append)
+        bench.main()
+        assert seen == [CPU]
 
-    def test_no_cache_emits_error_record(self, bench, capsys):
-        bench._emit_fallback("tunnel down", extra={"probes": 3})
-        record = _emitted_record(capsys)
-        assert record["value"] == 0.0
-        assert record["error"] == "tunnel down"
-        assert record["probes"] == 3
-        # Even the error record says what it was asked to measure.
-        assert record["requested_config"]["batch"] == bench.BATCH
+    def test_tpu_runs_in_this_process(self, bench, monkeypatch):
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(bench, "_device_stamp", lambda: dict(TPU))
+        seen = []
+        monkeypatch.setattr(bench, "worker", seen.append)
+        bench.main()
+        assert seen == [TPU]
+
+    def test_stamp_is_what_jax_reports(self, bench):
+        import jax
+
+        assert bench._device_stamp() == {
+            "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices())}
+
+    def test_script_exits_nonzero_on_a_non_tpu_default_backend(self):
+        """The whole script, with the backend left to JAX: whatever it
+        picks on a machine without a chip, the exit code is not 0 and
+        no record is printed."""
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME")}
+        proc = subprocess.run([sys.executable, _BENCH_PATH],
+                              capture_output=True, text=True, env=env,
+                              timeout=300)
+        assert proc.returncode != 0
+        assert not any(line.startswith("{")
+                       for line in proc.stdout.splitlines())
+
+    def test_no_parent_child_machinery_left(self, bench):
+        """One process for each chip: nothing in bench.py may start
+        another process or re-serve an old record."""
+        src = open(_BENCH_PATH).read()
+        for gone in ("subprocess", "--worker", "last_green", "best_pin",
+                     "os._exit", "SIGTERM"):
+            assert gone not in src, gone
+
+
+class TestPeakTable:
+    def test_pct_peak_uses_the_device_kinds_published_peak(self, bench):
+        assert bench._pct_peak(53.6, TPU) == pytest.approx(27.2)
+
+    def test_unknown_device_kind_raises(self, bench):
+        with pytest.raises(ValueError, match="No published peak"):
+            bench._pct_peak(
+                53.6, dict(TPU, device_kind="TPU v99"))
+
+    def test_cpu_run_has_no_utilization(self, bench):
+        assert bench._pct_peak(0.02, CPU) is None
 
 
 class TestSelfDescribingConfig:
-    """Round-5 contract: every emission records the REQUESTED config;
-    a stale re-serve captured under a different config says so
-    (config_mismatch + captured_config) instead of silently serving a
-    number measured under other knobs (the bench_spe5.json ambiguity)."""
+    """Every record carries the configuration it was asked for."""
 
-    def _green(self, bench, **extra):
-        record = {"metric": bench.METRIC, "value": 2243.4,
-                  "unit": "images/sec", "vs_baseline": 6.41,
-                  "platform": "tpu", "kernel_parity": "ok",
-                  "batch": 256, "image": 224}
-        record.update(extra)
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump(record, f)
-
-    def test_stale_reserve_same_config_no_mismatch(self, bench,
-                                                   capsys):
-        self._green(bench)
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert record["stale"] is True
-        assert record["requested_config"]["steps_per_execution"] == 1
-        assert "config_mismatch" not in record
-
-    def test_stale_reserve_under_spe_request_flags_mismatch(
-            self, bench, capsys, monkeypatch):
-        """The exact round-4 failure: SPE=5 requested, cache holds the
-        SPE=1 flagship — the re-serve must flag the mismatch and show
-        what the cached number was actually measured with."""
-        self._green(bench)  # legacy record: no steps_per_execution key
-        monkeypatch.setenv("BENCH_SPE", "5")
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert record["stale"] is True
-        assert record["requested_config"]["steps_per_execution"] == 5
-        assert record["config_mismatch"] is True
-        assert record["captured_config"]["steps_per_execution"] == 1
-
-    def test_captured_config_prefers_recorded_over_reconstruction(
-            self, bench, capsys, monkeypatch):
-        self._green(bench, requested_config={
-            "batch": 256, "image": 224, "steps_per_execution": 5,
-            "bf16_input": False, "space_to_depth": False})
-        monkeypatch.setenv("BENCH_SPE", "5")
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert "config_mismatch" not in record
-
-    def test_pin_provenance_is_not_a_mismatch(self, bench, capsys):
-        """`pinned` records where values came from, not what was
-        measured — a green captured with explicit env must re-serve
-        clean when the same values later arrive via best_pin.json."""
-        self._green(bench, requested_config={
-            "batch": 256, "image": 224, "steps_per_execution": 1,
-            "bf16_input": False, "space_to_depth": False})
-        bench._PIN_APPLIED = ["BENCH_BATCH"]
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert record["requested_config"]["pinned"] == ["BENCH_BATCH"]
-        assert "config_mismatch" not in record
-
-    def test_worker_flash_pins_enter_requested_config(
-            self, bench, monkeypatch):
+    def test_flash_block_pins_enter_requested_config(self, bench,
+                                                     monkeypatch):
         monkeypatch.setenv("CLOUD_TPU_FLASH_BLOCK_Q", "512")
         cfg = bench._requested_config()
         assert cfg["cloud_tpu_flash_block_q"] == 512
 
-    def test_malformed_env_never_crashes_the_fallback(
-            self, bench, capsys, monkeypatch):
-        """_requested_config runs inside the never-empty fallback path
-        (including the SIGTERM handler): a garbage env value must
-        degrade to the default, not raise."""
+    def test_malformed_env_degrades_to_defaults(self, bench,
+                                                monkeypatch):
         monkeypatch.setenv("BENCH_SPE", "garbage")
         monkeypatch.setenv("CLOUD_TPU_FLASH_BLOCK_Q", "auto")
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert record["requested_config"]["steps_per_execution"] == 1
-        assert record["requested_config"]["cloud_tpu_flash_block_q"] == 0
+        cfg = bench._requested_config()
+        assert cfg["steps_per_execution"] == 1
+        assert cfg["cloud_tpu_flash_block_q"] == 0
+        assert cfg["batch"] == bench.BATCH
 
-
-def test_worker_inherits_pin_provenance(monkeypatch):
-    """The worker subprocess sees pin-applied keys as explicitly-set
-    env; BENCH_PIN_APPLIED (exported by the parent's pin loop) must
-    carry the provenance across so worker-captured records still list
-    `pinned` honestly. Only worker mode (--worker in argv) may trust
-    the inherited marker — simulate it."""
-    monkeypatch.setenv("BENCH_IGNORE_PIN", "1")
-    monkeypatch.setenv("BENCH_PIN_APPLIED", "BENCH_SPE,BENCH_BATCH")
-    monkeypatch.setattr(sys, "argv", [sys.argv[0], "--worker"])
-    spec = importlib.util.spec_from_file_location(
-        "bench_pin_inherit", os.path.abspath(_BENCH_PATH))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod._requested_config()["pinned"] == [
-        "BENCH_SPE", "BENCH_BATCH"]
-
-
-def test_parent_clears_inherited_pin_provenance(monkeypatch):
-    """BENCH_PIN_APPLIED is a parent->worker handoff, not user
-    configuration: a PARENT invocation that inherits a stale marker
-    from an outer shell or driver must clear it at startup instead of
-    mislabeling explicitly-set knobs as pinned."""
-    monkeypatch.setenv("BENCH_IGNORE_PIN", "1")
-    monkeypatch.setenv("BENCH_PIN_APPLIED", "BENCH_SPE,BENCH_BATCH")
-    monkeypatch.setattr(sys, "argv", [sys.argv[0]])
-    spec = importlib.util.spec_from_file_location(
-        "bench_pin_parent", os.path.abspath(_BENCH_PATH))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert "pinned" not in mod._requested_config()
-    assert "BENCH_PIN_APPLIED" not in os.environ
-
-
-class TestCrashedWorker:
-    def test_rc_nonzero_overwrites_kernel_parity(self, bench,
-                                                 monkeypatch):
-        """A worker that prints kernel_parity='ok' then dies non-zero
-        must not be reported (or green-cached) as parity-ok."""
-        record_line = json.dumps({
-            "metric": bench.METRIC, "value": 2000.0, "platform": "tpu",
-            "kernel_parity": "ok"})
-
-        def fake_run(args, timeout):
-            return types.SimpleNamespace(
-                args=args, returncode=134, stdout=record_line + "\n",
-                stderr="Fatal Python error: Aborted\n")
-
-        monkeypatch.setattr(bench, "_bounded_run", fake_run)
-        record, err = bench._run_worker(timeout=5)
-        assert err is None
-        assert record["kernel_parity"].startswith("crashed rc=134")
-        assert record["worker_rc"] == 134
-
-    def test_timeout_marks_salvaged_record(self, bench, monkeypatch):
-        """A record salvaged from a timed-out (killed) worker keeps its
-        measurement and parity string but carries worker_rc, which
-        demotes it to the annotated cache tier — it can replace the
-        hand seed but never shadow a fully-green capture."""
-        import subprocess
-
-        record_line = json.dumps({
-            "metric": bench.METRIC, "value": 2000.0, "platform": "tpu",
-            "kernel_parity": "ok"})
-
-        def fake_run(args, timeout):
-            raise subprocess.TimeoutExpired(
-                args, timeout, output=record_line + "\n", stderr="")
-
-        monkeypatch.setattr(bench, "_bounded_run", fake_run)
-        record, err = bench._run_worker(timeout=5)
-        assert err is None
-        assert record["kernel_parity"] == "ok"  # the smoke did pass
-        assert record["worker_rc"].startswith("killed after")
-
-    def test_rc_zero_keeps_worker_parity(self, bench, monkeypatch):
-        record_line = json.dumps({
-            "metric": bench.METRIC, "value": 2000.0, "platform": "tpu",
-            "kernel_parity": "ok"})
-
-        def fake_run(args, timeout):
-            return types.SimpleNamespace(
-                args=args, returncode=0, stdout=record_line + "\n",
-                stderr="")
-
-        monkeypatch.setattr(bench, "_bounded_run", fake_run)
-        record, err = bench._run_worker(timeout=5)
-        assert err is None
-        assert record["kernel_parity"] == "ok"
-        assert "worker_rc" not in record
-
-
-class TestTieredCache:
-    """_maybe_cache/_cache_rank: fully-green (2) > annotated harness
-    capture (1) > self-reported hand seed (0); new record wins ties."""
-
-    def _cached(self, bench):
-        with open(bench.LAST_GREEN_PATH) as f:
-            return json.load(f)
-
-    def test_annotated_capture_replaces_hand_seed(self, bench):
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump({"metric": bench.METRIC, "value": 2452.8,
-                       "self_reported": True}, f)
-        record = {"metric": bench.METRIC, "value": 2272.2,
-                  "platform": "tpu",
-                  "kernel_parity": "timeout past 480s",
-                  "worker_rc": "killed after 480s timeout"}
-        assert bench._maybe_cache(record) is True
-        assert self._cached(bench)["value"] == 2272.2
-        # Annotations travel into the cache (and any stale emission).
-        assert "worker_rc" in self._cached(bench)
-
-    def test_annotated_capture_never_shadows_fully_green(self, bench):
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump({"metric": bench.METRIC, "value": 2400.0,
-                       "platform": "tpu", "kernel_parity": "ok"}, f)
-        record = {"metric": bench.METRIC, "value": 2500.0,
-                  "platform": "tpu", "kernel_parity": "error: Mosaic"}
-        assert bench._maybe_cache(record) is False
-        assert self._cached(bench)["value"] == 2400.0
-
-    def test_fully_green_replaces_everything(self, bench):
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump({"metric": bench.METRIC, "value": 2500.0,
-                       "platform": "tpu",
-                       "kernel_parity": "error: Mosaic"}, f)
-        record = {"metric": bench.METRIC, "value": 2300.0,
-                  "platform": "tpu", "kernel_parity": "ok"}
-        assert bench._maybe_cache(record) is True
-        assert self._cached(bench)["value"] == 2300.0
-        assert self._cached(bench)["kernel_parity"] == "ok"
-
-    def test_variant_series_gets_its_own_slot(self, bench):
-        """Each metric series (base, _s2d, _bf16in) caches into its own
-        slot: a variant capture lands beside -- never over -- the base
-        series' record, so every series keeps its fallback."""
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump({"metric": bench.METRIC, "value": 2400.0,
-                       "platform": "tpu", "kernel_parity": "ok"}, f)
-        record = {"metric": bench.METRIC + "_s2d", "value": 2600.0,
-                  "platform": "tpu", "kernel_parity": "ok",
-                  "worker_rc": "killed after 480s timeout"}
-        assert bench._maybe_cache(record) is True
-        assert self._cached(bench)["metric"] == bench.METRIC  # untouched
-        s2d = bench._read_slot(
-            bench._series_path(bench.METRIC + "_s2d"))
-        assert s2d["value"] == 2600.0
-
-    def test_corrupt_slot_never_kills_the_harness(self, bench):
-        """Valid-JSON-but-not-an-object slot contents (truncated write)
-        must read as empty, not crash _maybe_cache after a successful
-        measurement."""
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            f.write("[]")
-        record = {"metric": bench.METRIC, "value": 2300.0,
-                  "platform": "tpu", "kernel_parity": "ok"}
-        assert bench._maybe_cache(record) is True
-        assert self._cached(bench)["value"] == 2300.0
-
-    def test_cpu_or_empty_records_never_cache(self, bench, tmp_path):
-        assert bench._maybe_cache(
-            {"metric": bench.METRIC, "value": 999.0,
-             "platform": "cpu", "kernel_parity": "ok"}) is False
-        assert bench._maybe_cache(
-            {"metric": bench.METRIC, "value": 0.0,
-             "platform": "tpu", "kernel_parity": "ok"}) is False
-        assert not os.path.exists(bench.LAST_GREEN_PATH)
-
-    def test_stale_emission_of_annotated_capture_keeps_value(
-            self, bench, capsys):
-        """An annotated harness capture is NOT self_reported: its value
-        was measured by this code, so a stale replay serves it at face
-        value with the annotations (and stale flag) attached."""
-        with open(bench.LAST_GREEN_PATH, "w") as f:
-            json.dump({"metric": bench.METRIC, "value": 2272.2,
-                       "unit": "images/sec", "vs_baseline": 6.49,
-                       "platform": "tpu",
-                       "kernel_parity": "timeout past 480s",
-                       "worker_rc": "killed after 480s timeout"}, f)
-        bench._emit_fallback("tunnel down")
-        record = _emitted_record(capsys)
-        assert record["stale"] is True
-        assert record["value"] == 2272.2
-        assert record["worker_rc"].startswith("killed")
-
-
-class TestBestPin:
-    def test_pin_file_supplies_defaults_env_wins(self, tmp_path,
-                                                 monkeypatch):
-        """benchmarks/best_pin.json supplies fair-game defaults
-        (batch/spe/bf16-input) at import; explicit env still wins and
-        BENCH_S2D is never pinned (it changes the model)."""
-        import importlib.util
-        import json as json_lib
-
-        pin_path = tmp_path / "best_pin.json"
-        pin_path.write_text(json_lib.dumps(
-            {"BENCH_BATCH": 512, "BENCH_SPE": 5,
-             "BENCH_BF16_INPUT": 1, "BENCH_S2D": 1,
-             "source": "test"}))
-        monkeypatch.setenv("BENCH_SPE", "2")  # explicit env wins
-        monkeypatch.delenv("BENCH_BATCH", raising=False)
+    def test_named_config_expands_and_is_recorded(self, monkeypatch):
+        monkeypatch.setenv("BENCH_CONFIG", "bf16_s2d")
         monkeypatch.delenv("BENCH_BF16_INPUT", raising=False)
         monkeypatch.delenv("BENCH_S2D", raising=False)
-
         spec = importlib.util.spec_from_file_location(
-            "bench_pin_test", os.path.abspath(_BENCH_PATH))
+            "bench_named", _BENCH_PATH)
         mod = importlib.util.module_from_spec(spec)
-        monkeypatch.setattr("os.path.join",
-                            _join_redirect(str(pin_path)))
         try:
             spec.loader.exec_module(mod)
-            assert mod.BATCH == 512                      # pinned default
-            assert os.environ["BENCH_SPE"] == "2"        # env won
-            assert os.environ["BENCH_BF16_INPUT"] == "1"  # pinned
-            # S2D is not a pinnable key even when present in the file.
-            assert "BENCH_S2D" not in os.environ
+            cfg = mod._requested_config()
+            assert cfg["bf16_input"] and cfg["space_to_depth"]
+            assert cfg["named_config"] == "bf16_s2d"
+            assert mod._metric_name().endswith("_s2d_bf16in")
         finally:
-            # The import-time pin application mutates os.environ
-            # outside monkeypatch's bookkeeping — scrub what it set so
-            # nothing leaks into later tests.
-            for key in ("BENCH_BATCH", "BENCH_BF16_INPUT"):
-                os.environ.pop(key, None)
-
-
-def test_malformed_pin_key_keeps_applied_provenance(tmp_path,
-                                                    monkeypatch):
-    """A malformed later pin key aborts the pin loop, but keys already
-    applied to os.environ must still carry BENCH_PIN_APPLIED into the
-    worker — provenance is exported per-iteration, not after the loop."""
-    import importlib.util
-    import json as json_lib
-
-    pin_path = tmp_path / "best_pin.json"
-    pin_path.write_text(json_lib.dumps(
-        {"BENCH_BATCH": 512, "BENCH_SPE": None}))
-    for key in ("BENCH_BATCH", "BENCH_SPE", "BENCH_PIN_APPLIED"):
-        monkeypatch.delenv(key, raising=False)
-    monkeypatch.delenv("BENCH_IGNORE_PIN", raising=False)
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_pin_malformed", os.path.abspath(_BENCH_PATH))
-    mod = importlib.util.module_from_spec(spec)
-    monkeypatch.setattr("os.path.join", _join_redirect(str(pin_path)))
-    try:
-        spec.loader.exec_module(mod)
-        assert mod.BATCH == 512
-        assert os.environ["BENCH_PIN_APPLIED"] == "BENCH_BATCH"
-        assert mod._requested_config()["pinned"] == ["BENCH_BATCH"]
-    finally:
-        for key in ("BENCH_BATCH", "BENCH_PIN_APPLIED"):
-            os.environ.pop(key, None)
-
-
-def _join_redirect(pin_path):
-    """os.path.join that redirects only the best_pin.json lookup."""
-    real_join = os.path.join
-
-    def join(*parts):
-        if parts and parts[-1] == "best_pin.json":
-            return pin_path
-        return real_join(*parts)
-    return join
+            # The import-time expansion writes os.environ directly.
+            os.environ.pop("BENCH_BF16_INPUT", None)
+            os.environ.pop("BENCH_S2D", None)

@@ -309,8 +309,7 @@ def make_toy_batches(seed=0, steps=4, batch=32):
 
 
 class TestDatasetTransport:
-    """Round-2 gap: only in-memory numpy arrays crossed the wire
-    (VERDICT missing #2). Datasets now ship as references — a dotted
+    """Datasets ship as references — a dotted
     factory path + kwargs, or an npz shard manifest — with NO data
     bytes in the serialized assets (reference ships live tf.data
     datasets, client.py:151-189)."""

@@ -270,32 +270,69 @@ class TestDecodeBucketing:
 
 
 class TestPersistentCache:
+    """Where the cache lives is decided outside the code: JAX's own
+    `JAX_COMPILATION_CACHE_DIR` when set, else one fixed git-ignored
+    path in the checkout. The path is part of the cache key, so there
+    is no version, pid or time component in it."""
 
-    def test_env_override_and_version_scope(self, tmp_path,
-                                            monkeypatch):
+    @pytest.fixture
+    def config_updates(self, monkeypatch):
+        """Every `jax.config.update` key `compile_cache` issues."""
+        keys = []
+        real = jax.config.update
+
+        def spy(key, value):
+            keys.append(key)
+            return real(key, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        return keys
+
+    def test_variable_set_leaves_the_directory_to_jax(
+            self, tmp_path, monkeypatch, config_updates):
+        # What JAX itself does at import when the variable is set.
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        del config_updates[:]
+        try:
+            assert compile_cache.enable() == str(tmp_path)
+            assert compile_cache.is_enabled()
+            compile_cache.disable()
+            assert "jax_compilation_cache_dir" not in config_updates
+            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+            # It still lifts the floors that keep small executables out.
+            assert ("jax_persistent_cache_min_entry_size_bytes"
+                    in config_updates)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", None)
+
+    def test_variable_unset_uses_the_fixed_checkout_path(
+            self, monkeypatch):
         monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
-        scoped = compile_cache.resolve_dir(str(tmp_path))
-        assert scoped == os.path.join(str(tmp_path),
-                                      compile_cache.version_scope())
-        assert "jax-{}".format(jax.__version__) in scoped
-
-        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "env"))
-        assert compile_cache.resolve_dir("/ignored").startswith(
-            str(tmp_path / "env"))
-        for off in ("", "0", "off", "none"):
-            monkeypatch.setenv(compile_cache.ENV_VAR, off)
-            assert compile_cache.resolve_dir(str(tmp_path)) is None
-            assert compile_cache.enable(str(tmp_path)) is None
-            assert not compile_cache.is_enabled()
+        repo = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        assert compile_cache.CHECKOUT_DIR == os.path.join(
+            repo, "benchmarks", ".jax_cache")
+        assert compile_cache.ENV_VAR == "JAX_COMPILATION_CACHE_DIR"
+        try:
+            assert compile_cache.enable() == compile_cache.CHECKOUT_DIR
+            assert (jax.config.jax_compilation_cache_dir
+                    == compile_cache.CHECKOUT_DIR)
+            assert compile_cache.cache_dir() == compile_cache.CHECKOUT_DIR
+        finally:
+            compile_cache.disable()
+        assert jax.config.jax_compilation_cache_dir is None
+        assert compile_cache.cache_dir() is None
 
     def test_hit_after_restart_round_trip(self, tmp_path, monkeypatch):
         """enable() -> compile (miss, persisted) -> clear_caches (the
         in-process stand-in for a restart) -> recompile reads the disk
         entry and the hit lands in BOTH stats surfaces."""
         monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
-        resolved = compile_cache.enable(str(tmp_path))
-        assert resolved is not None and os.path.isdir(resolved)
-        assert compile_cache.cache_dir() == resolved
+        monkeypatch.setattr(compile_cache, "CHECKOUT_DIR",
+                            str(tmp_path))
+        resolved = compile_cache.enable()
+        assert resolved == str(tmp_path)
         try:
             f = runtime.instrumented_jit(lambda a: a * 3 + 1)
             f(jnp.arange(8, dtype=jnp.float32))
@@ -315,21 +352,62 @@ class TestPersistentCache:
             compile_cache.disable()
             assert not compile_cache.is_enabled()
 
+    def test_second_process_hits(self, tmp_path):
+        """Two real processes sharing the directory through JAX's
+        variable: the first compiles and persists, the second hits —
+        and neither had a directory set by code."""
+        import json
+        import subprocess
+        import sys
+
+        script = (
+            "import json, jax, jax.numpy as jnp\n"
+            "from cloud_tpu.parallel import compile_cache\n"
+            "before = jax.config.jax_compilation_cache_dir\n"
+            "compile_cache.enable()\n"
+            "jax.jit(lambda a: a * 5 + 2)(jnp.arange(16.0))"
+            ".block_until_ready()\n"
+            "print(json.dumps(dict(compile_cache.stats(), before=before,"
+            " after=jax.config.jax_compilation_cache_dir)))\n")
+        repo = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+
+        def run():
+            proc = subprocess.run([sys.executable, "-c", script],
+                                  capture_output=True, text=True,
+                                  env=env, timeout=300)
+            assert proc.returncode == 0, proc.stderr[-2000:]
+            return json.loads(proc.stdout.strip().splitlines()[-1])
+
+        first, second = run(), run()
+        for stats in (first, second):
+            assert stats["before"] == stats["after"] == str(tmp_path)
+        assert first["persistent_misses"] >= 1
+        assert first["persistent_hits"] == 0
+        assert second["persistent_hits"] >= 1
+
     def test_serialize_round_trip_where_backend_allows(self):
+        """The executable reloads on the devices it was compiled for
+        (by id), not on every device of the backend."""
         f = runtime.instrumented_jit(lambda a: a + 2)
         compiled = f.lower(
             jax.ShapeDtypeStruct((4,), jnp.float32)).compile()
         triple = compile_cache.serialize_executable(compiled)
         assert len(triple) == 3 and isinstance(triple[0], bytes)
-        try:
-            loaded = compile_cache.deserialize_executable(triple)
-        except Exception:
-            # The CPU backend in jaxlib 0.4.36 cannot re-load its own
-            # serialized executables ("Symbols not found") — the API
-            # contract here is "where the JAX AOT API allows", so the
-            # wrapper must raise cleanly, not segfault or corrupt.
-            return
+        loaded = compile_cache.deserialize_executable(triple)
         out = loaded(jnp.zeros((4,), jnp.float32))
+        np.testing.assert_allclose(np.asarray(out), 2.0)
+
+    def test_save_and_load_executable(self, tmp_path):
+        f = runtime.instrumented_jit(lambda a: a * 2)
+        compiled = f.lower(
+            jax.ShapeDtypeStruct((4,), jnp.float32)).compile()
+        path = compile_cache.save_executable(
+            str(tmp_path / "step.bin"), compiled)
+        out = compile_cache.load_executable(path)(
+            jnp.ones((4,), jnp.float32))
         np.testing.assert_allclose(np.asarray(out), 2.0)
 
 

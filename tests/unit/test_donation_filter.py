@@ -1,6 +1,6 @@
 """The decode-path donation-warning suppression must survive jax
 rewording the message around its core phrase (decoding._arm_donation_filter
-matches a `re.escape`d fragment, not jax 0.4.37's exact text)."""
+matches a `re.escape`d fragment, not the whole sentence)."""
 
 import warnings
 

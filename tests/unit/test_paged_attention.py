@@ -38,9 +38,11 @@ def _scenario(slots=3, pages_per_slot=4, page_size=16, heads=2,
     rng = np.random.default_rng(seed)
     num_pages = slots * pages_per_slot + 1
     cache_len = pages_per_slot * page_size
+    # Pool rows are [H*D] wide: one token's K (or V) for every head.
     shape = (num_pages, page_size, heads, head_dim)
-    key_pages = jnp.asarray(rng.normal(size=shape), dtype)
-    value_pages = jnp.asarray(rng.normal(size=shape), dtype)
+    fold = lambda a: a.reshape(num_pages, page_size, heads * head_dim)
+    key_pages = jnp.asarray(fold(rng.normal(size=shape)), dtype)
+    value_pages = jnp.asarray(fold(rng.normal(size=shape)), dtype)
     q = jnp.asarray(rng.normal(size=(slots, seq, heads, head_dim)),
                     dtype)
     page_table = jnp.asarray(
@@ -218,31 +220,39 @@ def test_shape_validation():
 # -- int8 quantized pages (graftpack, ISSUE 17) -----------------------
 
 
-def _quantize_pages(pages):
+def _quantize_pages(pages, heads=2):
     """Per-page per-head symmetric int8 quantization — the same
     contract the engine's page-write paths use: scale = amax / 127 over
     the page's (positions, head_dim) block, dequant = int8 * scale. An
     all-zero (never-written) page gets scale 0 so it dequantizes to
     exact zeros."""
-    arr = np.asarray(pages, np.float32)
+    folded = np.asarray(pages, np.float32)
+    arr = folded.reshape(folded.shape[:2] + (heads, -1))
     amax = np.max(np.abs(arr), axis=(1, 3))          # [num_pages, H]
     scale = (amax / 127.0).astype(np.float32)
     safe = np.where(scale > 0, scale, 1.0)
     q = np.clip(np.rint(arr / safe[:, None, :, None]), -127, 127)
-    return jnp.asarray(q, jnp.int8), jnp.asarray(scale)
+    return (jnp.asarray(q.reshape(folded.shape), jnp.int8),
+            jnp.asarray(scale))
+
+
+def _dequantize_pages(pages, scales):
+    heads = scales.shape[1]
+    arr = np.asarray(pages, np.float32)
+    unfolded = arr.reshape(arr.shape[:2] + (heads, -1))
+    return jnp.asarray((unfolded * np.asarray(scales)[:, None, :, None]
+                        ).reshape(arr.shape))
 
 
 def _int8_scenario(**kwargs):
     """A `_scenario` whose K/V pages are quantized to int8 + scales,
     plus the dequantized f32 pages every impl's output must match."""
     q, kp, vp, pt, allowed = _scenario(**kwargs)
-    kq, ks = _quantize_pages(kp)
-    vq, vs = _quantize_pages(vp)
-    kp_deq = jnp.asarray(np.asarray(kq, np.float32)
-                         * np.asarray(ks)[:, None, :, None])
-    vp_deq = jnp.asarray(np.asarray(vq, np.float32)
-                         * np.asarray(vs)[:, None, :, None])
-    return q, (kq, ks, kp_deq), (vq, vs, vp_deq), pt, allowed
+    heads = q.shape[2]
+    kq, ks = _quantize_pages(kp, heads)
+    vq, vs = _quantize_pages(vp, heads)
+    return (q, (kq, ks, _dequantize_pages(kq, ks)),
+            (vq, vs, _dequantize_pages(vq, vs)), pt, allowed)
 
 
 def _all_impls_int8(q, k3, v3, pt, allowed):

@@ -23,49 +23,6 @@ class TestTrace:
         with profiler.annotate("my_span"):
             jnp.ones((8,)).block_until_ready()
 
-    def test_trace_survives_missing_profile_options(self, tmp_path,
-                                                    monkeypatch):
-        """Regression: jax versions without `jax.profiler.ProfileOptions`
-        (or without start_trace's `profiler_options` kwarg) must fall
-        back to a plain start_trace — trace() raised AttributeError
-        here before the feature gate."""
-        import jax
-
-        monkeypatch.delattr(jax.profiler, "ProfileOptions",
-                            raising=False)
-        assert profiler._profile_options(2, 1) is None
-        log_dir = str(tmp_path / "prof_noopts")
-        with profiler.trace(log_dir):
-            x = jnp.ones((16, 16))
-            (x @ x).block_until_ready()
-        found = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
-                          recursive=True)
-        assert found, "fallback start_trace produced no trace"
-
-    def test_start_trace_falls_back_on_unknown_kwarg(self, tmp_path,
-                                                     monkeypatch):
-        """The half-feature case: ProfileOptions exists but start_trace
-        does not take profiler_options (or vice versa across jax
-        versions) — the TypeError path must land a plain start_trace."""
-        import jax
-
-        calls = []
-        original = jax.profiler.start_trace
-
-        def strict_start_trace(log_dir, **kwargs):
-            if kwargs:
-                raise TypeError("unexpected keyword argument "
-                                "'profiler_options'")
-            calls.append(log_dir)
-            return original(log_dir)
-
-        monkeypatch.setattr(jax.profiler, "start_trace",
-                            strict_start_trace)
-        log_dir = str(tmp_path / "prof_kwarg")
-        with profiler.trace(log_dir):
-            jnp.ones((8,)).block_until_ready()
-        assert calls == [log_dir]
-
     def test_device_memory_profile_bytes(self, tmp_path):
         path = str(tmp_path / "mem.pprof")
         data = profiler.device_memory_profile(path)

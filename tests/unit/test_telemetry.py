@@ -425,7 +425,7 @@ class TestLifecycle:
         assert not spans.enabled()
 
     def test_record_epoch_feeds_counters_and_mfu(self, tmp_path):
-        tele = telemetry.enable(str(tmp_path))
+        tele = telemetry.Telemetry(str(tmp_path), peak_tflops=197.0)
         tele.set_step_flops(1e12)
         tele.record_epoch(steps=10, examples=320, elapsed_secs=2.0)
         tele.flush(wait=True)
@@ -435,9 +435,25 @@ class TestLifecycle:
             "cloud_tpu_training_examples_total"] == 320
         assert snap["gauges"]["cloud_tpu_steps_per_sec"] == 5.0
         # 10 steps x 1e12 flops / 2 s = 5e12 flops/s over the peak.
-        expected = 100.0 * 5e12 / tele.peak_flops
         assert snap["gauges"]["cloud_tpu_mfu_pct_peak"] == pytest.approx(
-            expected)
+            100.0 * 5e12 / 197e12)
+
+    def test_no_utilization_gauge_on_the_cpu(self, tmp_path):
+        """A CPU run has no chip peak to be a share of: the session
+        looks the device up, finds the CPU, and writes no MFU."""
+        tele = telemetry.Telemetry(str(tmp_path))
+        assert tele.peak_flops is None
+        tele.set_step_flops(1e12)
+        tele.record_epoch(steps=10, examples=320, elapsed_secs=2.0)
+        tele.record_kernel_cost("paged_attention", 1e9, 1e6, 0.01)
+        gauges = tele.registry.snapshot()["gauges"]
+        assert "cloud_tpu_mfu_pct_peak" not in gauges
+        assert not any("pct_peak" in name for name in gauges)
+
+    def test_peak_table_is_keyed_by_device_kind(self):
+        assert telemetry.peak_tflops("TPU v5 lite") == 197.0
+        with pytest.raises(ValueError, match="No published peak"):
+            telemetry.peak_tflops("TPU v99")
 
     def test_observe_decode_weights_by_token(self, tmp_path):
         tele = telemetry.enable(str(tmp_path))
@@ -550,8 +566,8 @@ class TestFitEndToEnd:
             assert key in values
         assert values["cloud_tpu_step_latency_seconds_p99"] > 0
         assert values["cloud_tpu_step_latency_seconds_count"] == 16
-        # MFU gauge present and fed by jit cost analysis on CPU.
-        assert values["cloud_tpu_mfu_pct_peak"] > 0
+        # No MFU on the CPU: there is no chip peak to be a share of.
+        assert "cloud_tpu_mfu_pct_peak" not in values
         # The transfer/compile counter adapters mirrored the runtime
         # census.
         assert values["cloud_tpu_h2d_transfers_total"] > 0

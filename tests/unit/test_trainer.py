@@ -321,8 +321,8 @@ class TestReviewRegressions:
     def test_predict_pytree_outputs(self):
         """A tuple/dict-returning model (e.g. MoE's (out, aux)) must
         round-trip through predict() with its structure intact and
-        every leaf concatenated/truncated per batch dim (VERDICT r3
-        weak #5: np.asarray over a tuple crashed or mis-stacked)."""
+        every leaf concatenated/truncated per batch dim (np.asarray
+        over a tuple would crash or mis-stack)."""
         import flax.linen as nn
         import jax
         import jax.numpy as jnp

@@ -638,7 +638,7 @@ class TestResultsSummary:
 
 # ---------------------------------------------------------------------
 # Offline (pinned) Vizier surface: every REST call the client can make
-# must exist in the bundled discovery document (VERDICT r3 #7 — the
+# must exist in the bundled discovery document (the
 # fallback guarantee in build_service_client silently rots otherwise;
 # reference bar: the full bundled doc, tuner/constants.py:20-22).
 # ---------------------------------------------------------------------

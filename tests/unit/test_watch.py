@@ -26,8 +26,7 @@ def _watch_isolation(monkeypatch):
     for key in ("CLOUD_TPU_WATCH", "CLOUD_TPU_WATCH_DEADLINE",
                 "CLOUD_TPU_WATCH_STARTUP_DEADLINE",
                 "CLOUD_TPU_WATCH_INTERVAL", "CLOUD_TPU_WATCH_DIR",
-                "CLOUD_TPU_WATCH_PROBE", "CLOUD_TPU_WATCH_FATAL",
-                "CLOUD_TPU_EVENT_LOG"):
+                "CLOUD_TPU_WATCH_FATAL", "CLOUD_TPU_EVENT_LOG"):
         monkeypatch.delenv(key, raising=False)
     yield
     watch.uninstall()
@@ -48,7 +47,7 @@ class TestWatchdogStall:
         def victim():
             w = watch.Watchdog(stall_deadline=0.4,
                                startup_deadline=0.4,
-                               poll_interval=0.05, probe=False,
+                               poll_interval=0.05,
                                out_dir=str(tmp_path))
             w.start()
             try:
@@ -124,7 +123,7 @@ class TestWatchdogStall:
         def victim():
             w = watch.Watchdog(stall_deadline=0.3,
                                startup_deadline=0.3,
-                               poll_interval=0.05, probe=False,
+                               poll_interval=0.05,
                                out_dir=str(tmp_path))
             w.start()
             try:
@@ -146,7 +145,7 @@ class TestWatchdogStall:
 
     def test_check_raises_when_async_delivery_failed(self, tmp_path):
         w = watch.Watchdog(stall_deadline=0.2, startup_deadline=0.2,
-                           poll_interval=0.05, probe=False,
+                           poll_interval=0.05,
                            out_dir=str(tmp_path))
         # A tid that no longer exists: the async raise targets nothing,
         # so check() is the delivery point (the scope-exit guarantee).
@@ -163,7 +162,7 @@ class TestWatchdogStall:
 
     def test_notify_step_resets_deadline(self, tmp_path):
         w = watch.Watchdog(stall_deadline=0.5, startup_deadline=0.5,
-                           poll_interval=0.05, probe=False,
+                           poll_interval=0.05,
                            out_dir=str(tmp_path))
         w.start()
         try:
@@ -181,7 +180,7 @@ class TestWatchdogStall:
         restore + rebuild must not trip the tight steady-state stall
         deadline."""
         w = watch.Watchdog(stall_deadline=0.3, startup_deadline=3.0,
-                           poll_interval=0.05, probe=False,
+                           poll_interval=0.05,
                            out_dir=str(tmp_path))
         # Bogus tid: a firing would latch without async-raising into
         # this test thread.
@@ -203,7 +202,7 @@ class TestWatchdogStall:
 
     def test_reentry_clears_fired_latch(self, tmp_path):
         w = watch.Watchdog(stall_deadline=0.2, startup_deadline=0.2,
-                           poll_interval=0.05, probe=False,
+                           poll_interval=0.05,
                            out_dir=str(tmp_path))
         w.start(watched_tid=2 ** 31 + 12345)
         try:
@@ -246,7 +245,6 @@ class TestModuleSeam:
                                                monkeypatch):
         monkeypatch.setenv("CLOUD_TPU_WATCH", "1")
         monkeypatch.setenv("CLOUD_TPU_WATCH_DIR", str(tmp_path))
-        monkeypatch.setenv("CLOUD_TPU_WATCH_PROBE", "0")
         with watch.env_scope() as w:
             assert w is watch.current()
             names = [t.name for t in threading.enumerate()]
@@ -259,7 +257,6 @@ class TestModuleSeam:
                                                        monkeypatch):
         monkeypatch.setenv("CLOUD_TPU_WATCH", "1")
         monkeypatch.setenv("CLOUD_TPU_WATCH_DIR", str(tmp_path))
-        monkeypatch.setenv("CLOUD_TPU_WATCH_PROBE", "0")
         with watch.env_scope() as outer:
             with watch.env_scope() as inner:
                 assert inner is outer
@@ -271,7 +268,6 @@ class TestModuleSeam:
                                              monkeypatch):
         monkeypatch.setenv("CLOUD_TPU_WATCH", "1")
         monkeypatch.setenv("CLOUD_TPU_WATCH_DIR", str(tmp_path))
-        monkeypatch.setenv("CLOUD_TPU_WATCH_PROBE", "0")
         with pytest.raises(RuntimeError, match="boom"):
             with watch.env_scope():
                 raise RuntimeError("boom")
@@ -327,7 +323,6 @@ class TestTrainerIntegration:
         monkeypatch.setenv("CLOUD_TPU_WATCH_DEADLINE", "2")
         monkeypatch.setenv("CLOUD_TPU_WATCH_STARTUP_DEADLINE", "2")
         monkeypatch.setenv("CLOUD_TPU_WATCH_INTERVAL", "0.25")
-        monkeypatch.setenv("CLOUD_TPU_WATCH_PROBE", "0")
         monkeypatch.setenv("CLOUD_TPU_WATCH_DIR", str(tmp_path))
         x, y = self._fit_data()
         trainer = self._trainer()
