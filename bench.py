@@ -270,6 +270,7 @@ def _serve_worker(stamp):
     compile_cache.enable(min_compile_time_secs=1.0)
     import jax.numpy as jnp
 
+    from cloud_tpu import ops
     from cloud_tpu.parallel import runtime as runtime_lib
     from cloud_tpu.serving import Scheduler
     from cloud_tpu.serving.smoke import (build_model, build_requests,
@@ -313,9 +314,16 @@ def _serve_worker(stamp):
         after = runtime_lib.compile_stats()
         stats = scheduler.stats()
         # Model-exact per-tick cost of the paged decode-attention op
-        # (ops/paged_attention.py cost hook; what the scheduler feeds
-        # the kernel pct_peak/bytes gauges every tick).
-        kernel_costs = scheduler.engine.kernel_costs()
+        # (ops/paged_attention.py cost hook), all layers.
+        engine, lm = scheduler.engine, scheduler.engine.model
+        tick_cost = ops.paged_attention_cost(
+            engine.slots, engine.spec_k + 1 if engine.spec_on else 1,
+            lm.num_heads, lm.d_model // lm.num_heads, engine.page_size,
+            engine.pages_per_slot, dtype=lm.compute_dtype,
+            kv_dtype=jnp.int8 if engine.page_dtype == "int8" else None)
+        kernel_costs = {"paged_attention": {
+            key: tick_cost[key] * lm.num_layers
+            for key in ("flops", "bytes_moved")}}
     finally:
         scheduler.close()
 

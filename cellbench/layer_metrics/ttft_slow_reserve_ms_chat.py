@@ -1,0 +1,11 @@
+"""Over the window's requests whose time to the first token is at or above its
+90th percentile: the mean time spent reserving KV pages (its turn -> pages
+reserved; on a prefix hit also the hand-over to the tick thread), from the
+program's per-request record. The four `ttft_slow_*` add up to the slow
+decile's mean time to the first token."""
+
+from cellbench import request_records
+
+
+def read(observed):
+    return request_records.slow_phase_ms(observed, "reserve")

@@ -406,9 +406,9 @@ def decode_latency_start():
     telemetry = sys.modules.get("cloud_tpu.monitoring.telemetry")
     if telemetry is None or not telemetry.enabled():
         return None
-    import time
+    from cloud_tpu.monitoring import spans
 
-    return time.monotonic_ns()
+    return spans.begin("decode")
 
 
 def decode_latency_finish(start, n_tokens, result=None):
@@ -423,23 +423,19 @@ def decode_latency_finish(start, n_tokens, result=None):
     if start is None:
         return
     import sys
-    import time
+
+    from cloud_tpu.monitoring import spans
 
     telemetry = sys.modules.get("cloud_tpu.monitoring.telemetry")
-    if telemetry is None:
-        return
-    tele = telemetry.get()
+    tele = telemetry.get() if telemetry is not None else None
     if tele is None or not tele.active:
+        spans.end(start)
         return
     if result is not None:
         for leaf in jax.tree_util.tree_leaves(result):
             if isinstance(leaf, jax.Array):
                 leaf.block_until_ready()
-    elapsed_ns = time.monotonic_ns() - start
-    from cloud_tpu.monitoring import spans
-
-    spans.complete("decode", start, elapsed_ns)
-    tele.observe_decode(n_tokens, elapsed_ns / 1e9)
+    tele.observe_decode(n_tokens, spans.end(start) / 1e9)
 
 
 __all__ = ["acquire_cache", "best_effort_donation", "bucket_length",

@@ -9,33 +9,93 @@ format Perfetto and chrome://tracing load directly). Nesting needs no
 parent pointers: complete ("ph":"X") events on one thread nest by time
 containment, exactly how the viewers render them.
 
-Zero-cost discipline (same seam shape as the graftsan observer): the
-module-level tracer is None until `install()`; every record helper is
-one global load + None check when disabled — nothing is wrapped,
-patched, or allocated. The Trainer additionally gates its generator
-wrapping on `enabled()` so the disabled hot loop is byte-identical to
-the pre-graftscope one.
+Two sinks behind one call. `span()`, `begin()`/`end()` and
+`trace_steps()` always open a `jax.profiler.TraceAnnotation`: it costs
+a flag test (under half a microsecond) while no profile is being
+captured, and while one is (`monitoring.profiler.trace`) the span lands
+in the `.xplane.pb` on the profiler's clock, beside the device's ops,
+with its keyword ids (`rid=`) as the event's stats. The second sink is
+the module-level `SpanTracer`: None until `install()`, one global load
++ None check when absent. `jax` is imported on first use, so this
+module stays importable without it (the annotation is then a no-op).
 
-Span names are a contract (docs/training/README.md span table, the CI
-telemetry smoke, and the telemetry histograms all key on them):
+The tables below are the contract: docs (monitoring/README.md,
+serving/README.md, PERF.md section 3), the telemetry histograms
+(`telemetry.SPAN_HISTOGRAMS`), the benchmark's readers and
+`cellbench/tools/spans.py` key on these names, and
+tests/unit/test_span_names.py holds the code to them. `names(section)`
+parses them.
+
+Spans:
 
     step                  one epoch's step-loop section
     boundary              one epoch's end-of-epoch host work
     train_step            one step: data wait + dispatch + log append
+                          (under a profile the feeder's last, empty
+                          `next()` of an epoch shows as one more)
     data_wait             blocking on the input feeder inside a step
     dispatch              the jitted step-executable call
     d2h_fetch             a coalesced device->host readback
     checkpoint_snapshot   the donation-safe host copy before a save
     async_reader_drain    the off-thread metric fetch
     decode                one generate()/beam/speculative call
-    serve_prefill         one serving prefill: gather + dense prefill
-                          + first-token fetch (the TTFT device side)
+    tick_admit            tick thread, before a tick: resize, one
+                          prefill chunk, inserts of ready requests
+                          (the slot_insert dispatches)
+    tick_pace             one 5 ms nap of the tick thread, taken
+                          because an admission is in flight and a slot
+                          is free
+    tick_idle             the tick thread's wait (up to 50 ms) with no
+                          slot occupied
     serve_tick            one engine tick: dispatch + d2h fetch of the
                           committed tokens (the serving hot loop)
+    tick_dispatch         inside serve_tick: the `engine.tick()` call
+    tick_fetch            inside serve_tick: the blocking fetch of the
+                          tick's tokens
+    tick_commit           after a tick: tokens to their requests,
+                          completions, eviction
+    admit                 one request's turn in its admission window:
+                          probe, decision, reservation, prefill (rid)
+    admit_reserve         inside admit: the page-reservation rounds
+                          (rid)
+    serve_prefill         one serving prefill: gather + dense prefill
+                          + first-token fetch, the device side of
+                          TTFT (rid)
+    serve_prefill_chunk   one chunk of a chunked prefill (rid)
+    prefill_host          inside a prefill: array prep and the eager
+                          key split (rid)
+    prefill_dispatch      inside a prefill: the jitted prefill call
+                          (rid)
+    prefill_fetch         inside a prefill: the blocking fetch of the
+                          first token (rid)
 
-Request-scoped serving observability (per-request lifecycles rather
-than host sections) lives in serving/reqtrace.py; its JSONL records
-merge into the same Perfetto view via `monitoring/collect.py --serve`.
+Each `pl.pallas_call` passes one of these as `name=` (a constant
+beside the call), and the trace's op text carries it.
+
+Kernels:
+
+    flash_fwd               ops/attention.py forward
+    flash_bwd_dq            ops/attention.py backward, dq
+    flash_bwd_dkv           ops/attention.py backward, dk and dv
+    fused_swiglu_fwd        ops/fused_mlp.py forward
+    fused_rmsnorm           ops/fused_norm.py, no residual
+    fused_rmsnorm_residual  ops/fused_norm.py, residual add fused
+    paged_decode            ops/paged_attention.py decode walk
+
+The hot loops' jitted functions are named so (a constant beside the
+jit), and the trace's program line reads `jit_<name>`.
+
+Programs:
+
+    train_step      training/trainer.py, one optimizer step
+    serve_tick      serving/engine.py, one decode tick over all slots
+    serve_prefill   serving/engine.py, one dense prefill + first token
+    slot_insert     serving/engine.py, a prefill scattered into a slot
+    slot_evict      serving/engine.py, finished slots' rows zeroed
+
+Request-scoped serving observability (one record a request, whose
+phases tile its latency) lives in serving/reqtrace.py; its JSONL export
+merges into the same Perfetto view via `monitoring/collect.py --serve`.
 """
 
 import json
@@ -46,7 +106,8 @@ import threading
 import time
 
 __all__ = ["SpanTracer", "install", "uninstall", "current_tracer",
-           "enabled", "span", "begin", "end", "complete", "trace_steps"]
+           "enabled", "span", "begin", "end", "complete", "trace_steps",
+           "names"]
 
 #: Hard cap on buffered span events; beyond it new events are counted
 #: as dropped instead of growing the host heap without bound (a week of
@@ -54,11 +115,28 @@ __all__ = ["SpanTracer", "install", "uninstall", "current_tracer",
 _DEFAULT_MAX_EVENTS = 500_000
 
 
-class _NoopSpan:
-    """Shared do-nothing context manager returned by `span()` when the
-    tracer is disabled."""
+def names(section):
+    """The names in one table of this module's docstring ("Spans",
+    "Kernels" or "Programs"), in order."""
+    out, inside = [], False
+    for line in __doc__.splitlines():
+        if not line.startswith(" "):
+            if line:
+                inside = line == section + ":"
+            continue
+        if inside and line.startswith("    ") and line[4] != " ":
+            out.append(line.split()[0])
+    return tuple(out)
+
+
+class _NoAnnotation:
+    """Stands in for `jax.profiler.TraceAnnotation` where jax cannot be
+    imported."""
 
     __slots__ = ()
+
+    def __init__(self, name, **ids):
+        pass
 
     def __enter__(self):
         return None
@@ -67,7 +145,19 @@ class _NoopSpan:
         return False
 
 
-_NOOP = _NoopSpan()
+_annotation_cls = None
+
+
+def _annotation():
+    """`jax.profiler.TraceAnnotation`, imported on first use."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation_cls = TraceAnnotation
+        except ImportError:
+            _annotation_cls = _NoAnnotation
+    return _annotation_cls
 
 
 def _process_identity():
@@ -98,16 +188,20 @@ def _process_identity():
 
 
 class _Span:
-    """Context manager recording one complete event on exit."""
+    """Context manager recording one complete event on exit, inside an
+    optional profiler annotation."""
 
-    __slots__ = ("_tracer", "_name", "_t0")
+    __slots__ = ("_tracer", "_name", "_t0", "_annotation")
 
-    def __init__(self, tracer, name):
+    def __init__(self, tracer, name, annotation=None):
         self._tracer = tracer
         self._name = name
         self._t0 = 0
+        self._annotation = annotation
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
@@ -115,6 +209,8 @@ class _Span:
         t0 = self._t0
         self._tracer.complete(self._name, t0,
                               time.monotonic_ns() - t0)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -258,34 +354,50 @@ def enabled():
     return _tracer is not None
 
 
-def span(name):
-    """A recording context manager when a tracer is installed, else a
-    shared no-op (one global load + None check)."""
+def _annotate(name, ids):
+    """A profiler annotation carrying the ids that are not None."""
+    if ids:
+        ids = {k: v for k, v in ids.items() if v is not None}
+    return _annotation()(name, **ids)
+
+
+def span(name, **ids):
+    """Context manager round one section: a profiler annotation
+    carrying `ids` (`rid=`; a None is left out), which also records
+    into the tracer when one is installed."""
+    annotation = _annotate(name, ids)
     tracer = _tracer
     if tracer is None:
-        return _NOOP
-    return tracer.span(name)
+        return annotation
+    return _Span(tracer, name, annotation)
 
 
-def begin(name):
-    """Begin handle for code that cannot use `with` (loop phases).
-    Returns None when disabled; pass the handle to `end()`."""
-    if _tracer is None:
-        return None
-    return (name, time.monotonic_ns())
+def begin(name, **ids):
+    """Begin handle for code that cannot use `with` (loop phases);
+    pass it to `end()` on the same thread."""
+    annotation = _annotate(name, ids)
+    annotation.__enter__()
+    return (name, time.monotonic_ns(), annotation)
 
 
 def end(handle):
-    """Completes a `begin()` handle (no-op for None)."""
+    """Completes a `begin()` handle (no-op for None); returns the
+    span's ns."""
+    if handle is None:
+        return None
+    name, t0, annotation = handle
+    dur = time.monotonic_ns() - t0
+    annotation.__exit__(None, None, None)
     tracer = _tracer
-    if tracer is None or handle is None:
-        return
-    name, t0 = handle
-    tracer.complete(name, t0, time.monotonic_ns() - t0)
+    if tracer is not None:
+        tracer.complete(name, t0, dur)
+    return dur
 
 
 def complete(name, t0_ns, dur_ns):
-    """Records an already-measured span into the ambient tracer."""
+    """Records an already-measured span into the ambient tracer. It
+    cannot reach the profile (an annotation is opened, not back-dated):
+    sections of the program use `span()`."""
     tracer = _tracer
     if tracer is not None:
         tracer.complete(name, t0_ns, dur_ns)
@@ -304,22 +416,27 @@ def trace_steps(iterable, step_name="train_step",
     `break` raises GeneratorExit at the yield; the finally completes
     the in-flight span before the generator closes.
 
-    Callers gate on `enabled()` and pass the feeder through untouched
-    when tracing is off, keeping the disabled hot loop unchanged.
+    Always on: nothing tells Python whether a profile is being
+    captured, and a step costs two annotation pairs (about a
+    microsecond).
     """
-    tracer = _tracer
-    if tracer is None:
-        yield from iterable
-        return
+    annotate = _annotation()
     it = iter(iterable)
     while True:
-        t0 = time.monotonic_ns()
+        step = begin(step_name)
+        _, t0, step_annotation = step
+        wait = annotate(wait_name)
+        wait.__enter__()
         try:
             item = next(it)
         except StopIteration:
+            # Nothing to record; only the annotations are closed.
+            wait.__exit__(None, None, None)
+            step_annotation.__exit__(None, None, None)
             return
-        tracer.complete(wait_name, t0, time.monotonic_ns() - t0)
+        wait.__exit__(None, None, None)
+        complete(wait_name, t0, time.monotonic_ns() - t0)
         try:
             yield item
         finally:
-            tracer.complete(step_name, t0, time.monotonic_ns() - t0)
+            end(step)

@@ -46,6 +46,10 @@ from jax.sharding import PartitionSpec as P
 
 from cloud_tpu.ops import partition
 
+#: The kernel's declared name (`pl.pallas_call(name=)`; table in
+#: monitoring/spans.py). The backward is plain lax.
+FUSED_SWIGLU_FWD = "fused_swiglu_fwd"
+
 _BLOCK_ROWS = 128
 _LANES = 128
 # Double-buffered gate/up/down tiles may take this much of the 16 MiB
@@ -179,6 +183,7 @@ def _swiglu_forward(config, x, w_gate, w_up, w_down):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=config.interpret,
+        name=FUSED_SWIGLU_FWD,
     )(x, w_gate, w_up, w_down)
 
 

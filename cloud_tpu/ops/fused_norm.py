@@ -39,6 +39,11 @@ from jax.sharding import PartitionSpec as P
 
 from cloud_tpu.ops import partition
 
+#: The kernels' declared names (`pl.pallas_call(name=)`; table in
+#: monitoring/spans.py).
+FUSED_RMSNORM = "fused_rmsnorm"
+FUSED_RMSNORM_RESIDUAL = "fused_rmsnorm_residual"
+
 _BLOCK_ROWS = 128
 
 
@@ -108,6 +113,7 @@ def _norm_forward(config, x, residual, scale):
             out_shape=jax.ShapeDtypeStruct((rows, features), out_dtype,
                                            vma=vma),
             interpret=config.interpret,
+            name=FUSED_RMSNORM,
         )(x, scale)
         return normed, x
     kernel = functools.partial(_fwd_kernel, config=config)
@@ -122,6 +128,7 @@ def _norm_forward(config, x, residual, scale):
             jax.ShapeDtypeStruct((rows, features), x.dtype, vma=vma),
         ],
         interpret=config.interpret,
+        name=FUSED_RMSNORM_RESIDUAL,
     )(x, residual, scale)
     return normed, h
 

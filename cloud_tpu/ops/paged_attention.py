@@ -91,6 +91,18 @@ from jax.sharding import PartitionSpec as P
 
 from cloud_tpu.ops import partition
 
+#: The kernel's declared name (table in monitoring/spans.py): the
+#: trace's op text carries it, whatever module calls the kernel.
+PAGED_DECODE = "paged_decode"
+
+#: `pl.pallas_call(name=)` is the innermost scope, and XLA:TPU names
+#: the custom call by it (`%<name>.N`). The benchmark's accepted
+#: `paged_attn_roofline` finds this kernel as a custom call whose name
+#: holds `_paged_decode_attention` (the flax method it used to be named
+#: by), so the call passes the declared name behind that prefix until
+#: a `benchmark` PR moves the reader to the declared name.
+_CALL_PREFIX = "attention._paged_decode_attention."
+
 _NEG_INF = -1e30
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -302,6 +314,7 @@ def _paged_forward(config, q, key_pages, value_pages, page_table,
         out_shape=jax.ShapeDtypeStruct(
             q.shape, out_dtype, vma=partition.vma_of(*operands)),
         interpret=config.interpret,
+        name=_CALL_PREFIX + PAGED_DECODE,
     )(*operands)
 
 

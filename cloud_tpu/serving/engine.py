@@ -43,7 +43,6 @@ enforced oracles.
 
 import dataclasses
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +55,29 @@ from cloud_tpu.parallel import runtime
 class RetraceError(RuntimeError):
     """The warm engine traced or compiled something new — a static-shape
     leak in the serving path (the retrace sentinel)."""
+
+
+#: The serving loop's programs, named by the function that is jitted:
+#: the trace's program line reads `jit_<name>` (table in
+#: monitoring/spans.py). The spans round them use the same names.
+SERVE_TICK = "serve_tick"
+SERVE_PREFILL = "serve_prefill"
+SLOT_INSERT = "slot_insert"
+SLOT_EVICT = "slot_evict"
+
+
+def _program(name, impl, donate):
+    """`impl` jitted as a program called `name`: jit names a program
+    by the function it is given, and a bound `_impl` method would name
+    it by the Python that happens to hold it."""
+    from cloud_tpu.models.decoding import best_effort_donation
+
+    @functools.wraps(impl)
+    def program(*args):
+        return impl(*args)
+    program.__name__ = program.__qualname__ = name
+    return best_effort_donation(runtime.instrumented_jit(
+        program, donate_argnums=donate))
 
 
 @dataclasses.dataclass
@@ -170,7 +192,7 @@ def _serve_prefill_fns(decoder, temperature, top_k, top_p):
     (same gumbel shape)."""
 
     @functools.partial(runtime.instrumented_jit, donate_argnums=1)
-    def prefill(params, cache, tokens, rng, mask, last_idx):
+    def serve_prefill(params, cache, tokens, rng, mask, last_idx):
         logits, vars_ = decoder.apply({"params": params, "cache": cache},
                                       tokens, mask, mutable=["cache"])
         row = jax.lax.dynamic_slice_in_dim(
@@ -185,7 +207,7 @@ def _serve_prefill_fns(decoder, temperature, top_k, top_p):
         return vars_["cache"], tok
 
     from cloud_tpu.models.decoding import best_effort_donation
-    return best_effort_donation(prefill)
+    return best_effort_donation(serve_prefill)
 
 
 @functools.lru_cache(maxsize=64)
@@ -246,7 +268,7 @@ class ChunkedPrefill:
 
     def __init__(self, engine, prompt, max_new_tokens, rng, sampling,
                  chunk_size, prefix_len=0, gather_vec=None,
-                 key_override=None):
+                 key_override=None, rid=None):
         from cloud_tpu.models.decoding import bucket_length
 
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -265,6 +287,7 @@ class ChunkedPrefill:
                 "suffix in-cache.".format(prefix_len,
                                           engine.max_seq_len))
         self.engine = engine
+        self.rid = rid           # labels the chunks' spans
         self.chunk_size = int(chunk_size)
         self.prompt_len = prompt_len
         self.prefix_len = prefix_len
@@ -318,8 +341,11 @@ class ChunkedPrefill:
         if self._closed:
             raise RuntimeError(
                 "ChunkedPrefill already consumed or abandoned.")
+        with spans.span("serve_prefill_chunk", rid=self.rid):
+            return self._step()
+
+    def _step(self):
         engine = self.engine
-        t0_ns = time.monotonic_ns()
         if self._cache is None:
             self._acquire()
         i = self.chunks_done
@@ -333,8 +359,6 @@ class ChunkedPrefill:
                 self._dcache = _cache_prefill_fn(engine._dense_draft)(
                     engine._draft_params, self._dcache, tokens, mask)
             self.chunks_done = i + 1
-            spans.complete("serve_prefill_chunk", t0_ns,
-                           time.monotonic_ns() - t0_ns)
             return None
         tail, bucket = self._tail, self._tail_bucket
         tokens = np.zeros((1, bucket), np.int32)
@@ -365,8 +389,6 @@ class ChunkedPrefill:
             step_keys[:n_steps - 1] = np.asarray(
                 jax.random.split(self._key, n_steps - 1))
         first_host = int(runtime.device_fetch(first)[0])
-        spans.complete("serve_prefill_chunk", t0_ns,
-                       time.monotonic_ns() - t0_ns)
         self.chunks_done = i + 1
         self._closed = True
         return PrefillResult(first_token=first_host, pcache=pcache,
@@ -515,21 +537,19 @@ class DecodeEngine:
         }
         jit = runtime.instrumented_jit
         if self.spec_on:
-            self._tick = best_effort_donation(functools.partial(
-                jit, donate_argnums=(2, 3, 4))(self._spec_tick_impl))
-            self._insert = best_effort_donation(functools.partial(
-                jit, donate_argnums=(0, 1, 2))(self._insert_spec_impl))
-            self._evict = best_effort_donation(functools.partial(
-                jit, donate_argnums=(0, 1, 2))(self._evict_spec_impl))
+            self._tick = _program(SERVE_TICK, self._spec_tick_impl,
+                                  (2, 3, 4))
+            self._insert = _program(SLOT_INSERT, self._insert_spec_impl,
+                                    (0, 1, 2))
+            self._evict = _program(SLOT_EVICT, self._evict_spec_impl,
+                                   (0, 1, 2))
             self._resize = best_effort_donation(functools.partial(
                 jit, donate_argnums=(0, 1, 2))(self._resize_spec_impl))
         else:
-            self._tick = best_effort_donation(functools.partial(
-                jit, donate_argnums=(1, 2))(self._tick_impl))
-            self._insert = best_effort_donation(functools.partial(
-                jit, donate_argnums=(0, 1))(self._insert_impl))
-            self._evict = best_effort_donation(functools.partial(
-                jit, donate_argnums=(0, 1))(self._evict_impl))
+            self._tick = _program(SERVE_TICK, self._tick_impl, (1, 2))
+            self._insert = _program(SLOT_INSERT, self._insert_impl,
+                                    (0, 1))
+            self._evict = _program(SLOT_EVICT, self._evict_impl, (0, 1))
             self._resize = best_effort_donation(functools.partial(
                 jit, donate_argnums=(0, 1))(self._resize_impl))
         gather_exec = best_effort_donation(functools.partial(
@@ -548,12 +568,12 @@ class DecodeEngine:
         self._promote = best_effort_donation(functools.partial(
             jit, donate_argnums=(0,))(self._promote_impl))
         self._warm_stats = None
-        self._kernel_costs = {}
 
     # -- prefill ------------------------------------------------------
 
     def prefill(self, prompt, max_new_tokens, rng, sampling,
-                prefix_len=0, gather_vec=None, key_override=None):
+                prefix_len=0, gather_vec=None, key_override=None,
+                rid=None):
         """Canonical right-pad prefill for one request. `sampling` is a
         normalized dict: temperature (float), top_k (int|None), top_p
         (float|None), eos_token (int|None).
@@ -576,74 +596,85 @@ class DecodeEngine:
         completes bit-identical to the uninterrupted decode.
 
         Returns a `PrefillResult`; blocks until the first token is on
-        host (the TTFT point)."""
+        host (the TTFT point). `rid` labels the call's spans: the
+        whole of it is `serve_prefill` (gather + dense prefill + the
+        blocking first-token fetch, the device side of TTFT)."""
+        with spans.span(SERVE_PREFILL, rid=rid):
+            return self._prefill(prompt, max_new_tokens, rng, sampling,
+                                 prefix_len, gather_vec, key_override,
+                                 rid)
+
+    def _prefill(self, prompt, max_new_tokens, rng, sampling,
+                 prefix_len, gather_vec, key_override, rid):
         from cloud_tpu.models.decoding import (acquire_cache,
                                                bucket_length)
 
-        t0_ns = time.monotonic_ns()
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        prompt_len = int(prompt.shape[0])
-        prefix_len = int(prefix_len)
-        if not 0 <= prefix_len < prompt_len:
-            raise ValueError(
-                "prefix_len must be in [0, prompt_len); got {} for a "
-                "{}-token prompt.".format(prefix_len, prompt_len))
-        n_suffix = prompt_len - prefix_len
-        bucket = bucket_length(n_suffix, self.max_seq_len)
-        if prefix_len + bucket > self.max_seq_len:
-            raise ValueError(
-                "prefix ({}) + suffix bucket ({}) exceeds max_seq_len "
-                "{}; the scheduler trims the match to keep the padded "
-                "suffix in-cache.".format(prefix_len, bucket,
-                                          self.max_seq_len))
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :n_suffix] = prompt[prefix_len:]
-        mask = np.zeros((1, bucket), bool)
-        mask[0, :n_suffix] = True
-        if key_override is None:
-            key, prefill_rng = jax.random.split(rng)
-        else:
-            # Same aval as a split key row (uint32[2], the legacy raw
-            # key layout categorical accepts), so the override path
-            # reuses the warmed prefill executable — no retrace.
-            prefill_rng = jnp.asarray(key_override[0], jnp.uint32)
-            key = None
+        with spans.span("prefill_host", rid=rid):
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            prompt_len = int(prompt.shape[0])
+            prefix_len = int(prefix_len)
+            if not 0 <= prefix_len < prompt_len:
+                raise ValueError(
+                    "prefix_len must be in [0, prompt_len); got {} for a "
+                    "{}-token prompt.".format(prefix_len, prompt_len))
+            n_suffix = prompt_len - prefix_len
+            bucket = bucket_length(n_suffix, self.max_seq_len)
+            if prefix_len + bucket > self.max_seq_len:
+                raise ValueError(
+                    "prefix ({}) + suffix bucket ({}) exceeds max_seq_len "
+                    "{}; the scheduler trims the match to keep the padded "
+                    "suffix in-cache.".format(prefix_len, bucket,
+                                              self.max_seq_len))
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :n_suffix] = prompt[prefix_len:]
+            mask = np.zeros((1, bucket), bool)
+            mask[0, :n_suffix] = True
+            if key_override is None:
+                key, prefill_rng = jax.random.split(rng)
+            else:
+                # Same aval as a split key row (uint32[2], the legacy raw
+                # key layout categorical accepts), so the override path
+                # reuses the warmed prefill executable — no retrace.
+                prefill_rng = jnp.asarray(key_override[0], jnp.uint32)
+                key = None
 
-        cache = _plain(acquire_cache(self._dense, 1))
-        gvec = None
-        if prefix_len:
-            gvec = jnp.asarray(gather_vec, jnp.int32)
-            cache = self._gather(cache, self.cache, gvec,
-                                 np.int32(prefix_len))
-        fn = _serve_prefill_fns(
-            self._dense, float(sampling["temperature"]),
-            sampling["top_k"], sampling["top_p"])
-        pcache, first = fn(self._params, cache, jnp.asarray(tokens),
-                           prefill_rng, jnp.asarray(mask),
-                           np.int32(n_suffix - 1))
-        dpcache = None
-        if self.spec_on:
-            dcache = _plain(acquire_cache(self._dense_draft, 1))
+            cache = _plain(acquire_cache(self._dense, 1))
+            gvec = None
             if prefix_len:
-                dcache = self._gather(dcache, self.draft_cache, gvec,
-                                      np.int32(prefix_len))
-            dpcache = _cache_prefill_fn(self._dense_draft)(
-                self._draft_params, dcache, jnp.asarray(tokens),
-                jnp.asarray(mask))
-        step_keys = np.zeros((self.max_new_cap - 1, 2), np.uint32)
-        if key_override is not None:
-            rest = np.asarray(key_override[1], np.uint32).reshape(-1, 2)
-            if max_new_tokens > 1:
-                step_keys[:max_new_tokens - 1] = \
-                    rest[:max_new_tokens - 1]
-        elif max_new_tokens > 1:
-            step_keys[:max_new_tokens - 1] = np.asarray(
-                jax.random.split(key, max_new_tokens - 1))
-        first_host = int(runtime.device_fetch(first)[0])
-        # Span covers gather + dense prefill + the blocking first-token
-        # fetch — the device side of TTFT (no-op with no tracer).
-        spans.complete("serve_prefill", t0_ns,
-                       time.monotonic_ns() - t0_ns)
+                gvec = jnp.asarray(gather_vec, jnp.int32)
+                cache = self._gather(cache, self.cache, gvec,
+                                     np.int32(prefix_len))
+            fn = _serve_prefill_fns(
+                self._dense, float(sampling["temperature"]),
+                sampling["top_k"], sampling["top_p"])
+        with spans.span("prefill_dispatch", rid=rid):
+            pcache, first = fn(self._params, cache, jnp.asarray(tokens),
+                               prefill_rng, jnp.asarray(mask),
+                               np.int32(n_suffix - 1))
+            dpcache = None
+            if self.spec_on:
+                dcache = _plain(acquire_cache(self._dense_draft, 1))
+                if prefix_len:
+                    dcache = self._gather(dcache, self.draft_cache,
+                                          gvec, np.int32(prefix_len))
+                dpcache = _cache_prefill_fn(self._dense_draft)(
+                    self._draft_params, dcache, jnp.asarray(tokens),
+                    jnp.asarray(mask))
+        # The eager split of the tick schedule queues behind the
+        # prefill just dispatched, and reading it back waits for both.
+        with spans.span("prefill_host", rid=rid):
+            step_keys = np.zeros((self.max_new_cap - 1, 2), np.uint32)
+            if key_override is not None:
+                rest = np.asarray(key_override[1],
+                                  np.uint32).reshape(-1, 2)
+                if max_new_tokens > 1:
+                    step_keys[:max_new_tokens - 1] = \
+                        rest[:max_new_tokens - 1]
+            elif max_new_tokens > 1:
+                step_keys[:max_new_tokens - 1] = np.asarray(
+                    jax.random.split(key, max_new_tokens - 1))
+        with spans.span("prefill_fetch", rid=rid):
+            first_host = int(runtime.device_fetch(first)[0])
         return PrefillResult(first_token=first_host, pcache=pcache,
                              dpcache=dpcache, step_keys=step_keys,
                              bucket=bucket, n_steps=int(max_new_tokens),
@@ -651,7 +682,7 @@ class DecodeEngine:
 
     def prefill_chunks(self, prompt, max_new_tokens, rng, sampling,
                        chunk_size, prefix_len=0, gather_vec=None,
-                       key_override=None):
+                       key_override=None, rid=None):
         """Chunked-prefill continuation for one request: the suffix
         runs as `chunk_plan()` windows — fixed `chunk_size` chunks
         through the cache-only executable, then a pow2-bucketed tail
@@ -677,7 +708,7 @@ class DecodeEngine:
                               sampling, chunk_size,
                               prefix_len=prefix_len,
                               gather_vec=gather_vec,
-                              key_override=key_override)
+                              key_override=key_override, rid=rid)
 
     def release_prefill(self, result):
         """Parks a consumed (or abandoned) prefill's dense cache(s)
@@ -813,39 +844,6 @@ class DecodeEngine:
             raise RetraceError(
                 "serving path traced/compiled after warm-up: {} "
                 "(static-shape leak).".format(grew))
-
-    def kernel_costs(self, slots=None):
-        """Per-TICK cost rows for the telemetry kernel gauges: the
-        paged-attention flops / bytes-moved one tick dispatches (all
-        layers, verify-window width when speculating), from the jit
-        cost-analysis hook in ops/paged_attention.py. Computed lazily
-        (one uninstrumented lowering — the retrace sentinel counts only
-        `instrumented_jit` sites) and cached PER GEOMETRY: a tick's
-        cost scales with its slot count, so A/B rows from different
-        ladder rungs must never share one entry. Defaults to the
-        current rung; the scheduler pairs the rows with the measured
-        tick latency for the pct_peak gauge."""
-        slots = int(self.slots if slots is None else slots)
-        if slots not in self._kernel_costs:
-            from cloud_tpu import ops
-
-            model = self.model
-            head_dim = model.d_model // model.num_heads
-            seq = self.spec_k + 1 if self.spec_on else 1
-            cost = ops.paged_attention_cost(
-                slots, seq, model.num_heads, head_dim,
-                self.page_size, self.pages_per_slot,
-                dtype=model.compute_dtype,
-                kv_dtype=(jnp.int8 if self.page_dtype == "int8"
-                          else None))
-            layers = model.num_layers
-            self._kernel_costs[slots] = {
-                "paged_attention": {
-                    "flops": cost["flops"] * layers,
-                    "bytes_moved": cost["bytes_moved"] * layers,
-                },
-            }
-        return self._kernel_costs[slots]
 
     # -- jitted bodies ------------------------------------------------
 

@@ -60,14 +60,44 @@ whose envelope matches ``cloud_tpu.utils.events`` job-event records
 trace plus ``serve_report.json`` (TTFT/TPOT percentiles, queue-wait
 breakdown, SLO goodput).
 
-Zero-cost discipline (same contract as spans.py): when
-``CLOUD_TPU_REQTRACE`` is unset nothing is installed — ``get()`` returns
-None, the Scheduler stamps no rids and emits no events, and no file or
-thread is ever created. The tracer itself never spawns threads either;
-buffered lines are appended synchronously on terminal events or when the
-buffer fills.
+The record (always on). Every request the Scheduler serves carries one
+`RequestRecord`, filled in memory by the scheduler's one marking call at
+each boundary it passes, on `time.monotonic()`:
+
+    t_submit    submit() took it
+    t_dequeued  the admission thread popped its window off the queue
+    t_admit     its own turn in the window began (the requests ahead of
+                it in the window are prefilled first)
+    t_reserved  its KV pages were reserved
+    t_first     its first token was on the host (the TTFT point)
+    t_insert    it was written into a decode slot
+    token_times the tick's commit time of each later token
+    t_done      the result was handed to the caller
+
+so that the phases `queue`, `window`, `reserve` and `prefill` add up to
+`ttft_s` exactly and, with `await_slot`, `decode` and `finish`, to
+`latency_s` (`RequestRecord.phases()`); a phase a path does not have is
+0, never missing. `ServeResult.trace` is the record, `ttft_s` and
+`latency_s` are computed from it, and the last `RECENT_CAP` finished
+ones are `recent()`: process-wide, bounded, the lock held for an
+append. A record names the ordinal of the Scheduler that served it and
+`recent()` gives the last started one's unless asked otherwise. Warm-up
+traffic stays out of `recent()` and of the JSONL. Nothing is written
+anywhere: the cost a request is some ten clock reads and one append,
+and a tick, one float a live slot.
+
+The JSONL (opt-in) is an export of the same marks. When
+``CLOUD_TPU_REQTRACE`` is unset no tracer is installed — ``get()``
+returns None, no events are built, and no file or thread is ever
+created. The tracer itself never spawns threads either; the scheduler's
+marks go through ``record()``, which only buffers, and buffered lines
+are appended when the buffer fills, at ``flush()`` and at the
+scheduler's ``close()`` — never on the tick thread's ``complete``.
+``emit()`` (other callers) also flushes on a terminal event.
 """
 
+import collections
+import itertools
 import json
 import os
 import socket
@@ -83,8 +113,108 @@ _TRUTHY_OFF = ("", "0", "off", "false", "none")
 # ticks per active slot (overridable via CLOUD_TPU_REQTRACE_TICK_EVERY).
 DEFAULT_TICK_EVERY = 8
 
+#: How many finished requests' records `recent()` keeps.
+RECENT_CAP = 4096
+
 _tracer = None
 _lock = threading.Lock()
+_recent = collections.deque(maxlen=RECENT_CAP)
+_recent_lock = threading.Lock()
+_rids = itertools.count()
+_servers = itertools.count(1)
+_last_server = 0
+
+
+class RequestRecord:
+    """One request's boundaries (see the module docstring). Times are
+    `time.monotonic()` seconds; a boundary not reached yet is None."""
+
+    __slots__ = ("rid", "server", "path", "prompt_len", "bucket",
+                 "max_new_tokens", "new_tokens", "prefix_len",
+                 "t_submit", "t_dequeued", "t_admit", "t_reserved",
+                 "t_first", "t_insert", "token_times", "t_done")
+
+    def __init__(self, rid, server, prompt_len, max_new_tokens,
+                 t_submit):
+        self.rid = rid                # None for warm-up traffic
+        self.server = server          # ordinal of its Scheduler
+        # miss | hit | miss_on_tick | chunked | requeue
+        self.path = "miss"
+        self.prompt_len = prompt_len
+        self.bucket = 0               # pow2 width its prefill ran at
+        self.max_new_tokens = max_new_tokens
+        self.new_tokens = 0           # tokens the device produced
+        self.prefix_len = 0           # tokens served from the cache
+        self.t_submit = t_submit
+        self.t_dequeued = self.t_admit = self.t_reserved = None
+        self.t_first = self.t_insert = self.t_done = None
+        self.token_times = []
+
+    @property
+    def ttft_s(self):
+        return self.t_first - self.t_submit
+
+    @property
+    def latency_s(self):
+        return self.t_done - self.t_submit
+
+    def phases(self):
+        """The finished request's latency, tiled: the first four add up
+        to `ttft_s`, all seven to `latency_s`."""
+        last = self.token_times[-1] if self.token_times else self.t_insert
+        return {
+            "queue": self.t_dequeued - self.t_submit,
+            "window": self.t_admit - self.t_dequeued,
+            "reserve": self.t_reserved - self.t_admit,
+            "prefill": self.t_first - self.t_reserved,
+            "await_slot": self.t_insert - self.t_first,
+            "decode": last - self.t_insert,
+            "finish": self.t_done - last,
+        }
+
+    def token_gaps(self):
+        """Seconds from each later token to the one before it; the
+        first from `t_first`, so it holds the wait for a slot."""
+        times = [self.t_first] + self.token_times
+        return [b - a for a, b in zip(times, times[1:])]
+
+
+def new_rid():
+    """A process-unique request id ("r000042")."""
+    return "r%06d" % next(_rids)
+
+
+def new_server():
+    """The ordinal of a Scheduler that starts now; `recent()` follows
+    the last one."""
+    global _last_server
+    with _recent_lock:
+        _last_server = next(_servers)
+        return _last_server
+
+
+def publish(record):
+    """Keeps a finished request's record among the last RECENT_CAP."""
+    with _recent_lock:
+        _recent.append(record)
+
+
+def recent(server=None):
+    """The kept records, oldest first: of the Scheduler started last,
+    of the one with ordinal `server`, or (`server=0`) of all."""
+    with _recent_lock:
+        records = list(_recent)
+        if server is None:
+            server = _last_server
+    if server == 0:
+        return records
+    return [r for r in records if r.server == server]
+
+
+def clear():
+    """Empties `recent()`."""
+    with _recent_lock:
+        _recent.clear()
 
 
 def env_enabled():
@@ -153,8 +283,17 @@ class RequestTracer:
         return rid
 
     def emit(self, rid, event, **fields):
-        """Records one lifecycle event. ``rid=None`` marks a global
-        (request-independent) event such as prefix_evict."""
+        """Records one lifecycle event and makes a terminal one
+        durable. ``rid=None`` marks a global (request-independent)
+        event such as prefix_evict."""
+        self.record(rid, event, **fields)
+        if event in ("complete", "fail", "shed"):
+            self.flush()
+
+    def record(self, rid, event, **fields):
+        """Buffers one lifecycle event; the file is appended to only
+        when the buffer fills (the scheduler's marks come here, so a
+        tick never waits for a terminal event's write)."""
         payload = {"rid": rid, "event": event}
         payload.update(fields)
         record = {
@@ -167,11 +306,10 @@ class RequestTracer:
             "payload": payload,
         }
         line = json.dumps(record, sort_keys=True) + "\n"
-        terminal = event in ("complete", "fail", "shed")
         with self._lock:
             self._buffer.append(line)
             self._emitted += 1
-            if terminal or len(self._buffer) >= self._flush_every:
+            if len(self._buffer) >= self._flush_every:
                 self._flush_locked()
 
     def events_emitted(self):
@@ -232,11 +370,18 @@ def maybe_enable():
 
 __all__ = [
     "DEFAULT_TICK_EVERY",
+    "RECENT_CAP",
+    "RequestRecord",
     "RequestTracer",
+    "clear",
     "default_path",
     "env_enabled",
     "get",
     "install",
     "maybe_enable",
+    "new_rid",
+    "new_server",
+    "publish",
+    "recent",
     "uninstall",
 ]

@@ -39,17 +39,25 @@ Phase labels: the tick thread runs under `runtime.set_phase
 from the training "step" phase, so graftsan GS001 (d2h-in-step-loop)
 correctly treats the per-tick fetch as a sanctioned, attributed read.
 
-Request tracing (graftlens): with `CLOUD_TPU_REQTRACE=1` every request
-gets a rid at submit() and its lifecycle lands as typed reqtrace JSONL
-events (serving/reqtrace.py): submitted -> queued -> radix_probe ->
-pages_reserved -> prefill -> slot_insert -> tick_commit* -> complete |
-fail. Boundary-event timestamps tile submit..complete, so the waterfall
-the collector's --serve mode renders accounts for end-to-end latency.
-With the env unset no tracer is installed: rids stay None, no events,
-no file, no threads — the PR 6 zero-hooks discipline, test-pinned.
-Queue-wait and page-reservation-wait histograms are host-side and
-always on (warm-reset like TTFT), feeding `stats()` and ROADMAP item
-4's predicted-TTFT admission.
+Request records (graftlens): every request gets a rid at submit() and
+a `reqtrace.RequestRecord` that the scheduler's one marking call
+(`_mark`) stamps at each boundary it passes — submit, dequeued (its
+window popped), admit (its own turn in the window), reserved, first
+(token on the host), insert, each later token's commit, done — always,
+in memory, clock reads only. The phases queue + window + reserve +
+prefill add up to `ServeResult.ttft_s` exactly (both are computed from
+the record, which is `ServeResult.trace`), and the last 4096 finished
+records are `reqtrace.recent()`. With `CLOUD_TPU_REQTRACE=1` the same
+marks are exported as typed reqtrace JSONL events (serving/reqtrace.py):
+submitted -> queued -> radix_probe -> pages_reserved -> prefill ->
+slot_insert -> tick_commit* -> complete | fail, buffered and written
+when the buffer fills and at close(); with the env unset no tracer is
+installed: no events, no file, no threads. The sections of both threads
+are graftscope spans (`monitoring/spans.py` has the table), which a
+profile capture shows beside the device's ops, those of one request
+under its rid. Queue-wait and page-reservation-wait histograms are
+host-side and always on (warm-reset like TTFT), feeding `stats()` and
+ROADMAP item 4's predicted-TTFT admission.
 
 Fault handling (graftstorm): chaos serving injections (analysis/
 chaos.py SERVE_KINDS, tick-indexed) are consumed at the top of every
@@ -153,28 +161,28 @@ class ServeRequest:
 class ServeResult:
     """A completed request: `tokens` is prompt + continuation, the
     `generate()` row contract. `prefix_len` is the token count served
-    from the prefix cache (0 = cold prefill)."""
+    from the prefix cache (0 = cold prefill). `trace` is the request's
+    `reqtrace.RequestRecord`: `ttft_s` and `latency_s` are computed
+    from it, and its `phases()` add up to them."""
     tokens: np.ndarray
     ttft_s: float
     latency_s: float
     prefix_len: int = 0
+    trace: Optional[reqtrace.RequestRecord] = None
 
 
 class _Slot:
-    __slots__ = ("request", "pages", "emitted", "future", "t_submit",
-                 "ttft_s", "prefix_len", "rid", "trace_ticks",
-                 "step_keys", "result_prefix_len")
+    __slots__ = ("request", "pages", "emitted", "future", "rec",
+                 "prefix_len", "trace_ticks", "step_keys",
+                 "result_prefix_len")
 
-    def __init__(self, request, pages, future, t_submit, ttft_s,
-                 prefix_len, rid=None):
+    def __init__(self, request, pages, future, rec, prefix_len):
         self.request = request
         self.pages = pages
         self.emitted = []
         self.future = future
-        self.t_submit = t_submit
-        self.ttft_s = ttft_s
+        self.rec = rec  # the request's reqtrace.RequestRecord
         self.prefix_len = prefix_len
-        self.rid = rid
         self.trace_ticks = 0  # ticks since the last tick_commit event
         # Retained per-slot rng schedule (the PrefillResult's host
         # uint32[max_new_cap-1, 2] array): a fault after n emitted
@@ -190,31 +198,26 @@ class _Slot:
 class _ReadyItem:
     """A miss-path prefill waiting for a free slot (admission thread
     already ran the prefill and holds the reserved pages)."""
-    __slots__ = ("request", "result", "pages", "future", "t_submit",
-                 "ttft_s", "rid")
+    __slots__ = ("request", "result", "pages", "future", "rec")
 
-    def __init__(self, request, result, pages, future, t_submit,
-                 ttft_s, rid=None):
+    def __init__(self, request, result, pages, future, rec):
         self.request = request
         self.result = result
         self.pages = pages
         self.future = future
-        self.t_submit = t_submit
-        self.ttft_s = ttft_s
-        self.rid = rid
+        self.rec = rec
 
 
 class _HitTicket:
     """A prefix-cache hit waiting for the tick thread: no pages, no
     prefill yet — the hit prefill must read the engine's live pool
     cache, which only the tick thread may touch."""
-    __slots__ = ("request", "future", "t_submit", "rid", "t_reserve0")
+    __slots__ = ("request", "future", "rec", "t_reserve0")
 
-    def __init__(self, request, future, t_submit, rid=None):
+    def __init__(self, request, future, rec):
         self.request = request
         self.future = future
-        self.t_submit = t_submit
-        self.rid = rid
+        self.rec = rec
         # First reservation attempt: a page-starved hit retries across
         # _insert_ready passes, so the cumulative reserve wait must
         # survive the ticket being re-queued.
@@ -228,19 +231,17 @@ class _RequeueItem:
     prompt + emitted at completion reassembles the original row.
     `key`/`rest` are the original schedule rows the continuation's
     prefill and ticks must consume (engine.prefill key_override)."""
-    __slots__ = ("request", "key", "rest", "future", "t_submit",
-                 "ttft_s", "result_prefix_len", "rid")
+    __slots__ = ("request", "key", "rest", "future", "rec",
+                 "result_prefix_len")
 
-    def __init__(self, request, key, rest, future, t_submit, ttft_s,
-                 result_prefix_len, rid=None):
+    def __init__(self, request, key, rest, future, rec,
+                 result_prefix_len):
         self.request = request
         self.key = key
         self.rest = rest
         self.future = future
-        self.t_submit = t_submit
-        self.ttft_s = ttft_s
+        self.rec = rec
         self.result_prefix_len = result_prefix_len
-        self.rid = rid
 
 
 class _ChunkItem:
@@ -250,17 +251,15 @@ class _ChunkItem:
     `kind` selects the insert variant — "miss" (admission-thread
     reservation, registers in the trie), "hit" (shared + fresh pages,
     CoW partial page, registers), "requeue" (key-override
-    continuation: original TTFT carried, no register)."""
+    continuation: the record keeps the original TTFT, no register)."""
     __slots__ = ("kind", "request", "chunked", "pages", "shared",
                  "fresh", "partial_page", "partial_len", "prefix_len",
-                 "result_prefix_len", "future", "t_submit", "ttft_s",
-                 "rid", "result", "t_prefill0", "counts_pending",
-                 "hold_released")
+                 "result_prefix_len", "future", "rec", "result",
+                 "t_prefill0", "counts_pending", "hold_released")
 
-    def __init__(self, kind, request, chunked, future, t_submit,
-                 rid=None, pages=(), shared=(), fresh=(),
-                 partial_page=None, partial_len=0, prefix_len=0,
-                 result_prefix_len=0, ttft_s=0.0):
+    def __init__(self, kind, request, chunked, future, rec, pages=(),
+                 shared=(), fresh=(), partial_page=None, partial_len=0,
+                 prefix_len=0, result_prefix_len=0):
         self.kind = kind
         self.request = request
         self.chunked = chunked
@@ -272,9 +271,7 @@ class _ChunkItem:
         self.prefix_len = prefix_len
         self.result_prefix_len = result_prefix_len
         self.future = future
-        self.t_submit = t_submit
-        self.ttft_s = ttft_s
-        self.rid = rid
+        self.rec = rec
         self.result = None       # PrefillResult once the tail chunk ran
         self.t_prefill0 = None   # first chunk dispatch (prefill span)
         self.counts_pending = (kind != "requeue"
@@ -434,6 +431,9 @@ class Scheduler:
         # the same device work as a full one (the batch-synchronous
         # waste this engine exists to avoid).
         self._pending_inserts = 0
+        # 5 ms naps the tick thread took for the sake of an admission
+        # in flight (each is a `tick_pace` span).
+        self._tick_paces = 0
         from cloud_tpu.monitoring.telemetry import Histogram
         self._ttft_hist = Histogram("ttft")
         self._ttft_hit_hist = Histogram("ttft_hit")
@@ -445,10 +445,12 @@ class Scheduler:
         # because the predicted-TTFT admission model samples its p50
         # even when telemetry export is off.
         self._prefill_hist = Histogram("prefill")
-        # graftlens request tracer; installed at start() when
-        # CLOUD_TPU_REQTRACE asks for it, else stays None and every
-        # rid in the pipeline stays None (zero events, zero file).
+        # graftlens JSONL export of the request records; installed at
+        # start() when CLOUD_TPU_REQTRACE asks for it, else stays None
+        # (zero events, zero file). The records themselves are always
+        # kept (reqtrace.recent()), under this server's ordinal.
         self._trace = None
+        self._server = 0
         self._trace_suppress = False  # warmup traffic is not traced
         # -- graftstorm: SLO-aware admission + chaos state ------------
         if slo_ttft is None:
@@ -552,6 +554,7 @@ class Scheduler:
             return self
         self._started = True
         self._trace = reqtrace.maybe_enable()
+        self._server = reqtrace.new_server()
         self._load_admission_model()
         self._t_start = time.monotonic()
         self._prefill_thread = threading.Thread(
@@ -738,7 +741,7 @@ class Scheduler:
         self._resize_events.append(event)
         trace = self._trace
         if trace is not None and not self._trace_suppress:
-            trace.emit(None, "resize", **event)
+            trace.record(None, "resize", **event)
         reg = _registry()
         if reg is not None:
             from cloud_tpu.monitoring import telemetry
@@ -756,33 +759,38 @@ class Scheduler:
             raise self._failure
         self._validate(request)
         future = Future()
-        t_submit = time.monotonic()
+        # Warm-up requests are synthetic: they get a record (the
+        # result's times come from it) but no rid, so they reach
+        # neither recent() nor the JSONL nor the spans' ids.
         rid = None
-        trace = None if self._trace_suppress else self._trace
-        if trace is not None:
-            rid = trace.new_request()
-            trace.emit(rid, "submitted",
-                       prompt_len=len(request.prompt),
-                       max_new=int(request.max_new_tokens))
+        if not self._trace_suppress:
+            rid = (self._trace.new_request() if self._trace is not None
+                   else reqtrace.new_rid())
+        rec = reqtrace.RequestRecord(
+            rid, self._server, len(request.prompt),
+            int(request.max_new_tokens), time.monotonic())
+        self._trace_emit(rid, "submitted", prompt_len=rec.prompt_len,
+                         max_new=rec.max_new_tokens)
         if request.max_new_tokens == 0:
+            for boundary in ("dequeued", "admit", "reserved", "first",
+                             "insert"):
+                self._mark(rec, boundary, rec.t_submit, event=False)
+            self._mark(rec, "done", rec.t_submit, ttft_s=0.0,
+                       latency_s=0.0, tokens=0, prefix_len=0)
             future.set_result(ServeResult(
                 tokens=np.asarray(request.prompt, np.int32),
-                ttft_s=0.0, latency_s=0.0))
-            if rid is not None:
-                trace.emit(rid, "complete", ttft_s=0.0, latency_s=0.0,
-                           tokens=0, prefix_len=0)
+                ttft_s=0.0, latency_s=0.0, trace=rec))
             return future
         if request.max_new_tokens > 1:
             self._pending_inserts += 1
         try:
-            self._admit_q.put((request, future, t_submit, rid,
-                               {"defers": 0}), timeout=timeout)
+            self._admit_q.put((request, future, rec, {"defers": 0}),
+                              timeout=timeout)
         except queue.Full:
             if request.max_new_tokens > 1:
                 self._pending_inserts -= 1
-            if rid is not None:
-                trace.emit(rid, "fail", error="queue.Full: admission "
-                           "queue full (load shed)")
+            self._trace_emit(rid, "fail", error="queue.Full: admission "
+                             "queue full (load shed)")
             raise
         self._observe_queue()
         return future
@@ -883,31 +891,41 @@ class Scheduler:
             window.sort(key=lambda item: (-self._probe(item[0]),
                                           -self._bucket(item[0])))
             admitted = 0
-            for request, future, t_submit, rid, meta in window:
+            for request, future, rec, meta in window:
                 if self._stop.is_set():
                     return
-                verdict, reason, predicted = self._admission_decision(
-                    request, t_submit, admitted, meta)
-                if verdict == "defer":
-                    meta["defers"] += 1
-                    try:
-                        self._admit_q.put_nowait(
-                            (request, future, t_submit, rid, meta))
-                        self._observe_queue()
-                        continue
-                    except queue.Full:
-                        verdict, reason = "shed", "queue_full"
-                if verdict == "shed":
-                    self._shed(request, future, rid, reason, predicted)
-                    continue
-                admitted += 1
-                try:
-                    self._admit_one(request, future, t_submit, rid)
-                except BaseException as exc:  # noqa: BLE001
-                    if request.max_new_tokens > 1:
-                        self._pending_inserts -= 1
-                    self._trace_fail(rid, exc)
-                    future.set_exception(exc)
+                # Its own turn begins: the window's requests are taken
+                # one after another, so the time since `dequeued` is
+                # the wait for the prefills ahead of it.
+                self._mark(rec, "admit")
+                with spans.span("admit", rid=rec.rid):
+                    admitted += self._admit_turn(request, future, rec,
+                                                 meta, admitted)
+
+    def _admit_turn(self, request, future, rec, meta, admitted):
+        """One request's turn in its window: decision, then admission.
+        Returns 1 when it was admitted, 0 when deferred or shed."""
+        verdict, reason, predicted = self._admission_decision(
+            request, rec.t_submit, admitted, meta)
+        if verdict == "defer":
+            meta["defers"] += 1
+            try:
+                self._admit_q.put_nowait((request, future, rec, meta))
+                self._observe_queue()
+                return 0
+            except queue.Full:
+                verdict, reason = "shed", "queue_full"
+        if verdict == "shed":
+            self._shed(request, future, rec.rid, reason, predicted)
+            return 0
+        try:
+            self._admit_one(request, future, rec)
+        except BaseException as exc:  # noqa: BLE001
+            if request.max_new_tokens > 1:
+                self._pending_inserts -= 1
+            self._trace_fail(rec.rid, exc)
+            future.set_exception(exc)
+        return 1
 
     def _next_window(self):
         window = []
@@ -925,18 +943,48 @@ class Scheduler:
         # request waterfall and the predicted-TTFT admission input.
         now = time.monotonic()
         reg = _registry()
-        trace = self._trace
-        for _, _, t_submit, rid, _ in window:
-            wait = max(now - t_submit, 0.0)
+        for _, _, rec, _ in window:
+            wait = max(now - rec.t_submit, 0.0)
             self._queue_wait_hist.observe(wait)
             if reg is not None:
                 from cloud_tpu.monitoring import telemetry
                 reg.histogram(
                     telemetry.SERVE_QUEUE_WAIT_HISTOGRAM).observe(wait)
-            if rid is not None and trace is not None:
-                trace.emit(rid, "queued", wait_s=wait)
+            self._mark(rec, "dequeued", now, wait_s=wait)
         self._observe_queue()
         return window
+
+    def _reserve_blocking(self, request, rec):
+        """The admission thread's reservation for a miss: rounds of
+        `_reserve_with_pressure` until the pool gives the pages
+        (blocking is the backpressure). Marks `reserved`; a request
+        that needs no pages passes straight through. Returns the pages,
+        or None when the scheduler closed meanwhile."""
+        if request.max_new_tokens <= 1:
+            self._mark(rec, "reserved", event=False)
+            return []
+        need = self.pool.pages_needed(len(request.prompt),
+                                      request.max_new_tokens,
+                                      slack=self._spec_slack())
+        pages = None
+        t_reserve0 = time.monotonic()
+        with spans.span("admit_reserve", rid=rec.rid):
+            while pages is None and not self._stop.is_set():
+                pages = self._reserve_with_pressure(need, timeout=0.2)
+        if pages is None:
+            return None
+        now = time.monotonic()
+        self._observe_reserve_wait(now - t_reserve0)
+        self._mark(rec, "reserved", now, pages=len(pages),
+                   wait_s=now - t_reserve0)
+        return pages
+
+    def _fail_closed(self, future, rec):
+        """Shutdown overtook an admission blocked on the pool."""
+        self._pending_inserts -= 1
+        error = RuntimeError("scheduler closed")
+        self._trace_fail(rec.rid, error)
+        future.set_exception(error)
 
     def _reserve_with_pressure(self, need, timeout):
         """One blocking-reserve round; a failed round applies LRU
@@ -1052,85 +1100,74 @@ class Scheduler:
             reason=reason, predicted_ttft=predicted,
             slo_ttft=self._slo_ttft))
 
-    def _admit_one(self, request, future, t_submit, rid=None):
+    def _admit_one(self, request, future, rec):
         sampling = self._sampling(request)
         matched = self._probe(request)
-        self._trace_emit(rid, "radix_probe", hit=matched > 0,
+        self._trace_emit(rec.rid, "radix_probe", hit=matched > 0,
                          matched_tokens=int(matched))
         if request.max_new_tokens > 1 and matched > 0:
             # Prefix-cache hit: hand the whole admission to the tick
             # thread — the gather-prefill reads the engine's live pool
             # cache, which every tick donates, so no other thread may
             # read it concurrently.
+            rec.path = "hit"
             with self._ready_lock:
-                self._ready.append(_HitTicket(request, future, t_submit,
-                                              rid=rid))
+                self._ready.append(_HitTicket(request, future, rec))
             self._wake.set()
             return
         if self._prefill_chunk is not None:
-            self._admit_miss_chunked(request, future, t_submit, rid,
-                                     sampling)
+            self._admit_miss_chunked(request, future, rec, sampling)
             return
         while True:
             # Re-entered on a transient PrefillFailed: the reservation
             # is released and retaken, so the retry re-queues behind
             # live backpressure instead of squatting on pages.
-            pages = []
-            if request.max_new_tokens > 1:
-                need = self.pool.pages_needed(len(request.prompt),
-                                              request.max_new_tokens,
-                                              slack=self._spec_slack())
-                pages = None
-                t_reserve0 = time.monotonic()
-                while not self._stop.is_set():
-                    pages = self._reserve_with_pressure(need,
-                                                        timeout=0.2)
-                    if pages is not None:
-                        break
-                if pages is None:  # shutdown while blocked on the pool
-                    self._pending_inserts -= 1
-                    error = RuntimeError("scheduler closed")
-                    self._trace_fail(rid, error)
-                    future.set_exception(error)
-                    return
-                wait = time.monotonic() - t_reserve0
-                self._observe_reserve_wait(wait)
-                self._trace_emit(rid, "pages_reserved",
-                                 pages=len(pages), wait_s=wait)
-            t_prefill0 = time.monotonic()
+            pages = self._reserve_blocking(request, rec)
+            if pages is None:  # shutdown while blocked on the pool
+                self._fail_closed(future, rec)
+                return
             try:
                 result = self._engine_prefill(
                     np.asarray(request.prompt, np.int32),
                     request.max_new_tokens,
-                    jax.random.PRNGKey(request.rng_seed), sampling)
+                    jax.random.PRNGKey(request.rng_seed), sampling,
+                    rid=rec.rid)
             except PrefillFailed as exc:
                 if pages:
                     self.pool.free(pages)
-                self._note_fault(exc, rid=rid, slot=None)
-                self._note_requeue(rid, tokens_done=0)
+                self._note_fault(exc, rid=rec.rid, slot=None)
+                self._note_requeue(rec.rid, tokens_done=0)
                 continue
             except BaseException:
                 if pages:
                     self.pool.free(pages)
                 raise
             break
-        ttft = time.monotonic() - t_submit
-        self._record_ttft(ttft, hit=False)
-        self._observe_prefill(time.monotonic() - t_prefill0)
-        self._trace_emit(rid, "prefill", bucket=int(result.bucket),
-                         prefix_len=0,
-                         dur_s=time.monotonic() - t_prefill0)
+        self._first_token(rec, result, hit=False)
         if request.max_new_tokens == 1:
             # Completes at prefill: no slot, no pages, no tick.
             self.engine.release_prefill(result)
-            self._complete(request, future, t_submit, ttft,
-                           [result.first_token], prefix_len=0, rid=rid)
+            self._mark(rec, "insert", rec.t_first, event=False)
+            self._complete(request, future, rec, [result.first_token],
+                           prefix_len=0)
             return
         with self._ready_lock:
             self._ready.append(_ReadyItem(request, result, pages,
-                                          future, t_submit, ttft,
-                                          rid=rid))
+                                          future, rec))
         self._wake.set()
+
+    def _first_token(self, rec, result, hit, prefix_len=0, t0=None,
+                     **fields):
+        """The TTFT point of a request admitted for the first time: its
+        prefill returned with the first token on the host. `t0` is
+        where the prefill began (default: the reservation)."""
+        now = time.monotonic()
+        dur = now - (rec.t_reserved if t0 is None else t0)
+        rec.bucket = int(result.bucket)
+        self._mark(rec, "first", now, bucket=rec.bucket,
+                   prefix_len=int(prefix_len), dur_s=dur, **fields)
+        self._record_ttft(rec.ttft_s, hit=hit)
+        self._observe_prefill(dur)
 
     def _record_ttft(self, ttft, hit):
         self._ttft_hist.observe(ttft)
@@ -1160,42 +1197,24 @@ class Scheduler:
             return 1
         return (n_suffix - 1) // self._prefill_chunk + 1
 
-    def _admit_miss_chunked(self, request, future, t_submit, rid,
-                            sampling):
+    def _admit_miss_chunked(self, request, future, rec, sampling):
         """Miss admission with chunking on: reserve pages here (same
         blocking backpressure as the whole-prefill path), then hand the
         request to the tick thread as a ChunkedPrefill continuation —
         the admission thread never touches the device, so a long
         prompt cannot monopolize the chip between ticks. Chaos
         `prefill_fail` moves to chunk dispatch."""
-        pages = []
-        if request.max_new_tokens > 1:
-            need = self.pool.pages_needed(len(request.prompt),
-                                          request.max_new_tokens,
-                                          slack=self._spec_slack())
-            pages = None
-            t_reserve0 = time.monotonic()
-            while not self._stop.is_set():
-                pages = self._reserve_with_pressure(need, timeout=0.2)
-                if pages is not None:
-                    break
-            if pages is None:  # shutdown while blocked on the pool
-                self._pending_inserts -= 1
-                error = RuntimeError("scheduler closed")
-                self._trace_fail(rid, error)
-                future.set_exception(error)
-                return
-            wait = time.monotonic() - t_reserve0
-            self._observe_reserve_wait(wait)
-            self._trace_emit(rid, "pages_reserved", pages=len(pages),
-                             wait_s=wait)
+        rec.path = "chunked"
+        pages = self._reserve_blocking(request, rec)
+        if pages is None:  # shutdown while blocked on the pool
+            self._fail_closed(future, rec)
+            return
         chunked = self.engine.prefill_chunks(
             np.asarray(request.prompt, np.int32),
             request.max_new_tokens, jax.random.PRNGKey(request.rng_seed),
-            sampling, self._prefill_chunk)
+            sampling, self._prefill_chunk, rid=rec.rid)
         self._enqueue_chunk_item(_ChunkItem(
-            "miss", request, chunked, future, t_submit, rid=rid,
-            pages=pages))
+            "miss", request, chunked, future, rec, pages=pages))
 
     def _enqueue_chunk_item(self, item):
         self.pool.note_prefill_hold(len(item.all_pages()))
@@ -1234,7 +1253,7 @@ class Scheduler:
         if item.counts_pending:
             self._pending_inserts -= 1
         if not item.future.done():
-            self._trace_fail(item.rid, error)
+            self._trace_fail(item.rec.rid, error)
             item.future.set_exception(error)
 
     def _step_chunks(self):
@@ -1263,8 +1282,8 @@ class Scheduler:
         if armed:
             self._note_fault(
                 PrefillFailed("graftchaos: injected prefill_fail"),
-                rid=item.rid, slot=None)
-            self._note_requeue(item.rid, tokens_done=0)
+                rid=item.rec.rid, slot=None)
+            self._note_requeue(item.rec.rid, tokens_done=0)
             with self._ready_lock:
                 self._chunks.appendleft(item)
             return True
@@ -1280,7 +1299,7 @@ class Scheduler:
         dur = time.monotonic() - t0
         self._chunks_dispatched += 1
         self._observe_prefill_chunk(dur)
-        self._trace_emit(item.rid, "prefill_chunk", i=int(i),
+        self._trace_emit(item.rec.rid, "prefill_chunk", i=int(i),
                          n=int(item.chunked.n_chunks),
                          tokens=int(item.chunked.chunk_tokens(i)),
                          dur_s=dur)
@@ -1289,15 +1308,15 @@ class Scheduler:
                 self._chunks.appendleft(item)
             return True
         item.result = result
-        now = time.monotonic()
-        if item.kind != "requeue":
-            item.ttft_s = now - item.t_submit
-            self._record_ttft(item.ttft_s, hit=item.kind == "hit")
-        self._observe_prefill(now - item.t_prefill0)
-        self._trace_emit(item.rid, "prefill", bucket=int(result.bucket),
-                         prefix_len=int(item.prefix_len),
-                         dur_s=now - item.t_prefill0,
-                         chunks=int(item.chunked.n_chunks))
+        rec = item.rec
+        if item.kind == "requeue":
+            self._later_prefill(rec, result, item.t_prefill0,
+                                chunks=int(item.chunked.n_chunks))
+        else:
+            self._first_token(rec, result, hit=item.kind == "hit",
+                              prefix_len=item.prefix_len,
+                              t0=item.t_prefill0,
+                              chunks=int(item.chunked.n_chunks))
         if item.kind == "hit":
             self._prefix_tokens_served += item.prefix_len
         if item.request.max_new_tokens == 1:
@@ -1305,10 +1324,11 @@ class Scheduler:
             self.engine.release_prefill(result)
             item.result = None
             self._release_chunk_hold(item)
-            self._complete(item.request, item.future, item.t_submit,
-                           item.ttft_s, [result.first_token],
-                           prefix_len=item.result_prefix_len,
-                           rid=item.rid)
+            if item.kind != "requeue":
+                self._mark(rec, "insert", rec.t_first, event=False)
+            self._complete(item.request, item.future, rec,
+                           [result.first_token],
+                           prefix_len=item.result_prefix_len)
             return True
         with self._ready_lock:
             self._ready.append(item)
@@ -1324,9 +1344,8 @@ class Scheduler:
             return
         held = item.pages_held()
         slot = self._free_slots.pop()
-        state = _Slot(item.request, held, item.future, item.t_submit,
-                      item.ttft_s, prefix_len=item.prefix_len,
-                      rid=item.rid)
+        state = _Slot(item.request, held, item.future, item.rec,
+                      prefix_len=item.prefix_len)
         state.result_prefix_len = item.result_prefix_len
         state.emitted.append(item.result.first_token)
         state.step_keys = item.result.step_keys
@@ -1342,7 +1361,7 @@ class Scheduler:
         self.engine.insert(slot, item.result, page_vec, scatter_vec,
                            self._sampling(item.request))
         item.result = None
-        self._trace_emit(item.rid, "slot_insert", slot=slot)
+        self._inserted(item.rec, slot, first=item.kind != "requeue")
         if item.kind == "hit" and item.partial_len:
             # The divergent page was reconstructed into a fresh page by
             # the insert scatter — device-side copy-on-write done.
@@ -1463,7 +1482,7 @@ class Scheduler:
         persistent tick never stops), return its pages exactly once
         (prefix-trie references survive untouched), and requeue its
         request with retained progress."""
-        self._note_fault(fault, rid=state.rid, slot=slot)
+        self._note_fault(fault, rid=state.rec.rid, slot=slot)
         evict_mask = np.zeros((self.engine.slots,), bool)
         evict_mask[slot] = True
         self.engine.evict(evict_mask)
@@ -1489,18 +1508,14 @@ class Scheduler:
             # eos already latched: the remaining decode is pure eos
             # replay, which _complete's fill reproduces on host.
             done = emitted[:emitted.index(eos) + 1]
-            self._complete(request, state.future, state.t_submit,
-                           state.ttft_s, done,
-                           prefix_len=state.result_prefix_len,
-                           rid=state.rid)
+            self._complete(request, state.future, state.rec, done,
+                           prefix_len=state.result_prefix_len)
             return
         if n >= request.max_new_tokens:
-            self._complete(request, state.future, state.t_submit,
-                           state.ttft_s, emitted,
-                           prefix_len=state.result_prefix_len,
-                           rid=state.rid)
+            self._complete(request, state.future, state.rec, emitted,
+                           prefix_len=state.result_prefix_len)
             return
-        self._note_requeue(state.rid, tokens_done=n)
+        self._note_requeue(state.rec.rid, tokens_done=n)
         cont = dataclasses.replace(
             request,
             prompt=[int(t) for t in request.prompt] + emitted,
@@ -1508,8 +1523,7 @@ class Scheduler:
         item = _RequeueItem(
             cont, np.array(state.step_keys[n - 1], np.uint32),
             np.array(state.step_keys[n:], np.uint32),
-            state.future, state.t_submit, state.ttft_s,
-            state.result_prefix_len, rid=state.rid)
+            state.future, state.rec, state.result_prefix_len)
         with self._ready_lock:
             self._ready.appendleft(item)
         self._wake.set()
@@ -1540,6 +1554,25 @@ class Scheduler:
             reg.histogram(
                 telemetry.SERVE_PREFILL_HISTOGRAM).observe(dur)
 
+    def _later_prefill(self, rec, result, t0, **fields):
+        """A requeued request's re-prefill returned: its token is a
+        LATER token of the request (the TTFT point stays where the
+        first admission put it), so it joins `token_times`."""
+        now = time.monotonic()
+        rec.path = "requeue"
+        rec.token_times.append(now)
+        self._observe_prefill(now - t0)
+        self._trace_emit(rec.rid, "prefill", bucket=int(result.bucket),
+                         prefix_len=0, dur_s=now - t0, **fields)
+
+    def _inserted(self, rec, slot, first=True):
+        """The request was written into decode slot `slot`; only its
+        first insertion is the record's `t_insert`."""
+        if first:
+            self._mark(rec, "insert", slot=slot)
+        else:
+            self._trace_emit(rec.rid, "slot_insert", slot=slot)
+
     # -- tick thread --------------------------------------------------
 
     def _tick_loop(self):
@@ -1557,20 +1590,22 @@ class Scheduler:
                     watch.heartbeat()
                     watch.check()
                 self._chaos_pre_tick()
-                # Tick boundary: the only point the geometry may move —
-                # never mid-tick, never from another thread.
-                self._maybe_resize()
-                stepped = self._step_chunks()
-                self._insert_ready()
+                with spans.span("tick_admit"):
+                    # Tick boundary: the only point the geometry may
+                    # move — never mid-tick, never from another thread.
+                    self._maybe_resize()
+                    stepped = self._step_chunks()
+                    self._insert_ready()
                 if not any(s is not None for s in self._slots):
                     self._t_last_commit = None
                     if stepped:
                         # A continuation advanced and nothing decodes:
                         # drain chunks back-to-back, no idle sleep.
                         continue
-                    if not self._wake.wait(timeout=0.05):
-                        continue
-                    self._wake.clear()
+                    with spans.span("tick_idle"):
+                        woke = self._wake.wait(timeout=0.05)
+                    if woke:
+                        self._wake.clear()
                     continue
                 if (self._free_slots
                         # A stale read only mis-times one 5 ms pacing
@@ -1586,26 +1621,28 @@ class Scheduler:
                     # them, so waiting on them would stall every
                     # resident slot for nothing.
                     skips += 1
-                    self._wake.wait(timeout=0.005)
+                    self._tick_paces += 1
+                    with spans.span("tick_pace"):
+                        self._wake.wait(timeout=0.005)
                     self._wake.clear()
                     continue
                 skips = 0
-                t0 = time.monotonic()
-                out = self.engine.tick()
-                fetched = runtime.device_fetch(out)
-                t_commit = time.monotonic()
+                with spans.span("serve_tick"):
+                    t0 = time.monotonic()
+                    with spans.span("tick_dispatch"):
+                        out = self.engine.tick()
+                    with spans.span("tick_fetch"):
+                        fetched = runtime.device_fetch(out)
+                    t_commit = time.monotonic()
                 elapsed = t_commit - t0
-                # monotonic() and monotonic_ns() share an epoch, so the
-                # span timestamps line up with the tracer's records.
-                spans.complete("serve_tick", int(t0 * 1e9),
-                               int(elapsed * 1e9))
                 self._ticks += 1
                 if self._t_last_commit is not None:
                     self._observe_decode_gap(
                         t_commit - self._t_last_commit,
                         sum(s is not None for s in self._slots))
                 self._t_last_commit = t_commit
-                self._distribute(fetched, elapsed)
+                with spans.span("tick_commit"):
+                    self._distribute(fetched, elapsed, t_commit)
                 if self.strict_no_retrace:
                     self.engine.check_no_retrace()
         except BaseException as exc:  # noqa: BLE001
@@ -1627,7 +1664,9 @@ class Scheduler:
                         return
                     item = self._ready.popleft()
                 if isinstance(item, _HitTicket):
-                    if not self._admit_hit(item):
+                    with spans.span("admit", rid=item.rec.rid):
+                        admitted = self._admit_hit(item)
+                    if not admitted:
                         blocked.append(item)
                     continue
                 if isinstance(item, _RequeueItem):
@@ -1645,16 +1684,15 @@ class Scheduler:
 
     def _insert_miss_item(self, item):
         slot = self._free_slots.pop()
-        state = _Slot(item.request, item.pages, item.future,
-                      item.t_submit, item.ttft_s, prefix_len=0,
-                      rid=item.rid)
+        state = _Slot(item.request, item.pages, item.future, item.rec,
+                      prefix_len=0)
         state.emitted.append(item.result.first_token)
         state.step_keys = item.result.step_keys
         self._slots[slot] = state
         vec = self.pool.page_vec(item.pages)
         self.engine.insert(slot, item.result, vec, vec,
                            self._sampling(item.request))
-        self._trace_emit(item.rid, "slot_insert", slot=slot)
+        self._inserted(item.rec, slot)
         self._register(item.request, item.pages)
         self._pending_inserts -= 1
         self._observe_gauges()
@@ -1664,86 +1702,68 @@ class Scheduler:
         reserve (non-blocking — a starved requeue stays queued), cold
         re-prefill under the key_override schedule, insert. No new TTFT
         observation — the request's TTFT happened at its ORIGINAL
-        prefill and is carried through. Returns False when pages are
+        prefill and stays in its record. Returns False when pages are
         not available yet."""
-        request = item.request
+        request, rec = item.request, item.rec
         if self._stop.is_set():
             if not item.future.done():
                 error = (self._failure
                          or RuntimeError("scheduler closed"))
-                self._trace_fail(item.rid, error)
+                self._trace_fail(rec.rid, error)
                 item.future.set_exception(error)
             return True
         key_override = (item.key, item.rest)
+        pages = []
+        if request.max_new_tokens > 1:
+            need = self.pool.pages_needed(len(request.prompt),
+                                          request.max_new_tokens,
+                                          slack=self._spec_slack())
+            pages = self._reserve_with_pressure(need, timeout=0.01)
+            if pages is None:
+                return False
+            self._trace_emit(rec.rid, "pages_reserved",
+                             pages=len(pages), wait_s=0.0)
         if self._prefill_chunk is not None:
-            pages = []
-            if request.max_new_tokens > 1:
-                need = self.pool.pages_needed(len(request.prompt),
-                                              request.max_new_tokens,
-                                              slack=self._spec_slack())
-                pages = self._reserve_with_pressure(need, timeout=0.01)
-                if pages is None:
-                    return False
-                self._trace_emit(item.rid, "pages_reserved",
-                                 pages=len(pages), wait_s=0.0)
             chunked = self.engine.prefill_chunks(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
                 jax.random.PRNGKey(request.rng_seed),
                 self._sampling(request), self._prefill_chunk,
-                key_override=key_override)
+                key_override=key_override, rid=rec.rid)
             self._enqueue_chunk_item(_ChunkItem(
-                "requeue", request, chunked, item.future,
-                item.t_submit, rid=item.rid, pages=pages,
-                result_prefix_len=item.result_prefix_len,
-                ttft_s=item.ttft_s))
+                "requeue", request, chunked, item.future, rec,
+                pages=pages,
+                result_prefix_len=item.result_prefix_len))
             return True
-        if request.max_new_tokens == 1:
-            # Single remaining token: completes at prefill, no slot.
-            try:
-                result = self._engine_prefill(
-                    np.asarray(request.prompt, np.int32), 1,
-                    jax.random.PRNGKey(request.rng_seed),
-                    self._sampling(request),
-                    key_override=key_override)
-            except PrefillFailed as exc:
-                self._note_fault(exc, rid=item.rid, slot=None)
-                return False
-            self.engine.release_prefill(result)
-            self._complete(request, item.future, item.t_submit,
-                           item.ttft_s, [result.first_token],
-                           prefix_len=item.result_prefix_len,
-                           rid=item.rid)
-            return True
-        need = self.pool.pages_needed(len(request.prompt),
-                                      request.max_new_tokens,
-                                      slack=self._spec_slack())
-        pages = self._reserve_with_pressure(need, timeout=0.01)
-        if pages is None:
-            return False
-        self._trace_emit(item.rid, "pages_reserved", pages=len(pages),
-                         wait_s=0.0)
         t_prefill0 = time.monotonic()
         try:
             result = self._engine_prefill(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
                 jax.random.PRNGKey(request.rng_seed),
-                self._sampling(request), key_override=key_override)
+                self._sampling(request), key_override=key_override,
+                rid=rec.rid)
         except PrefillFailed as exc:
-            self.pool.free(pages)
-            self._note_fault(exc, rid=item.rid, slot=None)
+            if pages:
+                self.pool.free(pages)
+            self._note_fault(exc, rid=rec.rid, slot=None)
             return False
         except BaseException:
-            self.pool.free(pages)
+            if pages:
+                self.pool.free(pages)
             raise
-        self._observe_prefill(time.monotonic() - t_prefill0)
-        self._trace_emit(item.rid, "prefill",
-                         bucket=int(result.bucket), prefix_len=0,
-                         dur_s=time.monotonic() - t_prefill0)
+        if request.max_new_tokens == 1:
+            # Single remaining token: completes at prefill, no slot.
+            rec.path = "requeue"
+            rec.token_times.append(time.monotonic())
+            self.engine.release_prefill(result)
+            self._complete(request, item.future, rec,
+                           [result.first_token],
+                           prefix_len=item.result_prefix_len)
+            return True
+        self._later_prefill(rec, result, t_prefill0)
         slot = self._free_slots.pop()
-        state = _Slot(request, pages, item.future, item.t_submit,
-                      item.ttft_s, prefix_len=0, rid=item.rid)
+        state = _Slot(request, pages, item.future, rec, prefix_len=0)
         state.result_prefix_len = item.result_prefix_len
         state.emitted.append(result.first_token)
         state.step_keys = result.step_keys
@@ -1751,7 +1771,7 @@ class Scheduler:
         vec = self.pool.page_vec(pages)
         self.engine.insert(slot, result, vec, vec,
                            self._sampling(request))
-        self._trace_emit(item.rid, "slot_insert", slot=slot)
+        self._inserted(rec, slot, first=False)
         self._observe_gauges()
         return True
 
@@ -1763,13 +1783,13 @@ class Scheduler:
         when fresh pages cannot be reserved yet."""
         from cloud_tpu.models.decoding import bucket_length
 
-        request = ticket.request
+        request, rec = ticket.request, ticket.rec
         if self._stop.is_set():
             self._pending_inserts -= 1
             if not ticket.future.done():
                 error = (self._failure
                          or RuntimeError("scheduler closed"))
-                self._trace_fail(ticket.rid, error)
+                self._trace_fail(rec.rid, error)
                 ticket.future.set_exception(error)
             return True
         prompt = [int(t) for t in request.prompt]
@@ -1806,17 +1826,10 @@ class Scheduler:
             if held:
                 self.pool.free(held)
             return self._admit_miss_on_tick(ticket, total)
-        if ticket.t_reserve0 is None:
-            ticket.t_reserve0 = time.monotonic()
-        fresh = self._reserve_with_pressure(total - len(shared),
-                                            timeout=0.01)
+        fresh = self._reserve_on_tick(ticket, total - len(shared))
         if fresh is None:
             self.pool.free(held)
             return False
-        wait = time.monotonic() - ticket.t_reserve0
-        self._observe_reserve_wait(wait)
-        self._trace_emit(ticket.rid, "pages_reserved",
-                         pages=len(fresh), wait_s=wait)
         if self._prefill_chunk is not None:
             # The gather runs lazily at the first chunk step (tick
             # thread — safe); the held refs keep the prefix pages'
@@ -1826,41 +1839,32 @@ class Scheduler:
                 jax.random.PRNGKey(request.rng_seed),
                 self._sampling(request), self._prefill_chunk,
                 prefix_len=prefix_len,
-                gather_vec=self.pool.page_vec(held))
+                gather_vec=self.pool.page_vec(held), rid=rec.rid)
             self._enqueue_chunk_item(_ChunkItem(
-                "hit", request, chunked, ticket.future,
-                ticket.t_submit, rid=ticket.rid, shared=shared,
-                fresh=fresh, partial_page=partial_page,
+                "hit", request, chunked, ticket.future, rec,
+                shared=shared, fresh=fresh, partial_page=partial_page,
                 partial_len=partial_len, prefix_len=prefix_len,
                 result_prefix_len=prefix_len))
             return True
-        t_prefill0 = time.monotonic()
         try:
             result = self._engine_prefill(
                 np.asarray(prompt, np.int32), request.max_new_tokens,
                 jax.random.PRNGKey(request.rng_seed),
                 self._sampling(request), prefix_len=prefix_len,
-                gather_vec=self.pool.page_vec(held))
+                gather_vec=self.pool.page_vec(held), rid=rec.rid)
         except PrefillFailed as exc:
             self.pool.free(held + fresh)
-            self._note_fault(exc, rid=ticket.rid, slot=None)
-            self._note_requeue(ticket.rid, tokens_done=0)
+            self._note_fault(exc, rid=rec.rid, slot=None)
+            self._note_requeue(rec.rid, tokens_done=0)
             return False
         except BaseException:
             self.pool.free(held + fresh)
             raise
-        ttft = time.monotonic() - ticket.t_submit
-        self._record_ttft(ttft, hit=True)
-        self._observe_prefill(time.monotonic() - t_prefill0)
-        self._trace_emit(ticket.rid, "prefill",
-                         bucket=int(result.bucket),
-                         prefix_len=int(prefix_len),
-                         dur_s=time.monotonic() - t_prefill0)
+        self._first_token(rec, result, hit=True, prefix_len=prefix_len)
         self._prefix_tokens_served += prefix_len
         slot = self._free_slots.pop()
-        state = _Slot(request, shared + fresh, ticket.future,
-                      ticket.t_submit, ttft, prefix_len=prefix_len,
-                      rid=ticket.rid)
+        state = _Slot(request, shared + fresh, ticket.future, rec,
+                      prefix_len=prefix_len)
         state.emitted.append(result.first_token)
         state.step_keys = result.step_keys
         self._slots[slot] = state
@@ -1868,7 +1872,7 @@ class Scheduler:
         scatter_vec = self.pool.page_vec([0] * len(shared) + fresh)
         self.engine.insert(slot, result, page_vec, scatter_vec,
                            self._sampling(request))
-        self._trace_emit(ticket.rid, "slot_insert", slot=slot)
+        self._inserted(rec, slot)
         if partial_len:
             # The divergent page was reconstructed into its fresh page
             # by the insert scatter — the device-side copy-on-write.
@@ -1879,60 +1883,67 @@ class Scheduler:
         self._observe_gauges()
         return True
 
+    def _reserve_on_tick(self, ticket, need):
+        """One short reservation round for a ticket the tick thread
+        admits (the tick must not block): the pages and the `reserved`
+        mark, or None when the pool has none yet (the ticket stays
+        queued and its wait keeps running)."""
+        rec = ticket.rec
+        if ticket.t_reserve0 is None:
+            ticket.t_reserve0 = time.monotonic()
+        with spans.span("admit_reserve", rid=rec.rid):
+            pages = self._reserve_with_pressure(need, timeout=0.01)
+        if pages is None:
+            return None
+        now = time.monotonic()
+        self._observe_reserve_wait(now - ticket.t_reserve0)
+        self._mark(rec, "reserved", now, pages=len(pages),
+                   wait_s=now - ticket.t_reserve0)
+        return pages
+
     def _admit_miss_on_tick(self, ticket, need):
         """Fallback when a probed hit vanished before `match`: admit it
         as a miss without bouncing back to the admission thread."""
-        request = ticket.request
-        if ticket.t_reserve0 is None:
-            ticket.t_reserve0 = time.monotonic()
-        pages = self._reserve_with_pressure(need, timeout=0.01)
+        request, rec = ticket.request, ticket.rec
+        rec.path = "miss_on_tick"
+        pages = self._reserve_on_tick(ticket, need)
         if pages is None:
             return False
-        wait = time.monotonic() - ticket.t_reserve0
-        self._observe_reserve_wait(wait)
-        self._trace_emit(ticket.rid, "pages_reserved",
-                         pages=len(pages), wait_s=wait)
         if self._prefill_chunk is not None:
             chunked = self.engine.prefill_chunks(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
                 jax.random.PRNGKey(request.rng_seed),
-                self._sampling(request), self._prefill_chunk)
+                self._sampling(request), self._prefill_chunk,
+                rid=rec.rid)
             self._enqueue_chunk_item(_ChunkItem(
-                "miss", request, chunked, ticket.future,
-                ticket.t_submit, rid=ticket.rid, pages=pages))
+                "miss", request, chunked, ticket.future, rec,
+                pages=pages))
             return True
-        t_prefill0 = time.monotonic()
         try:
             result = self._engine_prefill(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
                 jax.random.PRNGKey(request.rng_seed),
-                self._sampling(request))
+                self._sampling(request), rid=rec.rid)
         except PrefillFailed as exc:
             self.pool.free(pages)
-            self._note_fault(exc, rid=ticket.rid, slot=None)
-            self._note_requeue(ticket.rid, tokens_done=0)
+            self._note_fault(exc, rid=rec.rid, slot=None)
+            self._note_requeue(rec.rid, tokens_done=0)
             return False
         except BaseException:
             self.pool.free(pages)
             raise
-        ttft = time.monotonic() - ticket.t_submit
-        self._record_ttft(ttft, hit=False)
-        self._observe_prefill(time.monotonic() - t_prefill0)
-        self._trace_emit(ticket.rid, "prefill",
-                         bucket=int(result.bucket), prefix_len=0,
-                         dur_s=time.monotonic() - t_prefill0)
+        self._first_token(rec, result, hit=False)
         slot = self._free_slots.pop()
-        state = _Slot(request, pages, ticket.future, ticket.t_submit,
-                      ttft, prefix_len=0, rid=ticket.rid)
+        state = _Slot(request, pages, ticket.future, rec, prefix_len=0)
         state.emitted.append(result.first_token)
         state.step_keys = result.step_keys
         self._slots[slot] = state
         vec = self.pool.page_vec(pages)
         self.engine.insert(slot, result, vec, vec,
                            self._sampling(request))
-        self._trace_emit(ticket.rid, "slot_insert", slot=slot)
+        self._inserted(rec, slot)
         self._register(request, pages)
         self._pending_inserts -= 1
         self._observe_gauges()
@@ -1991,7 +2002,7 @@ class Scheduler:
             self._note_fault(HostTierCorrupt(
                 "host-tier digest mismatch at {} pages; entry dropped, "
                 "falling back to re-prefill.".format(n_h)),
-                rid=ticket.rid, slot=None)
+                rid=ticket.rec.rid, slot=None)
             reg = _registry()
             if reg is not None:
                 from cloud_tpu.monitoring import telemetry
@@ -2011,7 +2022,7 @@ class Scheduler:
         self.engine.promote_pages(entry["pages"], shared + ext,
                                   n_skip=n_t)
         tier.note_promote()
-        self._trace_emit(ticket.rid, "page_promote", pages=len(ext),
+        self._trace_emit(ticket.rec.rid, "page_promote", pages=len(ext),
                          prefix_len=n_h * page)
         reg = _registry()
         if reg is not None:
@@ -2047,20 +2058,19 @@ class Scheduler:
         if not tier.put(key, host_tree, n_full,
                         tree_digest(host_tree)):
             return  # oversized for the tier budget — refused, not LRUed
-        self._trace_emit(state.rid, "page_demote", pages=n_full,
+        self._trace_emit(state.rec.rid, "page_demote", pages=n_full,
                          tokens=len(key))
         reg = _registry()
         if reg is not None:
             from cloud_tpu.monitoring import telemetry
             reg.counter(telemetry.SERVE_PAGE_DEMOTES_TOTAL).inc(n_full)
 
-    def _distribute(self, fetched, elapsed):
+    def _distribute(self, fetched, elapsed, t_commit):
         n_active = sum(s is not None for s in self._slots)
         if n_active:
             self._token_hist.observe(elapsed, count=n_active)
             # Geometry stamp: tick latency and occupancy roll up under
-            # the rung this tick RAN at (kernel_costs() is likewise
-            # keyed per geometry), never a mixed aggregate.
+            # the rung this tick RAN at, never a mixed aggregate.
             g = self._geom()
             g["ticks"] += 1
             g["active_sum"] += n_active
@@ -2073,18 +2083,10 @@ class Scheduler:
                 reg.histogram(
                     telemetry.SERVE_TICK_SECONDS
                     % self.engine.slots).observe(elapsed)
-                # Kernel cost rows: one tick's paged-attention flops /
-                # bytes over its measured wall time — pct_peak and
-                # bytes_moved track the fused-kernel A/B alongside the
-                # token-latency p99 this histogram already exports.
-                for name, cost in self.engine.kernel_costs().items():
-                    telemetry.get().record_kernel_cost(
-                        name, cost["flops"], cost["bytes_moved"],
-                        elapsed)
         if self.engine.spec_on:
-            self._distribute_spec(fetched)
+            self._distribute_spec(fetched, t_commit)
         else:
-            self._distribute_plain(fetched)
+            self._distribute_plain(fetched, t_commit)
         trace = self._trace
         if trace is not None:
             # Batched tick commits: one event per tick_every ticks per
@@ -2093,31 +2095,32 @@ class Scheduler:
             # the slot-occupancy timeline without per-token event cost.
             every = trace.tick_every
             for state in self._slots:
-                if state is None or state.rid is None:
+                if state is None or state.rec.rid is None:
                     continue
                 state.trace_ticks += 1
                 if state.trace_ticks >= every:
                     state.trace_ticks = 0
-                    trace.emit(state.rid, "tick_commit",
+                    trace.record(state.rec.rid, "tick_commit",
                                tokens_committed=len(state.emitted),
                                active_slots=n_active,
                                ticks=self._ticks,
                                slots=self.engine.slots)
 
-    def _distribute_plain(self, fetched):
+    def _distribute_plain(self, fetched, t_commit):
         tokens_row, finished_row = fetched[0], fetched[1]
         evict_mask = np.zeros((self.engine.slots,), bool)
         for slot, state in enumerate(self._slots):
             if state is None:
                 continue
             state.emitted.append(int(tokens_row[slot]))
+            state.rec.token_times.append(t_commit)
             if finished_row[slot]:
                 self._finish_slot(slot, state, evict_mask)
         if evict_mask.any():
             self.engine.evict(evict_mask)
             self._observe_gauges()
 
-    def _distribute_spec(self, fetched):
+    def _distribute_spec(self, fetched, t_commit):
         from cloud_tpu.models.speculative import observe_accept_rate
 
         k = self.engine.spec_k
@@ -2131,6 +2134,7 @@ class Scheduler:
             c = int(count_row[slot])
             state.emitted.extend(
                 int(fetched[j][slot]) for j in range(c))
+            state.rec.token_times.extend([t_commit] * c)
             n_acc = int(accept_row[slot])
             if n_acc >= 0:
                 self._accepted_draft_tokens += n_acc
@@ -2148,13 +2152,11 @@ class Scheduler:
         self._free_slots.append(slot)
         self._maybe_demote(state)
         self.pool.free(state.pages)
-        self._complete(state.request, state.future, state.t_submit,
-                       state.ttft_s, state.emitted,
-                       prefix_len=state.result_prefix_len,
-                       rid=state.rid)
+        self._complete(state.request, state.future, state.rec,
+                       state.emitted,
+                       prefix_len=state.result_prefix_len)
 
-    def _complete(self, request, future, t_submit, ttft, emitted,
-                  prefix_len, rid=None):
+    def _complete(self, request, future, rec, emitted, prefix_len):
         # A speculative tick can overshoot max_new_tokens by up to
         # spec_k accepted tokens — the greedy chain is identical, so
         # truncation is exact.
@@ -2167,7 +2169,14 @@ class Scheduler:
         tokens = np.concatenate([
             np.asarray(request.prompt, np.int32),
             np.asarray(emitted, np.int32)])
-        latency = time.monotonic() - t_submit
+        # The record keeps one time a token the device produced: a
+        # speculative overshoot goes, the host's eos fill has none.
+        rec.new_tokens = min(1 + len(rec.token_times),
+                             rec.max_new_tokens)
+        del rec.token_times[rec.new_tokens - 1:]
+        rec.prefix_len = int(prefix_len)
+        now = time.monotonic()
+        ttft, latency = rec.ttft_s, now - rec.t_submit
         self._completed += 1
         self._tokens_out += request.max_new_tokens
         reg = _registry()
@@ -2179,20 +2188,40 @@ class Scheduler:
             wall = max(time.monotonic() - self._t_start, 1e-9)
             reg.gauge(telemetry.SERVE_REQUESTS_PER_SEC).set(
                 self._completed / wall)
-        self._trace_emit(rid, "complete", ttft_s=ttft,
-                         latency_s=latency,
-                         tokens=int(request.max_new_tokens),
-                         prefix_len=int(prefix_len))
+        self._mark(rec, "done", now, ttft_s=ttft, latency_s=latency,
+                   tokens=int(request.max_new_tokens),
+                   prefix_len=rec.prefix_len)
+        if rec.rid is not None:
+            reqtrace.publish(rec)
         future.set_result(ServeResult(tokens=tokens, ttft_s=ttft,
                                       latency_s=latency,
-                                      prefix_len=prefix_len))
+                                      prefix_len=prefix_len, trace=rec))
 
     # -- shared helpers -----------------------------------------------
+
+    #: The JSONL event each boundary of the record exports as (the
+    #: `admit` boundary is the record's alone).
+    _BOUNDARY_EVENTS = {"dequeued": "queued", "reserved": "pages_reserved",
+                        "first": "prefill", "insert": "slot_insert",
+                        "done": "complete"}
+
+    def _mark(self, rec, boundary, now=None, event=True, **fields):
+        """The one marking call: stamps `rec.t_<boundary>` and, where a
+        JSONL tracer is installed, exports the boundary's event with
+        `fields`. `event=False` marks a boundary the path passes
+        without work (an empty phase), which the JSONL never showed."""
+        setattr(rec, "t_" + boundary,
+                time.monotonic() if now is None else now)
+        if event and boundary in self._BOUNDARY_EVENTS:
+            self._trace_emit(rec.rid, self._BOUNDARY_EVENTS[boundary],
+                             **fields)
 
     def _trace_emit(self, rid, event, **fields):
         trace = self._trace
         if trace is not None and rid is not None:
-            trace.emit(rid, event, **fields)
+            # Buffered: the file is written when the buffer fills and
+            # at close(), never for the sake of one event.
+            trace.record(rid, event, **fields)
 
     def _trace_fail(self, rid, error):
         self._trace_emit(rid, "fail", error="{}: {}".format(
@@ -2261,7 +2290,7 @@ class Scheduler:
             if isinstance(item, _ReadyItem) and item.pages:
                 self.pool.free(item.pages)
             if not item.future.done():
-                self._trace_fail(item.rid, error)
+                self._trace_fail(item.rec.rid, error)
                 item.future.set_exception(error)
         for item in chunks:
             self._fail_chunk_item(item, error)
@@ -2273,16 +2302,16 @@ class Scheduler:
                 if state.pages:
                     self.pool.free(state.pages)
                 if not state.future.done():
-                    self._trace_fail(state.rid, error)
+                    self._trace_fail(state.rec.rid, error)
                     state.future.set_exception(error)
             self._slots[slot] = None
         while True:
             try:
-                _, future, _, rid, _ = self._admit_q.get_nowait()
+                _, future, rec, _ = self._admit_q.get_nowait()
             except queue.Empty:
                 break
             if not future.done():
-                self._trace_fail(rid, error)
+                self._trace_fail(rec.rid, error)
                 future.set_exception(error)
 
     # -- invariants ---------------------------------------------------
@@ -2331,9 +2360,10 @@ class Scheduler:
         sentinel is armed."""
         from cloud_tpu.models.decoding import bucket_length
 
-        # Warm-up requests are synthetic: stamp no rids and emit no
-        # trace events, so every traced lifecycle in the JSONL is real
-        # traffic and the zero-orphans CI assertion stays meaningful.
+        # Warm-up requests are synthetic: stamp no rids, emit no trace
+        # events and keep no records, so every lifecycle in the JSONL
+        # and in reqtrace.recent() is real traffic and the zero-orphans
+        # CI assertion stays meaningful.
         self._trace_suppress = True
         vocab = self.engine.model.vocab_size
         configs = []
@@ -2418,6 +2448,7 @@ class Scheduler:
         self._prefill_chunk_hist = Histogram("prefill_chunk")
         self._decode_gap_hist = Histogram("decode_gap")
         self._chunks_dispatched = 0
+        self._tick_paces = 0
         self._t_last_commit = None
         self._completed = 0
         self._tokens_out = 0
@@ -2525,6 +2556,7 @@ class Scheduler:
             "requests_completed": self._completed,
             "tokens_emitted": self._tokens_out,
             "ticks": self._ticks,
+            "tick_paces": self._tick_paces,
             "elapsed_seconds": wall,
             "requests_per_sec": self._completed / wall,
             "tokens_per_sec": self._tokens_out / wall,
@@ -2568,7 +2600,6 @@ class Scheduler:
                                    if g["ticks"] else 0.0),
                 "tick_latency": g["tick_hist"].snapshot(),
                 "decode_gap": g["decode_gap_hist"].snapshot(),
-                "kernel_costs": self.engine.kernel_costs(s),
             }
         out["geometry"] = {
             "slots": self.engine.slots,

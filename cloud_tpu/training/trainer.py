@@ -42,6 +42,11 @@ from cloud_tpu.training import data as data_lib
 
 logger = logging.getLogger("cloud_tpu")
 
+#: The training loop's program, named by the function that is jitted
+#: (`train_step` in `_make_train_step_body`): the trace's program line
+#: reads `jit_train_step` (table in monitoring/spans.py).
+TRAIN_STEP = "train_step"
+
 
 def _env_sanitized(method):
     """Runs a Trainer entry point under a graftsan env scope.
@@ -2166,9 +2171,9 @@ class Trainer:
             runtime.set_phase("step")
             # graftscope: the whole step-loop section is one "step"
             # span; each feeder iteration becomes a "train_step" span
-            # containing "data_wait" + "dispatch". begin() is None and
-            # trace_steps is skipped when telemetry is off, so the
-            # disabled hot loop is unchanged.
+            # containing "data_wait" + "dispatch". Always on: each is a
+            # profiler annotation (a flag test while no profile is
+            # captured) and a SpanTracer record when telemetry is on.
             step_section = spans_lib.begin("step")
             spe = self.steps_per_execution
             multi_step = getattr(self, "_jit_multi_step", None)
@@ -2183,8 +2188,7 @@ class Trainer:
                     size=prefetch,
                     feed=lambda item: unpack(item) + (
                         self._feed_grouped(item),))
-                if spans_lib.enabled():
-                    feeder = spans_lib.trace_steps(feeder)
+                feeder = spans_lib.trace_steps(feeder)
                 first = True
                 for kind, batch_examples, w_sum, fed in feeder:
                     if self._abort_epoch:
@@ -2295,8 +2299,7 @@ class Trainer:
                 feed=lambda item: unpack(item) + (
                     self._feed(item[2][0] if item[0] == "padded"
                                else item[2]),))
-            if spans_lib.enabled():
-                feeder = spans_lib.trace_steps(feeder)
+            feeder = spans_lib.trace_steps(feeder)
             for kind, batch_examples, w_sum, batch in feeder:
                 if self._abort_epoch:
                     break

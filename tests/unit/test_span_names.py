@@ -1,0 +1,320 @@
+"""The names the program declares for its spans, kernels and programs.
+
+`monitoring/spans.py`'s docstring tables are the contract the benchmark's
+readers, the docs and `cellbench/tools/spans.py` key on. Here the code is
+held to them: every span the program opens stands in the table and the
+other way round, each Pallas call carries its declared name in the jaxpr,
+each program of the two hot loops lowers to a module named after it, and
+under a short profile capture the spans land on the profiler's host plane
+beside the ops, those of one request under its rid. All at toy widths on
+the CPU.
+"""
+
+import glob
+import importlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cloud_tpu.monitoring import spans
+from cloud_tpu.ops import fused_mlp, fused_norm
+from cloud_tpu.serving import engine as engine_lib
+from cloud_tpu.training import trainer as trainer_lib
+
+# `cloud_tpu.ops` exports functions under these two modules' names.
+attention_ops = importlib.import_module("cloud_tpu.ops.attention")
+paged_ops = importlib.import_module("cloud_tpu.ops.paged_attention")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+F32 = jnp.float32
+
+
+@pytest.fixture(autouse=True)
+def _no_tracer():
+    spans.uninstall()
+    yield
+    spans.uninstall()
+
+
+# ------------------------------------------------------------ the tables
+
+def _span_literals():
+    """Every name the program passes to span()/begin(), by reading its
+    source."""
+    call = re.compile(
+        r'spans(?:_lib)?\.(?:span|begin)\(\s*(?:"([a-z_0-9]+)"|([A-Z_]+))')
+    found = set()
+    for path in glob.glob(os.path.join(ROOT, "cloud_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path, encoding="utf-8") as f:
+            for literal, constant in call.findall(f.read()):
+                found.add(literal or constant)
+    return found
+
+
+def test_span_table_and_code_name_the_same_spans():
+    found = _span_literals()
+    # engine.py opens its program's span under the program's constant.
+    assert "SERVE_PREFILL" in found
+    found = (found - {"SERVE_PREFILL"}) | {engine_lib.SERVE_PREFILL}
+    # trace_steps() opens these two by its defaults.
+    found |= {"train_step", "data_wait"}
+    table = spans.names("Spans")
+    assert len(table) == len(set(table))
+    assert found == set(table)
+
+
+def test_histogram_spans_stand_in_the_table():
+    from cloud_tpu.monitoring import telemetry
+    assert set(telemetry.SPAN_HISTOGRAMS) <= set(spans.names("Spans"))
+
+
+def test_kernel_and_program_tables_equal_the_constants():
+    assert spans.names("Kernels") == (
+        attention_ops.FLASH_FWD, attention_ops.FLASH_BWD_DQ,
+        attention_ops.FLASH_BWD_DKV, fused_mlp.FUSED_SWIGLU_FWD,
+        fused_norm.FUSED_RMSNORM, fused_norm.FUSED_RMSNORM_RESIDUAL,
+        paged_ops.PAGED_DECODE)
+    assert spans.names("Programs") == (
+        trainer_lib.TRAIN_STEP, engine_lib.SERVE_TICK,
+        engine_lib.SERVE_PREFILL, engine_lib.SLOT_INSERT,
+        engine_lib.SLOT_EVICT)
+
+
+# --------------------------------------------------------------- kernels
+
+def _pallas_names(fn, *args):
+    """`name=` of every pallas_call equation in fn's jaxpr, nested
+    jaxprs (custom_vjp, pjit) included."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+                continue
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+def _flash(q, k, v):
+    return attention_ops.flash_attention(q, k, v, causal=True,
+                                         interpret=True)
+
+
+def _flash_loss(q, k, v):
+    return jnp.sum(_flash(q, k, v))
+
+
+def _kernel_cases():
+    q = jnp.ones((1, 128, 2, 64), F32)
+    x, w = jnp.ones((128, 128), F32), jnp.ones((128,), F32)
+    wide = jnp.ones((128, 256), F32)
+    pages = jnp.ones((5, 8, 128), F32)
+    yield ("flash_fwd", _flash, (q, q, q), [attention_ops.FLASH_FWD])
+    yield ("flash_bwd", jax.grad(_flash_loss, argnums=(0, 1, 2)),
+           (q, q, q), [attention_ops.FLASH_FWD, attention_ops.FLASH_BWD_DQ,
+                       attention_ops.FLASH_BWD_DKV])
+    yield ("fused_swiglu_fwd",
+           lambda x, g, u, d: fused_mlp.fused_swiglu(
+               x, g, u, d, impl="fused", interpret=True),
+           (x, wide, wide, wide.T), [fused_mlp.FUSED_SWIGLU_FWD])
+    yield ("fused_rmsnorm",
+           lambda x, w: fused_norm.fused_rmsnorm(
+               x, w, impl="fused", interpret=True),
+           (x, w), [fused_norm.FUSED_RMSNORM])
+    yield ("fused_rmsnorm_residual",
+           lambda x, w: fused_norm.fused_rmsnorm(
+               x, w, residual=x, impl="fused", interpret=True),
+           (x, w), [fused_norm.FUSED_RMSNORM_RESIDUAL])
+    yield ("paged_decode",
+           lambda q, kp, vp: paged_ops.paged_decode_attention(
+               q, kp, vp, jnp.zeros((2, 4), jnp.int32),
+               jnp.ones((2, 1, 32), bool), interpret=True),
+           (jnp.ones((2, 1, 2, 64), F32), pages, pages),
+           [paged_ops.PAGED_DECODE])
+
+
+@pytest.mark.parametrize("case", list(_kernel_cases()),
+                         ids=lambda case: case[0])
+def test_pallas_call_carries_its_declared_name(case):
+    _, fn, args, declared = case
+    got = _pallas_names(fn, *args)
+    assert len(got) == len(declared), got
+    for name, want in zip(sorted(got), sorted(declared)):
+        # The declared name is the call's last dotted component (the
+        # attention kernels keep the accepted readers' prefix in front).
+        assert name.split(".")[-1] == want, (name, want)
+
+
+def test_attention_calls_keep_the_accepted_readers_prefix():
+    """`flash_roofline` and `paged_attn_roofline` (benchmark files this
+    repo may not edit) find the kernels by what stands before ` = ` in
+    the trace, which XLA:TPU takes from the call's name."""
+    from cellbench.layer_metrics import flash_roofline, paged_attn_roofline
+
+    text = "%{}.7 = bf16[8] custom-call(bf16[8] %a)"
+    for declared in (attention_ops.FLASH_FWD, attention_ops.FLASH_BWD_DQ,
+                     attention_ops.FLASH_BWD_DKV):
+        name = attention_ops._CALL_PREFIX + declared
+        assert flash_roofline.is_flash(text.format(name))
+        assert not paged_attn_roofline.is_paged(text.format(name))
+    name = paged_ops._CALL_PREFIX + paged_ops.PAGED_DECODE
+    assert paged_attn_roofline.is_paged(text.format(name))
+    assert not flash_roofline.is_flash(text.format(name))
+
+
+# -------------------------------------------------------------- programs
+
+@pytest.fixture(scope="module")
+def toy_engine():
+    from cloud_tpu.models import TransformerLM
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=32, d_ff=64, max_seq_len=32,
+                          compute_dtype=F32)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    return engine_lib.DecodeEngine(model, params, slots=2, page_size=8,
+                                   num_pages=9), params
+
+
+def _module_name(lowered):
+    return re.search(r"module @(\w+)", lowered.as_text()).group(1)
+
+
+def _serve_programs(toy):
+    engine, params = toy
+    from cloud_tpu.models.decoding import acquire_cache
+
+    dense = engine_lib._plain(acquire_cache(engine._dense, 1))
+    vec = jnp.zeros((engine.pages_per_slot,), jnp.int32)
+    keys = jnp.zeros((engine.max_new_cap - 1, 2), jnp.uint32)
+    scalars = (np.int32(0), vec, vec, keys, np.int32(2), np.int32(1),
+               np.float32(0.0), np.int32(64), np.float32(1.0), np.int32(0),
+               False)
+    prefill = engine_lib._serve_prefill_fns(engine._dense, 0.0, None, None)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    return {
+        engine_lib.SERVE_TICK: (engine._tick,
+                                (params, engine.cache, engine.ctl)),
+        engine_lib.SLOT_INSERT: (engine._insert,
+                                 (engine.cache, engine.ctl, dense) + scalars),
+        engine_lib.SLOT_EVICT: (engine._evict,
+                                (engine.cache, engine.ctl,
+                                 jnp.zeros((2,), bool))),
+        engine_lib.SERVE_PREFILL: (prefill,
+                                   (params, dense, tokens,
+                                    jax.random.PRNGKey(0),
+                                    jnp.ones((1, 8), bool), np.int32(7))),
+    }
+
+
+@pytest.mark.parametrize("program", [
+    engine_lib.SERVE_TICK, engine_lib.SERVE_PREFILL, engine_lib.SLOT_INSERT,
+    engine_lib.SLOT_EVICT])
+def test_serving_program_lowers_under_its_declared_name(toy_engine, program):
+    fn, args = _serve_programs(toy_engine)[program]
+    # best_effort_donation wraps the InstrumentedJit it was given.
+    assert _module_name(fn.__wrapped__.lower(*args)) == "jit_" + program
+
+
+def test_train_step_lowers_under_its_declared_name():
+    import optax
+
+    from cloud_tpu.models import MLP
+    from cloud_tpu.parallel import runtime
+    from cloud_tpu.training import Trainer
+
+    runtime.reset()
+    trainer = Trainer(MLP(hidden=8, num_classes=4),
+                      optimizer=optax.sgd(0.1))
+    x = np.zeros((8, 3), np.float32)
+    y = np.zeros((8,), np.int32)
+    trainer.fit(x, y, epochs=1, batch_size=8, verbose=False)
+    lowered = trainer._jit_train_step.lower(
+        trainer.state, (jnp.asarray(x), jnp.asarray(y)))
+    assert _module_name(lowered) == "jit_" + trainer_lib.TRAIN_STEP
+
+
+# ----------------------------------------------------- the seam's sinks
+
+def test_span_without_tracer_or_profile_reaches_no_sink(tmp_path,
+                                                        monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    assert spans.current_tracer() is None
+    with spans.span("serve_tick"):
+        with spans.span("serve_prefill", rid="r1"):
+            pass
+    spans.end(spans.begin("step"))
+    assert list(spans.trace_steps([1, 2])) == [1, 2]
+    assert spans.current_tracer() is None and os.listdir(tmp_path) == []
+
+
+def test_span_with_tracer_records_as_before():
+    tracer = spans.install()
+    with spans.span("serve_prefill", rid="r7"):
+        with spans.span("prefill_host", rid=None):
+            pass
+    handle = spans.begin("step")
+    assert spans.end(handle) >= 0
+    events = tracer.events()
+    assert [e[0] for e in events] == ["prefill_host", "serve_prefill",
+                                      "step"]
+    inner, outer = events[0], events[1]
+    assert outer[2] <= inner[2] and inner[2] + inner[3] <= outer[2] + outer[3]
+    assert spans.names("Spans")[0] == "step"
+
+
+def test_profile_capture_holds_the_spans_and_a_rid(tmp_path):
+    """Under a capture the program's spans land in the `.xplane.pb`'s host
+    plane, as the benchmark's reducer loads it, and carry the request."""
+    from cellbench import tracing
+    from cloud_tpu.models import TransformerLM
+    from cloud_tpu.serving import Scheduler, ServeRequest
+
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=32, d_ff=64, max_seq_len=32,
+                          compute_dtype=F32)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    with Scheduler(model, params, slots=2, page_size=8) as sched:
+        sched.warmup([8], sampling_configs=[(("temperature", 0.0),)])
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            results = [sched.submit(ServeRequest(
+                prompt=[3, 5, 7, i], max_new_tokens=3,
+                temperature=0.0)).result(timeout=300) for i in (1, 2)]
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    _, _, host, _ = tracing.load_xplane(path)
+    assert {"serve_tick", "tick_dispatch", "tick_fetch", "serve_prefill",
+            "tick_commit", "admit"} <= set(host.names)
+    rids = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name in ("admit", "serve_prefill",
+                                  "prefill_dispatch"):
+                    rids.setdefault(event.name, set()).add(
+                        dict(event.stats).get("rid"))
+    wanted = {r.trace.rid for r in results}
+    assert None not in wanted
+    # Spans of one request share its rid.
+    assert rids["admit"] == rids["serve_prefill"] == wanted
+    assert rids["prefill_dispatch"] == wanted
