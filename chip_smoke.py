@@ -103,8 +103,9 @@ def kernels_phase(train_widths, serve_widths, seq=1024, slots=8,
     """Each Pallas kernel against its reference at the two models'
     widths: flash fwd+bwd (causal, masked, the train model's GQA
     group), fused RMSNorm fwd+bwd, fused SwiGLU fwd, paged decode over
-    bf16 and int8 pages. `interpret` is for the CPU test only. Returns
-    {kernel: worst relative error}."""
+    bf16 and int8 pages for the plain tick and a verify window.
+    `interpret` is for the CPU test only. Returns {kernel: worst
+    relative error}."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -191,26 +192,37 @@ def kernels_phase(train_widths, serve_widths, seq=1024, slots=8,
     page_table = jnp.asarray(
         1 + rng.permutation(slots * pages_per_slot).reshape(
             slots, pages_per_slot), jnp.int32)
-    depth = np.linspace(1, cache_len, slots).astype(int)
-    allowed = jnp.asarray(
-        np.arange(cache_len)[None, None, :] < depth[:, None, None])
-    q = normal(slots, 1, heads, head_dim)
-    paged_args = (q, normal(*pool), normal(*pool), page_table, allowed)
-    compare(
-        "paged_bf16",
-        lambda *a: ops.paged_decode_attention(*a, interpret=interpret),
-        ops.paged_attention_reference, paged_args, ())
+    pools = (normal(*pool), normal(*pool))
     int8 = lambda: jnp.asarray(rng.integers(-127, 128, pool), jnp.int8)
     scales = lambda: jnp.asarray(
         rng.uniform(0.5, 1.5, (num_pages, heads)) / 127.0, jnp.float32)
-    compare(
-        "paged_int8",
-        lambda q, kp, vp, pt, al, ks, vs: ops.paged_decode_attention(
-            q, kp, vp, pt, al, interpret=interpret, key_scales=ks,
-            value_scales=vs),
-        lambda q, kp, vp, pt, al, ks, vs: ops.paged_attention_reference(
-            q, kp, vp, pt, al, key_scales=ks, value_scales=vs),
-        (q, int8(), int8(), page_table, allowed, scales(), scales()), ())
+    quantized = (int8(), int8(), scales(), scales())
+    # The plain tick (one query row) and a verify window of four: row
+    # i of a window sees the slot's depth less the rows after it.
+    for window, tag in ((1, ""), (4, "_seq4")):
+        depth = np.linspace(window, cache_len, slots).astype(int)
+        upto = depth[:, None] - (window - 1) + np.arange(window)
+        allowed = jnp.asarray(
+            np.arange(cache_len)[None, None, :] < upto[:, :, None])
+        q = normal(slots, window, heads, head_dim)
+        paged_args = (q,) + pools + (page_table, allowed)
+        if window == 1:
+            tick_args = paged_args
+        compare(
+            "paged_bf16" + tag,
+            lambda *a: ops.paged_decode_attention(*a,
+                                                  interpret=interpret),
+            ops.paged_attention_reference, paged_args, ())
+        compare(
+            "paged_int8" + tag,
+            lambda q, kp, vp, pt, al, ks, vs: ops.paged_decode_attention(
+                q, kp, vp, pt, al, interpret=interpret, key_scales=ks,
+                value_scales=vs),
+            lambda q, kp, vp, pt, al, ks, vs:
+            ops.paged_attention_reference(
+                q, kp, vp, pt, al, key_scales=ks, value_scales=vs),
+            (q,) + quantized[:2] + (page_table, allowed)
+            + quantized[2:], ())
 
     bad = sorted(k for k, v in errs.items() if not v <= tol)
     print("kernels: {} tol={:g} ".format("FAILED" if bad else "ok", tol)
@@ -220,13 +232,13 @@ def kernels_phase(train_widths, serve_widths, seq=1024, slots=8,
     if not interpret:
         # What was checked above is what `auto` dispatches to here.
         picks = {
-            "attention": lambda: ops.attention(q, q, q),
+            "attention": lambda: ops.attention(*tick_args[:1] * 3),
             "fused_rmsnorm": lambda: ops.fused_rmsnorm(
                 x, jnp.ones((d_model,), jnp.float32)),
             "fused_swiglu": lambda: ops.fused_swiglu(
                 x, *(jnp.zeros(s, bf16) for s in (
                     (d_model, 128), (d_model, 128), (128, d_model)))),
-            "paged_attention": lambda: ops.paged_attention(*paged_args),
+            "paged_attention": lambda: ops.paged_attention(*tick_args),
         }
         for name, call in picks.items():
             _check("pallas_call" in str(jax.make_jaxpr(call)()),

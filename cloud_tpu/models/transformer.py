@@ -228,14 +228,15 @@ class CausalSelfAttention(nn.Module):
 
         # Impl selection (ops/paged_attention.py): "auto" runs the
         # Pallas paged kernel on TPU — the page table rides as a
-        # scalar-prefetch operand, so the pool is block-indexed page by
-        # page with online softmax in VMEM, never materialized as a
-        # dense [slots, cache_len, H, D] gather — and the gathered-lax
-        # reference elsewhere, which is bitwise the dense path's math
-        # (engine-vs-solo bit-identity). CLOUD_TPU_PAGED_KERNEL=1/0
-        # force-overrides. Every paged decode — engine tick,
-        # speculative verify window, solo paged decode — routes
-        # through this one call.
+        # scalar-prefetch operand, so the pool is block-indexed a
+        # group of pages a grid step, as far as `allowed` has a slot
+        # live and no further, with online softmax in VMEM, never
+        # materialized as a dense [slots, cache_len, H, D] gather —
+        # and the gathered-lax reference elsewhere, which is bitwise
+        # the dense path's math (engine-vs-solo bit-identity).
+        # CLOUD_TPU_PAGED_KERNEL=1/0 force-overrides. Every paged
+        # decode — engine tick, speculative verify window, solo paged
+        # decode — routes through this one call.
         from cloud_tpu.ops import paged_attention
         return paged_attention(
             q, key_pages.value, value_pages.value, page_table.value,

@@ -12,27 +12,56 @@ row-major before every call — at H*D wide the device layout is the
 kernel's own. The lax path materializes a dense
 `[slots, cache_len, H, D]` view by gathering the pool through the page
 table every tick; this kernel never does — the page table rides as a
-scalar-prefetch operand, so each grid step's K/V block is *indexed*
+scalar-prefetch operand, so each grid step's K/V blocks are *indexed*
 straight out of the pool in HBM (the gather becomes block addressing)
 and streamed through VMEM with FlashAttention-style online softmax.
 
 Grid and masking contract (see /opt/skills/guides/pallas_guide.md):
-- Grid is (slots, pages_per_slot) with the page dimension innermost.
-  Program (s, j) serves every head of slot s against logical page j;
-  its K/V block is the whole physical page `page_table[s, j]`
-  (`[P, H*D]`, the array's full trailing dims, which is what Mosaic's
-  block rule asks of a 16-row page) — `PrefetchScalarGridSpec` places
-  the table in SMEM before the kernel runs so the BlockSpec index maps
-  can read it.
-- Heads stay folded in lanes. Per query row the scores are
-  `(k * q_row) @ seg`, with `seg` the `[H*D, H]` 0/1 matrix that sums
-  each head's D lanes, giving `[P, H]` (keys on sublanes, heads on
-  lanes); `p @ seg.T` spreads the probabilities back over the lanes to
-  weight V. No per-head slicing or relayout; the matmuls are f32 at
-  HIGHEST precision, so the sums are the reference's f32 accumulation.
-- VMEM scratch (acc `[seq, H*D]`, m/l `[seq, H]`) carries the
-  online-softmax state across page steps; the output block is written
-  on the last page step.
+- The walk goes a *group* of G logical pages at a time and ends at
+  the slot's last live page. `live_pages[s]` is 1 + the last page on
+  which `allowed` lets any query row of slot s attend (0 for an
+  evicted slot), taken from the mask the caller already gives; a slot
+  takes `live_groups(live_pages[s], G)` steps and no more, so a slot
+  500 tokens deep costs 4 steps of 128 keys, not its table's 64 pages.
+- The grid has one dimension, the steps of every slot in turn, and
+  its size is a value of the call (a dynamic grid bound): `_schedule`
+  lists per step its slot and group, and the lists, the page table and
+  `live_pages` are the scalar-prefetch operands
+  (`PrefetchScalarGridSpec`, placed in SMEM before the kernel runs so
+  the BlockSpec index maps can read them). A grid of
+  (slots, groups) with the dead steps skipped inside cost 1.5 us a
+  dead step on the v5e, most of a chat tick; so the dead steps are not
+  in the grid. An evicted slot keeps one step, which computes nothing
+  and writes its zeros.
+- Step t's K (and V) arrive as G blocks, each the whole physical page
+  `page_table[slot[t], group[t] * G + g]` (`[P, H*D]`, the array's
+  full trailing dims, which is what Mosaic's block rule asks of a
+  16-row page): the pool is passed G times and Pallas's own pipeline
+  double-buffers the 2G copies of the next step behind this one. The
+  table is padded to whole groups with the scratch page and the mask
+  with False, so `pages_per_slot` need not be a multiple of G. (The
+  other form, the pool left in HBM and the kernel issuing its own
+  `make_async_copy`s for live pages only, does not compile for a
+  1600-wide pool: this Mosaic refuses an HBM slice whose minor extent
+  is not a multiple of 128. PERF.md section 6, PR 27.)
+- G comes from the shapes (`group_pages`): the most pages whose
+  blocks fit `_VMEM_BUDGET`, a whole number of 128-key lane tiles — 8
+  for the serve cells' 16-token bf16 pages 1600 wide, 16 for int8
+  pages, more under a tp mesh where the local width is a share.
+- Heads stay folded in lanes and keys go on lanes too. The query
+  is laid block-diagonal once a slot, `qbd[(i, h), c] = q[i, c]` on
+  head h's lanes and 0 elsewhere (heads padded to a multiple of 8
+  sublanes), so one product `qbd @ k.T` gives `[seq * H8, G * P]`
+  scores for every head and query row, and `p @ v` gives
+  `[seq * H8, H*D]` of which row (i, h) is kept on head h's lanes at
+  the end. bf16 (and int8) pages meet a bf16 query in one native MXU
+  pass with f32 accumulation — exact products, the reference's own
+  `einsum(..., preferred_element_type=f32)` — and the probabilities
+  are cast as the reference casts them (bf16 for bf16 pages); f32
+  operands run at HIGHEST. No per-head slicing or relayout.
+- VMEM scratch (qbd and acc `[seq * H8, H*D]`, m/l `[seq * H8, 128]`)
+  carries the online-softmax state across a slot's steps; the output
+  block is written on the slot's last step.
 - Masking is purely the caller's `allowed [slots, seq, cache_len]`
   (from `decoding.paged_slot_update`): it already encodes per-query
   causality over *logical* key slots plus slot validity, so freed /
@@ -40,16 +69,17 @@ Grid and masking contract (see /opt/skills/guides/pallas_guide.md):
   kernel zeroes masked probabilities explicitly (`p = where(mask, ...)`)
   rather than relying on exp underflow, so a fully-masked row (e.g. a
   padded query row or an evicted slot) outputs zeros, never a uniform
-  average over pool garbage.
+  average over pool garbage. Holes inside the live range are the
+  mask's, as before: the live bound only ends the walk.
 - `seq` (1 for the plain tick, spec_k + 1 for the speculative verify
-  window) is a static unrolled loop over query rows.
+  window) rides in the rows of the one product a step.
 
 The gathered-lax reference below is bitwise the math
 `models/transformer.py::_paged_decode_attention` shipped before this
 kernel (gather -> f32 einsum -> -1e30 mask -> softmax -> cast ->
 einsum), so engine-vs-solo bit-identity pins keep holding wherever the
 reference is selected. Off-TPU the kernel path executes as
-`_paged_walk_lax` — the same page-block walk and online-softmax update
+`_paged_walk_lax` — the same walk by groups and online-softmax update
 order, vectorized in lax (Mosaic can't compile there, and Pallas
 interpret mode is two orders of magnitude too slow for a serving
 tick) — which is what the `CLOUD_TPU_PAGED_KERNEL=1` smoke measures;
@@ -63,12 +93,14 @@ the dequant contract, identical across kernel/walk/reference:
 
     k_f32 = k_int8.astype(f32) * scale[page, head]
 
-and both the QK and PV dots run in f32 (int8 quantization already
-costs ~0.4% relative error, so bf16 intermediate rounding would
-dominate it). In the kernel the page's `[1, H]` scale row is one more
-VMEM block indexed through the page table (not SMEM, whose size would
-cap the pool); the dequant folds into the `[P, H]` scores and
-probabilities as a per-head multiply, and nothing dequantized is ever
+and the PV dot runs in f32 (int8 quantization already costs ~0.4%
+relative error, so bf16 rounding of the probabilities would dominate
+it); the QK dot takes the int8 values as bf16, which holds them
+exactly. In the kernel a group's scales are one more VMEM block,
+`[H8, G]` (heads on sublanes like the scores' rows), gathered through
+the page table before the call — a few KB, not the pool; the dequant
+folds into the `[rows, keys]` scores and probabilities as a multiply
+by the page's scale over its keys, and nothing dequantized is ever
 materialized in HBM. The walk and reference grow the same math,
 so the parity suite covers all three impls in int8 mode too. A zero
 scale means an all-zero (never-written) page and dequantizes to exact
@@ -105,6 +137,13 @@ _CALL_PREFIX = "attention._paged_decode_attention."
 
 _NEG_INF = -1e30
 _HIGHEST = jax.lax.Precision.HIGHEST
+_LANES = 128
+_SUBLANES = 8
+
+#: VMEM a grid step's group of pages may take (`group_pages`). Fixed
+#: on the v5e at the serve cells' shape (16-token bf16 pages 1600
+#: wide, seq 1), where it gives G = 8: PERF.md section 6, PR 27.
+_VMEM_BUDGET = 6 * 1024 * 1024
 
 
 class _PagedConfig(NamedTuple):
@@ -112,6 +151,7 @@ class _PagedConfig(NamedTuple):
     heads: int
     seq: int
     page_size: int
+    group: int
     interpret: bool
     quantized: bool = False
 
@@ -186,126 +226,267 @@ def paged_attention_reference(q, key_pages, value_pages, page_table,
 
 
 # ---------------------------------------------------------------------------
+# The walk's geometry
+# ---------------------------------------------------------------------------
+
+
+def _round_up(x, multiple):
+    return -(-x // multiple) * multiple
+
+
+def group_pages(page_size, heads, width, itemsize, seq, pages_per_slot):
+    """G, the logical pages one grid step serves.
+
+    The most pages whose VMEM fits `_VMEM_BUDGET`: per page a K and a
+    V block, each double-buffered by the pipeline at the page itemsize
+    and joined once into the step's f32-or-narrower operand; per call
+    the `[seq * H8, width]` block-diagonal query, accumulator and
+    step product. Where a slot has more pages than that, G is a whole
+    number of 128-key lane tiles (the scores are `[rows, G * page_size]`
+    with keys on lanes, and the mask block must be lane-aligned), and
+    never less than one.
+    """
+    lanes = _round_up(width, _LANES)
+    rows = seq * _round_up(heads, _SUBLANES)
+    per_page = 2 * page_size * lanes * (2 * itemsize + 4)
+    fixed = 3 * rows * lanes * 4
+    tile = math.lcm(page_size, _LANES) // page_size
+    fit = max(_VMEM_BUDGET - fixed, 0) // per_page
+    group = max(fit // tile, 1) * tile
+    return pages_per_slot if group >= pages_per_slot else group
+
+
+def live_groups(live_pages, group):
+    """Groups the walk serves for a slot whose last live page is
+    `live_pages - 1`: the grid steps that compute, and (times
+    `group`) the pages it fetches. Python ints or traced scalars."""
+    return (live_pages + group - 1) // group
+
+
+def walked_tokens(depth, page_size, group):
+    """Keys the kernel's walk fetches for a slot `depth` tokens deep:
+    the depth rounded up to whole groups (the scheduler's
+    `kv_walked_tokens`)."""
+    pages = -(-depth // page_size)
+    return live_groups(pages, group) * group * page_size
+
+
+# ---------------------------------------------------------------------------
 # Kernel
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(pt_ref, q_ref, k_ref, v_ref, a_ref, seg_ref,
-                  segt_ref, *rest, config, num_pages):
-    """One (slot, logical page) step for every head. Int8 pages bring
-    two more inputs, the page's `[1, H]` K and V scale rows:
-    `s = ((k_i8 * q) @ seg) * (ks * sm_scale)` and
-    `acc += sum_p (p * vs) @ seg.T * v_i8` are exactly the pre-dot
-    dequant contract because a scale is constant over its head's
-    lanes."""
+def _operand_dtypes(q_dtype, page_dtype):
+    """(QK operand dtype, PV operand dtype). bf16 pages (and int8
+    pages, whose values bf16 holds exactly) meet a bf16 query in one
+    native MXU pass — exact products, f32 accumulation: the
+    reference's `einsum(..., preferred_element_type=f32)`. The
+    probabilities are cast as the reference casts them: to bf16 for
+    bf16 pages, else f32 (f32 and int8 pages; f32 operands run at
+    HIGHEST)."""
+    narrow = (q_dtype == jnp.bfloat16
+              and page_dtype in (jnp.bfloat16, jnp.int8))
+    qk = jnp.bfloat16 if narrow else jnp.float32
+    pv = jnp.bfloat16 if page_dtype == jnp.bfloat16 else jnp.float32
+    return qk, pv
+
+
+def _dot(a, b, contract_b):
+    """a [m, k] with b [k, n] (contract_b 0) or b [n, k] (1) -> f32."""
+    precision = _HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(
+        a, b, (((1,), (contract_b,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+
+
+def _paged_kernel(slot_ref, group_ref, pt_ref, live_ref, q_ref, a_ref,
+                  segt_ref, *rest, config):
+    """One step of the walk: every head of one slot against one group
+    of G logical pages. The grid runs over the live groups alone;
+    step t serves group `group_ref[t]` of slot `slot_ref[t]`.
+
+    rest: G key-page blocks, G value-page blocks, (int8: the group's
+    `[H8, G]` K and V scale blocks), the output block, then scratch:
+    the block-diagonal query `[seq * H8, width]`, acc (same shape,
+    f32), m and l `[seq * H8, 128]`, the `[seq, width]` f32 output
+    rows."""
     del pt_ref  # consumed by the BlockSpec index maps
+    group, seq, page = config.group, config.seq, config.page_size
+    k_refs, v_refs, rest = rest[:group], rest[group:2 * group], rest[
+        2 * group:]
     if config.quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    ji = pl.program_id(1)
-    page = config.page_size
+        ks_ref, vs_ref, *rest = rest
+    o_ref, qbd_ref, acc_ref, m_ref, l_ref, rows_ref = rest
+    ti = pl.program_id(0)
+    ji = group_ref[ti]
+    steps = live_groups(live_ref[slot_ref[ti]], group)
+    hp = qbd_ref.shape[0] // seq          # heads, padded to sublanes
+    keys = group * page
+    qk_dtype, pv_dtype = _operand_dtypes(q_ref.dtype, k_refs[0].dtype)
 
     @pl.when(ji == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        # Row (i, h) of the block-diagonal query is query row i on
+        # head h's lanes and zero elsewhere, so one [rows, width] x
+        # [keys, width]^T product gives every head's scores.
+        for i in range(seq):
+            q = q_ref[0, i:i + 1, :].astype(jnp.float32)
+            qbd_ref[i * hp:(i + 1) * hp, :] = (
+                q * segt_ref[...]).astype(qk_dtype)
 
-    def dot(a, b):
-        return jnp.dot(a, b, precision=_HIGHEST,
-                       preferred_element_type=jnp.float32)
+    def per_row(blocks):
+        """`seq` blocks `[H8, keys]` or `[1, keys]`, one a query row
+        -> `[seq * H8, keys]`."""
+        tiles = [jnp.broadcast_to(b, (hp, keys)) for b in blocks]
+        return tiles[0] if seq == 1 else jnp.concatenate(tiles, axis=0)
 
-    k = k_ref[0].astype(jnp.float32)     # [P, H*D], page pt[slot, ji]
-    v = v_ref[0].astype(jnp.float32)
-    seg = seg_ref[...]                   # [H*D, H]
-    segt = segt_ref[...]                 # [H, H*D]
-    scale = config.sm_scale
-    if config.quantized:
-        scale = ks_ref[0] * config.sm_scale          # [1, H]
-    for i in range(config.seq):
-        row = slice(i, i + 1)
-        q = q_ref[0, row, :].astype(jnp.float32)     # [1, H*D]
-        s = dot(k * q, seg) * scale                  # [P, H]
-        mask = a_ref[0, 0, i * page:(i + 1) * page, :] != 0  # [P, 1]
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[row, :]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)             # [1, H]
-        # Explicit zero where masked: exp(s - m) underflows to 0 for
-        # normal rows, but a fully-masked row (evicted slot, scratch
-        # page) has m == s == -inf and exp(0) == 1 would leak pool
-        # garbage.
-        p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
-        l_ref[row, :] = alpha * l_ref[row, :] + jnp.sum(
-            p, axis=0, keepdims=True)
+    def spread(scales_ref, lane_page):
+        """The group's [H8, G] scale block -> [rows, keys]: page g's
+        scale over its `page` key lanes, the same for every query
+        row."""
+        out = jnp.zeros((hp, keys), jnp.float32)
+        for g in range(group):
+            out = jnp.where(lane_page == g,
+                            scales_ref[0, 0, :, g:g + 1], out)
+        return per_row([out] * seq)
+
+    @pl.when(ji < steps)        # false only on an evicted slot's step
+    def _step():
+        join = lambda refs, dtype: jnp.concatenate(
+            [r[0].astype(dtype) for r in refs], axis=0)
+        k = join(k_refs, qk_dtype)                   # [keys, width]
+        v = join(v_refs, pv_dtype)
+        s = _dot(qbd_ref[...], k, 1) * config.sm_scale  # [rows, keys]
         if config.quantized:
-            p = p * vs_ref[0]
-        pv = jnp.sum(dot(p, segt) * v, axis=0, keepdims=True)
-        acc_ref[row, :] = acc_ref[row, :] * dot(alpha, segt) + pv
-        m_ref[row, :] = m_next
+            lane_page = jax.lax.broadcasted_iota(
+                jnp.int32, (hp, keys), 1) // page
+            s = s * spread(ks_ref, lane_page)
+        mask = per_row(
+            [a_ref[0, i:i + 1, :] for i in range(seq)]) != 0
+        s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)             # [rows, 1]
+        # Explicit zero where masked: exp(s - m) underflows to 0 for
+        # normal rows, but a fully-masked row (a padded head row, a
+        # hole that covers the whole group) has m == s == -inf and
+        # exp(0) == 1 would leak pool garbage.
+        p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
+        l_next = alpha * l_ref[:, :1] + jnp.sum(p, axis=1,
+                                                keepdims=True)
+        if config.quantized:
+            p = p * spread(vs_ref, lane_page)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(
+            p.astype(pv_dtype), v, 0)
+        m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
 
-    @pl.when(ji == num_pages - 1)
+    @pl.when(ji + 1 >= steps)
     def _finalize():
-        l = l_ref[...]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / dot(safe_l, segt)).astype(
-            o_ref.dtype)
+        l = l_ref[:, :1]
+        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        # Row (i, h) holds head h's output on head h's lanes (and
+        # other heads' keys against this head's weights elsewhere):
+        # keep the diagonal blocks.
+        for i in range(seq):
+            rows_ref[i:i + 1, :] = jnp.sum(
+                out[i * hp:(i + 1) * hp] * segt_ref[...], axis=0,
+                keepdims=True)
+        o_ref[0] = rows_ref[...].astype(o_ref.dtype)
+
+
+def _schedule(live_pages, group, most):
+    """The walk as a list of grid steps: per step its slot and its
+    group, and the number of steps. A slot takes `live_groups` steps,
+    and an evicted slot one, which computes nothing and writes its
+    zeros; entries past the count (to `most`) are never run. Sums
+    over a [steps, slots] comparison: a few dozen integers, no scan
+    and no gather."""
+    steps = jnp.maximum(live_groups(live_pages, group), 1)
+    slots = steps.shape[0]
+    upto = jnp.arange(slots)[:, None] >= jnp.arange(slots)[None, :]
+    ends = jnp.sum(jnp.where(upto, steps[None, :], 0), axis=1)
+    t = jnp.arange(most, dtype=jnp.int32)
+    past = t[:, None] >= ends[None, :]      # step t is past slot s
+    slot_of = jnp.minimum(jnp.sum(past, axis=1), slots - 1)
+    group_of = t - jnp.sum(jnp.where(past, steps[None, :], 0), axis=1)
+    return (slot_of.astype(jnp.int32), group_of.astype(jnp.int32),
+            ends[-1].astype(jnp.int32))
 
 
 def _paged_forward(config, q, key_pages, value_pages, page_table,
-                   allowed, key_scales=None, value_scales=None):
-    """q: [S, seq, H*D]; allowed: [S, pages_per_slot, seq*P, 1] int32;
-    scales (int8 mode): [N, 1, H] -> out [S, seq, H*D].
+                   live_pages, allowed, key_scales=None,
+                   value_scales=None):
+    """q: [S, seq, H*D]; page_table: [S, groups * G] (padded with the
+    scratch page); live_pages: [S]; allowed: [S, seq, groups * G * P]
+    int32; scales (int8 mode): [S, groups, H8, G] -> out [S, seq, H*D].
 
-    The page table is the scalar-prefetch operand: index maps read
-    `pt[s, j]` to address each program's physical K/V page (and, in
-    int8 mode, its scale rows), so the pool is only ever touched at the
-    pages a slot actually owns.
+    The schedule (`_schedule`), the page table and the live bound
+    are the scalar-prefetch operands and the grid's one dimension is
+    the schedule's length, a value of the call: block g of step t's K
+    (and V) is physical page `pt[slot[t], group[t] * G + g]`, so the
+    pool is only ever touched at the pages of groups a slot has live.
     """
     slots, seq, width = q.shape
-    heads = config.heads
+    heads, group = config.heads, config.group
     page_size = config.page_size
-    pages_per_slot = page_table.shape[1]
-    kernel = functools.partial(_paged_kernel, config=config,
-                               num_pages=pages_per_slot)
-    # seg[c, h] = 1 where lane c belongs to head h.
-    seg = (jnp.arange(width)[:, None] // (width // heads)
-           == jnp.arange(heads)[None, :]).astype(jnp.float32)
-    operands = [page_table, q, key_pages, value_pages, allowed, seg,
-                seg.T]
+    hp = _round_up(heads, _SUBLANES)
+    rows = seq * hp
+    qk_dtype, _ = _operand_dtypes(q.dtype, key_pages.dtype)
+    kernel = functools.partial(_paged_kernel, config=config)
+    # segt[h, c] = 1 where lane c belongs to head h; rows past `heads`
+    # are zero, so the padded rows score 0 and are dropped at the end.
+    segt = (jnp.arange(hp)[:, None]
+            == jnp.arange(width)[None, :] // (width // heads)
+            ).astype(jnp.float32)
+    slot_of, group_of, total = _schedule(
+        live_pages, group, slots * (page_table.shape[1] // group))
+    operands = [slot_of, group_of, page_table, live_pages, q, allowed,
+                segt]
+    operands += [key_pages] * group + [value_pages] * group
     if config.quantized:
         operands += [key_scales, value_scales]
     operands = partition.common_vma(*operands)
 
-    whole = lambda shape: pl.BlockSpec(shape, lambda s, j, pt: (0, 0))
-    slot_block = pl.BlockSpec((1, seq, width),
-                              lambda s, j, pt: (s, 0, 0))
+    slot_block = pl.BlockSpec(
+        (1, seq, width), lambda t, slot, grp, pt, live: (
+            slot[t], 0, 0))
     # K/V blocks are single physical pages, gathered by block
-    # *indexing* through the prefetched table — never an HBM
+    # *indexing* through the prefetched schedule — never an HBM
     # materialization of the dense [S, cache_len, H, D] view.
-    page_block = pl.BlockSpec((1, page_size, width),
-                              lambda s, j, pt: (pt[s, j], 0, 0))
+    page_blocks = [
+        pl.BlockSpec(
+            (1, page_size, width),
+            lambda t, slot, grp, pt, live, g=g: (
+                pt[slot[t], grp[t] * group + g], 0, 0))
+        for g in range(group)]
     in_specs = [
-        slot_block, page_block, page_block,
-        pl.BlockSpec((1, 1, seq * page_size, 1),
-                     lambda s, j, pt: (s, j, 0, 0)),
-        whole((width, heads)), whole((heads, width)),
-    ]
+        slot_block,
+        pl.BlockSpec((1, seq, group * page_size),
+                     lambda t, slot, grp, pt, live: (
+                         slot[t], 0, grp[t])),
+        pl.BlockSpec((hp, width), lambda t, slot, grp, pt, live: (0, 0)),
+    ] + page_blocks * 2
     if config.quantized:
-        scale_block = pl.BlockSpec((1, 1, heads),
-                                   lambda s, j, pt: (pt[s, j], 0, 0))
-        in_specs += [scale_block, scale_block]
+        in_specs += [pl.BlockSpec(
+            (1, 1, hp, group), lambda t, slot, grp, pt, live: (
+                slot[t], grp[t], 0, 0))] * 2
+    scratch_shapes = [
+        pltpu.VMEM((rows, width), qk_dtype),
+        pltpu.VMEM((rows, width), jnp.float32),
+        pltpu.VMEM((rows, _LANES), jnp.float32),
+        pltpu.VMEM((rows, _LANES), jnp.float32),
+        pltpu.VMEM((seq, width), jnp.float32),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(slots, pages_per_slot),
+        num_scalar_prefetch=4,
+        grid=(total,),
         in_specs=in_specs,
         out_specs=slot_block,
-        scratch_shapes=[
-            pltpu.VMEM((seq, width), jnp.float32),
-            pltpu.VMEM((seq, heads), jnp.float32),
-            pltpu.VMEM((seq, heads), jnp.float32),
-        ],
+        scratch_shapes=scratch_shapes,
     )
     out_dtype = q.dtype if config.quantized else value_pages.dtype
     return pl.pallas_call(
@@ -318,53 +499,115 @@ def _paged_forward(config, q, key_pages, value_pages, page_table,
     )(*operands)
 
 
+def _grouped(page_table, allowed, page_size, group):
+    """The walk's view of a call: the table padded with the scratch
+    page and the mask with False to whole groups, and per slot
+    1 + the last page on which any query row may attend (0 for an
+    evicted slot)."""
+    pages_per_slot = page_table.shape[1]
+    pad = _round_up(pages_per_slot, group) - pages_per_slot
+    table = jnp.pad(page_table, ((0, 0), (0, pad)))
+    mask = jnp.pad(allowed, ((0, 0), (0, 0), (0, pad * page_size)))
+    live_keys = jnp.max(
+        jnp.where(allowed, 1 + jnp.arange(allowed.shape[2]), 0),
+        axis=(1, 2)).astype(jnp.int32)
+    live_pages = (live_keys + page_size - 1) // page_size
+    return table, mask, live_pages
+
+
 def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
                     sm_scale, key_scales=None, value_scales=None):
-    """The kernel's defining math as vectorized lax: walk the page
-    blocks in grid order, gathering ONLY the slots' own pages (one
-    [slots, P, H*D] take per logical page — never the dense
-    [slots, cache_len] view), with the exact online-softmax update
-    sequence `_paged_kernel` runs per step. This is the off-TPU
-    execution of the kernel path: Mosaic can't compile there and
-    Pallas interpret mode is ~100x too slow for a serving tick, so the
-    `CLOUD_TPU_PAGED_KERNEL=1` smoke runs this form while the parity
-    suite pins it against the true interpreted kernel
+    """The kernel's defining math as vectorized lax: walk the table in
+    grid order a group of G pages at a time, gathering ONLY the slots'
+    own pages (one [slots, G * P, H*D] take per group — never the
+    dense [slots, cache_len] view), with the exact online-softmax
+    update sequence `_paged_kernel` runs per step. It walks every
+    group: one past a slot's last live page is wholly masked, which
+    leaves m, l and acc bit for bit as they were (alpha = exp(0) = 1,
+    p = 0), so the kernel's live bound changes no value. This is the
+    off-TPU execution of the kernel path: Mosaic can't compile there
+    and Pallas interpret mode is ~100x too slow for a serving tick,
+    so the `CLOUD_TPU_PAGED_KERNEL=1` smoke runs this form while the
+    parity suite pins it against the true interpreted kernel
     (`interpret=True`) and the gathered reference. Int8 pages are
-    dequantized per page block in f32 (the module dequant contract)."""
+    dequantized per group in f32 (the module dequant contract)."""
     page_size = key_pages.shape[1]
     slots, seq, heads, head_dim = q.shape
-    pages_per_slot = page_table.shape[1]
     quantized = key_scales is not None
-    am = allowed.reshape(slots, seq, pages_per_slot, page_size)
+    group = group_pages(page_size, heads, heads * head_dim,
+                        key_pages.dtype.itemsize, seq,
+                        page_table.shape[1])
+    keys = group * page_size
+    table, mask, _ = _grouped(page_table, allowed, page_size, group)
+    _, pv_dtype = _operand_dtypes(q.dtype, key_pages.dtype)
     m = jnp.full((slots, heads, seq, 1), _NEG_INF, jnp.float32)
     l = jnp.zeros((slots, heads, seq, 1), jnp.float32)
     acc = jnp.zeros((slots, heads, seq, head_dim), jnp.float32)
-    for j in range(pages_per_slot):
-        pages = page_table[:, j]
-        k = key_pages[pages].reshape(slots, page_size, heads,
-                                     head_dim)
-        v = value_pages[pages].reshape(k.shape)
-        if quantized:
-            k = k.astype(jnp.float32) * key_scales[pages][:, None, :,
-                                                          None]
-            v = v.astype(jnp.float32) * value_scales[pages][:, None, :,
-                                                            None]
+    for j in range(table.shape[1] // group):
+        pages = table[:, j * group:(j + 1) * group]
+
+        def take(pool, scales):
+            x = pool[pages].reshape(slots, group, page_size, heads,
+                                    head_dim)
+            if quantized:
+                x = x.astype(jnp.float32) * scales[pages][
+                    :, :, None, :, None]
+            return x.reshape(slots, keys, heads, head_dim)
+
+        k = take(key_pages, key_scales)
+        v = take(value_pages, value_scales)
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                        preferred_element_type=jnp.float32) * sm_scale
-        mask = am[:, :, j, :][:, None]       # [slots, 1, seq, P]
-        s = jnp.where(mask, s, _NEG_INF)
-        m_curr = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m, m_curr)
+        live = mask[:, None, :, j * keys:(j + 1) * keys]
+        s = jnp.where(live, s, _NEG_INF)       # [slots, H, seq, keys]
+        m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_next)
-        p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
+        p = jnp.where(live, jnp.exp(s - m_next), 0.0)
         l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.einsum("bhqk,bkhd->bhqd",
-                                       p.astype(v.dtype), v)
+        acc = acc * alpha + jnp.einsum(
+            "bhqk,bkhd->bhqd", p.astype(pv_dtype), v.astype(pv_dtype),
+            preferred_element_type=jnp.float32)
         m = m_next
     safe_l = jnp.where(l == 0.0, 1.0, l)
     out_dtype = q.dtype if quantized else value_pages.dtype
     out = (acc / safe_l).astype(out_dtype)
     return jnp.transpose(out, (0, 2, 1, 3))
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _paged_call(q, key_pages, value_pages, page_table, allowed, *scales,
+                sm_scale, interpret):
+    """One device's call, `[slots, seq, H', D]` over its H' heads: the
+    walk's geometry from the shapes, then the kernel. Jitted because a
+    model's layers all make this call with the same shapes, and a
+    jitted callee is traced and lowered once a program and not once a
+    layer (the serve cells' set-up traces the 24-layer tick several
+    times: PERF.md section 6, PR 27)."""
+    slots, seq, local_heads, head_dim = q.shape
+    page_size = key_pages.shape[1]
+    width = local_heads * head_dim
+    group = group_pages(page_size, local_heads, width,
+                        key_pages.dtype.itemsize, seq,
+                        page_table.shape[1])
+    config = _PagedConfig(sm_scale=sm_scale, heads=local_heads, seq=seq,
+                          page_size=page_size, group=group,
+                          interpret=interpret, quantized=bool(scales))
+    table, mask, live_pages = _grouped(page_table, allowed, page_size,
+                                       group)
+
+    def by_group(sc):
+        """[N, H'] page scales -> [slots, groups, H8, G]: each group's
+        rows through the table, heads on sublanes."""
+        rows = jnp.pad(sc[table], ((0, 0), (0, 0), (
+            0, _round_up(local_heads, _SUBLANES) - local_heads)))
+        return jnp.swapaxes(
+            rows.reshape(slots, -1, group, rows.shape[-1]), 2, 3)
+
+    out = _paged_forward(
+        config, q.reshape(slots, seq, width), key_pages, value_pages,
+        table, live_pages, mask.astype(jnp.int32),
+        *(by_group(sc) for sc in scales))
+    return out.reshape(q.shape)
 
 
 def paged_decode_attention(q, key_pages, value_pages, page_table,
@@ -421,28 +664,6 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     if quantized:
         args += [key_scales, value_scales]
 
-    def kernel(q, key_pages, value_pages, page_table, allowed,
-               *scales):
-        """[slots, seq, H', D] over one device's H' heads."""
-        local_heads = q.shape[2]
-        config = _PagedConfig(sm_scale=float(sm_scale),
-                              heads=local_heads, seq=seq,
-                              page_size=page_size,
-                              interpret=bool(interpret),
-                              quantized=quantized)
-        # [slots, pages, seq * P, 1]: per page, each query row's P key
-        # flags as a sublane column.
-        amask = jnp.transpose(
-            allowed.astype(jnp.int32).reshape(
-                slots, seq, pages_per_slot, page_size),
-            (0, 2, 1, 3)).reshape(slots, pages_per_slot,
-                                  seq * page_size, 1)
-        out = _paged_forward(
-            config, q.reshape(slots, seq, local_heads * head_dim),
-            key_pages, value_pages, page_table, amask,
-            *(sc[:, None, :] for sc in scales))
-        return out.reshape(q.shape)
-
     def plan(mesh):
         """Heads over the model axis; slots share the pool, so every
         other axis sees the whole call."""
@@ -454,6 +675,8 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
             specs += [P(None, tp)] * 2
         return tuple(specs), by_head, None
 
+    kernel = functools.partial(_paged_call, sm_scale=float(sm_scale),
+                               interpret=bool(interpret))
     return partition.per_shard(kernel, args, plan, interpret)
 
 

@@ -123,6 +123,7 @@ import jax
 import numpy as np
 
 from cloud_tpu.monitoring import spans
+from cloud_tpu.ops.paged_attention import group_pages, walked_tokens
 from cloud_tpu.parallel import runtime
 from cloud_tpu.serving import reqtrace
 from cloud_tpu.serving.engine import DecodeEngine
@@ -434,6 +435,21 @@ class Scheduler:
         # 5 ms naps the tick thread took for the sake of an admission
         # in flight (each is a `tick_pace` span).
         self._tick_paces = 0
+        # Running sums over ticks and occupied slots: the keys a slot
+        # attends to, and the keys the paged kernel's walk fetches for
+        # that depth (whole groups of `_kv_group` pages; the kernel's
+        # own arithmetic, at the unsharded width). live / walked is
+        # the share of the walk that is not dead. A verify window
+        # reaches `_kv_reach` keys past the plain tick's.
+        m = self.engine.model
+        self._kv_reach = self.engine.spec_k if self.engine.spec_on else 0
+        self._kv_group = group_pages(
+            page_size, m.num_heads, m.d_model,
+            1 if kv_dtype == "int8"
+            else np.dtype(m.compute_dtype).itemsize,
+            self._kv_reach + 1, self.engine.pages_per_slot)
+        self._kv_live_tokens = 0
+        self._kv_walked_tokens = 0
         from cloud_tpu.monitoring.telemetry import Histogram
         self._ttft_hist = Histogram("ttft")
         self._ttft_hit_hist = Histogram("ttft_hit")
@@ -2075,6 +2091,16 @@ class Scheduler:
             g["ticks"] += 1
             g["active_sum"] += n_active
             g["tick_hist"].observe(elapsed)
+            # What this tick's attention read: the token it consumed
+            # sits at prompt + emitted - 1, so that many keys and
+            # itself.
+            for state in self._slots:
+                if state is not None:
+                    depth = (len(state.request.prompt)
+                             + len(state.emitted) + self._kv_reach)
+                    self._kv_live_tokens += depth
+                    self._kv_walked_tokens += walked_tokens(
+                        depth, self.pool.page_size, self._kv_group)
             reg = _registry()
             if reg is not None:
                 from cloud_tpu.monitoring import telemetry
@@ -2449,6 +2475,8 @@ class Scheduler:
         self._decode_gap_hist = Histogram("decode_gap")
         self._chunks_dispatched = 0
         self._tick_paces = 0
+        self._kv_live_tokens = 0
+        self._kv_walked_tokens = 0
         self._t_last_commit = None
         self._completed = 0
         self._tokens_out = 0
@@ -2557,6 +2585,8 @@ class Scheduler:
             "tokens_emitted": self._tokens_out,
             "ticks": self._ticks,
             "tick_paces": self._tick_paces,
+            "kv_live_tokens": self._kv_live_tokens,
+            "kv_walked_tokens": self._kv_walked_tokens,
             "elapsed_seconds": wall,
             "requests_per_sec": self._completed / wall,
             "tokens_per_sec": self._tokens_out / wall,

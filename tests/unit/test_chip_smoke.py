@@ -27,7 +27,8 @@ def test_kernels_phase_interpreted():
                                     interpret=True)
     assert set(errs) == {"flash_causal_gqa2", "flash_masked",
                          "fused_rmsnorm", "fused_swiglu", "paged_bf16",
-                         "paged_int8"}
+                         "paged_int8", "paged_bf16_seq4",
+                         "paged_int8_seq4"}
 
 
 def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):

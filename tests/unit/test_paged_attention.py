@@ -381,6 +381,117 @@ def test_int8_dispatch_through_public_entrypoint():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(walk))
 
 
+# -- the walk's groups and its live bound (ISSUE 27) --------------------
+
+EDGE_PAGES = 19   # not a multiple of the group: the last one is partial
+KINDS = ("f32", "bf16", "int8")
+
+
+@pytest.fixture
+def group_of_8(monkeypatch):
+    """At toy widths every slot fits one group; a budget this small
+    gives the cells' G = 8 (one 128-key lane tile of 16-token pages)."""
+    monkeypatch.setattr(pa, "_VMEM_BUDGET", 300 * 1024)
+    group = pa.group_pages(16, 2, 128, 4, 3, EDGE_PAGES)
+    assert group == 8
+    return group
+
+
+def _edge_case(kind, seq, allowed_fn, page_table_fn=None, slots=2):
+    """reference / walk / interpreted kernel, and the tolerance of the
+    page type, over `EDGE_PAGES` pages a slot with the caller's mask."""
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    make = _int8_scenario if kind == "int8" else _scenario
+    q, k, v, pt, _ = make(slots=slots, pages_per_slot=EDGE_PAGES,
+                          seq=seq, dtype=dtype, seed=5)
+    cache_len = EDGE_PAGES * 16
+    allowed = jnp.asarray(allowed_fn(np.arange(cache_len)[None, None, :],
+                                     np.arange(seq)[None, :, None]))
+    if page_table_fn is not None:
+        pt = jnp.asarray(page_table_fn(np.asarray(pt).copy()))
+    if kind == "int8":
+        ref, walk, kern = _all_impls_int8(q, k, v, pt, allowed)
+        ref = pa.paged_attention_reference(q, k[2], v[2], pt, allowed)
+    else:
+        ref, walk, kern = _all_impls(q, k, v, pt, allowed)
+    tol = 2e-2 if kind == "bf16" else TOL
+    as_f32 = lambda x: np.asarray(x, np.float32)
+    return as_f32(ref), as_f32(walk), as_f32(kern), tol
+
+
+@pytest.mark.parametrize("live", ["0", "1", "G-1", "G", "G+1", "all"])
+@pytest.mark.parametrize("seq", [1, 3])
+@pytest.mark.parametrize("kind", KINDS)
+def test_depths_on_the_groups_edges(group_of_8, kind, seq, live):
+    """Slot 0 is `live` pages deep (the frontier of its last query row
+    one key short of the page's end), slot 1 ten pages: the walk ends
+    where the slot does, whole groups before it and a part of one at
+    it, and an evicted slot (0 pages) reads exact zeros."""
+    pages = {"0": 0, "1": 1, "G-1": group_of_8 - 1, "G": group_of_8,
+             "G+1": group_of_8 + 1, "all": EDGE_PAGES}[live]
+    depth = np.array([max(pages * 16 - 1, 0), 10 * 16 - 5])
+    frontier = lambda row: np.where(
+        depth[:, None, None] > 0,
+        depth[:, None, None] - (seq - 1) + row, 0)
+    ref, walk, kern, tol = _edge_case(
+        kind, seq, lambda key, row: key < frontier(row))
+    first = 0 if pages else 1
+    np.testing.assert_allclose(kern[first:], ref[first:], atol=tol,
+                               rtol=tol)
+    np.testing.assert_allclose(walk[first:], ref[first:], atol=tol,
+                               rtol=tol)
+    if kind == "f32":
+        np.testing.assert_allclose(walk, kern, atol=1e-6, rtol=1e-6)
+    if not pages:
+        np.testing.assert_array_equal(kern[0], np.zeros_like(kern[0]))
+        np.testing.assert_array_equal(walk[0], np.zeros_like(walk[0]))
+
+
+@pytest.mark.parametrize("seq", [1, 3])
+@pytest.mark.parametrize("kind", KINDS)
+def test_hole_inside_the_live_range(group_of_8, kind, seq):
+    """`allowed` stays the only mask: keys 40-199 are denied (the
+    rest of group 0 and part of group 1) below a frontier in group 2;
+    the live bound is the last page allowed, not a count of keys."""
+    ref, walk, kern, tol = _edge_case(
+        kind, seq, lambda key, row: (key < 270 + row) & (
+            (key < 40) | (key >= 200)) | np.zeros((2, 1, 1), bool))
+    np.testing.assert_allclose(kern, ref, atol=tol, rtol=tol)
+    np.testing.assert_allclose(walk, ref, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_last_live_page_shared_with_another_slot(group_of_8, kind):
+    """Slot 0's last live page (its tenth, in group 1) is also slot
+    1's third: two tables name one physical page at different places
+    of their walks."""
+    depth = np.array([10 * 16 - 3, 3 * 16 - 7])
+
+    def share(pt):
+        pt[1, 2] = pt[0, 9]
+        return pt
+
+    ref, walk, kern, tol = _edge_case(
+        kind, 1, lambda key, row: key < depth[:, None, None] + 0 * row,
+        page_table_fn=share)
+    np.testing.assert_allclose(kern, ref, atol=tol, rtol=tol)
+    np.testing.assert_allclose(walk, ref, atol=tol, rtol=tol)
+
+
+def test_group_pages_from_the_shapes():
+    """G at the serve cells' shape and how it follows the shapes: a
+    whole number of 128-key lane tiles, fewer for wider or deeper
+    calls, the whole slot where that is smaller."""
+    assert pa.group_pages(16, 25, 1600, 2, 1, 64) == 8
+    assert pa.group_pages(16, 25, 1600, 1, 1, 64) == 16   # int8 pages
+    assert pa.group_pages(16, 25, 1600, 2, 1, 4) == 4     # one group
+    assert pa.group_pages(16, 13, 832, 2, 1, 64) == 24    # tp = 2
+    assert pa.group_pages(16, 100, 6400, 2, 1, 64) == 8   # never 0
+    assert pa.group_pages(128, 25, 1600, 2, 1, 8) == 1
+    for depth, want in ((0, 0), (1, 128), (128, 128), (129, 256)):
+        assert pa.walked_tokens(depth, 16, 8) == want
+
+
 def test_cost_hook():
     """The telemetry row: positive flops and bytes, and the fused
     bytes figure stays below the dense-gather materialization (the

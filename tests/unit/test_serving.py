@@ -363,6 +363,37 @@ class TestSchedulerStats:
         assert stats["ttft_hit"]["count"] == 0
         assert stats["ttft_miss"]["count"] == 1
 
+    @pytest.mark.parametrize("depths", [(1, 16, 17), (5, 31), (32,)])
+    def test_kv_walk_sums(self, model, params, depths):
+        """`kv_live_tokens` sums the occupied slots' depths a tick and
+        `kv_walked_tokens` what the paged kernel's walk fetches for
+        them: the kernel's own helper, whole groups of pages."""
+        import types
+
+        from cloud_tpu.ops.paged_attention import (group_pages,
+                                                   walked_tokens)
+        from cloud_tpu.serving import Scheduler
+        sched = Scheduler(model, params, slots=4, page_size=4)
+        group = group_pages(4, model.num_heads, model.d_model, 4, 1,
+                            model.max_seq_len // 4)
+        assert sched._kv_group == group
+        for slot, depth in enumerate(depths):
+            sched._slots[slot] = types.SimpleNamespace(
+                request=types.SimpleNamespace(prompt=[1] * (depth - 1)),
+                emitted=[2],
+                rec=types.SimpleNamespace(token_times=[], rid=None))
+        fetched = (np.zeros(4, np.int32), np.zeros(4, bool))
+        sched._distribute(fetched, 0.01, 0.0)
+        stats = sched.stats()
+        assert stats["kv_live_tokens"] == sum(depths)
+        assert stats["kv_walked_tokens"] == sum(
+            walked_tokens(d, 4, group) for d in depths)
+        assert stats["kv_live_tokens"] <= stats["kv_walked_tokens"]
+        # The next tick sees every slot one token deeper.
+        sched._distribute(fetched, 0.01, 0.0)
+        assert sched.stats()["kv_live_tokens"] == 2 * sum(depths) + len(
+            depths)
+
     def test_partial_wait_histograms(self, model, params):
         from cloud_tpu.serving import Scheduler
         sched = Scheduler(model, params, slots=2)

@@ -221,6 +221,38 @@ def test_paged_decode_lowers_for_tpu_with_auto(as_tpu, page_dtype):
                 lowering_platforms=("tpu",)).compile()
 
 
+@pytest.mark.parametrize("seq", [1, 4])
+@pytest.mark.parametrize("page_dtype", ["bf16", "int8"])
+def test_paged_kernel_compiles_at_the_cells_geometry(as_tpu, page_dtype,
+                                                     seq):
+    """The serve cells' call — 16 slots, 64 pages of 16 tokens, 25
+    heads x 64 — compiles for a v5e with no chip, as one Mosaic custom
+    call under the name the benchmark's reader looks for; so does the
+    verify window (`seq` 4) and the int8 pool."""
+    devices = _tpu_topology()
+    if devices is None:
+        pytest.skip("no libtpu to describe a v5e")
+    slots, ppn, page, heads, hd = 16, 64, 16, 25, 64
+    pages = slots * ppn + 1
+    pool = S((pages, page, heads * hd),
+             BF16 if page_dtype == "bf16" else jnp.int8)
+    specs = [S((slots, seq, heads, hd), BF16), pool, pool,
+             S((slots, ppn), jnp.int32),
+             S((slots, seq, ppn * page), jnp.bool_)]
+    fn = ops.paged_attention
+    if page_dtype == "int8":
+        specs += [S((pages, heads), F32)] * 2
+        fn = lambda q, kp, vp, pt, al, ks, vs: ops.paged_attention(
+            q, kp, vp, pt, al, key_scales=ks, value_scales=vs)
+    one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
+    text = jax.jit(fn, in_shardings=one).trace(*specs).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert "_paged_decode_attention.paged_decode" in calls[0]
+
+
 # -- values under a mesh (interpreted, CPU devices) ---------------------
 
 
