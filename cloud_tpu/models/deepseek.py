@@ -36,7 +36,7 @@ expanded one. Weights import from HF `DeepseekV3ForCausalLM` via
 "interleaved" rope style; rotate-half otherwise).
 """
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -245,11 +245,23 @@ class DeepseekMoE(nn.Module):
     scoring: str = "sigmoid"  # "softmax" for DeepSeek-V2
     group_select: str = "top2sum"  # "max" for DeepSeek-V2
     route_bias: bool = True  # V3 e_score_correction_bias
+    # Expert-parallel share: the ids of the routed experts this holder
+    # has (None = all). The router keeps its `num_experts` outputs and
+    # its `top_k` choices and the gates are normalized over all the
+    # chosen; only the chosen experts held here are computed (plus the
+    # shared expert, which every holder has whole), and that partial
+    # sum is the output — what an exchange would add is not stood in
+    # for. Summed over the holders, the shared expert counted once,
+    # the parts give the uncut layer (tests/unit/test_moe.py).
+    held_experts: Optional[Tuple[int, ...]] = None
+    param_dtype: jnp.dtype = jnp.float32  # experts + shared expert
 
     @nn.compact
-    def __call__(self, x, deterministic=True):
+    def __call__(self, x, deterministic=True, token_mask=None):
+        """x: [batch, seq, d]; token_mask: optional [batch, seq], real
+        tokens (a pad is routed nowhere and counted nowhere)."""
         del deterministic
-        from cloud_tpu.models.moe import routed_expert_ffn
+        from cloud_tpu.models import moe
 
         batch, seq, d_model = x.shape
         tokens = batch * seq
@@ -264,7 +276,55 @@ class DeepseekMoE(nn.Module):
             "router", nn.initializers.lecun_normal(),
             (d_model, self.num_experts), jnp.float32)
         x2d = x.reshape(tokens, d_model)
-        logits = jnp.asarray(x2d, jnp.float32) @ router_kernel
+        # NOTE: a non-learned load-balancing buffer in V3
+        # checkpoints. It only feeds the (non-differentiable)
+        # selection, so it gets zero gradient — but a
+        # weight-decaying optimizer (adamw) would still erode it;
+        # exclude it when fine-tuning, e.g.
+        # Trainer(trainable=lambda p: "router_bias" not in p).
+        router_bias = self.param(
+            "router_bias", nn.initializers.zeros,
+            (self.num_experts,), jnp.float32) if self.route_bias else None
+        with jax.named_scope(moe.MOE_ROUTER):
+            top_idx, gates, aux_loss = self._route(
+                x2d, router_kernel, router_bias, group_size)
+        if token_mask is not None:
+            token_mask = token_mask.reshape(tokens)
+        if not self.is_initializing():
+            real = (tokens if token_mask is None
+                    else jnp.sum(token_mask.astype(jnp.int32)))
+            self.sow(moe.MOE_STATS, "pairs_routed", real * self.top_k)
+
+        if self.capacity_factor is None:
+            capacity = None
+        else:
+            capacity = max(1, int(self.capacity_factor * tokens
+                                  * self.top_k / self.num_experts))
+        with jax.named_scope(moe.MOE_ROUTED_EXPERTS):
+            routed = moe.routed_expert_ffn(
+                self, x2d, top_idx, gates, self.num_experts, self.d_ff,
+                capacity, act, self.compute_dtype,
+                held_experts=self.held_experts, token_mask=token_mask,
+                param_dtype=self.param_dtype)
+        with jax.named_scope(moe.MOE_SHARED_EXPERT):
+            shared = SwiGLU(self.d_ff * self.n_shared_experts,
+                            self.compute_dtype,
+                            activation=self.activation,
+                            param_dtype=self.param_dtype,
+                            name="shared")(x)
+        out = (routed.reshape(batch, seq, d_model) + shared).astype(
+            x.dtype)
+        return out, aux_loss
+
+    def _route(self, x2d, router_kernel, router_bias, group_size):
+        """(top_idx [T, k], gates [T, k], aux_loss) over all
+        `num_experts`, in float32."""
+        tokens = x2d.shape[0]
+        # HIGHEST: on the TPU a float32 product otherwise rounds its
+        # operands to bfloat16, and the choice of experts turns on
+        # differences of scores far below that.
+        logits = jnp.dot(jnp.asarray(x2d, jnp.float32), router_kernel,
+                         precision=jax.lax.Precision.HIGHEST)
         if self.scoring == "sigmoid":
             scores = jax.nn.sigmoid(logits)               # [T, E]
         elif self.scoring == "softmax":
@@ -273,19 +333,8 @@ class DeepseekMoE(nn.Module):
             raise ValueError(
                 "Unknown scoring {!r}; expected 'sigmoid' or "
                 "'softmax'.".format(self.scoring))
-        if self.route_bias:
-            # NOTE: a non-learned load-balancing buffer in V3
-            # checkpoints. It only feeds the (non-differentiable)
-            # selection, so it gets zero gradient — but a
-            # weight-decaying optimizer (adamw) would still erode it;
-            # exclude it when fine-tuning, e.g.
-            # Trainer(trainable=lambda p: "router_bias" not in p).
-            router_bias = self.param(
-                "router_bias", nn.initializers.zeros,
-                (self.num_experts,), jnp.float32)
-            choice = scores + router_bias[None, :]
-        else:
-            choice = scores
+        choice = (scores if router_bias is None
+                  else scores + router_bias[None, :])
 
         if self.n_group > 1:
             grouped = choice.reshape(tokens, self.n_group, group_size)
@@ -312,28 +361,16 @@ class DeepseekMoE(nn.Module):
 
         # Balance term at the Mixtral scale (num_experts * sum f_e*P_e,
         # = top_k when uniform), over per-token-normalized scores so
-        # sigmoid and softmax scoring share a scale.
-        sel = jax.nn.one_hot(top_idx, self.num_experts,
-                             dtype=jnp.float32)
+        # sigmoid and softmax scoring share a scale. The assignment
+        # counts are a scatter-add over the choices: no [T, k, E]
+        # one-hot.
+        counts = jnp.zeros((self.num_experts,), jnp.float32).at[
+            top_idx.reshape(-1)].add(1.0)
         norm_scores = scores / (scores.sum(axis=-1, keepdims=True)
                                 + 1e-20)
         aux_loss = self.num_experts * jnp.sum(
-            sel.sum(axis=1).mean(axis=0) * norm_scores.mean(axis=0))
-
-        if self.capacity_factor is None:
-            capacity = tokens
-        else:
-            capacity = max(1, int(self.capacity_factor * tokens
-                                  * self.top_k / self.num_experts))
-        routed = routed_expert_ffn(self, x2d, top_idx, gates,
-                                   self.num_experts, self.d_ff,
-                                   capacity, act, self.compute_dtype)
-        shared = SwiGLU(self.d_ff * self.n_shared_experts,
-                        self.compute_dtype, activation=self.activation,
-                        name="shared")(x)
-        out = (routed.reshape(batch, seq, d_model) + shared).astype(
-            x.dtype)
-        return out, aux_loss
+            counts / tokens * norm_scores.mean(axis=0))
+        return top_idx, gates, aux_loss
 
 
 class DeepseekBlock(nn.Module):

@@ -233,8 +233,17 @@ class GQAttention(nn.Module):
     logit_softcap: Optional[float] = None  # Gemma2 tanh cap on logits
     qk_norm: bool = False  # Gemma3 per-head RMSNorm on q/k (pre-RoPE)
     norm_eps: float = 1e-6  # eps for the qk norms
+    use_rope: bool = True  # False: q/k unrotated (a NoPE layer)
+    param_dtype: jnp.dtype = jnp.float32  # projections' stored dtype
+    # Paged-pool decode (serving/engine.py), as CausalSelfAttention's:
+    # page rows are H_kv * head_dim wide.
+    page_size: int = 0
+    num_pages: int = 0
+    page_dtype: str = ""
 
     def _rope(self, x, positions):
+        if not self.use_rope:
+            return x
         return apply_rope(x, positions, self.rope_theta, self.rope_style,
                           self.rope_scaling)
 
@@ -249,7 +258,8 @@ class GQAttention(nn.Module):
         head_dim = self.head_dim or d_model // self.num_heads
         dense = lambda feats, name: nn.DenseGeneral(
             feats, axis=-1, use_bias=self.qkv_bias,
-            dtype=self.compute_dtype, name=name)
+            dtype=self.compute_dtype, param_dtype=self.param_dtype,
+            name=name)
         q = dense((self.num_heads, head_dim), "query")(x)
         k = dense((self.num_kv_heads, head_dim), "key")(x)
         v = dense((self.num_kv_heads, head_dim), "value")(x)
@@ -268,7 +278,10 @@ class GQAttention(nn.Module):
             # left-padded-prompt contract (generate(prompt_mask=)):
             # padded slots are never attended and don't advance the
             # per-example logical position.
-            out = self._decode_attention(q, k, v, mask)
+            if self.page_size:
+                out = self._paged_decode_attention(q, k, v, mask)
+            else:
+                out = self._decode_attention(q, k, v, mask)
         else:
             positions = jnp.arange(x.shape[1])
             q = self._rope(q, positions)
@@ -299,7 +312,33 @@ class GQAttention(nn.Module):
                                     impl=self.attention_impl)
         out = out.astype(self.compute_dtype)
         return nn.DenseGeneral(d_model, axis=(-2, -1), use_bias=False,
-                               dtype=self.compute_dtype, name="out")(out)
+                               dtype=self.compute_dtype,
+                               param_dtype=self.param_dtype,
+                               name="out")(out)
+
+    def _paged_decode_attention(self, q, k, v, mask=None):
+        """Decode over the paged KV pool: the write and the read
+        `CausalSelfAttention` makes, at H_kv-wide rows, with RoPE at
+        each slot's own depth and a window layer's band
+        (`decoding.paged_kv_attention` holds the contract)."""
+        from cloud_tpu.models.decoding import paged_kv_attention
+        if self.logit_softcap:
+            raise NotImplementedError(
+                "logit_softcap is not supported over the paged pool.")
+        return paged_kv_attention(
+            self, q, k, v, mask, cache_len=self.cache_len,
+            page_size=self.page_size, num_pages=self.num_pages,
+            page_dtype=self.page_dtype, store_dtype=self.compute_dtype,
+            sm_scale=self.attn_scale or 1.0 / np.sqrt(q.shape[-1]),
+            impl=self.attention_impl, rotate=self._rope,
+            window=self.sliding_window)
+
+    def _flash_selected(self):
+        """What `ops.attention` would pick for this module's impl."""
+        import jax
+        return self.attention_impl == "flash" or (
+            self.attention_impl == "auto"
+            and jax.default_backend() == "tpu")
 
     def _decode_attention(self, q, k, v, mask=None):
         """KV-cache attention at H_kv width (the point of GQA: the cache
@@ -339,6 +378,31 @@ class GQAttention(nn.Module):
             cached_k.value, k.astype(self.compute_dtype), (0, idx, 0, 0))
         cached_v.value = lax.dynamic_update_slice(
             cached_v.value, v.astype(self.compute_dtype), (0, idx, 0, 0))
+
+        if seq > 1 and self._flash_selected():
+            # A prefill window where the flash kernel runs: the dense
+            # einsum below holds [H, seq, L] float32 scores (4 GB for
+            # 64 heads of a 4096-token window), the kernel none. The
+            # queries are laid at their own rows of an L-long frame
+            # and the kernel runs over the cache as self-attention:
+            # causal in cache order, which is the order of logical
+            # positions because a prompt's real tokens are contiguous
+            # (pads lie to one side and are invalid), so the window's
+            # band over rows is the band over positions. Rows outside
+            # the window compute nothing that is read; on a window
+            # layer the tiles outside the band are skipped.
+            from cloud_tpu.ops.attention import flash_attention
+            frame = lax.dynamic_update_slice(
+                jnp.zeros((batch, self.cache_len) + q.shape[2:], q.dtype),
+                q, (0, idx, 0, 0))
+            out = flash_attention(
+                frame, cached_k.value, cached_v.value, causal=True,
+                sm_scale=self.attn_scale,
+                mask=self.get_variable("cache", "slot_valid"),
+                window=self.sliding_window,
+                logit_softcap=self.logit_softcap)
+            return lax.dynamic_slice(
+                out, (0, idx, 0, 0), (batch, seq) + q.shape[2:])
 
         if self.sliding_window:
             # Same band as the training-time kernel, on LOGICAL
@@ -381,12 +445,13 @@ class _DenseKernel(nn.Module):
     directly instead of calling the Dense module."""
 
     features: int
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, in_features):
         return self.param("kernel",
                           nn.linear.default_kernel_init,
-                          (in_features, self.features), jnp.float32)
+                          (in_features, self.features), self.param_dtype)
 
 
 class SwiGLU(nn.Module):
@@ -405,6 +470,7 @@ class SwiGLU(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16
     activation: str = "silu"
     impl: str = "auto"
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
@@ -414,9 +480,11 @@ class SwiGLU(nn.Module):
                 .format(self.activation, sorted(_GATE_ACTIVATIONS)))
         from cloud_tpu.ops import fused_swiglu
         features = x.shape[-1]
-        w_gate = _DenseKernel(self.d_ff, name="gate")(features)
-        w_up = _DenseKernel(self.d_ff, name="up")(features)
-        w_down = _DenseKernel(features, name="down")(self.d_ff)
+        kernel = lambda feats, name: _DenseKernel(
+            feats, self.param_dtype, name=name)
+        w_gate = kernel(self.d_ff, "gate")(features)
+        w_up = kernel(self.d_ff, "up")(features)
+        w_down = kernel(features, "down")(self.d_ff)
         impl = "reference" if self.impl == "reference" else "auto"
         return fused_swiglu(x, w_gate, w_up, w_down,
                             activation=self.activation,
@@ -475,10 +543,21 @@ class LlamaBlock(nn.Module):
     attn_scale: Optional[float] = None
     logit_softcap: Optional[float] = None
     qk_norm: bool = False
-    moe_experts: int = 0  # > 0: Mixtral-style top-k MoE replaces the MLP
+    moe_experts: int = 0  # > 0: a top-k MoE replaces the MLP
     moe_top_k: int = 2
     moe_capacity_factor: Optional[float] = 2.0  # None = drop-free
     moe_norm_topk: bool = True  # False for some Qwen3-MoE checkpoints
+    moe_router: str = "softmax"  # see LlamaLM
+    moe_d_ff: Optional[int] = None
+    moe_shared_experts: int = 1
+    moe_routed_scale: float = 1.0
+    moe_held_experts: Optional[Tuple[int, ...]] = None
+    pre_norms: bool = True  # False: no norm on the sub-layers' inputs
+    use_rope: bool = True
+    param_dtype: jnp.dtype = jnp.float32
+    page_size: int = 0  # paged-pool decode (serving); see attention
+    num_pages: int = 0
+    page_dtype: str = ""
 
     @nn.compact
     def __call__(self, x, mask=None, deterministic=True):
@@ -487,7 +566,7 @@ class LlamaBlock(nn.Module):
         fnorm = lambda name: FusedRMSNorm(
             epsilon=self.norm_eps, dtype=self.compute_dtype,
             impl=self.attention_impl, name=name)
-        y = fnorm("norm_attn")(x)
+        y = fnorm("norm_attn")(x) if self.pre_norms else x
         y = GQAttention(self.num_heads, self.num_kv_heads,
                         self.compute_dtype, self.attention_impl,
                         self.rope_theta, rope_style=self.rope_style,
@@ -501,26 +580,56 @@ class LlamaBlock(nn.Module):
                         logit_softcap=self.logit_softcap,
                         qk_norm=self.qk_norm,
                         norm_eps=self.norm_eps,
+                        use_rope=self.use_rope,
+                        param_dtype=self.param_dtype,
+                        page_size=self.page_size,
+                        num_pages=self.num_pages,
+                        page_dtype=self.page_dtype,
                         name="attention")(y, mask)
         if self.post_norms:
             # Gemma2/3 sandwich norms: each sublayer's OUTPUT is
             # normalized before the residual add (the residual stream
-            # itself stays un-normalized).
+            # itself stays un-normalized). With `pre_norms=False` it
+            # is the only norm of the sub-layer (EXAONE 4.0).
             y = norm("norm_attn_post")(y)
         if self.dropout_rate:
             # Dropout sits between the sublayer output and the residual
             # add, so the fused tail (add + norm in one pass) does not
             # apply; the param tree is identical either way.
             y = nn.Dropout(self.dropout_rate)(y, deterministic=deterministic)
+        if not self.pre_norms:
+            x = x + y
+            y = x
+        elif self.dropout_rate:
             x = x + y
             y = norm("norm_mlp")(x)
         else:
             y, x = fnorm("norm_mlp")(y, residual=x)
-        if self.moe_experts:
+        if self.moe_experts and self.moe_router == "sigmoid":
+            from cloud_tpu.models.deepseek import DeepseekMoE
+            y, aux_loss = DeepseekMoE(
+                num_experts=self.moe_experts, top_k=self.moe_top_k,
+                d_ff=self.moe_d_ff or self.d_ff,
+                norm_topk_prob=self.moe_norm_topk,
+                routed_scaling_factor=self.moe_routed_scale,
+                n_shared_experts=self.moe_shared_experts,
+                capacity_factor=self.moe_capacity_factor,
+                compute_dtype=self.compute_dtype,
+                activation=self.mlp_activation,
+                held_experts=self.moe_held_experts,
+                param_dtype=self.param_dtype, name="moe")(
+                    y, deterministic, token_mask=mask)
+            self.sow("losses", "moe_aux_loss", aux_loss,
+                     reduce_fn=lambda a, b: a + b, init_fn=lambda: 0.0)
+        elif self.moe_experts:
+            if self.moe_router != "softmax":
+                raise ValueError(
+                    "moe_router must be 'softmax' or 'sigmoid'; got "
+                    "{!r}.".format(self.moe_router))
             from cloud_tpu.models.moe import TopKMoEMLP
             y, aux_loss = TopKMoEMLP(
                 num_experts=self.moe_experts, top_k=self.moe_top_k,
-                d_ff=self.d_ff,
+                d_ff=self.moe_d_ff or self.d_ff,
                 capacity_factor=self.moe_capacity_factor,
                 compute_dtype=self.compute_dtype,
                 activation=self.mlp_activation,
@@ -534,7 +643,8 @@ class LlamaBlock(nn.Module):
         else:
             y = SwiGLU(self.d_ff, self.compute_dtype,
                        activation=self.mlp_activation,
-                       impl=self.attention_impl, name="mlp")(y)
+                       impl=self.attention_impl,
+                       param_dtype=self.param_dtype, name="mlp")(y)
         if self.post_norms:
             y = norm("norm_mlp_post")(y)
         if self.dropout_rate:
@@ -585,19 +695,67 @@ class LlamaLM(nn.Module):
     attn_kinds: Optional[Tuple[str, ...]] = None
     rope_theta_local: Optional[float] = None  # Gemma3: 10_000
     rope_scaling_local: Optional[RopeScaling] = None
-    # Mixtral/Qwen3-MoE family: top-k routed MoE FFN in every block.
+    # Mixtral/Qwen3-MoE family: top-k routed MoE FFN in every block
+    # past the first `first_k_dense`. moe_router "softmax" is
+    # `TopKMoEMLP` (softmax then top-k); "sigmoid" is `DeepseekMoE`
+    # (DeepSeek-V3 / EXAONE-MoE: sigmoid scores, a selection bias,
+    # gates normalized over the chosen and scaled by
+    # `moe_routed_scale`, `moe_shared_experts` always-on experts, and
+    # optionally only `moe_held_experts` of the routed ones held
+    # here). `moe_d_ff` is an expert's width (None = d_ff).
     moe_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: Optional[float] = 2.0  # None = drop-free
     moe_norm_topk: bool = True
+    moe_router: str = "softmax"
+    moe_d_ff: Optional[int] = None
+    moe_shared_experts: int = 1
+    moe_routed_scale: float = 1.0
+    moe_held_experts: Optional[Tuple[int, ...]] = None
+    first_k_dense: int = 0  # leading blocks that keep the dense MLP
+    # EXAONE 4.0 family switches: with `post_block_norms`, no norm on
+    # the sub-layers' inputs (the norm sits on their outputs alone);
+    # "global" layers of an `attn_kinds` pattern unrotated (NoPE).
+    pre_block_norms: bool = True
+    global_rope: bool = True
+    # Stored dtype of the matrices (embedding, projections, MLPs,
+    # experts, head); norm scales and the router stay float32. A
+    # served model sets the served dtype here so that `model.init`
+    # declares the shapes in it.
+    param_dtype: jnp.dtype = jnp.float32
+    # Paged-pool decode (serving/engine.py), as TransformerLM's.
+    kv_page_size: int = 0
+    kv_num_pages: int = 0
+    kv_page_dtype: str = ""  # "int8" = quantized pages (graftpack)
+
+    def __post_init__(self):
+        # Module fields key jit and lru caches, so they stay hashable:
+        # a configuration file's lists become tuples, its
+        # "LLLG"-style pattern the kinds, its dtype name a dtype.
+        kinds = self.attn_kinds
+        if isinstance(kinds, str):
+            kinds = tuple({"L": "local", "G": "global"}.get(c, c)
+                          for c in kinds)
+        elif isinstance(kinds, list):
+            kinds = tuple(kinds)
+        object.__setattr__(self, "attn_kinds", kinds)
+        if isinstance(self.moe_held_experts, list):
+            object.__setattr__(self, "moe_held_experts",
+                               tuple(self.moe_held_experts))
+        object.__setattr__(self, "param_dtype",
+                           jnp.dtype(self.param_dtype))
+        super().__post_init__()
 
     def _layer_attn(self, i):
-        """(window, theta, scaling) for layer i under attn_kinds."""
+        """(window, theta, scaling, rotated) for layer i under
+        attn_kinds."""
         if self.attn_kinds is None:
-            return self.sliding_window, self.rope_theta, self.rope_scaling
+            return (self.sliding_window, self.rope_theta,
+                    self.rope_scaling, True)
         kind = self.attn_kinds[i % len(self.attn_kinds)]
         if kind == "global":
-            return None, self.rope_theta, self.rope_scaling
+            return (None, self.rope_theta, self.rope_scaling,
+                    self.global_rope)
         if kind != "local":
             raise ValueError(
                 "attn_kinds entries must be 'local' or 'global'; got "
@@ -608,7 +766,7 @@ class LlamaLM(nn.Module):
                 "is not set.")
         return (self.sliding_window,
                 self.rope_theta_local or self.rope_theta,
-                self.rope_scaling_local)
+                self.rope_scaling_local, True)
 
     @nn.compact
     def __call__(self, tokens, mask=None, deterministic=True):
@@ -619,14 +777,15 @@ class LlamaLM(nn.Module):
                     seq, self.max_seq_len))
         num_kv = self.num_kv_heads or self.num_heads
         x = nn.Embed(self.vocab_size, self.d_model,
-                     dtype=self.compute_dtype, name="embed")(tokens)
+                     dtype=self.compute_dtype,
+                     param_dtype=self.param_dtype, name="embed")(tokens)
         if self.scale_embed:
             # Gemma convention: the normalizer is cast to the compute
             # dtype BEFORE multiplying (a bf16-rounded sqrt(d), matching
             # checkpoints trained that way).
             x = x * jnp.asarray(self.d_model ** 0.5, self.compute_dtype)
         for i in range(self.num_layers):
-            window, theta, scaling = self._layer_attn(i)
+            window, theta, scaling, rotated = self._layer_attn(i)
             x = LlamaBlock(self.num_heads, num_kv, self.d_ff,
                            self.compute_dtype, self.attention_impl,
                            theta, self.rope_style,
@@ -642,17 +801,32 @@ class LlamaLM(nn.Module):
                            attn_scale=self.attn_scale,
                            logit_softcap=self.attn_logit_softcap,
                            qk_norm=self.qk_norm,
-                           moe_experts=self.moe_experts,
+                           moe_experts=(self.moe_experts
+                                        if i >= self.first_k_dense
+                                        else 0),
                            moe_top_k=self.moe_top_k,
                            moe_capacity_factor=self.moe_capacity_factor,
                            moe_norm_topk=self.moe_norm_topk,
+                           moe_router=self.moe_router,
+                           moe_d_ff=self.moe_d_ff,
+                           moe_shared_experts=self.moe_shared_experts,
+                           moe_routed_scale=self.moe_routed_scale,
+                           moe_held_experts=self.moe_held_experts,
+                           pre_norms=self.pre_block_norms,
+                           use_rope=rotated,
+                           param_dtype=self.param_dtype,
+                           page_size=self.kv_page_size,
+                           num_pages=self.kv_num_pages,
+                           page_dtype=self.kv_page_dtype,
                            name="block_%d" % i)(x, mask, deterministic)
         x = FusedRMSNorm(epsilon=self.norm_eps,
                          dtype=self.compute_dtype,
                          impl=self.attention_impl,
                          name="norm_final")(x)
         logits = nn.Dense(self.vocab_size, use_bias=False,
-                          dtype=self.compute_dtype, name="lm_head")(x)
+                          dtype=self.compute_dtype,
+                          param_dtype=self.param_dtype,
+                          name="lm_head")(x)
         logits = logits.astype(jnp.float32)
         if self.final_logit_softcap:
             cap = float(self.final_logit_softcap)

@@ -20,6 +20,7 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 
@@ -116,14 +117,15 @@ class TopKMoEMLP(nn.Module):
     as `models.llama.SwiGLU`, stacked on a leading [num_experts] dim
     that `expert_parallel_rules` shards over the "ep" mesh axis.
 
-    Routing uses the same dense one-hot dispatch/combine einsums as
-    `MoEMLP` (static shapes, MXU-tiled, XLA inserts the all-to-alls),
+    The chosen (token, expert) pairs are sorted by expert and each
+    projection is one grouped product over them (`routed_expert_ffn`:
+    static shapes, no `[T, E, C]` dispatch tensor at any T),
     processed slot-major so a token's top-1 choice wins capacity over
     any token's top-2 choice. `capacity_factor=None` disables dropping
-    entirely (capacity = tokens): exact HF-Mixtral inference semantics,
-    at O(T^2) dispatch-tensor cost — right for checkpoint-parity and
-    small-batch decode, wrong for large-scale training (set a factor,
-    conventionally 1.25-2.0, and let the aux loss balance load).
+    entirely: exact HF-Mixtral inference semantics, at the same cost
+    as a factor — a factor only sheds the pairs past it (set one,
+    conventionally 1.25-2.0, to bound an expert's share of a training
+    batch, and let the aux loss balance load).
 
     Call returns (output, aux_loss); `LlamaBlock` sows the aux loss
     into the "losses" collection like `TransformerBlock` does.
@@ -151,7 +153,7 @@ class TopKMoEMLP(nn.Module):
                 "top_k={} must be in [1, num_experts={}].".format(
                     k, self.num_experts))
         if self.capacity_factor is None:
-            capacity = tokens
+            capacity = None
         else:
             capacity = max(1, int(self.capacity_factor * tokens * k
                                   / self.num_experts))
@@ -175,10 +177,10 @@ class TopKMoEMLP(nn.Module):
         # SUMMED over the k routes (mean over tokens only), so a
         # uniform router scores top_k — coefficients calibrated
         # against HF (router_aux_loss_coef) transfer unchanged.
-        sel = jax.nn.one_hot(top_idx, self.num_experts,
-                             dtype=jnp.float32)           # [T, k, E]
+        counts = jnp.zeros((self.num_experts,), jnp.float32).at[
+            top_idx.reshape(-1)].add(1.0)
         aux_loss = self.num_experts * jnp.sum(
-            sel.sum(axis=1).mean(axis=0) * probs.mean(axis=0))
+            counts / tokens * probs.mean(axis=0))
 
         out = routed_expert_ffn(self, x.reshape(tokens, d_model),
                                 top_idx, gates, self.num_experts,
@@ -187,59 +189,113 @@ class TopKMoEMLP(nn.Module):
         return out.reshape(batch, seq, d_model).astype(x.dtype), aux_loss
 
 
+#: Declared scopes of an expert layer's three parts (table "Scopes" in
+#: monitoring/spans.py): `jax.named_scope`s, so every op the part
+#: lowers to carries the name in its `op_name`, whatever program (the
+#: serve tick, a prefill, a train step) holds the layer.
+MOE_ROUTER = "moe_router"
+MOE_ROUTED_EXPERTS = "moe_routed_experts"
+MOE_SHARED_EXPERT = "moe_shared_expert"
+
+#: Collection an expert layer sows its counters of one call into, for
+#: a caller that makes it mutable (the serve tick): `pairs_routed`
+#: (token, choice) pairs of real tokens, `pairs_held` those whose
+#: expert is held here, `experts_touched` held experts with at least
+#: one pair, `expert_load` pairs a held expert.
+MOE_STATS = "moe_stats"
+
+
 def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
-                      capacity, act, compute_dtype):
-    """Dense-dispatch top-k SwiGLU expert computation, shared by
-    `TopKMoEMLP` (Mixtral) and `models.deepseek.DeepseekMoE`.
+                      capacity, act, compute_dtype, held_experts=None,
+                      token_mask=None, param_dtype=jnp.float32):
+    """Grouped (sort/segment) top-k SwiGLU expert computation, shared
+    by `TopKMoEMLP` (Mixtral) and `models.deepseek.DeepseekMoE`.
 
     x2d: [T, d] tokens; top_idx/gates: [T, k] selected experts and
-    combine weights (any routing recipe). Creates the stacked
-    expert_gate/up/down params on `module` (the caller's @nn.compact
-    scope) so `expert_parallel_rules` shards them over "ep".
+    combine weights over all `num_experts` (any routing recipe).
+    `held_experts`: the ids of the experts this module holds (None =
+    all): only their weights exist — stacked expert_gate/up/down
+    params `[len(held), ...]` on `module` (the caller's @nn.compact
+    scope), in `param_dtype`, which `expert_parallel_rules` shards
+    over "ep" — and only the chosen pairs whose expert is held are
+    computed; what the absent experts would have added is left out
+    (the share of an expert-parallel layout that this holder
+    computes, before its exchange). `token_mask` [T] marks real
+    tokens: a pad's pairs go nowhere.
 
-    Capacity assignment is slot-major: all slot-0 (highest-gate)
-    assignments claim expert queue positions before any slot-1
-    assignment, so when capacity binds the lowest-priority routes are
-    shed first. dispatch[t, e, c] = 1 iff token t occupies slot c of
-    expert e via ANY of its k routes (routes are distinct experts, so
-    the sum over slots never overlaps); combine carries the gate.
+    The pairs are sorted by expert and each projection is ONE grouped
+    product over them (`jax.lax.ragged_dot`: a group a held expert),
+    so no `[T, E, .]` dispatch mask exists at any T and nothing is
+    dropped unless `capacity` says so. Capacity (None = drop-free) is
+    slot-major: the sort is stable over the slot-major list of pairs,
+    so within an expert all slot-0 (highest-gate) assignments stand
+    before any slot-1 assignment, and when capacity binds the
+    lowest-priority routes past it are shed first.
     Returns [T, d] in compute_dtype.
     """
     tokens, d_model = x2d.shape
     k = top_idx.shape[1]
-    sel = jax.nn.one_hot(top_idx, num_experts, dtype=jnp.float32)
-    sel_sm = jnp.transpose(sel, (1, 0, 2)).reshape(
-        k * tokens, num_experts)                      # [kT, E]
-    position = (jnp.cumsum(sel_sm, axis=0) - 1.0) * sel_sm
-    keep = (position < capacity).astype(jnp.float32) * sel_sm
-    slot = jnp.sum(position * keep, axis=-1).astype(jnp.int32)
-    slot_oh = jax.nn.one_hot(slot, capacity, dtype=jnp.float32)
-
-    disp = (keep[:, :, None] * slot_oh[:, None, :]).reshape(
-        k, tokens, num_experts, capacity)
-    dispatch = disp.sum(axis=0)                       # [T, E, C]
-    gates_sm = jnp.transpose(gates, (1, 0)).reshape(k, tokens)
-    combine = (disp * gates_sm[:, :, None, None].astype(
-        jnp.float32)).sum(axis=0)
-
-    xf = x2d.astype(compute_dtype)
-    expert_in = jnp.einsum("tec,td->ecd",
-                           dispatch.astype(compute_dtype), xf)
+    held = (tuple(range(num_experts)) if held_experts is None
+            else tuple(int(e) for e in held_experts))
+    n_held = len(held)
+    if len(set(held)) != n_held or not all(
+            0 <= e < num_experts for e in held):
+        raise ValueError(
+            "held_experts must be distinct ids in [0, {}); got "
+            "{}.".format(num_experts, held))
     init = nn.initializers.lecun_normal(batch_axis=(0,))
     w_gate = module.param("expert_gate", init,
-                          (num_experts, d_model, d_ff), jnp.float32)
+                          (n_held, d_model, d_ff), param_dtype)
     w_up = module.param("expert_up", init,
-                        (num_experts, d_model, d_ff), jnp.float32)
+                        (n_held, d_model, d_ff), param_dtype)
     w_down = module.param("expert_down", init,
-                          (num_experts, d_ff, d_model), jnp.float32)
-    g = jnp.einsum("ecd,edf->ecf", expert_in,
-                   w_gate.astype(compute_dtype))
-    u = jnp.einsum("ecd,edf->ecf", expert_in,
-                   w_up.astype(compute_dtype))
-    expert_out = jnp.einsum("ecf,efd->ecd", act(g) * u,
-                            w_down.astype(compute_dtype))
-    return jnp.einsum("tec,ecd->td",
-                      combine.astype(compute_dtype), expert_out)
+                          (n_held, d_ff, d_model), param_dtype)
+
+    # A pair's group: its expert's row in the stacked weights, or
+    # `n_held` (sorted last, computed by no one) for an expert that
+    # is not held and for a pad's pairs.
+    if held_experts is None:
+        local = top_idx
+    else:
+        rows = np.full((num_experts,), n_held, np.int32)
+        rows[list(held)] = np.arange(n_held, dtype=np.int32)
+        local = jnp.asarray(rows)[top_idx]
+    if token_mask is not None:
+        local = jnp.where(token_mask.reshape(tokens, 1), local, n_held)
+    # Slot-major: pair j * T + t is token t's j-th choice.
+    local = jnp.transpose(local).reshape(k * tokens).astype(jnp.int32)
+    order = jnp.argsort(local, stable=True)
+    group = local[order]
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[local].add(1)[:n_held]
+    computed = group < n_held
+    kept = computed
+    if capacity is not None and capacity < tokens:
+        starts = jnp.cumsum(sizes) - sizes
+        rank = jnp.arange(k * tokens) - starts[
+            jnp.minimum(group, n_held - 1)]
+        kept = computed & (rank < capacity)
+    if not module.is_initializing():   # counters are no variables
+        module.sow(MOE_STATS, "pairs_held", jnp.sum(sizes))
+        module.sow(MOE_STATS, "experts_touched",
+                   jnp.sum((sizes > 0).astype(jnp.int32)))
+        module.sow(MOE_STATS, "expert_load", sizes)
+
+    token_of = order % tokens
+    xs = x2d.astype(compute_dtype)[token_of]              # [kT, d]
+    grouped = lambda a, w: jax.lax.ragged_dot(
+        a, w.astype(compute_dtype), sizes)
+    hidden = act(grouped(xs, w_gate)) * grouped(xs, w_up)
+    out = grouped(hidden.astype(compute_dtype), w_down)   # [kT, d]
+    # Rows past the held pairs belong to no group: whatever the
+    # product left there is not read.
+    weight = jnp.transpose(gates).reshape(k * tokens)[order]
+    out = jnp.where(kept[:, None],
+                    out.astype(jnp.float32) * weight[:, None], 0.0)
+    # Back to slot-major order, and a token's k routes summed.
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(k * tokens, dtype=order.dtype))
+    return out[back].reshape(k, tokens, d_model).sum(axis=0).astype(
+        compute_dtype)
 
 
 def expert_parallel_rules(ep_axis: str = "ep"):

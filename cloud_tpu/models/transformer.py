@@ -124,170 +124,21 @@ class CausalSelfAttention(nn.Module):
         return jnp.einsum("bhqk,bkhd->bqhd", weights, cached_v.value)
 
     def _paged_decode_attention(self, q, k, v, mask=None):
-        """Decode over the paged KV pool (continuous batching). The
-        batch dimension is SLOTS, each at its own depth: physical K/V
-        live in a shared page pool `[num_pages, page_size, H*D]` (a row
-        per token, heads folded into lanes — the device layout
-        ops/paged_attention.py's kernel reads without a copy), each
-        slot's logical `[cache_len]` view is its page table's gather
-        over the pool. Writes are per-slot scatters at `slot_steps[s]`;
-        insertion/eviction are index updates on the page table and
-        validity rows (serving/engine.py), so the tick executable never
-        retraces.
-
-        `seq` is 1 for the plain tick and k+1 for the speculative
-        verify window — each slot's tokens land at consecutive logical
-        positions from its own pointer and every query attends exactly
-        the keys a solo decode at its depth would (per-query causality
-        from `paged_slot_update`).
-
-        Per-slot math is EXACTLY `_decode_attention`'s per-row math
-        over the gathered logical view (same masking, same f32 einsum),
-        which is what makes engine tokens bit-identical to solo
-        `generate()` — see tests/unit/test_serving.py.
-
-        Pages may be SHARED between slots (radix prefix cache,
-        serving/prefixcache.py): shared pages sit strictly below every
-        holder's write pointer, so they are only ever gathered, never
-        scattered to — copy-on-write happens at insert time by routing
-        divergent content into fresh pages.
-
-        The scratch page (physical page 0) is never handed out by the
-        pool allocator: freed/empty page-table rows are all 0, so an
-        inactive slot's write lands in scratch and its garbage is
-        masked to exact-zero weight, never attended by anyone.
-        """
-        from cloud_tpu.models.decoding import paged_slot_update
-
-        slots, seq, heads, head_dim = q.shape
-        if not self.cache_len or self.cache_len % self.page_size:
-            raise ValueError(
-                "cache_len ({}) must be a positive multiple of "
-                "page_size ({}).".format(self.cache_len, self.page_size))
-        if self.num_pages < 2:
-            raise ValueError("num_pages must be >= 2 (page 0 is the "
-                             "scratch page).")
-        if self.page_dtype not in ("", "int8"):
-            raise ValueError(
-                "page_dtype must be '' or 'int8'; got {!r}.".format(
-                    self.page_dtype))
-        quantized = self.page_dtype == "int8"
-        pages_per_slot = self.cache_len // self.page_size
-        page_store = jnp.int8 if quantized else self.compute_dtype
-        pool_shape = (self.num_pages, self.page_size, heads * head_dim)
-        key_pages = self.variable("cache", "key_pages", jnp.zeros,
-                                  pool_shape, page_store)
-        value_pages = self.variable("cache", "value_pages", jnp.zeros,
-                                    pool_shape, page_store)
-        page_table = self.variable(
-            "cache", "page_table", jnp.zeros, (slots, pages_per_slot),
-            jnp.int32)
-        if quantized:
-            # Per-page per-head symmetric scales; 0 = never-written
-            # page (dequantizes to exact zeros). They live in the same
-            # attention cache subtree as the pages, so the engine's
-            # _map_attention / paged_slot_rewind carry them for free.
-            key_scales = self.variable(
-                "cache", "key_scales", jnp.zeros,
-                (self.num_pages, heads), jnp.float32)
-            value_scales = self.variable(
-                "cache", "value_scales", jnp.zeros,
-                (self.num_pages, heads), jnp.float32)
-
-        pos, allowed = paged_slot_update(self, mask, slots, seq,
-                                         self.cache_len)
-        # Physical write targets: slot s's page for each token's
-        # logical position pos[s, j]. Inactive/evicted slots resolve
-        # to page 0 (scratch) via their zeroed page-table row.
-        phys = jnp.take_along_axis(page_table.value,
-                                   pos // self.page_size, 1)
-        off = pos % self.page_size
-        if quantized:
-            if mask is not None:
-                # Zero invalid tokens pre-quantize so pad garbage never
-                # inflates a real page's amax scale (their positions are
-                # masked from attention either way).
-                m = mask.reshape(slots, seq).astype(k.dtype)
-                k = k * m[:, :, None, None]
-                v = v * m[:, :, None, None]
-            key_pages.value, key_scales.value = _quantized_page_write(
-                key_pages.value, key_scales.value, k, phys, off)
-            value_pages.value, value_scales.value = (
-                _quantized_page_write(value_pages.value,
-                                      value_scales.value, v, phys,
-                                      off))
-            scales_kw = dict(key_scales=key_scales.value,
-                             value_scales=value_scales.value)
-        else:
-            rows = lambda x: x.astype(self.compute_dtype).reshape(
-                slots, seq, heads * head_dim)
-            key_pages.value = key_pages.value.at[phys, off].set(rows(k))
-            value_pages.value = value_pages.value.at[phys, off].set(
-                rows(v))
-            scales_kw = {}
-
-        # Impl selection (ops/paged_attention.py): "auto" runs the
-        # Pallas paged kernel on TPU — the page table rides as a
-        # scalar-prefetch operand, so the pool is block-indexed a
-        # group of pages a grid step, as far as `allowed` has a slot
-        # live and no further, with online softmax in VMEM, never
-        # materialized as a dense [slots, cache_len, H, D] gather —
-        # and the gathered-lax reference elsewhere, which is bitwise
-        # the dense path's math (engine-vs-solo bit-identity).
-        # CLOUD_TPU_PAGED_KERNEL=1/0 force-overrides. Every paged
-        # decode — engine tick, speculative verify window, solo paged
-        # decode — routes through this one call.
-        from cloud_tpu.ops import paged_attention
-        return paged_attention(
-            q, key_pages.value, value_pages.value, page_table.value,
-            allowed, sm_scale=1.0 / np.sqrt(head_dim),
-            impl=self.attention_impl, **scales_kw)
-
-
-def _quantized_page_write(pages, scales, x, phys, off):
-    """Write [slots, seq, H, D] decode K/V into int8 pages with
-    per-page per-head amax rescale.
-
-    pages: [N, P, H*D] int8; scales: [N, H] f32; phys/off: [slots,
-    seq] physical page / in-page offset per token. Returns the updated
-    (pages, scales).
-
-    Per position j (static python loop — seq is 1 for the plain tick,
-    spec_k + 1 for the verify window): the page's scale grows
-    monotonically to cover the new token's amax
-    (`new = max(old, amax / 127)`), the page's existing block is
-    rescaled by `old / new` and the token quantized at `new`. When the
-    scale doesn't grow the rescale factor is exactly 1.0 and
-    `round(x * 1.0) == x` for int8-range values in f32, so the rewrite
-    is an exact no-op — steady-state decode never degrades earlier
-    tokens. Duplicate physical targets across slots only happen at the
-    scratch page (inactive slots' zeroed table rows); its undefined
-    winner is never attended. Scales only *reset* at page-granular
-    rewrites (the engine insert scatter / host-tier promote), which
-    cover every recycled page before a decode write can touch it.
-    """
-    slots, seq, heads, head_dim = x.shape
-    page_size = pages.shape[1]
-    xf = x.astype(jnp.float32)
-    rows = jnp.arange(slots)
-    for j in range(seq):
-        p = phys[:, j]                       # [slots]
-        o = off[:, j]
-        xj = xf[:, j]                        # [slots, H, D]
-        amax = jnp.max(jnp.abs(xj), axis=-1)  # [slots, H]
-        old = scales[p]
-        new = jnp.maximum(old, amax / 127.0)
-        safe = jnp.where(new > 0, new, 1.0)
-        factor = (old / safe)[:, None, :, None]
-        block = pages[p].astype(jnp.float32).reshape(
-            slots, page_size, heads, head_dim)
-        block = jnp.clip(jnp.round(block * factor), -127, 127)
-        qx = jnp.clip(jnp.round(xj / safe[:, :, None]), -127, 127)
-        block = block.at[rows, o].set(qx)
-        pages = pages.at[p].set(block.astype(jnp.int8).reshape(
-            slots, page_size, heads * head_dim))
-        scales = scales.at[p].set(new)
-    return pages, scales
+        """Decode over the paged KV pool (continuous batching): the
+        write of this call's K/V rows and the read through
+        `ops.paged_attention`, shared with `GQAttention`
+        (`decoding.paged_kv_attention` holds the contract). Per-slot
+        math is EXACTLY `_decode_attention`'s per-row math over the
+        gathered logical view, which is what makes engine tokens
+        bit-identical to solo `generate()` — see
+        tests/unit/test_serving.py."""
+        from cloud_tpu.models.decoding import paged_kv_attention
+        return paged_kv_attention(
+            self, q, k, v, mask, cache_len=self.cache_len,
+            page_size=self.page_size, num_pages=self.num_pages,
+            page_dtype=self.page_dtype, store_dtype=self.compute_dtype,
+            sm_scale=1.0 / np.sqrt(q.shape[-1]),
+            impl=self.attention_impl)
 
 
 class TransformerBlock(nn.Module):

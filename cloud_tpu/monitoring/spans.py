@@ -80,7 +80,38 @@ Kernels:
     fused_swiglu_fwd        ops/fused_mlp.py forward
     fused_rmsnorm           ops/fused_norm.py, no residual
     fused_rmsnorm_residual  ops/fused_norm.py, residual add fused
-    paged_decode            ops/paged_attention.py decode walk
+    paged_decode            ops/paged_attention.py decode walk, a full
+                            layer's (every live page of a slot)
+    paged_decode_window     the same walk over a window layer's band
+                            (from the slot's first live page)
+
+An expert layer's three parts run under `jax.named_scope`s of these
+names (constants in models/moe.py), inside whatever program holds the
+layer (`jit_serve_tick`, `jit_serve_prefill`, `jit_train_step`): every
+op a part lowers to carries the name in its `op_name`, and the grouped
+products of the routed experts are XLA:TPU's own kernel, whose
+instructions the trace names `ragged-dot*`.
+
+Scopes:
+
+    moe_router            scores over all experts, the top-k choice
+                          and the gates (float32)
+    moe_routed_experts    sort of the chosen pairs held here, the
+                          three grouped products, the weighted sum
+                          back to tokens
+    moe_shared_expert     the always-on expert's SwiGLU
+
+An expert model's tick also returns counters, which `Scheduler.stats()`
+sums over ticks and expert layers (fetched with the tick's tokens, in
+the same read-back).
+
+Counters:
+
+    moe_pairs_routed      (token, choice) pairs of the active slots
+    moe_pairs_held        those whose expert is held here
+    moe_experts_touched   held experts with at least one pair, summed
+                          a layer and tick
+    moe_expert_load       pairs a held expert (a vector)
 
 The hot loops' jitted functions are named so (a constant beside the
 jit), and the trace's program line reads `jit_<name>`.
@@ -117,7 +148,7 @@ _DEFAULT_MAX_EVENTS = 500_000
 
 def names(section):
     """The names in one table of this module's docstring ("Spans",
-    "Kernels" or "Programs"), in order."""
+    "Kernels", "Scopes", "Counters" or "Programs"), in order."""
     out, inside = [], False
     for line in __doc__.splitlines():
         if not line.startswith(" "):

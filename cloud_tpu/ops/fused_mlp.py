@@ -33,6 +33,7 @@ the kernel runs per shard (ops/partition.py).
 """
 
 import functools
+import math
 import os
 import types
 from typing import NamedTuple, Optional
@@ -66,6 +67,23 @@ _ACTIVATIONS = types.MappingProxyType({
     "gelu_tanh": lambda x: jax.nn.gelu(x, approximate=True),
     "gelu": lambda x: jax.nn.gelu(x, approximate=False),
 })
+
+
+#: Scoped VMEM a kernel's blocks may take on the TPU.
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def kernel_fits(rows, features, d_out, itemsize, block_rows=_BLOCK_ROWS):
+    """Whether one grid step's blocks fit scoped VMEM at the narrowest
+    d_ff tile `_ff_tile` falls back to: the three double-buffered
+    weight tiles, and a row the double-buffered input and output
+    blocks and the float32 accumulator. They do not at 6144 features
+    and 128 rows (18.9 MB; Mosaic refuses the call), while a decode
+    tick's few rows do."""
+    block = min(block_rows, max(rows, 1))
+    weights = 2 * (2 * features + d_out) * _LANES * itemsize
+    per_row = 2 * features * itemsize + 2 * d_out * itemsize + 4 * d_out
+    return weights + block * per_row <= _SCOPED_VMEM_BYTES
 
 
 class _MLPConfig(NamedTuple):
@@ -238,7 +256,9 @@ def fused_swiglu(x, w_gate, w_up, w_down, activation="silu",
     param dtype — cast to `compute_dtype` here, flax-style).
 
     impl: "fused" forces the Pallas kernel, "reference" the lax path;
-    "auto" picks the kernel on TPU, the reference elsewhere. The
+    "auto" picks the kernel on TPU where a step's blocks fit VMEM
+    (`kernel_fits`: not a many-row call at 6144 features, whose
+    products XLA's own matmuls run), the reference elsewhere. The
     `CLOUD_TPU_FUSED_MLP` env var ("1"/"0") is the deployment A/B
     override and beats `impl`; a forced kernel runs in interpret mode
     off-TPU. Differentiable w.r.t. x and all three weights either way.
@@ -266,7 +286,10 @@ def fused_swiglu(x, w_gate, w_up, w_down, activation="silu",
     elif impl == "reference":
         use_kernel = False
     else:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = jax.default_backend() == "tpu" and kernel_fits(
+            math.prod(x.shape[:-1]), features, w_down.shape[1],
+            jnp.dtype(compute_dtype or jnp.promote_types(
+                x.dtype, w_gate.dtype)).itemsize)
     if not use_kernel:
         return swiglu_reference(x, w_gate, w_up, w_down,
                                 activation=activation,

@@ -126,6 +126,9 @@ from cloud_tpu.ops import partition
 #: The kernel's declared name (table in monitoring/spans.py): the
 #: trace's op text carries it, whatever module calls the kernel.
 PAGED_DECODE = "paged_decode"
+#: The same walk over a window layer's band (`window=`): it starts at
+#: the slot's first live page as well as ending at its last.
+PAGED_DECODE_WINDOW = "paged_decode_window"
 
 #: `pl.pallas_call(name=)` is the innermost scope, and XLA:TPU names
 #: the custom call by it (`%<name>.N`). The benchmark's accepted
@@ -154,6 +157,13 @@ class _PagedConfig(NamedTuple):
     group: int
     interpret: bool
     quantized: bool = False
+    kv_heads: int = 0     # 0 = heads (MHA); fewer = grouped queries
+    banded: bool = False  # the mask has a lower edge (a window layer)
+
+    @property
+    def q_group(self):
+        """Query heads a key/value head serves."""
+        return self.heads // (self.kv_heads or self.heads)
 
 
 def _check_scales(key_pages, key_scales, value_scales, heads):
@@ -178,51 +188,81 @@ def _check_scales(key_pages, key_scales, value_scales, heads):
     return True
 
 
+def _kv_heads(q, key_pages):
+    """Key/value heads of a pool `[N, P, H_kv * D]` under `q`
+    `[slots, seq, H, D]`: H for full multi-head attention, a divisor
+    of H where each key/value head serves H / H_kv query heads."""
+    heads, head_dim = q.shape[2:]
+    kv_heads, rest = divmod(key_pages.shape[-1], head_dim)
+    if key_pages.ndim != 3 or rest or not kv_heads or heads % kv_heads:
+        raise ValueError(
+            "key_pages must be [num_pages, page_size, kv_heads * "
+            "head_dim] with kv_heads dividing the {} query heads of "
+            "{}; got {}.".format(heads, head_dim, key_pages.shape))
+    return kv_heads
+
+
 def paged_attention_reference(q, key_pages, value_pages, page_table,
                               allowed, sm_scale=None, key_scales=None,
                               value_scales=None):
     """Gathered-lax paged decode attention (the correctness oracle).
 
-    q: [slots, seq, H, D]; key_pages/value_pages: [N, P, H*D];
+    q: [slots, seq, H, D]; key_pages/value_pages: [N, P, H_kv*D]
+    (H_kv = H, or a divisor of it under grouped-query attention);
     page_table: [slots, pages_per_slot] int32; allowed:
     [slots, seq, cache_len] bool (True = attend) ->
     [slots, seq, H, D] in the page dtype (q's dtype for int8 pages).
 
     Logical per-slot [cache_len] views, one gather per call — bitwise
     the pre-kernel serving-tick math, kept verbatim so the kernel-off
-    engine stays bit-identical to solo `generate()` decodes. With
+    engine stays bit-identical to solo `generate()` decodes: the
+    dense cache's einsums of `TransformerLM` for H_kv = H, and of
+    `LlamaLM`'s grouped form otherwise. With
     `key_scales`/`value_scales` the int8 pages are dequantized into
     the gathered f32 view (the module-level dequant contract) and the
     whole computation stays f32.
     """
     page_size = key_pages.shape[1]
     heads, head_dim = q.shape[2:]
+    kv_heads = _kv_heads(q, key_pages)
     slots, pages_per_slot = page_table.shape
     cache_len = pages_per_slot * page_size
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
     quantized = _check_scales(key_pages, key_scales, value_scales,
-                              heads)
+                              kv_heads)
 
     def view(pages, scales):
-        """[slots, cache_len, H, D] logical view of the slots' pages."""
+        """[slots, cache_len, H_kv, D] logical view of the slots'
+        pages."""
         g = pages[page_table].reshape(slots, pages_per_slot, page_size,
-                                      heads, head_dim)
+                                      kv_heads, head_dim)
         if quantized:
             g = g.astype(jnp.float32) * scales[page_table][
                 :, :, None, :, None]
-        return g.reshape(slots, cache_len, heads, head_dim)
+        return g.reshape(slots, cache_len, kv_heads, head_dim)
 
     k_view = view(key_pages, key_scales)
     v_view = view(value_pages, value_scales)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_view,
-                        preferred_element_type=jnp.float32) * sm_scale
-    logits = jnp.where(allowed[:, None], logits, _NEG_INF)
     out_dtype = q.dtype if quantized else value_pages.dtype
-    weights = jax.nn.softmax(logits, axis=-1).astype(
-        jnp.float32 if quantized else value_pages.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights,
-                      v_view).astype(out_dtype)
+    weight_dtype = jnp.float32 if quantized else value_pages.dtype
+    if kv_heads == heads:
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_view,
+                            preferred_element_type=jnp.float32) * sm_scale
+        logits = jnp.where(allowed[:, None], logits, _NEG_INF)
+        weights = jax.nn.softmax(logits, axis=-1).astype(weight_dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", weights,
+                          v_view).astype(out_dtype)
+    # Grouped queries: `GQAttention._decode_attention`'s own einsums,
+    # each key/value head against its group of query heads.
+    seq = q.shape[1]
+    qg = q.reshape(slots, seq, kv_heads, heads // kv_heads, head_dim)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_view,
+                        preferred_element_type=jnp.float32) * sm_scale
+    logits = jnp.where(allowed[:, None, None], logits, _NEG_INF)
+    weights = jax.nn.softmax(logits, axis=-1).astype(weight_dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", weights, v_view)
+    return out.reshape(q.shape).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -299,41 +339,59 @@ def _dot(a, b, contract_b):
         preferred_element_type=jnp.float32)
 
 
-def _paged_kernel(slot_ref, group_ref, pt_ref, live_ref, q_ref, a_ref,
-                  segt_ref, *rest, config):
+def _paged_kernel(slot_ref, group_ref, pt_ref, live_ref, *rest, config):
     """One step of the walk: every head of one slot against one group
     of G logical pages. The grid runs over the live groups alone;
     step t serves group `group_ref[t]` of slot `slot_ref[t]`.
 
-    rest: G key-page blocks, G value-page blocks, (int8: the group's
-    `[H8, G]` K and V scale blocks), the output block, then scratch:
-    the block-diagonal query `[seq * H8, width]`, acc (same shape,
-    f32), m and l `[seq * H8, 128]`, the `[seq, width]` f32 output
-    rows."""
+    rest: (a window layer: the slots' first live group), the query
+    block, the mask block, `segt`, G key-page blocks, G value-page
+    blocks, (int8: the group's `[H8, G]` K and V scale blocks), the
+    output block, then scratch: the block-diagonal query
+    `[seq * H8, width]`, acc (same shape, f32), m and l
+    `[seq * H8, 128]`, and for full multi-head attention the
+    `[seq, width]` f32 output rows."""
     del pt_ref  # consumed by the BlockSpec index maps
     group, seq, page = config.group, config.seq, config.page_size
+    if config.banded:
+        first_ref, *rest = rest
+    q_ref, a_ref, segt_ref, *rest = rest
     k_refs, v_refs, rest = rest[:group], rest[group:2 * group], rest[
         2 * group:]
     if config.quantized:
         ks_ref, vs_ref, *rest = rest
-    o_ref, qbd_ref, acc_ref, m_ref, l_ref, rows_ref = rest
+    grouped = config.q_group > 1
+    if grouped:
+        o_ref, qbd_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, qbd_ref, acc_ref, m_ref, l_ref, rows_ref = rest
     ti = pl.program_id(0)
     ji = group_ref[ti]
+    first = first_ref[slot_ref[ti]] if config.banded else 0
     steps = live_groups(live_ref[slot_ref[ti]], group)
     hp = qbd_ref.shape[0] // seq          # heads, padded to sublanes
     keys = group * page
+    kv_heads = config.kv_heads or config.heads
+    depth = qbd_ref.shape[1] // kv_heads  # head size
     qk_dtype, pv_dtype = _operand_dtypes(q_ref.dtype, k_refs[0].dtype)
 
-    @pl.when(ji == 0)
+    @pl.when(ji == first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # Row (i, h) of the block-diagonal query is query row i on
         # head h's lanes and zero elsewhere, so one [rows, width] x
-        # [keys, width]^T product gives every head's scores.
+        # [keys, width]^T product gives every head's scores. Under
+        # grouped queries head h's lanes are those of the key/value
+        # head it reads, and its own `depth` features are laid over
+        # them.
         for i in range(seq):
-            q = q_ref[0, i:i + 1, :].astype(jnp.float32)
+            if grouped:
+                q = q_ref[0, i * hp:(i + 1) * hp, :].astype(jnp.float32)
+                q = jnp.concatenate([q] * kv_heads, axis=1)
+            else:
+                q = q_ref[0, i:i + 1, :].astype(jnp.float32)
             qbd_ref[i * hp:(i + 1) * hp, :] = (
                 q * segt_ref[...]).astype(qk_dtype)
 
@@ -392,20 +450,32 @@ def _paged_kernel(slot_ref, group_ref, pt_ref, live_ref, q_ref, a_ref,
         # other heads' keys against this head's weights elsewhere):
         # keep the diagonal blocks.
         for i in range(seq):
-            rows_ref[i:i + 1, :] = jnp.sum(
-                out[i * hp:(i + 1) * hp] * segt_ref[...], axis=0,
-                keepdims=True)
-        o_ref[0] = rows_ref[...].astype(o_ref.dtype)
+            kept = out[i * hp:(i + 1) * hp] * segt_ref[...]
+            if grouped:
+                # Head h's `depth` features, from the lanes of the
+                # key/value head it read.
+                o_ref[0, i * hp:(i + 1) * hp, :] = sum(
+                    kept[:, g * depth:(g + 1) * depth]
+                    for g in range(kv_heads)).astype(o_ref.dtype)
+            else:
+                rows_ref[i:i + 1, :] = jnp.sum(kept, axis=0,
+                                               keepdims=True)
+        if not grouped:
+            o_ref[0] = rows_ref[...].astype(o_ref.dtype)
 
 
-def _schedule(live_pages, group, most):
+def _schedule(live_pages, group, most, first_groups=None):
     """The walk as a list of grid steps: per step its slot and its
-    group, and the number of steps. A slot takes `live_groups` steps,
-    and an evicted slot one, which computes nothing and writes its
-    zeros; entries past the count (to `most`) are never run. Sums
-    over a [steps, slots] comparison: a few dozen integers, no scan
-    and no gather."""
-    steps = jnp.maximum(live_groups(live_pages, group), 1)
+    group, and the number of steps. A slot takes `live_groups` steps
+    (less the groups before `first_groups[s]`, where a window layer's
+    band starts), and an evicted slot one, which computes nothing and
+    writes its zeros; entries past the count (to `most`) are never
+    run. Sums over a [steps, slots] comparison: a few dozen integers,
+    no scan and no gather."""
+    steps = live_groups(live_pages, group)
+    if first_groups is not None:
+        steps = steps - first_groups
+    steps = jnp.maximum(steps, 1)
     slots = steps.shape[0]
     upto = jnp.arange(slots)[:, None] >= jnp.arange(slots)[None, :]
     ends = jnp.sum(jnp.where(upto, steps[None, :], 0), axis=1)
@@ -413,76 +483,88 @@ def _schedule(live_pages, group, most):
     past = t[:, None] >= ends[None, :]      # step t is past slot s
     slot_of = jnp.minimum(jnp.sum(past, axis=1), slots - 1)
     group_of = t - jnp.sum(jnp.where(past, steps[None, :], 0), axis=1)
+    if first_groups is not None:
+        own = slot_of[:, None] == jnp.arange(slots)[None, :]
+        group_of = group_of + jnp.sum(
+            jnp.where(own, first_groups[None, :], 0), axis=1)
     return (slot_of.astype(jnp.int32), group_of.astype(jnp.int32),
             ends[-1].astype(jnp.int32))
 
 
 def _paged_forward(config, q, key_pages, value_pages, page_table,
-                   live_pages, allowed, key_scales=None,
-                   value_scales=None):
-    """q: [S, seq, H*D]; page_table: [S, groups * G] (padded with the
+                   live_pages, allowed, first_groups=None,
+                   key_scales=None, value_scales=None):
+    """q: [S, seq, H*D] (grouped queries: [S, seq * H8, D], heads
+    padded to sublanes); page_table: [S, groups * G] (padded with the
     scratch page); live_pages: [S]; allowed: [S, seq, groups * G * P]
-    int32; scales (int8 mode): [S, groups, H8, G] -> out [S, seq, H*D].
+    int32; first_groups (a window layer): [S]; scales (int8 mode):
+    [S, groups, H8, G] -> out, shaped as q.
 
-    The schedule (`_schedule`), the page table and the live bound
+    The schedule (`_schedule`), the page table and the live bounds
     are the scalar-prefetch operands and the grid's one dimension is
     the schedule's length, a value of the call: block g of step t's K
     (and V) is physical page `pt[slot[t], group[t] * G + g]`, so the
     pool is only ever touched at the pages of groups a slot has live.
     """
-    slots, seq, width = q.shape
-    heads, group = config.heads, config.group
+    slots = q.shape[0]
+    width = key_pages.shape[2]
+    heads, group, seq = config.heads, config.group, config.seq
     page_size = config.page_size
     hp = _round_up(heads, _SUBLANES)
     rows = seq * hp
     qk_dtype, _ = _operand_dtypes(q.dtype, key_pages.dtype)
     kernel = functools.partial(_paged_kernel, config=config)
-    # segt[h, c] = 1 where lane c belongs to head h; rows past `heads`
-    # are zero, so the padded rows score 0 and are dropped at the end.
-    segt = (jnp.arange(hp)[:, None]
-            == jnp.arange(width)[None, :] // (width // heads)
-            ).astype(jnp.float32)
+    # segt[h, c] = 1 where lane c belongs to the key/value head that
+    # query head h reads; rows past `heads` are zero, so the padded
+    # rows score 0 and are dropped at the end.
+    kv_heads = config.kv_heads or heads
+    row_head = jnp.arange(hp) // config.q_group
+    segt = ((row_head[:, None]
+             == jnp.arange(width)[None, :] // (width // kv_heads))
+            & (jnp.arange(hp) < heads)[:, None]).astype(jnp.float32)
     slot_of, group_of, total = _schedule(
-        live_pages, group, slots * (page_table.shape[1] // group))
-    operands = [slot_of, group_of, page_table, live_pages, q, allowed,
-                segt]
+        live_pages, group, slots * (page_table.shape[1] // group),
+        first_groups)
+    scalars = [slot_of, group_of, page_table, live_pages]
+    if config.banded:
+        scalars.append(first_groups)
+    operands = scalars + [q, allowed, segt]
     operands += [key_pages] * group + [value_pages] * group
     if config.quantized:
         operands += [key_scales, value_scales]
     operands = partition.common_vma(*operands)
 
     slot_block = pl.BlockSpec(
-        (1, seq, width), lambda t, slot, grp, pt, live: (
-            slot[t], 0, 0))
+        (1,) + q.shape[1:], lambda t, slot, *_: (slot[t], 0, 0))
     # K/V blocks are single physical pages, gathered by block
     # *indexing* through the prefetched schedule — never an HBM
     # materialization of the dense [S, cache_len, H, D] view.
     page_blocks = [
         pl.BlockSpec(
             (1, page_size, width),
-            lambda t, slot, grp, pt, live, g=g: (
+            lambda t, slot, grp, pt, *_, g=g: (
                 pt[slot[t], grp[t] * group + g], 0, 0))
         for g in range(group)]
     in_specs = [
         slot_block,
         pl.BlockSpec((1, seq, group * page_size),
-                     lambda t, slot, grp, pt, live: (
-                         slot[t], 0, grp[t])),
-        pl.BlockSpec((hp, width), lambda t, slot, grp, pt, live: (0, 0)),
+                     lambda t, slot, grp, *_: (slot[t], 0, grp[t])),
+        pl.BlockSpec((hp, width), lambda t, *_: (0, 0)),
     ] + page_blocks * 2
     if config.quantized:
         in_specs += [pl.BlockSpec(
-            (1, 1, hp, group), lambda t, slot, grp, pt, live: (
+            (1, 1, hp, group), lambda t, slot, grp, *_: (
                 slot[t], grp[t], 0, 0))] * 2
     scratch_shapes = [
         pltpu.VMEM((rows, width), qk_dtype),
         pltpu.VMEM((rows, width), jnp.float32),
         pltpu.VMEM((rows, _LANES), jnp.float32),
         pltpu.VMEM((rows, _LANES), jnp.float32),
-        pltpu.VMEM((seq, width), jnp.float32),
     ]
+    if config.q_group == 1:
+        scratch_shapes.append(pltpu.VMEM((seq, width), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(scalars),
         grid=(total,),
         in_specs=in_specs,
         out_specs=slot_block,
@@ -495,7 +577,8 @@ def _paged_forward(config, q, key_pages, value_pages, page_table,
         out_shape=jax.ShapeDtypeStruct(
             q.shape, out_dtype, vma=partition.vma_of(*operands)),
         interpret=config.interpret,
-        name=_CALL_PREFIX + PAGED_DECODE,
+        name=_CALL_PREFIX + (PAGED_DECODE_WINDOW if config.banded
+                             else PAGED_DECODE),
     )(*operands)
 
 
@@ -515,16 +598,29 @@ def _grouped(page_table, allowed, page_size, group):
     return table, mask, live_pages
 
 
+def _first_groups(allowed, page_size, group):
+    """Per slot the group that holds the first key any query row may
+    attend (0 for an evicted slot): where a window layer's walk
+    starts. Keys before the band inside that group are the mask's."""
+    cache_len = allowed.shape[2]
+    first_key = jnp.min(
+        jnp.where(allowed, jnp.arange(cache_len), cache_len),
+        axis=(1, 2))
+    first_key = jnp.where(first_key == cache_len, 0, first_key)
+    return (first_key // (page_size * group)).astype(jnp.int32)
+
+
 def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
                     sm_scale, key_scales=None, value_scales=None):
     """The kernel's defining math as vectorized lax: walk the table in
     grid order a group of G pages at a time, gathering ONLY the slots'
-    own pages (one [slots, G * P, H*D] take per group — never the
+    own pages (one [slots, G * P, H_kv*D] take per group — never the
     dense [slots, cache_len] view), with the exact online-softmax
     update sequence `_paged_kernel` runs per step. It walks every
-    group: one past a slot's last live page is wholly masked, which
+    group: one past a slot's last live page (or before a window
+    layer's first) is wholly masked, which
     leaves m, l and acc bit for bit as they were (alpha = exp(0) = 1,
-    p = 0), so the kernel's live bound changes no value. This is the
+    p = 0), so the kernel's live bounds change no value. This is the
     off-TPU execution of the kernel path: Mosaic can't compile there
     and Pallas interpret mode is ~100x too slow for a serving tick,
     so the `CLOUD_TPU_PAGED_KERNEL=1` smoke runs this form while the
@@ -533,50 +629,56 @@ def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
     dequantized per group in f32 (the module dequant contract)."""
     page_size = key_pages.shape[1]
     slots, seq, heads, head_dim = q.shape
+    kv_heads = _kv_heads(q, key_pages)
     quantized = key_scales is not None
-    group = group_pages(page_size, heads, heads * head_dim,
+    group = group_pages(page_size, heads, kv_heads * head_dim,
                         key_pages.dtype.itemsize, seq,
                         page_table.shape[1])
     keys = group * page_size
     table, mask, _ = _grouped(page_table, allowed, page_size, group)
     _, pv_dtype = _operand_dtypes(q.dtype, key_pages.dtype)
-    m = jnp.full((slots, heads, seq, 1), _NEG_INF, jnp.float32)
-    l = jnp.zeros((slots, heads, seq, 1), jnp.float32)
-    acc = jnp.zeros((slots, heads, seq, head_dim), jnp.float32)
+    # [slots, H_kv, G_q, seq, .]: each key/value head with the query
+    # heads it serves (one a head for full multi-head attention).
+    qg = q.reshape(slots, seq, kv_heads, heads // kv_heads, head_dim)
+    stat = (slots, kv_heads, heads // kv_heads, seq)
+    m = jnp.full(stat + (1,), _NEG_INF, jnp.float32)
+    l = jnp.zeros(stat + (1,), jnp.float32)
+    acc = jnp.zeros(stat + (head_dim,), jnp.float32)
     for j in range(table.shape[1] // group):
         pages = table[:, j * group:(j + 1) * group]
 
         def take(pool, scales):
-            x = pool[pages].reshape(slots, group, page_size, heads,
+            x = pool[pages].reshape(slots, group, page_size, kv_heads,
                                     head_dim)
             if quantized:
                 x = x.astype(jnp.float32) * scales[pages][
                     :, :, None, :, None]
-            return x.reshape(slots, keys, heads, head_dim)
+            return x.reshape(slots, keys, kv_heads, head_dim)
 
         k = take(key_pages, key_scales)
         v = take(value_pages, value_scales)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                        preferred_element_type=jnp.float32) * sm_scale
-        live = mask[:, None, :, j * keys:(j + 1) * keys]
-        s = jnp.where(live, s, _NEG_INF)       # [slots, H, seq, keys]
+        live = mask[:, None, None, :, j * keys:(j + 1) * keys]
+        s = jnp.where(live, s, _NEG_INF)   # [slots, H_kv, G_q, seq, keys]
         m_next = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_next)
         p = jnp.where(live, jnp.exp(s - m_next), 0.0)
         l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc * alpha + jnp.einsum(
-            "bhqk,bkhd->bhqd", p.astype(pv_dtype), v.astype(pv_dtype),
+            "bhgqk,bkhd->bhgqd", p.astype(pv_dtype), v.astype(pv_dtype),
             preferred_element_type=jnp.float32)
         m = m_next
     safe_l = jnp.where(l == 0.0, 1.0, l)
     out_dtype = q.dtype if quantized else value_pages.dtype
     out = (acc / safe_l).astype(out_dtype)
-    return jnp.transpose(out, (0, 2, 1, 3))
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(q.shape)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "banded"))
 def _paged_call(q, key_pages, value_pages, page_table, allowed, *scales,
-                sm_scale, interpret):
+                sm_scale, interpret, banded=False):
     """One device's call, `[slots, seq, H', D]` over its H' heads: the
     walk's geometry from the shapes, then the kernel. Jitted because a
     model's layers all make this call with the same shapes, and a
@@ -585,35 +687,51 @@ def _paged_call(q, key_pages, value_pages, page_table, allowed, *scales,
     times: PERF.md section 6, PR 27)."""
     slots, seq, local_heads, head_dim = q.shape
     page_size = key_pages.shape[1]
-    width = local_heads * head_dim
+    width = key_pages.shape[2]
+    kv_heads = width // head_dim
+    q_group = local_heads // kv_heads
     group = group_pages(page_size, local_heads, width,
                         key_pages.dtype.itemsize, seq,
                         page_table.shape[1])
     config = _PagedConfig(sm_scale=sm_scale, heads=local_heads, seq=seq,
                           page_size=page_size, group=group,
-                          interpret=interpret, quantized=bool(scales))
+                          interpret=interpret, quantized=bool(scales),
+                          kv_heads=kv_heads, banded=banded)
     table, mask, live_pages = _grouped(page_table, allowed, page_size,
                                        group)
+    first = (_first_groups(allowed, page_size, group) if banded
+             else None)
+    hp = _round_up(local_heads, _SUBLANES)
 
     def by_group(sc):
-        """[N, H'] page scales -> [slots, groups, H8, G]: each group's
-        rows through the table, heads on sublanes."""
-        rows = jnp.pad(sc[table], ((0, 0), (0, 0), (
-            0, _round_up(local_heads, _SUBLANES) - local_heads)))
+        """[N, H_kv'] page scales -> [slots, groups, H8, G]: each
+        group's rows through the table, a row a query head, heads on
+        sublanes."""
+        rows = jnp.repeat(sc[table], q_group, axis=-1)
+        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, hp - local_heads)))
         return jnp.swapaxes(
             rows.reshape(slots, -1, group, rows.shape[-1]), 2, 3)
 
+    if q_group == 1:
+        rows = q.reshape(slots, seq, width)
+    else:
+        # A row a query head, `head_dim` wide: the kernel lays each
+        # over the lanes of the key/value head it reads.
+        rows = jnp.pad(q, ((0, 0), (0, 0), (0, hp - local_heads),
+                           (0, 0))).reshape(slots, seq * hp, head_dim)
     out = _paged_forward(
-        config, q.reshape(slots, seq, width), key_pages, value_pages,
-        table, live_pages, mask.astype(jnp.int32),
-        *(by_group(sc) for sc in scales))
+        config, rows, key_pages, value_pages, table, live_pages,
+        mask.astype(jnp.int32), first, *(by_group(sc) for sc in scales))
+    if q_group > 1:
+        out = out.reshape(slots, seq, hp, head_dim)[:, :, :local_heads]
     return out.reshape(q.shape)
 
 
 def paged_decode_attention(q, key_pages, value_pages, page_table,
                            allowed, sm_scale=None,
                            interpret: Optional[bool] = None,
-                           key_scales=None, value_scales=None):
+                           key_scales=None, value_scales=None,
+                           window: Optional[int] = None):
     """Pallas paged decode attention; layouts as the reference.
 
     Handles both the seq=1 plain tick and the seq=spec_k+1 speculative
@@ -622,7 +740,11 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     tolerance-level, not bitwise; fully-masked rows (evicted slots)
     output exact zeros. With scales given the pages
     are int8 and the kernel dequantizes in its block loads (module
-    docstring).
+    docstring). With `window` (a window layer: the caller's `allowed`
+    already carries the band) the walk starts at the slot's first
+    live page, so a tick reads at most
+    `ceil(window / page_size) + 1` pages a slot, rounded out to
+    groups, and the call goes under `PAGED_DECODE_WINDOW`.
 
     interpret: None (default) compiles the kernel on TPU and runs the
     lax page-walk form of the same math elsewhere; True forces Pallas
@@ -633,11 +755,7 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     slots, seq, heads, head_dim = q.shape
     pages_per_slot = page_table.shape[1]
     cache_len = pages_per_slot * page_size
-    if key_pages.ndim != 3 or key_pages.shape[2] != heads * head_dim:
-        raise ValueError(
-            "key_pages must be [num_pages, page_size, heads * head_dim"
-            " = {}] — the paged decode cache stores full-width heads; "
-            "got {}.".format(heads * head_dim, key_pages.shape))
+    kv_heads = _kv_heads(q, key_pages)
     if value_pages.shape != key_pages.shape:
         raise ValueError(
             "key_pages and value_pages must have identical shapes; "
@@ -649,7 +767,7 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
     quantized = _check_scales(key_pages, key_scales, value_scales,
-                              heads)
+                              kv_heads)
     if interpret is None:
         if jax.default_backend() != "tpu":
             return _paged_walk_lax(q, key_pages, value_pages,
@@ -667,7 +785,7 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     def plan(mesh):
         """Heads over the model axis; slots share the pool, so every
         other axis sees the whole call."""
-        tp = partition.model_axis(mesh, heads)
+        tp = partition.model_axis(mesh, heads, kv_heads)
         by_head = P(None, None, tp, None)
         pages = P(None, None, tp)
         specs = [by_head, pages, pages, P(), P()]
@@ -676,7 +794,8 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
         return tuple(specs), by_head, None
 
     kernel = functools.partial(_paged_call, sm_scale=float(sm_scale),
-                               interpret=bool(interpret))
+                               interpret=bool(interpret),
+                               banded=bool(window))
     return partition.per_shard(kernel, args, plan, interpret)
 
 
@@ -688,7 +807,8 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
 def paged_attention(q, key_pages, value_pages, page_table, allowed,
                     sm_scale=None, impl="auto",
                     interpret: Optional[bool] = None,
-                    key_scales=None, value_scales=None):
+                    key_scales=None, value_scales=None,
+                    window: Optional[int] = None):
     """Dispatching paged decode attention: Pallas kernel or gathered lax.
 
     impl: "paged" forces the kernel, "reference" forces the gathered
@@ -699,7 +819,10 @@ def paged_attention(q, key_pages, value_pages, page_table, allowed,
     (interpret mode off-TPU, so CPU CI drives the kernel code path),
     "0" forces the reference, unset/empty defers to `impl`.
     key_scales/value_scales select int8-page mode on whichever impl is
-    picked (the dequant contract in the module docstring).
+    picked (the dequant contract in the module docstring). `window`
+    says that `allowed` is a window layer's band (the mask itself
+    decides every weight): the kernel's walk then starts at the first
+    live page.
     """
     env = os.environ.get("CLOUD_TPU_PAGED_KERNEL", "").strip()
     if env == "1":
@@ -718,7 +841,8 @@ def paged_attention(q, key_pages, value_pages, page_table, allowed,
                                       sm_scale=sm_scale,
                                       interpret=interpret,
                                       key_scales=key_scales,
-                                      value_scales=value_scales)
+                                      value_scales=value_scales,
+                                      window=window)
     return paged_attention_reference(q, key_pages, value_pages,
                                      page_table, allowed,
                                      sm_scale=sm_scale,

@@ -132,8 +132,55 @@ def _pool_pages_view(cache):
     view = _map_attention(
         cache, lambda att: {k: (v if k in _GATHER_READS else None)
                             for k, v in att.items()})
-    view["pos_count"] = None
+    if "pos_count" in view:     # TransformerLM's learned positions
+        view["pos_count"] = None
     return view
+
+
+def attention_shape(model):
+    """(query heads, key/value heads, head size) of a served model:
+    what a page row holds is key/value heads x head size."""
+    heads = model.num_heads
+    kv_heads = getattr(model, "num_kv_heads", None) or heads
+    head_dim = getattr(model, "head_dim", None) or model.d_model // heads
+    return heads, kv_heads, head_dim
+
+
+def _served_model(model, role):
+    """The two classes whose attention writes and reads the page pool
+    (`decoding.paged_kv_attention`): the class of the model decides
+    every difference, there is no switch."""
+    from cloud_tpu.models.llama import LlamaLM
+    from cloud_tpu.models.transformer import TransformerLM
+    from cloud_tpu.parallel import SEQUENCE_PARALLEL_IMPLS
+
+    if not isinstance(model, (TransformerLM, LlamaLM)):
+        raise NotImplementedError(
+            "graftserve serves TransformerLM and LlamaLM (their "
+            "attention reads the page pool); got {} as the {}."
+            .format(type(model).__name__, role))
+    if isinstance(model, LlamaLM) and (
+            model.attn_logit_softcap
+            or model.attention_impl in SEQUENCE_PARALLEL_IMPLS):
+        raise NotImplementedError(
+            "the paged pool serves no attn_logit_softcap and no "
+            "sequence-parallel attention_impl.")
+
+
+def _moe_counters(stats):
+    """An expert model's sown counters of one apply (`moe.MOE_STATS`:
+    a tuple a call under each expert layer's path), summed over the
+    layers by name; {} for a model with no expert layer."""
+    totals = {}
+
+    def walk(tree):
+        for name, value in tree.items():
+            if isinstance(value, tuple):
+                totals[name] = totals.get(name, 0) + sum(value)
+            else:
+                walk(value)
+    walk(stats)
+    return totals
 
 
 def _sample_one(logits, key, temperature, top_k, top_p):
@@ -423,12 +470,7 @@ class DecodeEngine:
     def __init__(self, model, params, slots, page_size, num_pages,
                  max_new_cap=None, draft_model=None, draft_params=None,
                  spec_k=0, page_dtype="", ladder=None):
-        from cloud_tpu.models.transformer import TransformerLM
-
-        if not isinstance(model, TransformerLM):
-            raise NotImplementedError(
-                "graftserve v1 serves TransformerLM (dense causal "
-                "attention); got {}.".format(type(model).__name__))
+        _served_model(model, "model")
         if model.max_seq_len % page_size:
             raise ValueError(
                 "max_seq_len ({}) must be a multiple of page_size "
@@ -489,10 +531,7 @@ class DecodeEngine:
         self.cache = _plain(empty_cache(self._paged, self.slots))
 
         if self.spec_on:
-            if not isinstance(draft_model, TransformerLM):
-                raise NotImplementedError(
-                    "draft_model must be a TransformerLM; got "
-                    "{}.".format(type(draft_model).__name__))
+            _served_model(draft_model, "draft_model")
             if draft_model.vocab_size != model.vocab_size:
                 raise ValueError(
                     "draft vocab_size ({}) must match target ({}) — "
@@ -568,6 +607,11 @@ class DecodeEngine:
         self._promote = best_effort_donation(functools.partial(
             jit, donate_argnums=(0,))(self._promote_impl))
         self._warm_stats = None
+        #: The last tick's expert-layer counters, still on the device
+        #: (`_moe_counters`; {} for a model with no expert layer and
+        #: under speculation): the scheduler fetches them with the
+        #: tick's tokens, in the one read-back a tick makes.
+        self.tick_counters = {}
 
     # -- prefill ------------------------------------------------------
 
@@ -768,7 +812,7 @@ class DecodeEngine:
                 self._params, self._draft_params, self.cache,
                 self.draft_cache, self.ctl)
         else:
-            self.cache, self.ctl, out = self._tick(
+            self.cache, self.ctl, out, self.tick_counters = self._tick(
                 self._params, self.cache, self.ctl)
         return out
 
@@ -891,11 +935,12 @@ class DecodeEngine:
 
         result = _map_attention(pool_cache, seed, dense_cache)
         # _map_attention keeps non-attention leaves from its FIRST
-        # tree; the only one is pos_count, stripped to None by the
-        # caller's _pool_pages_view (its pool shape [slots] would bind
-        # the geometry) — install the dense [1] counter at the prefix
-        # depth.
-        result["pos_count"] = jnp.full((1,), prefix_len, jnp.int32)
+        # tree; the only one is TransformerLM's pos_count, stripped to
+        # None by the caller's _pool_pages_view (its pool shape [slots]
+        # would bind the geometry) — install the dense [1] counter at
+        # the prefix depth.
+        if "pos_count" in result:
+            result["pos_count"] = jnp.full((1,), prefix_len, jnp.int32)
         return result
 
     def _scatter_request(self, cache, pcache, slot, page_vec,
@@ -957,8 +1002,9 @@ class DecodeEngine:
             return out
 
         new_cache = _map_attention(cache, scatter, pcache)
-        new_cache["pos_count"] = cache["pos_count"].at[slot].set(
-            pcache["pos_count"][0])
+        if "pos_count" in cache:
+            new_cache["pos_count"] = cache["pos_count"].at[slot].set(
+                pcache["pos_count"][0])
         return new_cache
 
     def _arm_ctl(self, ctl, slot, step_keys_row, max_steps, first_tok,
@@ -1004,10 +1050,13 @@ class DecodeEngine:
         return new_cache, new_dcache, out_ctl
 
     def _tick_impl(self, params, cache, ctl):
+        from cloud_tpu.models.moe import MOE_STATS
+
         active = ctl["active"]
         logits, vars_ = self._paged.apply(
             {"params": params, "cache": cache},
-            ctl["cur_tok"][:, None], active[:, None], mutable=["cache"])
+            ctl["cur_tok"][:, None], active[:, None],
+            mutable=["cache", MOE_STATS])
         logits = logits[:, 0]  # [S, V]
         # Slot s's step i consumes generate()'s step_rngs[i]; after
         # insertion steps_done is 1 (the prefill token), so the first
@@ -1033,7 +1082,8 @@ class DecodeEngine:
         out_ctl["steps_done"] = steps
         out = jnp.stack([jnp.where(active, nxt, -1),
                          finished.astype(jnp.int32)])
-        return _plain(vars_["cache"]), out_ctl, out
+        return (_plain(vars_["cache"]), out_ctl, out,
+                _moe_counters(_plain(vars_.get(MOE_STATS, {}))))
 
     def _spec_tick_impl(self, params, draft_params, cache, dcache, ctl):
         """Draft/verify speculation, one executable per tick:
@@ -1135,10 +1185,12 @@ class DecodeEngine:
         # slots neither move their pointers nor validate anything).
         delta_t = jnp.where(active, k + 1 - c, 0)
         cache = paged_slot_rewind(cache, delta_t, self.max_seq_len)
-        cache["pos_count"] = cache["pos_count"] - delta_t
+        if "pos_count" in cache:
+            cache["pos_count"] = cache["pos_count"] - delta_t
         delta_d = jnp.where(active, jnp.maximum(k - c, 0), 0)
         dcache = paged_slot_rewind(dcache, delta_d, self.max_seq_len)
-        dcache["pos_count"] = dcache["pos_count"] - delta_d
+        if "pos_count" in dcache:
+            dcache["pos_count"] = dcache["pos_count"] - delta_d
         catch = active & (c == k + 1)
         _, dvars = self._paged_draft.apply(
             {"params": draft_params, "cache": dcache},
@@ -1249,13 +1301,12 @@ class DecodeEngine:
         on the same page ids). Feeds PagePool.page_bytes for the
         KV-hierarchy gauges."""
         def per_model(m):
-            head_dim = m.d_model // m.num_heads
+            _, kv_heads, head_dim = attention_shape(m)
             item = (1 if self.page_dtype == "int8"
                     else jnp.dtype(m.compute_dtype).itemsize)
-            per_layer = 2 * self.page_size * m.num_heads * head_dim \
-                * item
+            per_layer = 2 * self.page_size * kv_heads * head_dim * item
             if self.page_dtype == "int8":
-                per_layer += 2 * m.num_heads * 4
+                per_layer += 2 * kv_heads * 4
             return per_layer * m.num_layers
 
         total = per_model(self.model)
@@ -1273,7 +1324,9 @@ class DecodeEngine:
             return out
 
         new_cache = _map_attention(cache, clear)
-        new_cache["pos_count"] = jnp.where(keep, cache["pos_count"], 0)
+        if "pos_count" in cache:
+            new_cache["pos_count"] = jnp.where(keep, cache["pos_count"],
+                                               0)
         return new_cache
 
     def _evict_impl(self, cache, ctl, evict_mask):
@@ -1311,8 +1364,9 @@ class DecodeEngine:
             return out
 
         new_cache = _map_attention(cache, rs)
-        new_cache["pos_count"] = jnp.where(mask,
-                                           cache["pos_count"][src], 0)
+        if "pos_count" in cache:
+            new_cache["pos_count"] = jnp.where(
+                mask, cache["pos_count"][src], 0)
         return new_cache
 
     def _resize_ctl(self, ctl, perm):

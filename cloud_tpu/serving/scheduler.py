@@ -126,7 +126,7 @@ from cloud_tpu.monitoring import spans
 from cloud_tpu.ops.paged_attention import group_pages, walked_tokens
 from cloud_tpu.parallel import runtime
 from cloud_tpu.serving import reqtrace
-from cloud_tpu.serving.engine import DecodeEngine
+from cloud_tpu.serving.engine import DecodeEngine, attention_shape
 from cloud_tpu.serving.faults import (HostTierCorrupt, PoolSqueezed,
                                       PrefillFailed, ServeShed,
                                       SlotEvicted, SlotHang, fault_kind)
@@ -442,14 +442,24 @@ class Scheduler:
         # the share of the walk that is not dead. A verify window
         # reaches `_kv_reach` keys past the plain tick's.
         m = self.engine.model
+        heads, kv_heads, head_dim = attention_shape(m)
         self._kv_reach = self.engine.spec_k if self.engine.spec_on else 0
         self._kv_group = group_pages(
-            page_size, m.num_heads, m.d_model,
+            page_size, heads, kv_heads * head_dim,
             1 if kv_dtype == "int8"
             else np.dtype(m.compute_dtype).itemsize,
             self._kv_reach + 1, self.engine.pages_per_slot)
         self._kv_live_tokens = 0
         self._kv_walked_tokens = 0
+        # An expert model's counters, summed over ticks and expert
+        # layers (`moe.MOE_STATS`): (token, choice) pairs of active
+        # slots, those whose expert is held here, held experts that
+        # got at least one pair (a layer and tick), and the pairs a
+        # held expert. They come back with each tick's tokens.
+        self._moe_pairs_routed = 0
+        self._moe_pairs_held = 0
+        self._moe_experts_touched = 0
+        self._moe_expert_load = None
         from cloud_tpu.monitoring.telemetry import Histogram
         self._ttft_hist = Histogram("ttft")
         self._ttft_hit_hist = Histogram("ttft_hit")
@@ -1648,7 +1658,8 @@ class Scheduler:
                     with spans.span("tick_dispatch"):
                         out = self.engine.tick()
                     with spans.span("tick_fetch"):
-                        fetched = runtime.device_fetch(out)
+                        fetched, counters = runtime.device_fetch(
+                            (out, self.engine.tick_counters))
                     t_commit = time.monotonic()
                 elapsed = t_commit - t0
                 self._ticks += 1
@@ -1659,6 +1670,8 @@ class Scheduler:
                 self._t_last_commit = t_commit
                 with spans.span("tick_commit"):
                     self._distribute(fetched, elapsed, t_commit)
+                    if counters:
+                        self._count_moe(counters)
                 if self.strict_no_retrace:
                     self.engine.check_no_retrace()
         except BaseException as exc:  # noqa: BLE001
@@ -2132,6 +2145,14 @@ class Scheduler:
                                ticks=self._ticks,
                                slots=self.engine.slots)
 
+    def _count_moe(self, counters):
+        self._moe_pairs_routed += int(counters["pairs_routed"])
+        self._moe_pairs_held += int(counters["pairs_held"])
+        self._moe_experts_touched += int(counters["experts_touched"])
+        load = np.asarray(counters["expert_load"], np.int64)
+        self._moe_expert_load = (load if self._moe_expert_load is None
+                                 else self._moe_expert_load + load)
+
     def _distribute_plain(self, fetched, t_commit):
         tokens_row, finished_row = fetched[0], fetched[1]
         evict_mask = np.zeros((self.engine.slots,), bool)
@@ -2477,6 +2498,10 @@ class Scheduler:
         self._tick_paces = 0
         self._kv_live_tokens = 0
         self._kv_walked_tokens = 0
+        self._moe_pairs_routed = 0
+        self._moe_pairs_held = 0
+        self._moe_experts_touched = 0
+        self._moe_expert_load = None
         self._t_last_commit = None
         self._completed = 0
         self._tokens_out = 0
@@ -2587,6 +2612,11 @@ class Scheduler:
             "tick_paces": self._tick_paces,
             "kv_live_tokens": self._kv_live_tokens,
             "kv_walked_tokens": self._kv_walked_tokens,
+            "moe_pairs_routed": self._moe_pairs_routed,
+            "moe_pairs_held": self._moe_pairs_held,
+            "moe_experts_touched": self._moe_experts_touched,
+            "moe_expert_load": ([] if self._moe_expert_load is None
+                                else self._moe_expert_load.tolist()),
             "elapsed_seconds": wall,
             "requests_per_sec": self._completed / wall,
             "tokens_per_sec": self._tokens_out / wall,

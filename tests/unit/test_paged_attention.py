@@ -504,3 +504,91 @@ def test_cost_hook():
     cache_len = 16 * 4
     dense_gather = 2 * 8 * cache_len * 8 * 64 * 2  # K+V, bf16
     assert cost["bytes_moved"] < 2 * dense_gather
+
+
+# ---------------------------------------------------------------------------
+# Grouped queries (H_kv-wide rows) and a window layer's band
+# ---------------------------------------------------------------------------
+
+
+def _grouped_case(kind, seq, window, depths=(250, 37, 0), heads=8,
+                  kv_heads=2, head_dim=128):
+    """3 slots over `EDGE_PAGES` 16-token pages whose rows hold
+    `kv_heads` heads; slot s is `depths[s]` tokens deep (0: evicted).
+    Returns (reference, walk, interpreted kernel, tolerance)."""
+    rng = np.random.default_rng(11)
+    slots = len(depths)
+    num_pages = slots * EDGE_PAGES + 1
+    cache_len = EDGE_PAGES * 16
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(slots, seq, heads, head_dim)), dtype)
+    rows = lambda: rng.normal(size=(num_pages, 16, kv_heads * head_dim))
+    k, v = rows(), rows()
+    pt = jnp.asarray(1 + rng.permutation(num_pages - 1).reshape(
+        slots, EDGE_PAGES), jnp.int32)
+    depth = np.asarray(depths)[:, None, None]
+    at = depth - seq + np.arange(seq)[None, :, None]     # query positions
+    keys = np.arange(cache_len)[None, None, :]
+    allowed = (keys <= at) & (depth > 0)
+    if window:
+        allowed &= keys > at - window
+    allowed = jnp.asarray(allowed)
+    scales = {}
+    if kind == "int8":
+        def quantize(x):
+            x = x.reshape(num_pages, 16, kv_heads, head_dim)
+            scale = np.abs(x).max(axis=(1, 3)) / 127.0
+            quant = np.round(x / scale[:, None, :, None])
+            return (jnp.asarray(quant.reshape(num_pages, 16, -1), jnp.int8),
+                    jnp.asarray(scale, jnp.float32))
+        (k, ks), (v, vs) = quantize(k), quantize(v)
+        scales = dict(key_scales=ks, value_scales=vs)
+    else:
+        k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+    ref = pa.paged_attention_reference(q, k, v, pt, allowed, **scales)
+    walk = pa._paged_walk_lax(q, k, v, pt, allowed,
+                              1.0 / np.sqrt(head_dim), **scales)
+    kern = pa.paged_decode_attention(q, k, v, pt, allowed, interpret=True,
+                                     window=window, **scales)
+    as_f32 = lambda x: np.asarray(x, np.float32)
+    return (as_f32(ref), as_f32(walk), as_f32(kern),
+            2e-2 if kind == "bf16" else TOL)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+@pytest.mark.parametrize("seq", [1, 3])
+@pytest.mark.parametrize("kind", KINDS)
+def test_grouped_queries_and_window_parity(group_of_8, kind, seq, window):
+    """8 query heads on 2 key/value heads, rows 256 wide: kernel and
+    walk against the reference (`GQAttention`'s dense einsums), with
+    and without a band whose first key lies past the first group of a
+    250-token slot; an evicted slot reads exact zeros."""
+    ref, walk, kern, tol = _grouped_case(kind, seq, window)
+    np.testing.assert_allclose(kern[:2], ref[:2], atol=tol, rtol=tol)
+    np.testing.assert_allclose(walk[:2], ref[:2], atol=tol, rtol=tol)
+    assert not kern[2].any() and not walk[2].any()
+
+
+def test_window_walk_starts_at_the_first_live_group(group_of_8):
+    """A window layer's schedule: a slot 250 tokens deep with a band
+    of 40 keys (positions 210-249: pages 13-15, all in group 1) takes
+    one grid step, not two; a full layer's takes both."""
+    depth = np.array([250, 37, 0])[:, None, None]
+    keys = np.arange(EDGE_PAGES * 16)[None, None, :]
+    full = jnp.asarray((keys < depth) & (depth > 0))
+    band = jnp.asarray(np.asarray(full) & (keys > depth - 1 - 40))
+    table = jnp.zeros((3, EDGE_PAGES), jnp.int32)
+    most = 3 * (-(-EDGE_PAGES // group_of_8))
+
+    def steps(mask, banded):
+        _, _, live = pa._grouped(table, mask, 16, group_of_8)
+        first = (pa._first_groups(mask, 16, group_of_8) if banded
+                 else None)
+        slot_of, group_of, total = pa._schedule(live, group_of_8, most,
+                                                first)
+        total = int(total)
+        return list(zip(np.asarray(slot_of)[:total].tolist(),
+                        np.asarray(group_of)[:total].tolist()))
+
+    assert steps(full, False) == [(0, 0), (0, 1), (1, 0), (2, 0)]
+    assert steps(band, True) == [(0, 1), (1, 0), (2, 0)]

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from cloud_tpu.models import moe as moe_lib
 from cloud_tpu.monitoring import spans
 from cloud_tpu.ops import fused_mlp, fused_norm
 from cloud_tpu.serving import engine as engine_lib
@@ -78,7 +79,10 @@ def test_kernel_and_program_tables_equal_the_constants():
         attention_ops.FLASH_FWD, attention_ops.FLASH_BWD_DQ,
         attention_ops.FLASH_BWD_DKV, fused_mlp.FUSED_SWIGLU_FWD,
         fused_norm.FUSED_RMSNORM, fused_norm.FUSED_RMSNORM_RESIDUAL,
-        paged_ops.PAGED_DECODE)
+        paged_ops.PAGED_DECODE, paged_ops.PAGED_DECODE_WINDOW)
+    assert spans.names("Scopes") == (
+        moe_lib.MOE_ROUTER, moe_lib.MOE_ROUTED_EXPERTS,
+        moe_lib.MOE_SHARED_EXPERT)
     assert spans.names("Programs") == (
         trainer_lib.TRAIN_STEP, engine_lib.SERVE_TICK,
         engine_lib.SERVE_PREFILL, engine_lib.SLOT_INSERT,
@@ -144,6 +148,13 @@ def _kernel_cases():
                jnp.ones((2, 1, 32), bool), interpret=True),
            (jnp.ones((2, 1, 2, 64), F32), pages, pages),
            [paged_ops.PAGED_DECODE])
+    # Grouped queries (4 heads on 1 key/value head) over a window.
+    yield ("paged_decode_window",
+           lambda q, kp, vp: paged_ops.paged_decode_attention(
+               q, kp, vp, jnp.zeros((2, 4), jnp.int32),
+               jnp.ones((2, 1, 32), bool), interpret=True, window=8),
+           (jnp.ones((2, 1, 4, 128), F32), pages, pages),
+           [paged_ops.PAGED_DECODE_WINDOW])
 
 
 @pytest.mark.parametrize("case", list(_kernel_cases()),
@@ -318,3 +329,64 @@ def test_profile_capture_holds_the_spans_and_a_rid(tmp_path):
     # Spans of one request share its rid.
     assert rids["admit"] == rids["serve_prefill"] == wanted
     assert rids["prefill_dispatch"] == wanted
+
+
+# ------------------------------------------- an expert model's scopes
+
+@pytest.fixture(scope="module")
+def toy_expert_engine():
+    from cloud_tpu.models import LlamaLM
+    model = LlamaLM(vocab_size=64, num_layers=2, num_heads=2,
+                    num_kv_heads=1, d_model=32, d_ff=64, max_seq_len=32,
+                    compute_dtype=F32, attn_kinds="LG", sliding_window=8,
+                    moe_experts=8, moe_top_k=2, moe_router="sigmoid",
+                    moe_d_ff=16, moe_capacity_factor=None,
+                    moe_held_experts=(0, 1), first_k_dense=1)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    return engine_lib.DecodeEngine(model, params, slots=2, page_size=8,
+                                   num_pages=9), params
+
+
+@pytest.mark.parametrize("program", [engine_lib.SERVE_TICK,
+                                     engine_lib.SERVE_PREFILL])
+def test_expert_layer_lowers_under_its_declared_scopes(toy_expert_engine,
+                                                       program):
+    """Every part of the expert layer carries its scope in the op names
+    of the tick and of the prefill, under the program's own name."""
+    fn, args = _serve_programs(toy_expert_engine)[program]
+    lowered = fn.__wrapped__.lower(*args)
+    assert _module_name(lowered) == "jit_" + program
+    text = lowered.as_text(debug_info=True)
+    for scope in spans.names("Scopes"):
+        assert re.search(r"jit\({}\)/[^\"]*/moe/{}/".format(program, scope),
+                         text), scope
+
+
+def test_window_and_full_reads_are_told_apart_by_name(toy_expert_engine,
+                                                      monkeypatch):
+    """One window layer and one full layer: the tick's two paged reads
+    go under the two declared kernel names."""
+    # Traced only (nothing runs): what `impl="auto"` picks on the chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine, params = toy_expert_engine
+    names = _pallas_names(engine._tick_impl, params, engine.cache,
+                          engine.ctl)
+    paged = sorted(n.split(".")[-1] for n in names
+                   if n.startswith(paged_ops._CALL_PREFIX))
+    assert paged == [paged_ops.PAGED_DECODE, paged_ops.PAGED_DECODE_WINDOW]
+
+
+def test_counter_table_names_the_stats_an_expert_model_adds():
+    from cloud_tpu.models import TransformerLM
+    from cloud_tpu.serving import Scheduler
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=32, d_ff=64, max_seq_len=32,
+                          compute_dtype=F32)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    with Scheduler(model, params, slots=2, page_size=8) as sched:
+        stats = sched.stats()
+    table = spans.names("Counters")
+    assert set(table) == {k for k in stats if k.startswith("moe_")}
+    assert [stats[k] for k in table] == [0, 0, 0, []]
