@@ -236,9 +236,6 @@ def _requested_config():
         cfg["async_log"] = True
     if os.environ.get("BENCH_WARM", "0") == "1":
         cfg["warm"] = True
-    for key in ("CLOUD_TPU_FLASH_BLOCK_Q", "CLOUD_TPU_FLASH_BLOCK_K"):
-        if os.environ.get(key):
-            cfg[key.lower()] = _env_int(key, 0)
     if _CFG_NAME:
         # Provenance only (the expanded knobs above are what the run
         # measured).

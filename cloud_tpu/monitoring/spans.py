@@ -74,9 +74,13 @@ beside the call), and the trace's op text carries it.
 
 Kernels:
 
-    flash_fwd               ops/attention.py forward
-    flash_bwd_dq            ops/attention.py backward, dq
-    flash_bwd_dkv           ops/attention.py backward, dk and dv
+    flash_fwd               ops/attention.py forward: one call a layer,
+                            one grid step a kv head and live span pair
+                            (`ops.attention.flash_plan` has the tiles,
+                            spans and step counts of a shape)
+    flash_bwd_dq            ops/attention.py backward, dq (as above)
+    flash_bwd_dkv           ops/attention.py backward, dk and dv (as
+                            above, the group's heads summed inside)
     fused_swiglu_fwd        ops/fused_mlp.py forward
     fused_rmsnorm           ops/fused_norm.py, no residual
     fused_rmsnorm_residual  ops/fused_norm.py, residual add fused

@@ -5,21 +5,39 @@ wherever `tf.distribute` puts Keras layers (reference core/preprocess.py
 picks a strategy, TF picks kernels). Here the hot op is a hand-written
 TPU kernel: blockwise online-softmax attention that never materializes
 the [S, S] score matrix in HBM, keeps the matmuls on the MXU in bf16/f32,
-and streams K/V blocks through VMEM.
+and walks K/V blocks that sit in VMEM.
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
-- Grid is (batch*heads, q_blocks, k_blocks) with the k dimension
-  innermost; VMEM scratch (acc, m, l) carries the online-softmax state
-  across k steps, and the output block is written on the last k step.
-- m/l live in (block_q, 128) lane-broadcast scratch, and the saved
-  logsumexp residual is materialized lane-broadcast ([BH, S, 128]) so the
-  backward kernels can read it without cross-lane relayouts (Mosaic has
-  no cheap (N,1)<->(1,N) transpose).
-- Causal blocks strictly above the diagonal are skipped via `pl.when`.
-- Backward = two kernels (dq over k-blocks; dk/dv over q-blocks), the
-  standard FlashAttention-2 recomputation split, wired through
-  `jax.custom_vjp`.
-- Sequences are padded to a block multiple outside the custom_vjp, so
+- Two levels of tiling, both from the shapes (`flash_plan`). A GRID STEP
+  holds one kv head's span of k/v rows and a span of q rows of every q
+  head of its group: the whole padded sequence while that fits
+  `_VMEM_BUDGET`, else the most whole tiles that do. Inside the step a
+  `fori_loop` walks `[block_q, block_k]` score tiles over the spans. A
+  turn of that loop has a fixed latency of 0.5-0.75 us on the v5e
+  (product, row max, exp, row sum, product: a chain), the same a tile as
+  a grid step of the old 128 x 128 grid cost (PERF.md section 6, PR 29),
+  so a turn carries a fat tile and the group's heads side by side,
+  independent chains the scheduler interleaves; and neither level
+  visits a dead tile: the grid is the list of span pairs that hold a
+  visible entry (the schedule rides in scalar prefetch, as in
+  ops/paged_attention.py), and the in-kernel walk runs between the
+  causal / band bounds of its rows (`_live_blocks`, the same arithmetic
+  `flash_plan` counts with).
+- The online-softmax state (acc, m, l) is carried as values along a
+  row block's k walk and parked in VMEM scratch between span pairs; m/l
+  scratch is (group, span, 128) lane-broadcast.
+- The saved logsumexp and the backward's delta travel as ROWS,
+  `[B*H_kv, G, S/block_q, 1, block_q]` f32 (4 bytes a query, not 512): the
+  forward transposes its lane-broadcast column once a row block, dq
+  transposes back once a row block, and dk/dv computes the transposed
+  score tile `K Q^T`, where a query's statistic is a lane and broadcasts
+  down the sublanes for nothing (and dV = P^T dO, dK = dS^T Q become
+  plain products).
+- Backward = two kernels (dq walks k blocks; dk/dv walks q blocks of
+  every q head of the kv head's group, summing the group in its
+  accumulator), the standard FlashAttention-2 recomputation split,
+  wired through `jax.custom_vjp`.
+- Sequences are padded to a tile multiple outside the custom_vjp, so
   autodiff of pad/slice handles the edges; padded keys are masked inside
   the kernel, padded dO rows are zero so they contribute nothing.
 
@@ -30,11 +48,11 @@ kernels run per shard (ops/partition.py).
 
 import functools
 import math
-import os
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -63,15 +81,28 @@ _LANES = 128
 class _Config(NamedTuple):
     causal: bool
     sm_scale: float
-    block_q: int
-    block_k: int
+    tiles: "FlashPlan"  # the pass's tiles, spans and schedule
     kv_len: int  # true (unpadded) sequence length
-    heads: int   # q heads, folded into the grid's leading batch*heads dim
-    has_mask: bool  # per-example key mask streamed as [B, 1, S_pad] blocks
+    heads: int   # q heads of the call (a key mask row serves them all)
+    has_mask: bool  # per-example key mask, a (1, block_k) row a k block
     interpret: bool
     kv_group: int = 1  # q heads per kv head (grouped-query attention)
     window: int = 0  # sliding-window width; 0 = full causal
     softcap: float = 0.0  # Gemma2-style tanh logit cap; 0 = off
+    backward: Optional["FlashPlan"] = None  # the backward's own tiles
+
+    block_q = property(lambda self: self.tiles.block_q)
+    block_k = property(lambda self: self.tiles.block_k)
+    span_q = property(lambda self: self.tiles.span_q)
+    span_k = property(lambda self: self.tiles.span_k)
+    seq_pad = property(lambda self: self.tiles.seq_pad)
+    pairs = property(lambda self: self.tiles.pairs)
+
+    def for_backward(self):
+        """This call's config with the backward passes' plan in place
+        (same `block_q`: the saved logsumexp's rows are laid out by
+        it)."""
+        return self._replace(tiles=self.backward, backward=None)
 
 
 def repeat_kv(k, num_heads):
@@ -156,183 +187,448 @@ def mha_reference(q, k, v, causal=True, sm_scale=None, mask=None,
 
 
 # ---------------------------------------------------------------------------
-# Forward kernel
+# Tiles and schedule
 # ---------------------------------------------------------------------------
 
 
-def _block_mask(config, qi, ki, mask_ref):
-    """Combined validity mask for one (block_q, block_k) tile: global
-    kv padding, causal structure, and (when present) the per-example
-    key mask block."""
-    block_q, block_k = config.block_q, config.block_k
-    col = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = col < config.kv_len
+class FlashPlan(NamedTuple):
+    """What `flash_plan` chose for a shape; the kernels' grids and
+    walks are built from it."""
+    block_q: int  # rows of a score tile
+    block_k: int  # columns of a score tile
+    span_q: int   # q rows (of every head of a group) a grid step holds
+    span_k: int   # k/v rows a grid step holds
+    seq_pad: int  # the sequence padded to whole spans
+    pairs: Tuple[Tuple[int, int], ...]  # live (q span, k span), q-major
+
+    def grid_steps(self, batch=1, kv_heads=1):
+        """Grid steps of each pass for a call over `batch * kv_heads`
+        key/value heads: every pass runs one step a kv head and live
+        span pair (its group's q heads ride in the step)."""
+        steps = batch * kv_heads * len(self.pairs)
+        return {FLASH_FWD: steps, FLASH_BWD_DQ: steps,
+                FLASH_BWD_DKV: steps}
+
+    def tiles(self, causal=True, window=0):
+        """Score tiles a q head's walk visits in one pass: `walked` by
+        the bounds the kernels loop between, `live` by asking every
+        tile of the square whether it holds a visible entry, `dense`
+        the square itself. `pairs` / `pairs_live` count the grid's
+        span pairs the same two ways."""
+        per_q = self.span_q // self.block_q
+        per_k = self.span_k // self.block_k
+        walked = 0
+        for qi, kj in self.pairs:
+            for s in range(per_q):
+                lo, hi = _live_blocks(
+                    qi * self.span_q + s * self.block_q, self.block_q,
+                    self.block_k, kj * per_k, (kj + 1) * per_k, causal,
+                    window)
+                walked += max(hi - lo, 0)
+        rows = range(0, self.seq_pad, self.block_q)
+        cols = range(0, self.seq_pad, self.block_k)
+        live = sum(_live(r0, self.block_q, c0, self.block_k, causal,
+                         window) for r0 in rows for c0 in cols)
+        pairs_live = sum(
+            _live(r0, self.span_q, c0, self.span_k, causal, window)
+            for r0 in range(0, self.seq_pad, self.span_q)
+            for c0 in range(0, self.seq_pad, self.span_k))
+        return {"walked": walked, "live": live,
+                "dense": len(rows) * len(cols),
+                "pairs": len(self.pairs), "pairs_live": pairs_live}
+
+
+def _live(r0, rows, c0, cols, causal, window):
+    """Whether rows [r0, r0 + rows) see any of columns [c0, c0 + cols):
+    at or below the diagonal and, under a window, not wholly left of
+    the band (col > row - window)."""
+    if not causal:
+        return True
+    seen = c0 <= r0 + rows - 1
+    if window:
+        seen = seen and c0 + cols - 1 > r0 - window
+    return seen
+
+
+def _live_blocks(start, size, block, lo, hi, causal, window,
+                 transposed=False):
+    """[lo', hi') within [lo, hi): the blocks of `block` columns that
+    rows [start, start + size) see — or, `transposed`, the blocks of
+    `block` rows that see columns [start, start + size). Python ints
+    (`flash_plan`) or traced scalars (the kernels' loop bounds)."""
+    if not causal:
+        return lo, hi
+    traced = not isinstance(start, int)
+    most = jnp.maximum if traced else max
+    least = jnp.minimum if traced else min
+    if transposed:
+        lo = most(lo, start // block)
+        if window:
+            hi = least(hi, (start + size - 2 + window) // block + 1)
+        return lo, hi
+    hi = least(hi, (start + size - 1) // block + 1)
+    if window:
+        lo = most(lo, most(start - window + 1, 0) // block)
+    return lo, hi
+
+
+#: VMEM the Pallas calls may take (`vmem_limit_bytes`; the v5e has
+#: 128 MiB). `flash_plan` gives a grid step's resident blocks and
+#: scratch half of it and leaves the rest to the walk's score tiles.
+_VMEM_BUDGET = 64 * 1024 * 1024
+
+#: The f32 score tile a walk aims at, `[_BLOCK_Q, bytes / 4 /
+#: _BLOCK_Q]`, from the v5e's sweep at the train cell's shape (PERF.md
+#: section 6, PR 29). A turn of the forward's walk rescales the running
+#: max, sum and accumulator, work that costs as much for a narrow k
+#: block as for a wide one: 256 x 1024. The backward has no such
+#: per-turn work, holds two tiles a head (P and dS) and is bound by its
+#: products, so the less of the causal diagonal's dead half a tile
+#: drags in the better: 256 x 256.
+_BLOCK_Q = 256
+_SCORE_TILE_BYTES = 1024 * 1024
+_SCORE_TILE_BYTES_BACKWARD = 256 * 1024
+
+
+def _pow2_tile(target, cap):
+    """The largest 128 * 2^n within both `target` and `cap`."""
+    size = _LANES
+    while size * 2 <= min(target, cap):
+        size *= 2
+    return size
+
+
+def flash_plan(seq, head_dim, group=1, itemsize=2, causal=True, window=0,
+               block_q=None, block_k=None, backward=False):
+    """Tiles, spans and schedule for `seq` tokens at `head_dim`, `group`
+    q heads a kv head, operands of `itemsize` bytes.
+
+    Tiles. The score tile is `[block_q, block_k]` f32 of
+    `_SCORE_TILE_BYTES` (256 x 1024; `backward`, over the sequence as
+    the forward padded it: `_SCORE_TILE_BYTES_BACKWARD`, 256 x 256).
+    Each side is a 128 * 2^n of at most the sequence padded to 128 and,
+    under a window, the window padded to 128 (a wider tile would
+    compute columns the band never shows its rows), halved while
+    padding the sequence to it would add over an eighth. Explicit
+    `block_q` / `block_k` replace the rule (they must divide one
+    another).
+
+    Spans. A grid step's resident set gets half of `_VMEM_BUDGET`,
+    minor dims padded to 128 lanes. The k side comes first, a row of it
+    at the cost of the widest pass (dk/dv: k, v, dk, dv double-buffered
+    and two f32 accumulators): the whole padded sequence when that is
+    within half of the share. Then the q side, a row of it costing
+    every head of the group its q, dO and dq (or o) double-buffered, an
+    f32 accumulator and the lane-broadcast m and l. A side that does
+    not fit whole gets the most whole tiles that do and that divide the
+    padded sequence evenly, and the grid walks the span pairs that hold
+    a visible entry.
+    """
+    window = int(window or 0)
+    lane_seq = -(-seq // _LANES) * _LANES
+    auto = block_q is None or block_k is None
+    cap = min(lane_seq, -(-window // _LANES) * _LANES) if window \
+        else lane_seq
+    if block_q is None:
+        block_q = _pow2_tile(_BLOCK_Q, cap)
+    if block_k is None:
+        block_k = _pow2_tile(
+            (_SCORE_TILE_BYTES_BACKWARD if backward
+             else _SCORE_TILE_BYTES) // (4 * block_q), cap)
+    small, large = sorted((block_q, block_k))
+    if small <= 0 or large % small:
+        raise ValueError(
+            "block_q={} and block_k={} must divide one another.".format(
+                block_q, block_k))
+
+    def pads_too_far(tile):
+        padded = -(-seq // tile) * tile
+        return padded > (seq if backward else lane_seq + lane_seq // 8)
+
+    while auto and large > _LANES and pads_too_far(large):
+        # Padding to the larger tile would add over an eighth (the
+        # backward: anything to what the forward padded): halve it.
+        if block_k == large:
+            block_k //= 2
+        else:
+            block_q //= 2
+        small, large = sorted((block_q, block_k))
+    seq_pad = -(-seq // large) * large
+
+    lanes = -(-head_dim // _LANES) * _LANES
+    per_k_row = 8 * lanes * itemsize + 8 * lanes
+    per_q_row = group * (6 * lanes * itemsize + 4 * lanes
+                         + 2 * 4 * _LANES)
+    share = _VMEM_BUDGET // 2
+
+    def span(tile, fit):
+        """The most whole tiles within `fit` rows that divide the
+        padded sequence evenly (never less than one)."""
+        tiles = seq_pad // tile
+        return tile * max(n for n in range(1, tiles + 1)
+                          if tiles % n == 0 and n <= max(fit // tile, 1))
+
+    span_k = span(block_k, share // 2 // per_k_row)
+    span_q = span(block_q, (share - span_k * per_k_row) // per_q_row)
+    pairs = tuple(
+        (qi, kj) for qi in range(seq_pad // span_q)
+        for kj in range(seq_pad // span_k)
+        if _live(qi * span_q, span_q, kj * span_k, span_k, causal,
+                 window))
+    return FlashPlan(block_q, block_k, span_q, span_k, seq_pad, pairs)
+
+
+def _schedule(pairs, k_major=False):
+    """The grid's span pairs as the two int32 arrays (qi, kj) the index
+    maps read from scalar prefetch, q-major or (dk/dv) k-major."""
+    if k_major:
+        pairs = sorted(pairs, key=lambda p: (p[1], p[0]))
+    return tuple(jnp.asarray([pair[side] for pair in pairs], jnp.int32)
+                 for side in (0, 1))
+
+
+def _pair_ends(config, at, transposed=False):
+    """(first, last) k span a q span at row `at` meets in the schedule
+    — transposed, the q spans a k span at column `at` meets: where its
+    accumulators start and where they are written out."""
+    size, block = config.span_q, config.span_k
+    if transposed:
+        size, block = block, size
+    lo, hi = _live_blocks(at, size, block, 0, config.seq_pad // block,
+                          config.causal, config.window, transposed)
+    return lo, hi - 1
+
+
+# ---------------------------------------------------------------------------
+# Forward kernel
+# ---------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _column(row, size):
+    """A `(1, size)` row of per-query (or per-key) numbers as the
+    `(size, 1)` column a score tile with them on sublanes wants: the
+    row broadcast down 128 sublanes, transposed on the XLU."""
+    return jnp.broadcast_to(row, (_LANES, size)).T[:, :1]
+
+
+def _scores(config, a, b):
+    """Scaled (and soft-capped) logits of a tile, f32, with the
+    softcap's chain-rule factor d(cap * tanh(s / cap))/ds =
+    1 - tanh^2(s / cap) (None when capping is off). `a @ b.T`: q
+    against k for a `[block_q, block_k]` tile, k against q for the
+    transposed one."""
+    s = jax.lax.dot_general(
+        a, b, _NT, preferred_element_type=jnp.float32) * config.sm_scale
+    if not config.softcap:
+        return s, None
+    # Gemma2 logit soft-capping, before masking (the HF order; masked
+    # entries go to -inf either way, so the capped value never leaks).
+    t = jnp.tanh(s / config.softcap)
+    return config.softcap * t, 1.0 - t * t
+
+
+def _tile_mask(config, r0, c0, key_valid, transposed=False):
+    """Validity of a score tile whose first query is `r0` and first
+    key `c0`: kv padding, causal structure, window band and (when
+    present) the key mask, `(1, block_k)` — `(block_k, 1)` transposed.
+    Queries index sublanes and keys lanes, the other way round when
+    `transposed`. One mask serves every head of the group; None where
+    the call masks nothing (not causal, no padding, no key mask)."""
+    shape = ((config.block_k, config.block_q) if transposed
+             else (config.block_q, config.block_k))
+    key_axis = 0 if transposed else 1
+    key = jax.lax.broadcasted_iota(jnp.int32, shape, key_axis)
+    terms = []
+    if config.kv_len < config.seq_pad:
+        terms.append(key < config.kv_len - c0)
     if config.causal:
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        mask = mask & (col <= row)
+        # col <= row, as an offset between the two iotas.
+        ahead = key - jax.lax.broadcasted_iota(jnp.int32, shape,
+                                               1 - key_axis)
+        terms.append(ahead <= r0 - c0)
         if config.window:
             # Sliding-window band: col in (row - window, row] — the HF
             # Mistral convention (window keys visible including self).
-            mask = mask & (col > row - config.window)
-    if mask_ref is not None:
-        valid = mask_ref[...].reshape(1, block_k) != 0
-        mask = mask & jnp.broadcast_to(valid, (block_q, block_k))
-    return mask
+            terms.append(ahead > r0 - c0 - config.window)
+    if key_valid is not None:
+        terms.append(key_valid != 0)
+    return functools.reduce(jnp.logical_and, terms) if terms else None
 
 
-def _tile_live(config, qi, ki):
-    """Causal tile-skip condition: a (qi, ki) tile runs only if it
-    intersects the visible region — at or below the diagonal, and
-    (with a sliding window) not entirely below the band."""
-    cond = (ki * config.block_k <= qi * config.block_q
-            + config.block_q - 1)
-    if config.window:
-        cond = jnp.logical_and(
-            cond, (ki + 1) * config.block_k - 1
-            > qi * config.block_q - config.window)
-    return cond
+def _masked(logits, mask):
+    return logits if mask is None else jnp.where(mask, logits, _NEG_INF)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, config, num_k):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _unmasked_floor(stat):
+    """A row's running max or logsumexp, kept above the mask value: a
+    masked logit is -1e30, so `exp(logit - stat)` is an exact 0 where
+    masked — also in a row with no visible key yet, whose own statistic
+    IS the mask value and would make it exp(0) = 1 (such rows output
+    zeros)."""
+    return jnp.maximum(stat, _NEG_INF / 2)
 
-    @pl.when(ki == 0)
+
+def _unpack(config, refs):
+    """(q, k, v, mask or None, rest...) from a kernel's refs after the
+    two schedule arrays."""
+    if config.has_mask:
+        return refs
+    return refs[:3] + (None,) + refs[3:]
+
+
+def _fwd_kernel(qi_ref, kj_ref, *refs, config):
+    """One kv head, one live (q span, k span): for every row block of
+    the span, the group's q heads walk the row block's live k blocks
+    side by side — independent online-softmax chains in one loop body,
+    so one head's softmax fills the slots another's products leave."""
+    (q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+     acc_ref, m_ref, l_ref) = _unpack(config, refs)
+    block_q, block_k = config.block_q, config.block_k
+    heads = range(config.kv_group)
+    step = pl.program_id(1)
+    q0 = qi_ref[step] * config.span_q
+    kj = kj_ref[step]
+    k_lo = kj * (config.span_k // block_k)
+    first, last = _pair_ends(config, q0)
+
+    @pl.when(kj == first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * config.sm_scale
-        if config.softcap:
-            # Gemma2 logit soft-capping, cap * tanh(s / cap) — before
-            # masking (the HF order; masked entries go to -inf either
-            # way, so the capped value never leaks).
-            s = config.softcap * jnp.tanh(s / config.softcap)
-        mask = _block_mask(config, qi, ki, mask_ref)
-        s = jnp.where(mask, s, _NEG_INF)
+    def q_walk(s, _):
+        rows = pl.ds(pl.multiple_of(s * block_q, block_q), block_q)
+        r0 = q0 + s * block_q
+        q = [q_ref[0, g, rows, :] for g in heads]
 
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_curr = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_curr)
-        alpha = jnp.exp(m_prev - m_next)
-        # Explicit zero where masked: exp(s - m) underflows to 0 for
-        # normal rows, but a fully-masked row has m == s == -inf and
-        # exp(0) == 1 would leak mass (such rows output 0 instead).
-        p = jnp.where(mask, jnp.exp(s - m_next), 0.0)
-        l_next = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
+        def k_walk(j, carry):
+            cols = pl.ds(pl.multiple_of((j - k_lo) * block_k, block_k),
+                         block_k)
+            k = k_ref[0, cols, :]
+            v = v_ref[0, cols, :]
+            mask = _tile_mask(
+                config, r0, j * block_k,
+                None if mask_ref is None else mask_ref[0, j - k_lo])
+            out = []
+            for g in heads:
+                m_prev, l_prev, acc = carry[g]
+                logits, _ = _scores(config, q[g], k)
+                logits = _masked(logits, mask)
+                m_next = jnp.maximum(
+                    m_prev, jnp.max(logits, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_next)
+                p = jnp.exp(logits - _unmasked_floor(m_next))
+                l_next = alpha * l_prev + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+                acc = acc * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, _NN,
+                    preferred_element_type=jnp.float32)
+                out.append((m_next, l_next, acc))
+            return tuple(out)
 
-    if config.causal:
-        @pl.when(_tile_live(config, qi, ki))
-        def _masked_step():
-            _step()
-    else:
-        _step()
+        lo, hi = _live_blocks(r0, block_q, block_k, k_lo,
+                              k_lo + config.span_k // block_k,
+                              config.causal, config.window)
+        state = jax.lax.fori_loop(lo, hi, k_walk, tuple(
+            (m_ref[g, rows, :1], l_ref[g, rows, :1], acc_ref[g, rows, :])
+            for g in heads))
+        for g in heads:
+            m, l, acc = state[g]
+            m_ref[g, rows, :] = jnp.broadcast_to(m, (block_q, _LANES))
+            l_ref[g, rows, :] = jnp.broadcast_to(l, (block_q, _LANES))
+            acc_ref[g, rows, :] = acc
 
-    @pl.when(ki == num_k - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
-        lse = m_ref[:, :1] + jnp.log(safe_l)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape)
+        @pl.when(kj == last)
+        def _finalize():
+            for g in heads:
+                m, l, acc = state[g]
+                safe_l = jnp.where(l == 0.0, 1.0, l)
+                o_ref[0, g, rows, :] = (acc / safe_l).astype(o_ref.dtype)
+                lse = jnp.broadcast_to(m + jnp.log(safe_l),
+                                       (block_q, _LANES))
+                lse_ref[0, g, s] = lse.T[:1, :]
+        return 0
 
-
-def _mask_spec(config, transposed=False):
-    """BlockSpec for the [B, 1, S_pad] key-mask: one (1, 1, block_k)
-    strip per k-block, indexed by the example this program serves.
-
-    The mask rides with a singleton middle axis so the block's
-    second-to-last dim (1) EQUALS the array dim — Mosaic requires the
-    last two block dims be (divisible by 8, divisible by 128) or equal
-    to the array dims, and a rank-2 [B, S_pad] layout with (1, block_k)
-    blocks violates the sublane rule whenever B > 1 (caught by the
-    round-4 on-TPU parity smoke; interpret mode never checks this)."""
-    heads = config.heads
-    if transposed:  # dk/dv grid: (b over B*H_kv, j, t)
-        heads_kv = config.heads // config.kv_group
-        return pl.BlockSpec((1, 1, config.block_k),
-                            lambda b, j, t: (b // heads_kv, 0, j))
-    return pl.BlockSpec((1, 1, config.block_k),
-                        lambda b, i, j: (b // heads, 0, j))
+    jax.lax.fori_loop(0, config.span_q // block_q, q_walk, 0)
 
 
-def _maybe_mask(config, kernel):
-    """Adapts a mask-taking kernel body to the unmasked arg list."""
-    if config.has_mask:
-        return kernel
+def _specs(config, head_dim):
+    """BlockSpecs of a grid `(B*H_kv, span pairs)` whose index maps
+    read the schedule's (qi, kj): (the group's q-side span, its
+    per-query rows, the k/v span, the key mask's blocks)."""
+    heads_kv = config.heads // config.kv_group
+    at = lambda index: lambda b, t, qi, kj: index(b, qi[t], kj[t])
+    q_spec = pl.BlockSpec(
+        (1, config.kv_group, config.span_q, head_dim),
+        at(lambda b, i, j: (b, 0, i, 0)))
+    row_spec = pl.BlockSpec(
+        (1, config.kv_group, config.span_q // config.block_q, 1,
+         config.block_q), at(lambda b, i, j: (b, 0, i, 0, 0)))
+    k_spec = pl.BlockSpec((1, config.span_k, head_dim),
+                          at(lambda b, i, j: (b, j, 0)))
+    mask_spec = pl.BlockSpec(
+        (1, config.span_k // config.block_k, 1, config.block_k),
+        at(lambda b, i, j: (b // heads_kv, j, 0, 0)))
+    return q_spec, row_spec, k_spec, mask_spec
 
-    def adapted(q_ref, k_ref, v_ref, *rest):
-        return kernel(q_ref, k_ref, v_ref, None, *rest)
-    return adapted
+
+def _compiler_params(config):
+    if config.interpret:
+        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_BUDGET)
 
 
+def _row_shape(config, q):
+    """Per-query f32 statistics as rows: one `(1, block_q)` slab a row
+    block (a block's last two dims equal the array's, which Mosaic
+    accepts at any `block_q`)."""
+    return q.shape[:2] + (config.seq_pad // config.block_q, 1,
+                          config.block_q)
+
+
+@functools.partial(jax.jit, static_argnums=0)
 def _flash_forward(config, q, k, v, kmask):
-    """q: [B*H, S_pad, D]; k/v: [B*H_kv, S_pad, D] (H_kv = H/kv_group);
-    kmask: [B, 1, S_pad] int32 or None ->
-    (out [B*H, S_pad, D], lse [B*H, S_pad, 128]).
+    """q: [B*H_kv, G, S_pad, D] (G = kv_group q heads a kv head); k/v:
+    [B*H_kv, S_pad, D]; kmask: [B, S_pad/block_k, 1, block_k] int32 or
+    None -> (out like q, lse [B*H_kv, G, S_pad/block_q, 1, block_q]).
 
-    GQA streams each kv head's blocks to its group of q-head programs
-    via the index map (b // kv_group) — the H-wide expansion is never
-    materialized in HBM."""
+    GQA: a step holds one kv head's span and its whole group's q rows —
+    the H-wide expansion is never materialized in HBM. Jitted, so a
+    model's layers trace and lower the kernel once a program (PERF.md
+    section 6, PR 27)."""
     vma = partition.vma_of(q, k, v, kmask)
-    bh, seq, head_dim = q.shape
-    num_q = seq // config.block_q
-    num_k = seq // config.block_k
-    grid = (bh, num_q, num_k)
-    group = config.kv_group
-    kernel = _maybe_mask(
-        config, functools.partial(_fwd_kernel, config=config, num_k=num_k))
-    in_specs = [
-        pl.BlockSpec((1, config.block_q, head_dim),
-                     lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, config.block_k, head_dim),
-                     lambda b, i, j: (b // group, j, 0)),
-        pl.BlockSpec((1, config.block_k, head_dim),
-                     lambda b, i, j: (b // group, j, 0)),
-    ]
-    inputs = [q, k, v]
-    if config.has_mask:
-        in_specs.append(_mask_spec(config))
-        inputs.append(kmask)
+    head_dim = q.shape[-1]
+    group, span_q = config.kv_group, config.span_q
+    q_spec, row_spec, k_spec, mask_spec = _specs(config, head_dim)
+    mask_in = [kmask] if config.has_mask else []
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, config.block_q, head_dim),
-                         lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, config.block_q, _LANES),
-                         lambda b, i, j: (b, i, 0)),
-        ],
+        functools.partial(_fwd_kernel, config=config),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(q.shape[0], len(config.pairs)),
+            in_specs=[q_spec, k_spec, k_spec]
+            + [mask_spec] * len(mask_in),
+            out_specs=[q_spec, row_spec],
+            scratch_shapes=[
+                pltpu.VMEM((group, span_q, head_dim), jnp.float32),
+                pltpu.VMEM((group, span_q, _LANES), jnp.float32),
+                pltpu.VMEM((group, span_q, _LANES), jnp.float32),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, head_dim), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, seq, _LANES), jnp.float32,
+            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(_row_shape(config, q), jnp.float32,
                                  vma=vma),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((config.block_q, head_dim), jnp.float32),
-            pltpu.VMEM((config.block_q, _LANES), jnp.float32),
-            pltpu.VMEM((config.block_q, _LANES), jnp.float32),
-        ],
+        compiler_params=_compiler_params(config),
         interpret=config.interpret,
         name=_CALL_PREFIX + FLASH_FWD,
-    )(*inputs)
+    )(*_schedule(config.pairs), q, k, v, *mask_in)
     return out, lse
 
 
@@ -341,186 +637,208 @@ def _flash_forward(config, q, k, v, kmask):
 # ---------------------------------------------------------------------------
 
 
-def _attn_probs(config, qi, ki, q, k, lse_col, mask_ref):
-    """Recomputes the (block_q, block_k) probability block.
-
-    Returns (p, dcap): dcap is the softcap chain-rule factor
-    d(cap*tanh(s/cap))/ds = 1 - tanh^2(s/cap) to fold into dS, or None
-    when soft-capping is off.
-    """
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * config.sm_scale
-    dcap = None
-    if config.softcap:
-        t = jnp.tanh(s / config.softcap)
-        dcap = 1.0 - t * t
-        s = config.softcap * t
-    mask = _block_mask(config, qi, ki, mask_ref)
-    # Explicit zero (not just -inf logits): a fully-masked row carries
-    # lse == -inf and exp(-inf - -inf) == 1 would fabricate mass.
-    p = jnp.where(mask, jnp.exp(jnp.where(mask, s, _NEG_INF) - lse_col),
-                  0.0)
-    return p, dcap
+def _probs(logits, mask, lse):
+    """The probability tile recomputed from its logits and the saved
+    logsumexp; exactly 0 where masked (`_unmasked_floor`)."""
+    return jnp.exp(_masked(logits, mask) - _unmasked_floor(lse))
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_acc, *, config, num_k):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _dq_kernel(qi_ref, kj_ref, *refs, config):
+    (q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+     dq_ref, dq_acc) = _unpack(config, refs)
+    block_q, block_k = config.block_q, config.block_k
+    heads = range(config.kv_group)
+    step = pl.program_id(1)
+    q0 = qi_ref[step] * config.span_q
+    kj = kj_ref[step]
+    k_lo = kj * (config.span_k // block_k)
+    first, last = _pair_ends(config, q0)
 
-    @pl.when(ki == 0)
+    @pl.when(kj == first)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        p, dcap = _attn_probs(config, qi, ki, q, k, lse_ref[0][:, :1],
-                              mask_ref)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1]) * config.sm_scale
-        if dcap is not None:
-            ds = ds * dcap
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def q_walk(s, _):
+        rows = pl.ds(pl.multiple_of(s * block_q, block_q), block_q)
+        r0 = q0 + s * block_q
+        q = [q_ref[0, g, rows, :] for g in heads]
+        do = [do_ref[0, g, rows, :] for g in heads]
+        lse = [_column(lse_ref[0, g, s], block_q) for g in heads]
+        delta = [_column(delta_ref[0, g, s], block_q) for g in heads]
 
-    if config.causal:
-        @pl.when(_tile_live(config, qi, ki))
-        def _masked_step():
-            _step()
-    else:
-        _step()
+        def k_walk(j, carry):
+            cols = pl.ds(pl.multiple_of((j - k_lo) * block_k, block_k),
+                         block_k)
+            k = k_ref[0, cols, :]
+            v = v_ref[0, cols, :]
+            mask = _tile_mask(
+                config, r0, j * block_k,
+                None if mask_ref is None else mask_ref[0, j - k_lo])
+            out = []
+            for g in heads:
+                logits, dcap = _scores(config, q[g], k)
+                p = _probs(logits, mask, lse[g])
+                dp = jax.lax.dot_general(
+                    do[g], v, _NT, preferred_element_type=jnp.float32)
+                ds = p * (dp - delta[g]) * config.sm_scale
+                if dcap is not None:
+                    ds = ds * dcap
+                out.append(carry[g] + jax.lax.dot_general(
+                    ds.astype(k.dtype), k, _NN,
+                    preferred_element_type=jnp.float32))
+            return tuple(out)
 
-    @pl.when(ki == num_k - 1)
-    def _finalize():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        lo, hi = _live_blocks(r0, block_q, block_k, k_lo,
+                              k_lo + config.span_k // block_k,
+                              config.causal, config.window)
+        dq = jax.lax.fori_loop(
+            lo, hi, k_walk, tuple(dq_acc[g, rows, :] for g in heads))
+        for g in heads:
+            dq_acc[g, rows, :] = dq[g]
+
+        @pl.when(kj == last)
+        def _finalize():
+            for g in heads:
+                dq_ref[0, g, rows, :] = dq[g].astype(dq_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, config.span_q // block_q, q_walk, 0)
 
 
-def _dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_acc, dv_acc, *, config, num_q):
-    """Grid (B*H_kv, num_k, kv_group*num_q): each kv head's dk/dv block
-    accumulates over every q block of every q head in its group — the
-    GQA sum over the group happens in the same VMEM accumulator that
-    already sums over q blocks. t decomposes as g*num_q + i."""
-    ki = pl.program_id(1)
-    t = pl.program_id(2)
-    qi = jax.lax.rem(t, num_q)
+def _dkdv_kernel(qi_ref, kj_ref, *refs, config):
+    """Grid (B*H_kv, span pairs), k-major: a kv head's dk/dv span
+    accumulates over every live q span — and, inside, over every q
+    head of its group: the GQA sum happens in the same accumulator
+    that sums over row blocks. Score tiles are transposed, `[block_k,
+    block_q]`: keys on sublanes, queries on lanes."""
+    (q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+     dk_ref, dv_ref, dk_acc, dv_acc) = _unpack(config, refs)
+    block_q, block_k = config.block_q, config.block_k
+    heads = range(config.kv_group)
+    step = pl.program_id(1)
+    k0 = kj_ref[step] * config.span_k
+    qi = qi_ref[step]
+    q_lo = qi * (config.span_q // block_q)
+    first, last = _pair_ends(config, k0, transposed=True)
 
-    @pl.when(t == 0)
+    @pl.when(qi == first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        p, dcap = _attn_probs(config, qi, ki, q, k, lse_ref[0][:, :1],
-                              mask_ref)
-        # dV += P^T dO   (contract over the q rows)
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1]) * config.sm_scale
-        if dcap is not None:
-            ds = ds * dcap
-        # dK += dS^T Q
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def k_walk(s, _):
+        cols = pl.ds(pl.multiple_of(s * block_k, block_k), block_k)
+        c0 = k0 + s * block_k
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        key_valid = (None if mask_ref is None else
+                     _column(mask_ref[0, s].astype(jnp.float32), block_k))
 
-    if config.causal:
-        @pl.when(_tile_live(config, qi, ki))
-        def _masked_step():
-            _step()
-    else:
-        _step()
+        def q_walk(i, carry):
+            dk, dv = carry
+            rows = pl.ds(pl.multiple_of((i - q_lo) * block_q, block_q),
+                         block_q)
+            mask = _tile_mask(config, i * block_q, c0, key_valid,
+                              transposed=True)
+            for g in heads:
+                q = q_ref[0, g, rows, :]
+                do = do_ref[0, g, rows, :]
+                logits, dcap = _scores(config, k, q)
+                p = _probs(logits, mask, lse_ref[0, g, i - q_lo])
+                # dV += P^T dO
+                dv = dv + jax.lax.dot_general(
+                    p.astype(do.dtype), do, _NN,
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(
+                    v, do, _NT, preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta_ref[0, g, i - q_lo])
+                      * config.sm_scale)
+                if dcap is not None:
+                    ds = ds * dcap
+                # dK += dS^T Q
+                dk = dk + jax.lax.dot_general(
+                    ds.astype(q.dtype), q, _NN,
+                    preferred_element_type=jnp.float32)
+            return dk, dv
 
-    @pl.when(t == config.kv_group * num_q - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        lo, hi = _live_blocks(c0, block_k, block_q, q_lo,
+                              q_lo + config.span_q // block_q,
+                              config.causal, config.window,
+                              transposed=True)
+        dk, dv = jax.lax.fori_loop(
+            lo, hi, q_walk, (dk_acc[cols, :], dv_acc[cols, :]))
+        dk_acc[cols, :] = dk
+        dv_acc[cols, :] = dv
+
+        @pl.when(qi == last)
+        def _finalize():
+            dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, config.span_k // block_k, k_walk, 0)
 
 
-def _flash_backward(config, q, k, v, kmask, out, lse, g):
+def _flash_dq(config, q, k, v, kmask, g, lse, delta):
     vma = partition.vma_of(q, k, v, g)
-    bh, seq, head_dim = q.shape
-    bh_kv = k.shape[0]
-    num_q = seq // config.block_q
-    num_k = seq // config.block_k
-    group = config.kv_group
-
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (bh, seq, _LANES))
-
-    q_spec = pl.BlockSpec((1, config.block_q, head_dim),
-                          lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, config.block_q, _LANES),
-                            lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, config.block_k, head_dim),
-                          lambda b, i, j: (b // group, j, 0))
-
-    in_specs = [q_spec, k_spec, k_spec]
-    inputs = [q, k, v]
-    if config.has_mask:
-        in_specs.append(_mask_spec(config))
-        inputs.append(kmask)
-
-    dq = pl.pallas_call(
-        _maybe_mask(config, functools.partial(
-            _dq_kernel, config=config, num_k=num_k)),
-        grid=(bh, num_q, num_k),
-        in_specs=in_specs + [q_spec, row_spec, row_spec],
-        out_specs=[q_spec],
+    head_dim = q.shape[-1]
+    mask_in = [kmask] if config.has_mask else []
+    q_spec, row_spec, k_spec, mask_spec = _specs(config, head_dim)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, config=config),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(q.shape[0], len(config.pairs)),
+            in_specs=[q_spec, k_spec, k_spec]
+            + [mask_spec] * len(mask_in) + [q_spec, row_spec, row_spec],
+            out_specs=[q_spec],
+            scratch_shapes=[pltpu.VMEM(
+                (config.kv_group, config.span_q, head_dim),
+                jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma)],
-        scratch_shapes=[
-            pltpu.VMEM((config.block_q, head_dim), jnp.float32)],
+        compiler_params=_compiler_params(config),
         interpret=config.interpret,
         name=_CALL_PREFIX + FLASH_BWD_DQ,
-    )(*inputs, g, lse, delta)[0]
+    )(*_schedule(config.pairs), q, k, v, *mask_in, g, lse, delta)[0]
 
-    # dk/dv: one program per kv head and k-block; the innermost dim t
-    # fuses (group, q_blocks) so the group sum lands in the accumulator
-    # (see _dkdv_kernel). Index maps lift t -> (q head b*group + t//num_q,
-    # q block t%num_q).
-    qT_spec = pl.BlockSpec(
-        (1, config.block_q, head_dim),
-        lambda b, j, t: (b * group + t // num_q, t % num_q, 0))
-    rowT_spec = pl.BlockSpec(
-        (1, config.block_q, _LANES),
-        lambda b, j, t: (b * group + t // num_q, t % num_q, 0))
-    kT_spec = pl.BlockSpec((1, config.block_k, head_dim),
-                           lambda b, j, t: (b, j, 0))
-    inT_specs = [qT_spec, kT_spec, kT_spec]
-    if config.has_mask:
-        inT_specs.append(_mask_spec(config, transposed=True))
-    dk, dv = pl.pallas_call(
-        _maybe_mask(config, functools.partial(
-            _dkdv_kernel, config=config, num_q=num_q)),
-        grid=(bh_kv, num_k, group * num_q),
-        in_specs=inT_specs + [qT_spec, rowT_spec, rowT_spec],
-        out_specs=[kT_spec, kT_spec],
+
+def _flash_dkdv(config, q, k, v, kmask, g, lse, delta):
+    vma = partition.vma_of(q, k, v, g)
+    head_dim = q.shape[-1]
+    mask_in = [kmask] if config.has_mask else []
+    q_spec, row_spec, k_spec, mask_spec = _specs(config, head_dim)
+    return pl.pallas_call(
+        functools.partial(_dkdv_kernel, config=config),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(q.shape[0], len(config.pairs)),
+            in_specs=[q_spec, k_spec, k_spec]
+            + [mask_spec] * len(mask_in) + [q_spec, row_spec, row_spec],
+            out_specs=[k_spec, k_spec],
+            scratch_shapes=[
+                pltpu.VMEM((config.span_k, head_dim), jnp.float32),
+                pltpu.VMEM((config.span_k, head_dim), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
             jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((config.block_k, head_dim), jnp.float32),
-            pltpu.VMEM((config.block_k, head_dim), jnp.float32),
-        ],
+        compiler_params=_compiler_params(config),
         interpret=config.interpret,
         name=_CALL_PREFIX + FLASH_BWD_DKV,
-    )(*inputs, g, lse, delta)
+    )(*_schedule(config.pairs, k_major=True), q, k, v, *mask_in, g, lse,
+      delta)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _flash_backward(config, q, k, v, kmask, out, lse, g):
+    config = config.for_backward()
+    if kmask is not None:  # the same keys, a row a backward k block
+        kmask = kmask.reshape(kmask.shape[0], -1, 1, config.block_k)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(_row_shape(config, q))
+    dq = _flash_dq(config, q, k, v, kmask, g, lse, delta)
+    dk, dv = _flash_dkdv(config, q, k, v, kmask, g, lse, delta)
     return dq, dk, dv
 
 
@@ -555,8 +873,6 @@ def _flash_attention_masked_fwd(config, q, k, v, kmask):
 
 
 def _flash_attention_masked_bwd(config, residuals, g):
-    import numpy as np
-
     q, k, v, kmask, out, lse = residuals
     dq, dk, dv = _flash_backward(config, q, k, v, kmask, out, lse, g)
     # Integer mask: the cotangent is the symbolic zero, float0.
@@ -587,8 +903,8 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
         causal: Apply a causal (autoregressive) mask.
         window: Sliding-window (Mistral-style) attention — row i
             attends keys in (i-window, i]; requires causal=True. Tiles
-            entirely below the band are skipped in the grid
-            (_tile_live), so long-sequence cost scales with S*window,
+            entirely outside the band are never visited
+            (_live_blocks), so long-sequence cost scales with S*window,
             not S^2.
         sm_scale: Softmax temperature; default 1/sqrt(D).
         logit_softcap: Gemma2-style tanh logit capping — logits become
@@ -602,12 +918,10 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
             just contiguous prefixes. Rows whose keys are ALL masked
             output zeros — and since round 4 `mha_reference` adopts the
             same convention, kernel and oracle agree on every row.
-        block_q / block_k: Kernel tile sizes along the sequence. S is
-            padded up to a multiple internally. Default (None) is 128,
-            overridable process-wide via CLOUD_TPU_FLASH_BLOCK_Q /
-            CLOUD_TPU_FLASH_BLOCK_K — the deployment hook for a
-            `benchmarks/flash_autotune.py` pin, so a measured best
-            config applies without touching call sites.
+        block_q / block_k: Rows and columns of the score tile the
+            kernels walk. Default (None): from the shapes, by
+            `flash_plan`'s rule. S is padded up to a multiple
+            internally.
         interpret: Force Pallas interpret mode. Default: interpret
             everywhere except on real TPU backends.
 
@@ -630,19 +944,11 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
                          "causal=True.")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if block_q is None:
-        block_q = int(os.environ.get("CLOUD_TPU_FLASH_BLOCK_Q", 128))
-    if block_k is None:
-        block_k = int(os.environ.get("CLOUD_TPU_FLASH_BLOCK_K", 128))
-
-    block = max(block_q, block_k)
-    if block_q % min(block_q, block_k) or block_k % min(block_q, block_k):
-        raise ValueError(
-            "block_q={} and block_k={} must divide one another.".format(
-                block_q, block_k))
-    seq_pad = -(-seq // block) * block
-    block_q = min(block_q, seq_pad)
-    block_k = min(block_k, seq_pad)
+    shape = (head_dim, heads // h_kv, q.dtype.itemsize, causal, window)
+    tiles = flash_plan(seq, *shape, block_q, block_k)
+    seq_pad = tiles.seq_pad
+    backward = flash_plan(seq_pad, *shape, tiles.block_q, block_k,
+                          backward=True)
 
     if mask is not None and mask.shape != (batch, seq):
         raise ValueError(
@@ -653,12 +959,12 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
         """One device's [B', S, H', D] block."""
         batch, _, heads, _ = q.shape
         config = _Config(causal=bool(causal), sm_scale=float(sm_scale),
-                         block_q=block_q, block_k=block_k, kv_len=seq,
-                         heads=heads, has_mask=bool(kmask),
-                         interpret=bool(interpret),
+                         tiles=tiles, kv_len=seq, heads=heads,
+                         has_mask=bool(kmask), interpret=bool(interpret),
                          kv_group=heads // k.shape[2],
                          window=int(window or 0),
-                         softcap=float(logit_softcap or 0.0))
+                         softcap=float(logit_softcap or 0.0),
+                         backward=backward)
 
         def fold(x):
             n_heads = x.shape[2]
@@ -668,17 +974,22 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
                 x = jnp.pad(x, ((0, 0), (0, seq_pad - seq), (0, 0)))
             return x
 
-        operands = [fold(q), fold(k), fold(v)]
+        def fold_q(x):
+            # A kv head's group of q heads is contiguous: a free view.
+            return fold(x).reshape(-1, config.kv_group, seq_pad, head_dim)
+
+        operands = [fold_q(q), fold(k), fold(v)]
         if kmask:
-            # [B, 1, S_pad]: the singleton axis makes the
-            # (1, 1, block_k) mask blocks legal under Mosaic's sublane
-            # rule (_mask_spec).
+            # [B, S_pad / block_k, 1, block_k]: a (1, block_k) row a k
+            # block, legal under Mosaic's sublane rule at any block_k
+            # (_row_shape).
             operands.append(jnp.pad(
                 kmask[0].astype(jnp.int32),
-                ((0, 0), (0, seq_pad - seq)))[:, None, :])
+                ((0, 0), (0, seq_pad - seq))).reshape(
+                    batch, seq_pad // tiles.block_k, 1, tiles.block_k))
         attend = _flash_attention_masked if kmask else _flash_attention
         out = attend(config, *partition.common_vma(*operands))
-        out = out[:, :seq].reshape(batch, heads, seq, head_dim)
+        out = out[:, :, :seq].reshape(batch, heads, seq, head_dim)
         return jnp.transpose(out, (0, 2, 1, 3))
 
     def plan(mesh):

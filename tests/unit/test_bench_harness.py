@@ -109,19 +109,11 @@ class TestPeakTable:
 class TestSelfDescribingConfig:
     """Every record carries the configuration it was asked for."""
 
-    def test_flash_block_pins_enter_requested_config(self, bench,
-                                                     monkeypatch):
-        monkeypatch.setenv("CLOUD_TPU_FLASH_BLOCK_Q", "512")
-        cfg = bench._requested_config()
-        assert cfg["cloud_tpu_flash_block_q"] == 512
-
     def test_malformed_env_degrades_to_defaults(self, bench,
                                                 monkeypatch):
         monkeypatch.setenv("BENCH_SPE", "garbage")
-        monkeypatch.setenv("CLOUD_TPU_FLASH_BLOCK_Q", "auto")
         cfg = bench._requested_config()
         assert cfg["steps_per_execution"] == 1
-        assert cfg["cloud_tpu_flash_block_q"] == 0
         assert cfg["batch"] == bench.BATCH
 
     def test_named_config_expands_and_is_recorded(self, monkeypatch):

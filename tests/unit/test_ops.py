@@ -14,6 +14,7 @@ import pytest
 pytestmark = pytest.mark.slow  # numeric-heavy: excluded from the fast tier
 
 from cloud_tpu.ops import attention, flash_attention, mha_reference
+from cloud_tpu.ops.attention import flash_plan
 
 TOL = 2e-5
 
@@ -275,35 +276,117 @@ def test_gqa_shape_validation():
         mha_reference(q, k, v[:, :, :1])
 
 
-def test_block_size_env_override(monkeypatch):
-    """CLOUD_TPU_FLASH_BLOCK_Q/K set the default tile sizes (the
-    deployment hook for a flash_autotune pin) without changing
-    numerics; explicit args still win."""
+def test_shape_chosen_tiles_and_explicit_overrides():
+    """The default tiles come from the shapes (`flash_plan`), explicit
+    `block_q=` / `block_k=` still win, and neither changes numerics; a
+    pair that does not divide still raises."""
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 512, 2, 64)),
                            jnp.float32) for _ in range(3))
     ref = mha_reference(q, k, v, causal=True)
-    monkeypatch.setenv("CLOUD_TPU_FLASH_BLOCK_Q", "256")
-    monkeypatch.setenv("CLOUD_TPU_FLASH_BLOCK_K", "128")
+    assert flash_plan(512, 64)[:4] == (256, 512, 512, 512)
     out = flash_attention(q, k, v, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
-    # Explicit argument beats the env default.
     out2 = flash_attention(q, k, v, causal=True, interpret=True,
-                           block_q=128, block_k=128)
+                           block_q=128, block_k=256)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
-    # A bad env pin fails loudly, not silently.
-    monkeypatch.setenv("CLOUD_TPU_FLASH_BLOCK_Q", "192")
     with pytest.raises(ValueError, match="divide"):
-        flash_attention(q, k, v, causal=True, interpret=True)
+        flash_attention(q, k, v, causal=True, interpret=True,
+                        block_q=192)
+
+
+def _loss_pair(g, expand=None, **kwargs):
+    """(flash loss, reference loss) over q, k, v for cotangent `g`."""
+    oracle = {name: value for name, value in kwargs.items()
+              if name not in ("block_q", "block_k")}
+
+    def flash_loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, interpret=True,
+                                       **kwargs) * g)
+
+    def ref_loss(q, k, v):
+        if expand:
+            k, v = _expand(k, expand), _expand(v, expand)
+        return jnp.sum(mha_reference(q, k, v, **oracle) * g)
+    return flash_loss, ref_loss
+
+
+_TILING_CASES = {
+    # (a) the shape-chosen 256 x 512 tiles: 4 row blocks x 2 k blocks.
+    "default_tiles_multi_block": dict(seq=1024, heads=2, kv_heads=2,
+                                      head_dim=16),
+    # (b) the cells' groups at their head sizes.
+    "group7_d64": dict(seq=256, heads=7, kv_heads=1, head_dim=64,
+                       block_q=64, block_k=128),
+    "group8_d128": dict(seq=256, heads=8, kv_heads=1, head_dim=128,
+                        block_q=128, block_k=64),
+    # (d) S not a multiple of the tile, default and forced tiles.
+    "ragged_default": dict(seq=300, heads=4, kv_heads=2, head_dim=32),
+    "ragged_small_tiles": dict(seq=200, heads=4, kv_heads=2,
+                               head_dim=32, block_q=32, block_k=64),
+    # (e) non-causal: every tile is live, the pad columns are masked.
+    "non_causal": dict(seq=200, heads=2, kv_heads=2, head_dim=32,
+                       causal=False, block_q=64, block_k=32),
+    "window_band": dict(seq=256, heads=4, kv_heads=2, head_dim=32,
+                        window=40, block_q=32, block_k=32),
+    "window_default_tiles": dict(seq=512, heads=2, kv_heads=1,
+                                 head_dim=32, window=128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TILING_CASES))
+def test_tiling_forward_and_gradients(case):
+    """Forward and all three gradients against the oracle at toy sizes
+    that force several row blocks and several k blocks a walk."""
+    spec = dict(_TILING_CASES[case])
+    seq, heads, kv_heads, head_dim = (spec.pop(name) for name in (
+        "seq", "heads", "kv_heads", "head_dim"))
+    spec.setdefault("causal", True)
+    q, k, v = _gqa_qkv(batch=1, seq=seq, heads=heads,
+                       kv_heads=kv_heads, head_dim=head_dim)
+    g = jnp.asarray(
+        np.random.default_rng(1).normal(size=q.shape), jnp.float32)
+    flash_loss, ref_loss = _loss_pair(g, expand=heads, **spec)
+    got = jax.value_and_grad(flash_loss, argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for name, a, b in zip("qkv", got[1], want[1]):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(
+            a, b, atol=1e-4, rtol=1e-4,
+            err_msg="{}: grad wrt {} diverges".format(case, name))
+
+
+@pytest.mark.parametrize("window", [None, 24])
+def test_prefill_frame_with_key_mask(window):
+    """(c) The serving prefill's use (`GQAttention._decode_attention`):
+    the prompt's queries laid at their rows of a longer frame, the
+    cache's key mask, a window band scaled down; only the prompt's rows
+    are read."""
+    frame, start, prompt = 256, 37, 150
+    q, k, v = _gqa_qkv(batch=2, seq=frame, heads=8, kv_heads=2,
+                       head_dim=32)
+    rows = np.arange(frame)
+    valid = jnp.asarray(
+        np.stack([(rows >= start) & (rows < start + prompt),
+                  rows < start + prompt]))
+    q = jnp.where(valid[:, :, None, None], q, 0.0)
+    out = flash_attention(q, k, v, causal=True, mask=valid,
+                          window=window, block_q=32, block_k=64,
+                          interpret=True)
+    ref = mha_reference(q, k, v, causal=True, mask=valid, window=window)
+    np.testing.assert_allclose(out[:, start:start + prompt],
+                               ref[:, start:start + prompt],
+                               atol=TOL, rtol=TOL)
 
 
 class TestSlidingWindow:
     """window=: banded causal attention (Mistral convention — row i
     attends keys in (i-window, i]). The reference is checked against a
     dense explicit-band oracle; the kernel against the reference,
-    including the tile-skip guard (_tile_live) at window widths that
+    including the walk's band bounds (_live_blocks) at window widths that
     kill whole tiles."""
 
     def _dense_band(self, q, k, v, window):

@@ -87,6 +87,16 @@ def _kernel_cases():
         lambda q, k, v, m: total(ops.attention(q, k, v, mask=m)),
         (0, 1, 2)),
         qkv + (S((4, SEQ), jnp.bool_),), (by_batch,) * 3 + (P("dp"),))
+    # The serving prefill's call (`GQAttention._decode_attention`) at
+    # K-EXAONE's widths: forward only, a 4096-row frame, the cache's
+    # key mask, a full layer and a window-128 layer.
+    frame = (S((1, 4096, 64, 128), BF16), S((1, 4096, 8, 128), BF16),
+             S((1, 4096, 8, 128), BF16), S((1, 4096), jnp.bool_))
+    by_head = (P(None, None, "tp", None),) * 3 + (P(),)
+    for name, window in (("flash_prefill_full", None),
+                         ("flash_prefill_window", 128)):
+        yield (name, lambda q, k, v, m, window=window: ops.attention(
+            q, k, v, mask=m, window=window), frame, by_head)
     yield ("fused_rmsnorm", jax.grad(
         lambda x, r, s: total(ops.fused_rmsnorm(x, s, residual=r)),
         (0, 1, 2)),
