@@ -31,8 +31,8 @@ test: native-test
 # rules (GL001-GL009). Run before pushing; pre-commit hooks run the
 # identical pair (see .pre-commit-config.yaml).
 lint:
-	ruff check cloud_tpu bench.py examples
-	python -m cloud_tpu.analysis.lint cloud_tpu bench.py examples tests --strict
+	ruff check cloud_tpu examples
+	python -m cloud_tpu.analysis.lint cloud_tpu examples tests --strict
 
 clean:
 	rm -rf $(NATIVE_BUILD) $(NATIVE_BUILD_REL)

@@ -461,9 +461,9 @@ class SwiGLU(nn.Module):
     proper, Llama/Mistral/Qwen) or "gelu_tanh"/"gelu" (GeGLU, the
     Gemma family). The tail runs through `ops.fused_swiglu` — a
     single-VMEM-pass Pallas kernel on TPU, the bitwise lax reference
-    elsewhere (`impl` follows the block's `attention_impl`,
-    `CLOUD_TPU_FUSED_MLP` overriding) — with the gate/up/down kernel
-    params exactly where the three `nn.Dense` modules kept them.
+    elsewhere (`impl` follows the block's `attention_impl`) — with
+    the gate/up/down kernel params exactly where the three `nn.Dense`
+    modules kept them.
     """
 
     d_ff: int
@@ -503,7 +503,7 @@ class FusedRMSNorm(nn.Module):
 
     `impl` follows the block's `attention_impl` ("reference" forces the
     lax path; anything else auto-selects — Pallas on TPU, lax
-    elsewhere, `CLOUD_TPU_FUSED_NORM` overriding)."""
+    elsewhere)."""
 
     epsilon: float = 1e-6
     dtype: Optional[jnp.dtype] = None

@@ -29,14 +29,10 @@ Design (the shard_map pipelining pattern, scaling-playbook shape):
   checkpointed scan already caps live activations at one tick's worth —
   while a true 1F1B interleave would require scheduling the backward by
   hand (custom_vjp over the whole schedule) instead of letting XLA
-  transpose the scan. Raise `num_microbatches` to shrink the bubble.
-  MEASURED (round 4, benchmarks/pipeline_schedule_bench.py, XLA
-  compiled-buffer analysis at pp=4, batch 16): peak temp memory FALLS
-  as M rises — 146.7 MB (M=4) -> 89.4 (M=8) -> 61.0 (M=16) — because
-  live activations scale with the microbatch SIZE (batch/M), the same
-  direction 1F1B optimizes; step time also falls (smaller bubble).
-  1F1B would add schedule complexity for memory behavior the remat'd
-  scan already has. Numbers in PERF.md §pipeline.
+  transpose the scan. Raise `num_microbatches` to shrink the bubble;
+  live activations scale with the microbatch SIZE (batch/M), so that
+  also lowers peak memory, the direction 1F1B optimizes. Not measured
+  on the chip: no cell runs a pipeline schedule yet.
 
 This is an `(init_fn, apply_fn)`-pair model (the Trainer's second model
 contract, trainer.py): `init` builds the param pytree directly — no

@@ -15,9 +15,8 @@ Two verification modes, selected by `temperature`:
   the draft's acceptance rate is decent. The parity tests pin exact
   equality in f32; in bf16 on TPU, XLA may tile the (k+1)-token
   verification forward differently from generate()'s single-token
-  steps, and a near-exact argmax tie could flip — rare in practice,
-  and benchmark config 10 reports the measured match fraction rather
-  than assuming it.
+  steps, and a near-exact argmax tie could flip — rare in practice;
+  the match fraction there is not measured.
 
 - Stochastic (temperature>0): the Leviathan et al. accept/reject
   scheme (arXiv 2211.17192). The draft SAMPLES each proposal from its
@@ -345,8 +344,7 @@ def generate_speculative(model, params, draft_model, draft_params,
             warp-invariant).
         return_stats: when True, returns (tokens, stats) where stats
             has `rounds`, `proposed`, `accepted_drafts`, and
-            `acceptance_rate` (accepted_drafts / proposed) — the
-            number benchmark config 10 reports.
+            `acceptance_rate` (accepted_drafts / proposed).
 
     Returns:
         [1, S + max_new_tokens] int32 — with temperature=0, identical

@@ -31,10 +31,10 @@ Env contract:
     CLOUD_TPU_TELEMETRY        1|on  -> Trainer entry points enable
     CLOUD_TPU_TELEMETRY_DIR    output directory (default ./telemetry)
 
-The MFU and kernel pct-of-peak gauges divide by the chip's published
-peak, looked up in `PEAK_TFLOPS` by the `device_kind` JAX reports. A
-CPU run has no such gauge; an accelerator missing from the table is an
-error, never a default.
+The MFU gauge divides by the chip's published peak, looked up in
+`PEAK_TFLOPS` by the `device_kind` JAX reports. A CPU run has no such
+gauge; an accelerator missing from the table is an error, never a
+default.
 """
 
 import bisect
@@ -53,9 +53,9 @@ __all__ = ["Counter", "Gauge", "Histogram", "Registry", "Telemetry",
            "enabled", "env_enabled", "env_scope"]
 
 #: Published bf16 peak of ONE chip in TFLOP/s, keyed by the
-#: `device_kind` JAX reports — the one denominator the MFU gauge and
-#: bench.py's pct_peak share. v5e: 197 (Google Cloud documentation,
-#: "TPU v5e"). Add a kind together with its source.
+#: `device_kind` JAX reports — the MFU gauge's denominator. v5e: 197
+#: (Google Cloud documentation, "TPU v5e"). Add a kind together with
+#: its source.
 PEAK_TFLOPS = {
     "TPU v5 lite": 197.0,
 }
@@ -187,15 +187,6 @@ SWEEP_RESUMES_TOTAL = "cloud_tpu_sweep_resumes_total"
 SWEEP_WARM_TRIALS_TOTAL = "cloud_tpu_sweep_warm_trials_total"
 SWEEP_BEST_SCORE = "cloud_tpu_sweep_best_score"
 SWEEP_COMPILE_SECONDS = "cloud_tpu_sweep_compile_seconds"
-
-#: Per-kernel cost rows (ops/ Pallas kernels: "paged_attention",
-#: "fused_norm"). Fed by `Telemetry.record_kernel_cost` from the jit
-#: cost-analysis hook (the PR 6 MFU idiom, per-kernel): the serving
-#: tick feeds paged_attention every tick with the measured tick
-#: latency; `ops.fused_norm.record_cost_row` is the bench/CI feed for
-#: the norm tail. `%s` is the kernel name.
-KERNEL_PCT_PEAK_GAUGE = "cloud_tpu_kernel_%s_pct_peak"
-KERNEL_BYTES_GAUGE = "cloud_tpu_kernel_%s_bytes_moved"
 
 
 class Counter:
@@ -522,22 +513,6 @@ class Telemetry:
                 self.registry.gauge(MFU_GAUGE).set(
                     100.0 * flops_per_sec / peak)
         self.flush()
-
-    def record_kernel_cost(self, kernel, flops, bytes_moved,
-                           elapsed_secs=None):
-        """Per-kernel cost row: bytes-moved always, pct-of-peak when
-        the caller knows the wall time one call took (MFU math, same
-        peak denominator as the step gauge). `kernel` is the row name
-        ("paged_attention", "fused_norm"); flops/bytes come from the
-        jit cost-analysis hook (ops.paged_attention_cost /
-        ops.fused_norm.fused_norm_cost)."""
-        self.registry.gauge(KERNEL_BYTES_GAUGE % kernel).set(
-            float(bytes_moved))
-        peak = (self.peak_flops
-                if flops and elapsed_secs and elapsed_secs > 0 else None)
-        if peak:
-            self.registry.gauge(KERNEL_PCT_PEAK_GAUGE % kernel).set(
-                100.0 * (float(flops) / float(elapsed_secs)) / peak)
 
     def observe_decode(self, n_tokens, elapsed_secs):
         """Per-token decode latency: one observation per generated

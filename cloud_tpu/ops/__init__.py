@@ -10,7 +10,6 @@ from cloud_tpu.ops.fused_mlp import swiglu_reference
 from cloud_tpu.ops.fused_norm import fused_rmsnorm
 from cloud_tpu.ops.fused_norm import rmsnorm_residual_reference
 from cloud_tpu.ops.paged_attention import paged_attention
-from cloud_tpu.ops.paged_attention import paged_attention_cost
 from cloud_tpu.ops.paged_attention import paged_attention_reference
 from cloud_tpu.ops.paged_attention import paged_decode_attention
 
@@ -18,5 +17,5 @@ __all__ = ["attention", "flash_attention", "mha_reference",
            "lm_head_loss", "lm_head_loss_reference",
            "fused_swiglu", "swiglu_reference",
            "fused_rmsnorm", "rmsnorm_residual_reference",
-           "paged_attention", "paged_attention_cost",
-           "paged_attention_reference", "paged_decode_attention"]
+           "paged_attention", "paged_attention_reference",
+           "paged_decode_attention"]

@@ -29,12 +29,12 @@ single-pass claim is for the forward serving/training hot path.
 
 On non-TPU backends a forced kernel runs in Pallas interpret mode, so
 parity tests exercise the same code path CPU-side. Under a device mesh
-the kernel runs per shard (ops/partition.py).
+the kernel runs per shard (ops/partition.py). Which path a call takes
+is `fused_swiglu`'s rule over `impl`, the platform and the shapes.
 """
 
 import functools
 import math
-import os
 import types
 from typing import NamedTuple, Optional
 
@@ -255,13 +255,19 @@ def fused_swiglu(x, w_gate, w_up, w_down, activation="silu",
     `kernel` params of the three bias-free Dense projections, any
     param dtype — cast to `compute_dtype` here, flax-style).
 
-    impl: "fused" forces the Pallas kernel, "reference" the lax path;
-    "auto" picks the kernel on TPU where a step's blocks fit VMEM
-    (`kernel_fits`: not a many-row call at 6144 features, whose
-    products XLA's own matmuls run), the reference elsewhere. The
-    `CLOUD_TPU_FUSED_MLP` env var ("1"/"0") is the deployment A/B
-    override and beats `impl`; a forced kernel runs in interpret mode
-    off-TPU. Differentiable w.r.t. x and all three weights either way.
+    impl selects the path, from the platform and the shapes; no
+    environment name does:
+      "auto"       the Pallas kernel on a TPU where a step's blocks
+                   fit VMEM (`kernel_fits`: not a many-row call at
+                   6144 features, whose products XLA's own matmuls
+                   run), else the lax reference; what every model passes;
+      "fused"      the kernel wherever it runs: compiled on a TPU, in
+                   Pallas interpret mode elsewhere (parity tests,
+                   chip_smoke.py);
+      "reference"  the lax path (what tests compare against).
+    `interpret` overrides that choice of mode and `block_rows` the row
+    block (`_BLOCK_ROWS` otherwise); both are for tests.
+    Differentiable w.r.t. x and all three weights on either path.
     """
     features = x.shape[-1]
     if w_gate.ndim != 2 or w_gate.shape[0] != features:
@@ -276,12 +282,7 @@ def fused_swiglu(x, w_gate, w_up, w_down, activation="silu",
         raise ValueError(
             "w_down must be [d_ff={}, d_out]; got {}.".format(
                 w_gate.shape[1], w_down.shape))
-    env = os.environ.get("CLOUD_TPU_FUSED_MLP", "").strip()
-    if env == "1":
-        use_kernel = True
-    elif env == "0":
-        use_kernel = False
-    elif impl == "fused":
+    if impl == "fused":
         use_kernel = True
     elif impl == "reference":
         use_kernel = False
@@ -302,8 +303,7 @@ def fused_swiglu(x, w_gate, w_up, w_down, activation="silu",
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if block_rows is None:
-        block_rows = int(os.environ.get("CLOUD_TPU_FUSED_MLP_BLOCK",
-                                        _BLOCK_ROWS))
+        block_rows = _BLOCK_ROWS
     if compute_dtype is None:
         compute_dtype = jnp.promote_types(x.dtype, w_gate.dtype)
     compute_dtype = jnp.dtype(compute_dtype)
@@ -352,69 +352,3 @@ def fused_swiglu(x, w_gate, w_up, w_down, activation="silu",
     return partition.per_shard(kernel, (x, w_gate, w_up, w_down), plan,
                                interpret)
 
-
-def fused_mlp_cost(shape, d_ff, dtype=jnp.bfloat16):
-    """Per-call flops / bytes-moved row for the telemetry gauges, via
-    the jit cost-analysis hook on the lax reference (PR 6 idiom);
-    bytes_moved is the fused single-pass traffic (x in, y out, three
-    weights — the [rows, d_ff] intermediates stay in VMEM). Returns
-    {"flops", "bytes_moved"}; never raises."""
-    rows = 1
-    for dim in shape[:-1]:
-        rows *= dim
-    features = shape[-1]
-    flops = 6.0 * rows * features * d_ff  # three matmuls
-    try:
-        args = [jax.ShapeDtypeStruct(tuple(shape), dtype),
-                jax.ShapeDtypeStruct((features, d_ff), jnp.float32),
-                jax.ShapeDtypeStruct((features, d_ff), jnp.float32),
-                jax.ShapeDtypeStruct((d_ff, features), jnp.float32)]
-        analysis = jax.jit(swiglu_reference).lower(
-            *args).cost_analysis()
-        flops = float(analysis.get("flops", flops) or flops)
-    except Exception:
-        pass
-    itemsize = jnp.dtype(dtype).itemsize
-    bytes_moved = float(2 * rows * features * itemsize
-                        + 3 * features * d_ff * 4)
-    return {"flops": flops, "bytes_moved": bytes_moved}
-
-
-def record_cost_row(shape, d_ff, dtype=jnp.bfloat16, iters=10):
-    """Times the jitted fused tail at `shape` and feeds the telemetry
-    kernel-cost row (`cloud_tpu_kernel_fused_mlp_pct_peak` /
-    `_bytes_moved`) — the bench/CI hook that turns the cost analysis
-    into a tracked pct-of-peak metric. No-op (returns None) when
-    telemetry is off; returns the per-call seconds otherwise."""
-    import sys
-    import time
-
-    telemetry = sys.modules.get("cloud_tpu.monitoring.telemetry")
-    if telemetry is None:
-        return None
-    tele = telemetry.get()
-    if tele is None or not tele.active:
-        return None
-    import numpy as np
-
-    rng = np.random.RandomState(0)
-    features = shape[-1]
-    x = jnp.asarray(rng.randn(*shape), dtype)
-    w_gate = jnp.asarray(rng.randn(features, d_ff) * 0.02, jnp.float32)
-    w_up = jnp.asarray(rng.randn(features, d_ff) * 0.02, jnp.float32)
-    w_down = jnp.asarray(rng.randn(d_ff, features) * 0.02, jnp.float32)
-
-    @jax.jit
-    def run(x, w_gate, w_up, w_down):
-        return fused_swiglu(x, w_gate, w_up, w_down)
-
-    jax.block_until_ready(run(x, w_gate, w_up, w_down))  # compile
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = run(x, w_gate, w_up, w_down)
-    jax.block_until_ready(out)
-    elapsed = (time.perf_counter() - t0) / max(iters, 1)
-    cost = fused_mlp_cost(shape, d_ff, dtype)
-    tele.record_kernel_cost("fused_mlp", cost["flops"],
-                            cost["bytes_moved"], elapsed)
-    return elapsed

@@ -24,11 +24,11 @@ the forward serving/training hot path.
 
 On non-TPU backends a forced kernel runs in Pallas interpret mode, so
 parity tests exercise the same code path CPU-side. Under a device mesh
-the kernel runs per shard (ops/partition.py).
+the kernel runs per shard (ops/partition.py). Which path a call takes
+is `fused_rmsnorm`'s rule over `impl` and the platform, nothing else.
 """
 
 import functools
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -209,11 +209,17 @@ def fused_rmsnorm(x, scale, residual=None, eps=1e-6, out_dtype=None,
     residual stream; x itself when residual is None); normed =
     RMSNorm(h) in `out_dtype` (default: h's dtype).
 
-    impl: "fused" forces the Pallas kernel, "reference" the lax path;
-    "auto" picks the kernel on TPU, the reference elsewhere. The
-    `CLOUD_TPU_FUSED_NORM` env var ("1"/"0") is the deployment A/B
-    override and beats `impl`; a forced kernel runs in interpret mode
-    off-TPU. Differentiable w.r.t. x, residual, and scale either way.
+    impl selects the path, from the platform; no environment name does:
+      "auto"       the Pallas kernel on a TPU, the lax reference
+                   elsewhere; what every model passes;
+      "fused"      the kernel wherever it runs: compiled on a TPU, in
+                   Pallas interpret mode elsewhere (parity tests,
+                   chip_smoke.py);
+      "reference"  the lax path (what tests compare against).
+    `interpret` overrides that choice of mode and `block_rows` the row
+    block (`_BLOCK_ROWS` rows a grid step otherwise); both are for
+    tests. Differentiable w.r.t. x, residual, and scale on either
+    path. tests/unit/test_kernel_selection.py holds the table.
     """
     features = x.shape[-1]
     if scale.shape != (features,):
@@ -224,12 +230,7 @@ def fused_rmsnorm(x, scale, residual=None, eps=1e-6, out_dtype=None,
         raise ValueError(
             "residual must match x's shape {}; got {}.".format(
                 x.shape, residual.shape))
-    env = os.environ.get("CLOUD_TPU_FUSED_NORM", "").strip()
-    if env == "1":
-        use_kernel = True
-    elif env == "0":
-        use_kernel = False
-    elif impl == "fused":
+    if impl == "fused":
         use_kernel = True
     elif impl == "reference":
         use_kernel = False
@@ -242,8 +243,7 @@ def fused_rmsnorm(x, scale, residual=None, eps=1e-6, out_dtype=None,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if block_rows is None:
-        block_rows = int(os.environ.get("CLOUD_TPU_FUSED_NORM_BLOCK",
-                                        _BLOCK_ROWS))
+        block_rows = _BLOCK_ROWS
     if out_dtype is None:
         out_dtype = x.dtype if residual is None else jnp.promote_types(
             x.dtype, residual.dtype)
@@ -295,74 +295,3 @@ def fused_rmsnorm(x, scale, residual=None, eps=1e-6, out_dtype=None,
     args = (x, scale) + ((residual,) if residual is not None else ())
     return partition.per_shard(kernel, args, plan, interpret)
 
-
-def fused_norm_cost(shape, dtype=jnp.bfloat16, with_residual=True):
-    """Per-call flops / bytes-moved row for the telemetry gauges, via
-    the jit cost-analysis hook on the lax reference (PR 6 idiom);
-    bytes_moved is the fused single-pass traffic (x [+ residual] in,
-    normed + h out, scale). Returns {"flops", "bytes_moved"}; never
-    raises."""
-    rows = 1
-    for dim in shape[:-1]:
-        rows *= dim
-    features = shape[-1]
-    n = float(rows * features)
-    flops = 4.0 * n  # add, square, two scaled multiplies per element
-    try:
-        args = [jax.ShapeDtypeStruct(tuple(shape), dtype),
-                jax.ShapeDtypeStruct((features,), jnp.float32)]
-        if with_residual:
-            fn = functools.partial(
-                lambda x, s, r: rmsnorm_residual_reference(
-                    x, s, residual=r))
-            args.append(jax.ShapeDtypeStruct(tuple(shape), dtype))
-        else:
-            fn = rmsnorm_residual_reference
-        analysis = jax.jit(fn).lower(*args).cost_analysis()
-        flops = float(analysis.get("flops", flops) or flops)
-    except Exception:
-        pass
-    itemsize = jnp.dtype(dtype).itemsize
-    tensors = 4 if with_residual else 2  # in (+res), normed, h is x
-    bytes_moved = float(tensors * n * itemsize + features * 4)
-    return {"flops": flops, "bytes_moved": bytes_moved}
-
-
-def record_cost_row(shape, dtype=jnp.bfloat16, with_residual=True,
-                    iters=10):
-    """Times the jitted fused tail at `shape` and feeds the telemetry
-    kernel-cost row (`cloud_tpu_kernel_fused_norm_pct_peak` /
-    `_bytes_moved`) — the bench/CI hook that turns the cost analysis
-    into a tracked pct-of-peak metric. No-op (returns None) when
-    telemetry is off; returns the per-call seconds otherwise."""
-    import sys
-    import time
-
-    telemetry = sys.modules.get("cloud_tpu.monitoring.telemetry")
-    if telemetry is None:
-        return None
-    tele = telemetry.get()
-    if tele is None or not tele.active:
-        return None
-    import numpy as np
-
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(*shape), dtype)
-    residual = jnp.asarray(rng.randn(*shape), dtype) if with_residual \
-        else None
-    scale = jnp.ones((shape[-1],), jnp.float32)
-
-    @jax.jit
-    def run(x, residual, scale):
-        return fused_rmsnorm(x, scale, residual=residual)
-
-    jax.block_until_ready(run(x, residual, scale))  # compile
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = run(x, residual, scale)
-    jax.block_until_ready(out)
-    elapsed = (time.perf_counter() - t0) / max(iters, 1)
-    cost = fused_norm_cost(shape, dtype, with_residual)
-    tele.record_kernel_cost("fused_norm", cost["flops"],
-                            cost["bytes_moved"], elapsed)
-    return elapsed

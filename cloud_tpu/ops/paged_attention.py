@@ -849,51 +849,3 @@ def paged_attention(q, key_pages, value_pages, page_table, allowed,
                                      key_scales=key_scales,
                                      value_scales=value_scales)
 
-
-def paged_attention_cost(slots, seq, heads, head_dim, page_size,
-                         pages_per_slot, dtype=jnp.bfloat16,
-                         kv_dtype=None):
-    """Per-call flops / bytes-moved row for the telemetry gauges.
-
-    flops come from the jit cost-analysis hook (the PR 6 idiom —
-    `lower().cost_analysis()`, exception-swallowed) on
-    the gathered reference at these shapes; bytes_moved is the kernel's
-    HBM traffic (q + out + the slot's own K/V pages + table + mask),
-    i.e. what the fused path touches — NOT the dense gather the
-    reference materializes. kv_dtype (default: `dtype`) sizes the K/V
-    page traffic separately so int8 pages report their real, smaller
-    byte movement (plus the per-page f32 scale reads). Returns
-    {"flops", "bytes_moved"}; never raises (falls back to the analytic
-    flop count).
-    """
-    cache_len = page_size * pages_per_slot
-    num_pages = slots * pages_per_slot + 1
-    itemsize = jnp.dtype(dtype).itemsize
-    kv_itemsize = jnp.dtype(kv_dtype or dtype).itemsize
-    quantized = kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
-    # 2 matmuls (qk^T, pv), 2 flops per MAC.
-    flops = 4.0 * slots * seq * cache_len * heads * head_dim
-    try:
-        shapes = (
-            jax.ShapeDtypeStruct((slots, seq, heads, head_dim), dtype),
-            jax.ShapeDtypeStruct((num_pages, page_size,
-                                  heads * head_dim), dtype),
-            jax.ShapeDtypeStruct((num_pages, page_size,
-                                  heads * head_dim), dtype),
-            jax.ShapeDtypeStruct((slots, pages_per_slot), jnp.int32),
-            jax.ShapeDtypeStruct((slots, seq, cache_len), jnp.bool_),
-        )
-        analysis = jax.jit(paged_attention_reference).lower(
-            *shapes).cost_analysis()
-        flops = float(analysis.get("flops", flops) or flops)
-    except Exception:
-        pass
-    bytes_moved = float(
-        2 * slots * cache_len * heads * head_dim * kv_itemsize  # K/V
-        + 2 * slots * seq * heads * head_dim * itemsize       # q + out
-        + slots * pages_per_slot * 4                          # table
-        + slots * seq * cache_len)                            # mask
-    if quantized:
-        # Per-page per-head f32 K and V scale rows.
-        bytes_moved += float(2 * slots * pages_per_slot * heads * 4)
-    return {"flops": flops, "bytes_moved": bytes_moved}

@@ -8,11 +8,12 @@ cost a managed resource in three pieces:
    WHERE it lives is decided outside the code: when
    `JAX_COMPILATION_CACHE_DIR` is set, JAX already has its directory
    and `enable()` sets none; otherwise it is `benchmarks/.jax_cache` in
-   the checkout (git-ignored). One fixed path either way — the path is
-   part of the cache key, so a directory that moves never hits.
+   the checkout (git-ignored; the directory holds nothing else). One
+   fixed path either way — the path is part of the cache key, so a
+   directory that moves never hits.
 2. Cache hit/miss stats — a `jax.monitoring` listener feeds persistent
-   cache hits into `runtime.compile_stats()["cache_hits"]` so tests and
-   bench can assert "the second process compiled nothing" as a counted
+   cache hits into `runtime.compile_stats()["cache_hits"]` so tests
+   can assert "the second process compiled nothing" as a counted
    invariant (the same doctrine as `runtime.transfer_stats()`).
 3. `serialize_executable` / `deserialize_executable` — thin wrappers
    over the JAX AOT serialization API for shipping a compiled step to

@@ -380,7 +380,7 @@ def reset():
 # metric reads into one call is exactly the round-trip win the counter
 # exists to pin.
 #
-# Tests and bench.py assert transfer behavior from these counters
+# Tests assert transfer behavior from these counters
 # instead of inferring it from wall clock — in particular that the
 # device-resident pipeline does ZERO per-step H2D data transfers after
 # its one-time upload, and that input_cast="bfloat16" halves the bytes
@@ -627,7 +627,7 @@ def record_d2h(tree):
 def device_fetch(tree):
     """The sanctioned instrumented readback: record, then device_get.
 
-    All Trainer/bench device->host reads route through here so the
+    All Trainer device->host reads route through here so the
     d2h counters stay an exhaustive census of fetch sites — and so one
     graftscope span ("d2h_fetch") times every round trip. Returns
     `jax.device_get(tree)` (host numpy leaves, same structure).
@@ -647,7 +647,7 @@ def transfer_stats():
 
 
 def reset_transfer_stats():
-    """Zeroes all transfer counters (test isolation / bench warmup
+    """Zeroes all transfer counters (test isolation / warm-up
     barrier)."""
     for key in _transfer_stats:
         _transfer_stats[key] = 0
@@ -706,7 +706,7 @@ def compile_stats():
 
 
 def reset_compile_stats():
-    """Zeroes all compile counters (test isolation / bench warmup
+    """Zeroes all compile counters (test isolation / warm-up
     barrier). Does NOT clear jax's own caches — an executable compiled
     before the reset stays warm, which is exactly what a steady-state
     invariant wants."""

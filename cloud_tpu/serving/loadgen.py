@@ -17,8 +17,7 @@ requests count against goodput by construction.
 The module is also the CI `serve-trace-smoke` driver: run with
 `CLOUD_TPU_REQTRACE=1` it produces the reqtrace JSONL that
 `monitoring/collect.py --serve` rolls into the per-request waterfall +
-`serve_report.json`, and `BENCH_SERVE_LOAD=1` (bench.py) records
-offered load vs. achieved goodput at several arrival rates.
+`serve_report.json`.
 
 Usage (CPU-friendly):
 

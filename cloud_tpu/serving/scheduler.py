@@ -2599,8 +2599,7 @@ class Scheduler:
                                  **cfg)).result(timeout=600)
 
     def stats(self):
-        """Host-side rollup for bench/smoke (works with telemetry
-        off)."""
+        """Host-side rollup (works with telemetry off)."""
         wall = max(time.monotonic() - (self._t_start or
                                        time.monotonic()), 1e-9)
         lookups = self._hits + self._misses

@@ -148,7 +148,7 @@ _stats = dict(_STATS_ZERO)
 
 def guard_stats():
     """Snapshot of the process-wide graftguard counters — the
-    telemetry-free introspection point (tests, bench records)."""
+    telemetry-free introspection point (tests)."""
     return dict(_stats)
 
 
@@ -197,8 +197,8 @@ class _GuardScope:
 def guard_scope():
     """Context manager scoping `guard_stats()` to one supervised run.
 
-    The module-global counters are process-wide by design (telemetry,
-    bench records); anything running MANY supervised fits in one
+    The module-global counters are process-wide by design
+    (telemetry); anything running MANY supervised fits in one
     process — a graftsweep trial, a test — needs per-run attribution.
     `with guard_scope() as guard:` snapshots on entry and `guard.stats()`
     returns only what accrued inside the scope, so trial K's faults

@@ -409,23 +409,3 @@ class TestPersistentCache:
         out = compile_cache.load_executable(path)(
             jnp.ones((4,), jnp.float32))
         np.testing.assert_allclose(np.asarray(out), 2.0)
-
-
-class TestBenchCensus:
-
-    def test_bench_record_carries_compile_census(self, tmp_path):
-        """Every bench record carries the census fields (acceptance
-        criterion) — checked against the worker's record dict builder
-        via a tiny subprocess-free shim: run the worker in-process is
-        too heavy for tier 1, so pin the field list at the source."""
-        import tokenize
-
-        with tokenize.open(os.path.join(
-                os.path.dirname(__file__), "..", "..",
-                "bench.py")) as fh:
-            src = fh.read()
-        for field in ('"n_traces"', '"n_compiles"',
-                      '"compile_seconds"', '"compile_cache_hits"',
-                      '"persistent_cache_hits"',
-                      '"persistent_cache_misses"'):
-            assert field in src, field

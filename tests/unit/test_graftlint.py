@@ -1239,7 +1239,7 @@ class TestSelfRun:
         targets = [os.path.join(repo_root, "cloud_tpu")]
         # tests/ is linted too: a pitfall in a test fixture that is
         # real code (not a string) must carry an explicit suppression.
-        for extra in ("bench.py", "examples", "tests"):
+        for extra in ("examples", "tests"):
             path = os.path.join(repo_root, extra)
             if os.path.exists(path):  # absent in installed layouts
                 targets.append(path)

@@ -447,7 +447,6 @@ class TestLifecycle:
         assert tele.peak_flops is None
         tele.set_step_flops(1e12)
         tele.record_epoch(steps=10, examples=320, elapsed_secs=2.0)
-        tele.record_kernel_cost("paged_attention", 1e9, 1e6, 0.01)
         gauges = tele.registry.snapshot()["gauges"]
         assert "cloud_tpu_mfu_pct_peak" not in gauges
         assert not any("pct_peak" in name for name in gauges)
