@@ -39,21 +39,31 @@ Spans:
     checkpoint_snapshot   the donation-safe host copy before a save
     async_reader_drain    the off-thread metric fetch
     decode                one generate()/beam/speculative call
-    tick_admit            tick thread, before a tick: resize, one
+    tick_admit            tick thread, before a dispatch: resize, one
                           prefill chunk, inserts of ready requests
-                          (the slot_insert dispatches)
+                          (the slot_insert dispatches), behind the
+                          tick in flight
     tick_pace             one 5 ms nap of the tick thread, taken
                           because an admission is in flight and a slot
-                          is free
+                          is free (nothing is in flight during it)
     tick_idle             the tick thread's wait (up to 50 ms) with no
-                          slot occupied
-    serve_tick            one engine tick: dispatch + d2h fetch of the
-                          committed tokens (the serving hot loop)
+                          slot occupied (nothing in flight)
+    serve_tick            one turn of the serving hot loop: the
+                          dispatch of tick n+1, then the d2h fetch of
+                          tick n where one was in flight (the first
+                          tick after a drain: the dispatch alone)
     tick_dispatch         inside serve_tick: the `engine.tick()` call
-    tick_fetch            inside serve_tick: the blocking fetch of the
-                          tick's tokens
-    tick_commit           after a tick: tokens to their requests,
-                          completions, eviction
+                          and the start of its tokens' copy to the
+                          host
+    tick_fetch            the blocking fetch of a tick's tokens: of
+                          the tick BEFORE the one just dispatched,
+                          inside serve_tick; of a tick that is
+                          drained (before a nap, an idle wait, a
+                          resize, a chaos event, close), on its own
+    tick_commit           after a tick_fetch, outside serve_tick: the
+                          fetched tick's tokens to their requests,
+                          completions, eviction (while the next tick
+                          is on the device, unless drained)
     admit                 one request's turn in its admission window:
                           probe, decision, reservation, prefill (rid)
     admit_reserve         inside admit: the page-reservation rounds

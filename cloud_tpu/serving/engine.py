@@ -806,7 +806,26 @@ class DecodeEngine:
         tokens (-1 on inactive slots), row spec_k + 1 the commit count,
         row spec_k + 2 the finished flag, row spec_k + 3 the accepted
         draft count (-1 on non-speculating slots). The scheduler
-        fetches it with `runtime.device_fetch`."""
+        fetches it with `runtime.device_fetch`.
+
+        A finished slot retires itself: the tick that reports a slot
+        `finished` clears its `ctl["active"]`, so the scheduler may
+        dispatch the next tick before it has read this one (it keeps
+        one tick in flight). To that blind tick the slot is inactive
+        like any other: its row is masked out of the model, its
+        `slot_steps` and `slot_valid` rows stay as they are, its `out`
+        column reads -1 (commit count 0) and `finished` 0, so a finish
+        is reported exactly once. The paged write is unconditional
+        (`decoding.paged_kv_attention`): the masked row's key and
+        value land at the slot's own write pointer, which after the
+        finishing tick is at most position `prompt + max_new - 1` — in
+        a page the request reserved for itself, or in the scratch page
+        where its table has no entry that far. That position is never
+        valid and never attended, and it is never in a shared page
+        (those lie strictly below every holder's pointer). `evict`
+        still zeroes the page-table and validity rows, and the page
+        ids return to the pool only when the scheduler commits the
+        finish, behind which every later insert is ordered."""
         if self.spec_on:
             (self.cache, self.draft_cache, self.ctl, out) = self._tick(
                 self._params, self._draft_params, self.cache,
@@ -1077,6 +1096,8 @@ class DecodeEngine:
         steps = ctl["steps_done"] + active.astype(jnp.int32)
         finished = active & (done | (steps >= ctl["max_steps"]))
         out_ctl = dict(ctl)
+        # A finished slot retires itself (see `tick`'s docstring).
+        out_ctl["active"] = active & ~finished
         out_ctl["cur_tok"] = jnp.where(active, nxt, ctl["cur_tok"])
         out_ctl["done"] = jnp.where(active, done, ctl["done"])
         out_ctl["steps_done"] = steps
@@ -1199,6 +1220,7 @@ class DecodeEngine:
         dcache = _plain(dvars["cache"])
 
         out_ctl = dict(ctl)
+        out_ctl["active"] = active & ~finished
         out_ctl["cur_tok"] = jnp.where(active, cur_tok, ctl["cur_tok"])
         out_ctl["done"] = jnp.where(active, done_new, ctl["done"])
         out_ctl["steps_done"] = steps

@@ -17,7 +17,11 @@ Two threads around a `DecodeEngine`:
   (one committed token per tick, or up to spec_k + 1 with speculative
   decode), fetches the tick output (the serving loop's single counted
   d2h round trip), completes/evicts finished slots, and returns their
-  pages.
+  pages. It keeps ONE tick in flight: tick n+1 is dispatched before
+  tick n's tokens are fetched, so the commit, the admissions and the
+  next dispatch run while the device works (`_tick_loop`); whatever
+  needs the slots' exact state (a nap, an idle wait, a resize, a chaos
+  event, close) first fetches and commits the tick in flight.
 
 Prefix sharing (graftshare): every inserted prompt's full pages are
 registered in a radix trie (serving/prefixcache.py). A later request
@@ -194,6 +198,20 @@ class _Slot:
         # (the continuation cold-prefills, but the REQUEST's cache-hit
         # status is a property of its original admission).
         self.result_prefix_len = prefix_len
+
+
+class _Flight:
+    """A tick on the device whose tokens the host has not read: what
+    its dispatch has to remember, because by the time it is committed
+    the engine holds the next tick's counters and a slot that finished
+    in the tick before may hold another request."""
+    __slots__ = ("out", "counters", "t_dispatch", "slots")
+
+    def __init__(self, out, counters, t_dispatch, slots):
+        self.out = out              # device tokens (`engine.tick()`)
+        self.counters = counters    # `engine.tick_counters`, this tick's
+        self.t_dispatch = t_dispatch
+        self.slots = slots          # slot -> _Slot it ran with (a copy)
 
 
 class _ReadyItem:
@@ -435,6 +453,13 @@ class Scheduler:
         # 5 ms naps the tick thread took for the sake of an admission
         # in flight (each is a `tick_pace` span).
         self._tick_paces = 0
+        # The tick on the device whose tokens are not fetched yet (tick
+        # thread only; other threads read it to wait for None), and
+        # the ticks that were dispatched while the one before them was
+        # still unfetched: beside `ticks`, the share of ticks for
+        # which the device did not wait for the host.
+        self._flight = None
+        self._ticks_overlapped = 0
         # Running sums over ticks and occupied slots: the keys a slot
         # attends to, and the keys the paged kernel's walk fetches for
         # that depth (whole groups of `_kv_group` pages; the kernel's
@@ -724,8 +749,13 @@ class Scheduler:
         jump on warmed executables too. Returns False when the live
         set does not fit `new_slots` (the caller retries after
         drains); occupancy cannot change between steps because the
-        whole walk runs inside one tick boundary on the tick thread."""
+        whole walk runs inside one tick boundary on the tick thread.
+        The geometry moves only with nothing in flight: the tick on
+        the device is fetched and committed first, so the rows the
+        gather migrates are the rows the host knows."""
         ladder = self.engine.ladder
+        if self.engine.slots != new_slots:
+            self._drain_tick()
         while self.engine.slots != new_slots:
             idx = ladder.index(self.engine.slots)
             step = (ladder[idx + 1] if new_slots > self.engine.slots
@@ -1451,7 +1481,13 @@ class Scheduler:
         plan = chaos.active_plan()
         if plan is None:
             return
-        for event in plan.pre_tick(self._ticks):
+        # Events are indexed by the ticks that have RUN: the one in
+        # flight counts, and is committed before a fault reads a
+        # slot's `emitted`.
+        due = plan.pre_tick(self._ticks + (self._flight is not None))
+        if due:
+            self._drain_tick()
+        for event in due:
             self._apply_chaos(event)
 
     def _apply_chaos(self, event):
@@ -1602,6 +1638,19 @@ class Scheduler:
     # -- tick thread --------------------------------------------------
 
     def _tick_loop(self):
+        """The tick thread: a pipeline of depth one between the host
+        and the device. In steady state an iteration admits
+        (`tick_admit`), dispatches tick n+1, and only then fetches and
+        commits tick n — so commit, admissions and the next dispatch
+        run while a tick is on the device, which needs none of them:
+        `cur_tok`, the step counters, the eos latch and the key
+        schedule live in `ctl`, and a tick retires the slots it
+        finishes (`DecodeEngine.tick`). Inserts and evictions are
+        dispatched behind the tick in flight and ordered with it by
+        the donated `cache` / `ctl`. Any iteration that does not
+        dispatch (no slot occupied, a pacing nap) and anything that
+        reasons about exact slot state (resize, chaos, close) first
+        fetches and commits the tick in flight (`_drain_tick`)."""
         runtime.set_phase("serve_tick")
         from cloud_tpu.monitoring import watch
         # Adopt an installed graftwatch: the tick thread becomes the
@@ -1623,6 +1672,7 @@ class Scheduler:
                     stepped = self._step_chunks()
                     self._insert_ready()
                 if not any(s is not None for s in self._slots):
+                    self._drain_tick()
                     self._t_last_commit = None
                     if stepped:
                         # A continuation advanced and nothing decodes:
@@ -1645,7 +1695,9 @@ class Scheduler:
                     # pages only ticks can free. In-flight chunked
                     # prefills are excluded — only this loop advances
                     # them, so waiting on them would stall every
-                    # resident slot for nothing.
+                    # resident slot for nothing. A finished tick's
+                    # tokens are never held behind a nap.
+                    self._drain_tick()
                     skips += 1
                     self._tick_paces += 1
                     with spans.span("tick_pace"):
@@ -1654,31 +1706,85 @@ class Scheduler:
                     continue
                 skips = 0
                 with spans.span("serve_tick"):
-                    t0 = time.monotonic()
-                    with spans.span("tick_dispatch"):
-                        out = self.engine.tick()
-                    with spans.span("tick_fetch"):
-                        fetched, counters = runtime.device_fetch(
-                            (out, self.engine.tick_counters))
-                    t_commit = time.monotonic()
-                elapsed = t_commit - t0
-                self._ticks += 1
-                if self._t_last_commit is not None:
-                    self._observe_decode_gap(
-                        t_commit - self._t_last_commit,
-                        sum(s is not None for s in self._slots))
-                self._t_last_commit = t_commit
-                with spans.span("tick_commit"):
-                    self._distribute(fetched, elapsed, t_commit)
-                    if counters:
-                        self._count_moe(counters)
-                if self.strict_no_retrace:
-                    self.engine.check_no_retrace()
+                    behind, self._flight = (self._flight,
+                                            self._dispatch_tick())
+                    fetched = (None if behind is None
+                               else self._fetch_tick(behind))
+                if behind is not None:
+                    self._ticks_overlapped += 1
+                    self._commit_tick(behind, *fetched)
+            self._drain_tick()
         except BaseException as exc:  # noqa: BLE001
             self._failure = exc
             self._stop.set()
             self.pool.close()
             self._fail_pending(exc)
+
+    def _dispatch_tick(self):
+        """Puts one tick on the device and returns what its commit
+        will need. The copy of its tokens (and of an expert model's
+        counters) to the host is started here, so that the transfer is
+        queued ahead of whatever program is dispatched next."""
+        t_dispatch = time.monotonic()
+        with spans.span("tick_dispatch"):
+            out = self.engine.tick()
+            # The next tick overwrites the attribute.
+            counters = self.engine.tick_counters
+            for leaf in jax.tree_util.tree_leaves((out, counters)):
+                leaf.copy_to_host_async()
+        return _Flight(out, counters, t_dispatch, list(self._slots))
+
+    def _fetch_tick(self, flight):
+        """Blocks until `flight`'s tokens are on the host: the serving
+        loop's one counted read-back a tick."""
+        with spans.span("tick_fetch"):
+            fetched, counters = runtime.device_fetch(
+                (flight.out, flight.counters))
+        return fetched, counters, time.monotonic()
+
+    def _commit_tick(self, flight, fetched, counters, t_commit):
+        """Hands a fetched tick's tokens to its requests. `elapsed` is
+        what the token cost a slot: commit to commit while the
+        pipeline is full, dispatch to commit for the first tick after
+        a drain."""
+        self._ticks += 1
+        # The slots this tick advanced: those it was dispatched with,
+        # less the ones the tick before it had finished (row s of a
+        # tick dispatched blind belongs neither to the request that
+        # left slot s nor to the one inserted there since).
+        live = [(slot, state) for slot, state in enumerate(flight.slots)
+                if state is not None and self._slots[slot] is state]
+        t_from = flight.t_dispatch
+        if self._t_last_commit is not None:
+            t_from = max(t_from, self._t_last_commit)
+            self._observe_decode_gap(t_commit - self._t_last_commit,
+                                     len(live))
+        self._t_last_commit = t_commit
+        with spans.span("tick_commit"):
+            self._distribute(live, fetched, t_commit - t_from, t_commit)
+            if counters:
+                self._count_moe(counters)
+        if self.strict_no_retrace:
+            self.engine.check_no_retrace()
+
+    def _drain_tick(self):
+        """Fetches and commits the tick in flight, if there is one
+        (tick thread only). After it the host's view of every slot is
+        exact: nothing is on the device that it has not read."""
+        flight = self._flight
+        if flight is not None:
+            self._commit_tick(flight, *self._fetch_tick(flight))
+            self._flight = None
+
+    def _settle(self, timeout=60.0):
+        """Another thread's wait for the tick thread to have drained
+        its pipeline: with no request in flight the last tick
+        dispatched (blind, behind the one that finished the last slot)
+        is fetched within an iteration."""
+        deadline = time.monotonic() + timeout
+        while (self._flight is not None and time.monotonic() < deadline
+               and self._tick_thread.is_alive()):
+            time.sleep(0.001)
 
     def _insert_ready(self):
         # Hit tickets blocked on page reservation are stashed and
@@ -2094,8 +2200,10 @@ class Scheduler:
             from cloud_tpu.monitoring import telemetry
             reg.counter(telemetry.SERVE_PAGE_DEMOTES_TOTAL).inc(n_full)
 
-    def _distribute(self, fetched, elapsed, t_commit):
-        n_active = sum(s is not None for s in self._slots)
+    def _distribute(self, live, fetched, elapsed, t_commit):
+        """`live`: the (slot, state) pairs the tick advanced, as
+        `_commit_tick` reads them from the dispatch's snapshot."""
+        n_active = len(live)
         if n_active:
             self._token_hist.observe(elapsed, count=n_active)
             # Geometry stamp: tick latency and occupancy roll up under
@@ -2107,13 +2215,12 @@ class Scheduler:
             # What this tick's attention read: the token it consumed
             # sits at prompt + emitted - 1, so that many keys and
             # itself.
-            for state in self._slots:
-                if state is not None:
-                    depth = (len(state.request.prompt)
-                             + len(state.emitted) + self._kv_reach)
-                    self._kv_live_tokens += depth
-                    self._kv_walked_tokens += walked_tokens(
-                        depth, self.pool.page_size, self._kv_group)
+            for _, state in live:
+                depth = (len(state.request.prompt)
+                         + len(state.emitted) + self._kv_reach)
+                self._kv_live_tokens += depth
+                self._kv_walked_tokens += walked_tokens(
+                    depth, self.pool.page_size, self._kv_group)
             reg = _registry()
             if reg is not None:
                 from cloud_tpu.monitoring import telemetry
@@ -2123,9 +2230,9 @@ class Scheduler:
                     telemetry.SERVE_TICK_SECONDS
                     % self.engine.slots).observe(elapsed)
         if self.engine.spec_on:
-            self._distribute_spec(fetched, t_commit)
+            self._distribute_spec(live, fetched, t_commit)
         else:
-            self._distribute_plain(fetched, t_commit)
+            self._distribute_plain(live, fetched, t_commit)
         trace = self._trace
         if trace is not None:
             # Batched tick commits: one event per tick_every ticks per
@@ -2133,8 +2240,9 @@ class Scheduler:
             # carrying committed-token progress and batch occupancy —
             # the slot-occupancy timeline without per-token event cost.
             every = trace.tick_every
-            for state in self._slots:
-                if state is None or state.rec.rid is None:
+            for slot, state in live:
+                if (self._slots[slot] is not state
+                        or state.rec.rid is None):
                     continue
                 state.trace_ticks += 1
                 if state.trace_ticks >= every:
@@ -2153,12 +2261,10 @@ class Scheduler:
         self._moe_expert_load = (load if self._moe_expert_load is None
                                  else self._moe_expert_load + load)
 
-    def _distribute_plain(self, fetched, t_commit):
+    def _distribute_plain(self, live, fetched, t_commit):
         tokens_row, finished_row = fetched[0], fetched[1]
         evict_mask = np.zeros((self.engine.slots,), bool)
-        for slot, state in enumerate(self._slots):
-            if state is None:
-                continue
+        for slot, state in live:
             state.emitted.append(int(tokens_row[slot]))
             state.rec.token_times.append(t_commit)
             if finished_row[slot]:
@@ -2167,7 +2273,7 @@ class Scheduler:
             self.engine.evict(evict_mask)
             self._observe_gauges()
 
-    def _distribute_spec(self, fetched, t_commit):
+    def _distribute_spec(self, live, fetched, t_commit):
         from cloud_tpu.models.speculative import observe_accept_rate
 
         k = self.engine.spec_k
@@ -2175,9 +2281,7 @@ class Scheduler:
         finished_row = fetched[k + 2]
         accept_row = fetched[k + 3]
         evict_mask = np.zeros((self.engine.slots,), bool)
-        for slot, state in enumerate(self._slots):
-            if state is None:
-                continue
+        for slot, state in live:
             c = int(count_row[slot])
             state.emitted.extend(
                 int(fetched[j][slot]) for j in range(c))
@@ -2375,6 +2479,7 @@ class Scheduler:
         if busy:
             raise RuntimeError(
                 "assert_drained called with requests in flight.")
+        self._settle()
         if clear_prefix and self.trie is not None:
             self.trie.clear()
         held = self.pool.leak_report()
@@ -2480,6 +2585,9 @@ class Scheduler:
             self.trie.clear()
             self.trie.reset_stats()
         self._warm_ladder(configs[0], max_new)
+        # The tick dispatched behind the last finish is still counted
+        # as warm-up.
+        self._settle()
         self.engine.mark_warm()
         self._trace_suppress = False
         # Warm-up TTFTs are compile times; restart the host-side stats
@@ -2496,6 +2604,7 @@ class Scheduler:
         self._decode_gap_hist = Histogram("decode_gap")
         self._chunks_dispatched = 0
         self._tick_paces = 0
+        self._ticks_overlapped = 0
         self._kv_live_tokens = 0
         self._kv_walked_tokens = 0
         self._moe_pairs_routed = 0
@@ -2609,6 +2718,7 @@ class Scheduler:
             "tokens_emitted": self._tokens_out,
             "ticks": self._ticks,
             "tick_paces": self._tick_paces,
+            "ticks_overlapped": self._ticks_overlapped,
             "kv_live_tokens": self._kv_live_tokens,
             "kv_walked_tokens": self._kv_walked_tokens,
             "moe_pairs_routed": self._moe_pairs_routed,

@@ -359,3 +359,46 @@ class TestGeometryStats:
         for g in per_geom.values():
             assert g["ticks"] == g["tick_latency"]["count"]
             assert 0.0 <= g["occupancy_mean"] <= 4.0
+
+
+class TestResizeDrainsThePipeline:
+    """The tick loop keeps one tick in flight; the geometry moves only
+    with nothing in flight (fast tier)."""
+
+    def test_resize_asked_for_with_a_tick_in_flight(self, model, params,
+                                                    monkeypatch):
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import TickLog, check_order
+        requests = [_greedy([2 + i, 7, 11], 14, seed=300 + i)
+                    for i in range(2)]
+        sched = Scheduler(model, params, slots=2, slots_min=2,
+                          slots_max=4)
+        log = TickLog(sched, monkeypatch)
+
+        def ask(n):
+            # From the tick thread, straight after a dispatch: what
+            # `request_resize(wait=False)` leaves for the next
+            # boundary, which this tick is still in flight at.
+            if n == 4:
+                sched._requested_resize = (4, "test")
+        log.on_dispatch = ask
+        with sched:
+            futures = [sched.submit(r, timeout=30) for r in requests]
+            results = [f.result(timeout=300) for f in futures]
+            sched.assert_drained()
+            geometry = sched.stats()["geometry"]
+        entries = log.since()
+        check_order(entries)   # a resize finds nothing in flight
+        at = entries.index(("resize", 4))
+        # The boundary found tick 4 unfetched: it was drained (fetched
+        # and committed with no tick dispatched behind it), then the
+        # rows moved, then tick 5 went out with nothing before it.
+        assert entries[at - 2:at] == [("drain", True), ("fetch", 4)], \
+            entries[:at + 1]
+        dispatched = [e for e in entries[:at] if e[0] == "dispatch"]
+        assert dispatched[-1] == ("dispatch", 4)
+        assert entries[at + 1] == ("dispatch", 5)
+        assert geometry["slots"] == 4
+        assert [(e["from"], e["to"], e["tick"])
+                for e in geometry["resize_events"]] == [(2, 4, 5)]
+        _assert_matches_oracle(model, params, requests, results)

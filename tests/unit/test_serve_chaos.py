@@ -429,3 +429,46 @@ class TestShedEndToEnd:
             _drained(sched)
         np.testing.assert_array_equal(res.tokens,
                                       _oracle(model, params, survivor))
+
+
+class TestFaultDrainsThePipeline:
+    """The tick loop keeps one tick in flight; a fault reads a slot's
+    `emitted`, so the tick in flight is committed first (fast tier)."""
+
+    def test_fault_fired_with_a_tick_in_flight(self, model, params,
+                                               monkeypatch):
+        from cloud_tpu.serving import Scheduler, ServeRequest
+        from tests.unit.tick_log import TickLog, check_order
+        requests = [
+            ServeRequest(prompt=[5, 6, 7, 8, 9], max_new_tokens=12,
+                         temperature=0.0, rng_seed=81),
+            ServeRequest(prompt=[9, 8, 7], max_new_tokens=12,
+                         temperature=0.9, top_p=0.9, rng_seed=82),
+        ]
+        sched = Scheduler(model, params, slots=2)
+        log = TickLog(sched, monkeypatch)
+
+        def arm(n):
+            # Events are indexed by the ticks that have run: this one
+            # is due at the boundary that finds tick 3 in flight.
+            if n == 0:
+                chaos.install("slot_evict@4")
+        log.on_dispatch = arm
+        with sched:
+            futures = [sched.submit(r, timeout=30) for r in requests]
+            results = [f.result(timeout=300) for f in futures]
+            stats = sched.stats()
+            sched.assert_drained(clear_prefix=True)
+            assert sched.pool.leak_report() == {}
+        entries = log.since()
+        check_order(entries)   # a fault finds nothing in flight
+        at = entries.index(("chaos", "slot_evict"))
+        assert entries[at - 2:at] == [("drain", True), ("fetch", 3)], \
+            entries[:at + 1]
+        dispatched = [e for e in entries[:at] if e[0] == "dispatch"]
+        assert dispatched[-1] == ("dispatch", 3)
+        assert stats["faults"] == {"slot_evict": 1}
+        assert stats["requeues"] == 1
+        for req, res in zip(requests, results):
+            np.testing.assert_array_equal(res.tokens,
+                                          _oracle(model, params, req))

@@ -383,14 +383,16 @@ class TestSchedulerStats:
                 emitted=[2],
                 rec=types.SimpleNamespace(token_times=[], rid=None))
         fetched = (np.zeros(4, np.int32), np.zeros(4, bool))
-        sched._distribute(fetched, 0.01, 0.0)
+        live = [(slot, state) for slot, state in enumerate(sched._slots)
+                if state is not None]
+        sched._distribute(live, fetched, 0.01, 0.0)
         stats = sched.stats()
         assert stats["kv_live_tokens"] == sum(depths)
         assert stats["kv_walked_tokens"] == sum(
             walked_tokens(d, 4, group) for d in depths)
         assert stats["kv_live_tokens"] <= stats["kv_walked_tokens"]
         # The next tick sees every slot one token deeper.
-        sched._distribute(fetched, 0.01, 0.0)
+        sched._distribute(live, fetched, 0.01, 0.0)
         assert sched.stats()["kv_live_tokens"] == 2 * sum(depths) + len(
             depths)
 
@@ -401,3 +403,226 @@ class TestSchedulerStats:
         stats = sched.stats()
         assert stats["queue_wait"]["count"] == 1
         assert stats["reserve_wait"]["count"] == 0
+
+
+# -- the one-deep tick pipeline (fast tier) ---------------------------
+
+
+def _greedy(prompt, max_new, seed=0, **kwargs):
+    from cloud_tpu.serving import ServeRequest
+    return ServeRequest(prompt=list(prompt), max_new_tokens=max_new,
+                        temperature=0.0, rng_seed=seed, **kwargs)
+
+
+class TestTickPipeline:
+    """Tick n+1 is on the device before tick n is fetched, and whatever
+    does not dispatch a tick first drains the one in flight."""
+
+    def test_dispatch_runs_one_ahead_and_drains(self, model, params,
+                                                monkeypatch):
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import TickLog, check_order
+        sched = Scheduler(model, params, slots=2, page_size=16)
+        log = TickLog(sched, monkeypatch)
+        with sched:
+            sched.warmup([4], sampling_configs=[(("temperature",
+                                                  0.0),)])
+            # The counter resets where `tick_paces` does, and warm-up's
+            # last (blind) tick is not left for the traffic's count.
+            stats = sched.stats()
+            assert (stats["ticks"], stats["tick_paces"],
+                    stats["ticks_overlapped"]) == (0, 0, 0)
+            assert sched._flight is None
+            check_order(log.since())
+            mark = log.mark()
+            requests = [_greedy([3 + i, 5, 7], 12, seed=i)
+                        for i in range(4)]
+            futures = [sched.submit(r, timeout=30) for r in requests]
+            results = [f.result(timeout=300) for f in futures]
+            sched.assert_drained()
+            stats = sched.stats()
+        entries = log.since(mark)
+        ticks, overlapped = check_order(entries)
+        assert stats["ticks"] == ticks
+        assert stats["ticks_overlapped"] == overlapped
+        # Two slots decode twelve tokens side by side: the steady state
+        # is there, and it overlaps.
+        assert overlapped >= 8
+        kinds = [e[0] for e in entries]
+        for i, entry in enumerate(entries):
+            if entry[0] == "nap":
+                # A nap and the idle wait come straight after a drain.
+                assert "drain" in kinds[max(i - 2, 0):i], entries[:i + 1]
+        assert stats["tick_paces"] == sum(
+            e == ("nap", 0.005) for e in entries)
+        # close() left nothing in flight either.
+        check_order(log.since())
+        for req, res in zip(requests, results):
+            np.testing.assert_array_equal(res.tokens,
+                                          _oracle(model, params, req))
+
+    def test_row_of_a_blind_tick_reaches_no_request(self, model, params):
+        """Commit reads the dispatch's snapshot: a tick dispatched
+        before its predecessor's finish was known carries a row for the
+        slot that finished (and may since hold another request). That
+        row is dropped; the rows of slots still running are kept."""
+        import types
+
+        from cloud_tpu.serving import Scheduler
+        from cloud_tpu.serving.scheduler import _Flight
+
+        def state(prompt_len):
+            return types.SimpleNamespace(
+                request=types.SimpleNamespace(prompt=[1] * prompt_len),
+                emitted=[2], trace_ticks=0,
+                rec=types.SimpleNamespace(token_times=[], rid=None))
+
+        sched = Scheduler(model, params, slots=4, page_size=4)
+        gone, stays, newcomer = state(3), state(5), state(7)
+        flight = _Flight(None, {}, 1.0, [gone, stays, None, None])
+        # Since the dispatch: slot 0's request finished (tick before)
+        # and another was inserted there; slot 2 was filled too.
+        sched._slots = [newcomer, stays, state(2), None]
+        fetched = (np.array([-1, 9, -1, -1], np.int32),
+                   np.zeros(4, np.int32))
+        sched._commit_tick(flight, fetched, {}, 2.0)
+        assert stays.emitted == [2, 9]
+        assert stays.rec.token_times == [2.0]
+        assert gone.emitted == newcomer.emitted == [2]
+        stats = sched.stats()
+        assert stats["ticks"] == 1
+        assert stats["kv_live_tokens"] == 5 + 1
+        assert stats["token_latency"]["count"] == 1
+        # Dispatch to commit for the first tick after a drain ...
+        assert stats["token_latency"]["sum"] == pytest.approx(1.0)
+        # ... and commit to commit while the pipeline is full.
+        sched._commit_tick(
+            _Flight(None, {}, 1.5, [newcomer, stays, None, None]),
+            (np.array([4, 8, -1, -1], np.int32), np.zeros(4, np.int32)),
+            {}, 2.5)
+        stats = sched.stats()
+        assert stats["token_latency"]["sum"] == pytest.approx(1.0 + 2 * 0.5)
+        assert stats["decode_gap"]["count"] == 2
+        assert newcomer.emitted == [2, 4] and stays.emitted == [2, 9, 8]
+
+
+def _spec_pair(model, params):
+    import jax.numpy as jnp
+
+    from cloud_tpu.models import TransformerLM
+    from cloud_tpu.serving.smoke import split_draft
+    draft_model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                                d_model=32, d_ff=64, max_seq_len=32,
+                                compute_dtype=jnp.float32)
+    target, draft = split_draft(params, draft_layers=1)
+    return target, dict(draft_model=draft_model, draft_params=draft,
+                        spec_k=2)
+
+
+@pytest.mark.parametrize("engine", ["plain", "speculative"])
+def test_collisions_with_a_tick_in_flight_stay_bit_identical(
+        model, params, engine):
+    """Two slots, a dozen short requests, some with an early eos:
+    finishes, evictions and inserts into the slot just freed all meet
+    a tick in flight, and every result is its solo `generate()`."""
+    from cloud_tpu.serving import Scheduler
+    target, extra = params, {}
+    if engine == "speculative":
+        target, extra = _spec_pair(model, params)
+    rng = np.random.default_rng(31)
+    requests = []
+    for i in range(12):
+        plen = int(rng.integers(2, 7))
+        req = _greedy(rng.integers(1, 64, (plen,)).tolist(),
+                      int(rng.integers(2, 5)), seed=200 + i)
+        if i % 3 == 0:
+            # eos = its own 2nd continuation token: it leaves early.
+            free_run = _oracle(model, target, dataclasses.replace(
+                req, max_new_tokens=4))
+            req = dataclasses.replace(req, max_new_tokens=4,
+                                      eos_token=int(free_run[plen + 1]))
+        requests.append(req)
+    refs = [_oracle(model, target, r) for r in requests]
+    with Scheduler(model, target, slots=2, page_size=16,
+                   **extra) as sched:
+        futures = [sched.submit(r, timeout=30) for r in requests]
+        results = [f.result(timeout=300) for f in futures]
+        sched.assert_drained(clear_prefix=True)
+        stats = sched.stats()
+        assert sched.pool.leak_report() == {}
+    for i, (ref, res) in enumerate(zip(refs, results)):
+        np.testing.assert_array_equal(
+            res.tokens, ref, err_msg="request {}".format(i))
+    assert stats["requests_completed"] == 12
+    assert stats["ticks_overlapped"] > 0
+
+
+@pytest.mark.parametrize("engine", ["plain", "speculative"])
+def test_finished_slot_retires_itself(model, params, engine):
+    """Engine level: after a tick reports a slot finished, a second
+    tick WITHOUT `evict` returns -1 and `finished` 0 for it, leaves its
+    `slot_steps` and `slot_valid` rows as they were and every page it
+    does not own byte for byte."""
+    import jax
+
+    from cloud_tpu.serving.engine import DecodeEngine, _map_attention
+    target, extra = params, {}
+    if engine == "speculative":
+        target, extra = _spec_pair(model, params)
+    eng = DecodeEngine(model, target, slots=2, page_size=4, num_pages=17,
+                       **extra)
+    k = eng.spec_k if eng.spec_on else 0
+    sampling = dict(temperature=0.0, top_k=None, top_p=None,
+                    eos_token=None)
+    # Slot 0 leaves after its first tick (max_new 2); slot 1 stays.
+    plans = [([5, 9, 3], 2, [1, 2]), ([7, 2, 8, 4, 6], 12, [3, 4, 5, 6, 7])]
+    for slot, (prompt, max_new, pages) in enumerate(plans):
+        result = eng.prefill(np.asarray(prompt, np.int32), max_new,
+                             jax.random.PRNGKey(slot), sampling)
+        vec = eng.pool_page_vec(pages)
+        eng.insert(slot, result, vec, vec, sampling)
+
+    def rows(cache):
+        found = []
+        _map_attention(cache, lambda att: found.append(att) or att)
+        return found
+
+    first = np.asarray(eng.tick())
+    finished_row = first[k + 2] if eng.spec_on else first[1]
+    assert list(finished_row) == [1, 0]
+    assert first[0][0] >= 0
+    assert list(np.asarray(eng.ctl["active"])) == [False, True]
+    caches = [eng.cache] + ([eng.draft_cache] if eng.spec_on else [])
+    before = [[{name: np.asarray(att[name]) for name in
+                ("slot_steps", "slot_valid", "key_pages", "value_pages")}
+               for att in rows(cache)] for cache in caches]
+
+    second = np.asarray(eng.tick())
+    if eng.spec_on:
+        assert list(second[:k + 1, 0]) == [-1] * (k + 1)
+        assert second[k + 1, 0] == 0 and second[k + 2, 0] == 0
+        assert second[k + 1, 1] >= 1
+    else:
+        assert second[0, 0] == -1 and second[1, 0] == 0
+        assert second[0, 1] >= 0
+    caches = [eng.cache] + ([eng.draft_cache] if eng.spec_on else [])
+    owned = plans[0][2]
+    others = [p for p in range(17) if p not in owned + plans[1][2]]
+    for was, cache in zip(before, caches):
+        for old, att in zip(was, rows(cache)):
+            assert np.asarray(att["slot_steps"])[0] == old["slot_steps"][0]
+            np.testing.assert_array_equal(
+                np.asarray(att["slot_valid"])[0], old["slot_valid"][0])
+            # The running slot did move.
+            assert np.asarray(att["slot_steps"])[1] > old["slot_steps"][1]
+            for name in ("key_pages", "value_pages"):
+                now = np.asarray(att[name])
+                # Pages of nobody (scratch, page 0, aside) and every
+                # valid position of the finished slot's own pages.
+                np.testing.assert_array_equal(now[others[1:]],
+                                              old[name][others[1:]])
+                flat_now = now[owned].reshape(-1, now.shape[-1])
+                flat_old = old[name][owned].reshape(-1, now.shape[-1])
+                valid = old["slot_valid"][0][:flat_now.shape[0]]
+                np.testing.assert_array_equal(flat_now[valid],
+                                              flat_old[valid])

@@ -331,6 +331,49 @@ def test_profile_capture_holds_the_spans_and_a_rid(tmp_path):
     assert rids["prefill_dispatch"] == wanted
 
 
+def test_tick_spans_nest_as_the_table_says():
+    """The tick thread's spans under the one-deep pipeline: a
+    `serve_tick` holds the dispatch of a tick and, where one was in
+    flight, the fetch of the tick BEFORE it; a drained tick's fetch
+    stands alone; `tick_commit` follows outside. Each tick is
+    dispatched, fetched and committed once."""
+    from cloud_tpu.models import TransformerLM
+    from cloud_tpu.serving import Scheduler, ServeRequest
+
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=32, d_ff=64, max_seq_len=32,
+                          compute_dtype=F32)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    tracer = spans.install()
+    with Scheduler(model, params, slots=2, page_size=8) as sched:
+        for future in [sched.submit(ServeRequest(
+                prompt=[3, 5, 7, i], max_new_tokens=8, temperature=0.0))
+                for i in (1, 2)]:
+            future.result(timeout=300)
+        sched.assert_drained()
+        stats = sched.stats()
+    by_name = {}
+    for name, _, start, dur in tracer.events():
+        by_name.setdefault(name, []).append((start, start + dur))
+
+    def inside(inner, outers):
+        return any(lo <= inner[0] and inner[1] <= hi for lo, hi in outers)
+
+    ticks = by_name["serve_tick"]
+    assert len(ticks) == stats["ticks"] > 0
+    for name in ("tick_dispatch", "tick_fetch", "tick_commit"):
+        assert len(by_name[name]) == stats["ticks"], name
+    assert all(inside(e, ticks) for e in by_name["tick_dispatch"])
+    assert not any(inside(e, ticks) for e in by_name["tick_commit"])
+    overlapped = sum(inside(e, ticks) for e in by_name["tick_fetch"])
+    assert overlapped == stats["ticks_overlapped"] > 0
+    # The d2h census stays exhaustive: every tick's fetch is a counted
+    # read-back.
+    for fetch in by_name["tick_fetch"]:
+        assert any(inside(e, [fetch]) for e in by_name["d2h_fetch"])
+
+
 # ------------------------------------------- an expert model's scopes
 
 @pytest.fixture(scope="module")
