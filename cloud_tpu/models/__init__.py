@@ -19,3 +19,5 @@ from cloud_tpu.models.transformer import (TransformerEncoder,
                                           TransformerLM, generate,
                                           tensor_parallel_rules)
 from cloud_tpu.models.vit import ViT, ViT_B16, ViT_L16, ViT_S16
+from cloud_tpu.models.mamba2 import Mamba2Mixer
+from cloud_tpu.models.nemotron_h import NemotronHLM

@@ -203,6 +203,27 @@ class MLAttention(nn.Module):
         return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
+class PlainMLP(nn.Module):
+    """`down(act(up(x)))`, bias-free, with an activation of
+    `moe.PLAIN_ACTIVATIONS`: the expert that is not gated."""
+
+    d_ff: int
+    compute_dtype: jnp.dtype = jnp.bfloat16
+    activation: str = "relu2"
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from cloud_tpu.models import moe
+
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.compute_dtype,
+            param_dtype=self.param_dtype, name=name)
+        hidden = moe.PLAIN_ACTIVATIONS[self.activation](
+            dense(self.d_ff, "up")(x))
+        return dense(x.shape[-1], "down")(hidden)
+
+
 class DeepseekMoE(nn.Module):
     """DeepSeek-V3 MoE: sigmoid group-limited routing + shared expert.
 
@@ -255,6 +276,15 @@ class DeepseekMoE(nn.Module):
     # the parts give the uncut layer (tests/unit/test_moe.py).
     held_experts: Optional[Tuple[int, ...]] = None
     param_dtype: jnp.dtype = jnp.float32  # experts + shared expert
+    # LatentMoE (Nemotron-H): the routed experts work in a space
+    # `latent_size` wide — `latent_down` before them, `latent_up` after
+    # their weighted sum — while the router and the shared expert read
+    # the layer's input at full width. None = experts at full width.
+    latent_size: Optional[int] = None
+    # Width of the shared expert (None = d_ff * n_shared_experts).
+    shared_d_ff: Optional[int] = None
+    # An `activation` of `moe.PLAIN_ACTIVATIONS` ("relu2") makes the
+    # experts, shared one included, plain two-product MLPs.
 
     @nn.compact
     def __call__(self, x, deterministic=True, token_mask=None):
@@ -270,7 +300,10 @@ class DeepseekMoE(nn.Module):
                 "num_experts={} must divide into n_group={} groups."
                 .format(self.num_experts, self.n_group))
         group_size = self.num_experts // self.n_group
-        act = _GATE_ACTIVATIONS[self.activation]
+        gated = self.activation not in moe.PLAIN_ACTIVATIONS
+        act = (_GATE_ACTIVATIONS if gated
+               else moe.PLAIN_ACTIVATIONS)[self.activation]
+        shared_d_ff = self.shared_d_ff or self.d_ff * self.n_shared_experts
 
         router_kernel = self.param(
             "router", nn.initializers.lecun_normal(),
@@ -300,18 +333,33 @@ class DeepseekMoE(nn.Module):
         else:
             capacity = max(1, int(self.capacity_factor * tokens
                                   * self.top_k / self.num_experts))
+        latent = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.compute_dtype,
+            param_dtype=self.param_dtype, name=name)
+        routed_in = x2d
+        if self.latent_size:
+            with jax.named_scope(moe.MOE_LATENT_DOWN):
+                routed_in = latent(self.latent_size, "latent_down")(x2d)
         with jax.named_scope(moe.MOE_ROUTED_EXPERTS):
             routed = moe.routed_expert_ffn(
-                self, x2d, top_idx, gates, self.num_experts, self.d_ff,
-                capacity, act, self.compute_dtype,
+                self, routed_in, top_idx, gates, self.num_experts,
+                self.d_ff, capacity, act, self.compute_dtype,
                 held_experts=self.held_experts, token_mask=token_mask,
-                param_dtype=self.param_dtype)
+                param_dtype=self.param_dtype, gated=gated)
+        if self.latent_size:
+            with jax.named_scope(moe.MOE_LATENT_UP):
+                routed = latent(d_model, "latent_up")(routed)
         with jax.named_scope(moe.MOE_SHARED_EXPERT):
-            shared = SwiGLU(self.d_ff * self.n_shared_experts,
-                            self.compute_dtype,
-                            activation=self.activation,
-                            param_dtype=self.param_dtype,
-                            name="shared")(x)
+            if gated:
+                shared = SwiGLU(shared_d_ff, self.compute_dtype,
+                                activation=self.activation,
+                                param_dtype=self.param_dtype,
+                                name="shared")(x)
+            else:
+                shared = PlainMLP(shared_d_ff, self.compute_dtype,
+                                  activation=self.activation,
+                                  param_dtype=self.param_dtype,
+                                  name="shared")(x)
         out = (routed.reshape(batch, seq, d_model) + shared).astype(
             x.dtype)
         return out, aux_loss

@@ -196,6 +196,16 @@ class TopKMoEMLP(nn.Module):
 MOE_ROUTER = "moe_router"
 MOE_ROUTED_EXPERTS = "moe_routed_experts"
 MOE_SHARED_EXPERT = "moe_shared_expert"
+#: A latent expert layer's two projections, round the routed experts.
+MOE_LATENT_DOWN = "moe_latent_down"
+MOE_LATENT_UP = "moe_latent_up"
+
+#: Activations of an expert that is NOT gated: `down(act(up(x)))`, two
+#: products (`relu2` = relu squared, the Nemotron-H family's). Every
+#: other activation name is a gate's: `down(act(gate(x)) * up(x))`.
+PLAIN_ACTIVATIONS = {
+    "relu2": lambda x: jnp.square(nn.relu(x)),
+}
 
 #: Collection an expert layer sows its counters of one call into, for
 #: a caller that makes it mutable (the serve tick): `pairs_routed`
@@ -207,9 +217,13 @@ MOE_STATS = "moe_stats"
 
 def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
                       capacity, act, compute_dtype, held_experts=None,
-                      token_mask=None, param_dtype=jnp.float32):
-    """Grouped (sort/segment) top-k SwiGLU expert computation, shared
-    by `TopKMoEMLP` (Mixtral) and `models.deepseek.DeepseekMoE`.
+                      token_mask=None, param_dtype=jnp.float32,
+                      gated=True):
+    """Grouped (sort/segment) top-k expert computation, shared by
+    `TopKMoEMLP` (Mixtral) and `models.deepseek.DeepseekMoE`: gated
+    experts `down(act(gate(x)) * up(x))`, three grouped products, or
+    with `gated=False` plain ones `down(act(up(x)))`, two (no
+    `expert_gate` parameter then).
 
     x2d: [T, d] tokens; top_idx/gates: [T, k] selected experts and
     combine weights over all `num_experts` (any routing recipe).
@@ -245,7 +259,8 @@ def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
             "{}.".format(num_experts, held))
     init = nn.initializers.lecun_normal(batch_axis=(0,))
     w_gate = module.param("expert_gate", init,
-                          (n_held, d_model, d_ff), param_dtype)
+                          (n_held, d_model, d_ff),
+                          param_dtype) if gated else None
     w_up = module.param("expert_up", init,
                         (n_held, d_model, d_ff), param_dtype)
     w_down = module.param("expert_down", init,
@@ -284,7 +299,10 @@ def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
     xs = x2d.astype(compute_dtype)[token_of]              # [kT, d]
     grouped = lambda a, w: jax.lax.ragged_dot(
         a, w.astype(compute_dtype), sizes)
-    hidden = act(grouped(xs, w_gate)) * grouped(xs, w_up)
+    if gated:
+        hidden = act(grouped(xs, w_gate)) * grouped(xs, w_up)
+    else:
+        hidden = act(grouped(xs, w_up))
     out = grouped(hidden.astype(compute_dtype), w_down)   # [kT, d]
     # Rows past the held pairs belong to no group: whatever the
     # product left there is not read.

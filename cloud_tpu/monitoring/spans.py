@@ -98,10 +98,13 @@ Kernels:
                             layer's (every live page of a slot)
     paged_decode_window     the same walk over a window layer's band
                             (from the slot's first live page)
+    ssm_decode_update       ops/ssm.py, a Mamba-2 layer's decode step:
+                            every slot's recurrent state read once and
+                            written once in place, one call a layer
 
-An expert layer's three parts run under `jax.named_scope`s of these
-names (constants in models/moe.py), inside whatever program holds the
-layer (`jit_serve_tick`, `jit_serve_prefill`, `jit_train_step`): every
+An expert layer's parts (constants in models/moe.py) and a Mamba-2
+layer's (models/mamba2.py) run under `jax.named_scope`s of these
+names, inside whatever program holds the layer (`jit_serve_tick`, `jit_serve_prefill`, `jit_train_step`): every
 op a part lowers to carries the name in its `op_name`, and the grouped
 products of the routed experts are XLA:TPU's own kernel, whose
 instructions the trace names `ragged-dot*`.
@@ -113,11 +116,22 @@ Scopes:
     moe_routed_experts    sort of the chosen pairs held here, the
                           three grouped products, the weighted sum
                           back to tokens
-    moe_shared_expert     the always-on expert's SwiGLU
+    moe_shared_expert     the always-on expert's MLP
+    moe_latent_down       a latent expert layer: the projection to the
+                          latent width, before the routed experts
+    moe_latent_up         and back from it, after their weighted sum
+    ssm_in_proj           a Mamba-2 layer: the projection to z, xBC, dt
+    ssm_conv              the causal depthwise convolution and its
+                          window's update
+    ssm_scan              the recurrence: the chunked scan of a
+                          sequence, or the tick's `ssm_decode_update`
+    ssm_gate_norm         the gate by silu(z) and the grouped RMSNorm
+    ssm_out_proj          the projection back to the model's width
 
 An expert model's tick also returns counters, which `Scheduler.stats()`
 sums over ticks and expert layers (fetched with the tick's tokens, in
-the same read-back).
+the same read-back); a model with recurrent (Mamba-2) layers adds a
+counter of its own to the same read-back, and a gauge.
 
 Counters:
 
@@ -126,6 +140,11 @@ Counters:
     moe_experts_touched   held experts with at least one pair, summed
                           a layer and tick
     moe_expert_load       pairs a held expert (a vector)
+    ssm_state_bytes       bytes of recurrent state resident beside the
+                          page pool, all slots (a gauge, not a sum)
+    ssm_slot_steps        slots advanced x recurrent layers, summed
+                          over ticks (each is one state read and
+                          written)
 
 The hot loops' jitted functions are named so (a constant beside the
 jit), and the trace's program line reads `jit_<name>`.

@@ -115,6 +115,30 @@ def _map_attention(cache, fn, *rest):
     return cache
 
 
+def _map_slot_state(cache, fn, *rest):
+    """Applies `fn` to every leaf OUTSIDE the paged-attention subtrees,
+    walking `rest` trees in parallel. Such a leaf is a slot's own
+    state, slot-major (`[slots, ...]` in the pool cache, `[1, ...]` in
+    a dense prefill cache): `TransformerLM`'s `pos_count`, a Mamba-2
+    layer's `conv_state` and `ssm_state` (models/mamba2.py). No page
+    holds it, so insertion copies the prefill's row into the slot's,
+    eviction zeroes the row and a resize moves it."""
+    if isinstance(cache, dict):
+        if "key_pages" in cache:
+            return cache
+        return {k: _map_slot_state(cache[k], fn,
+                                   *[r[k] if isinstance(r, dict) else r
+                                     for r in rest])
+                for k in cache}
+    return fn(cache, *rest)
+
+
+def _rows_where(keep, leaf):
+    """`leaf` on the rows where `keep` [slots] holds, zero on the others."""
+    return jnp.where(keep.reshape((-1,) + (1,) * (leaf.ndim - 1)), leaf,
+                     jnp.zeros((), leaf.dtype))
+
+
 _GATHER_READS = ("key_pages", "value_pages", "key_scales",
                  "value_scales")
 
@@ -123,18 +147,16 @@ def _pool_pages_view(cache):
     """Geometry-free view of a pool cache for the prefix gather. The
     gather reads only the page arrays (pool-indexed, fixed shape), but
     per-slot state (page_table [slots, ppn], slot_steps [slots],
-    slot_valid [slots, L], pos_count [slots]) rides along in the
-    pytree and would bind the executable's signature to one slot
-    count — a prefix hit after an elastic resize would then retrace.
-    Whitelisting the page arrays here, outside the jit boundary (keys
-    kept, unread leaves None'd), keeps one executable across every
-    geometry rung."""
+    slot_valid [slots, L], and the leaves of `_map_slot_state`) rides
+    along in the pytree and would bind the executable's signature to
+    one slot count — a prefix hit after an elastic resize would then
+    retrace. Whitelisting the page arrays here, outside the jit
+    boundary (keys kept, unread leaves None'd), keeps one executable
+    across every geometry rung."""
     view = _map_attention(
         cache, lambda att: {k: (v if k in _GATHER_READS else None)
                             for k, v in att.items()})
-    if "pos_count" in view:     # TransformerLM's learned positions
-        view["pos_count"] = None
-    return view
+    return _map_slot_state(view, lambda leaf: None)
 
 
 def attention_shape(model):
@@ -147,17 +169,20 @@ def attention_shape(model):
 
 
 def _served_model(model, role):
-    """The two classes whose attention writes and reads the page pool
-    (`decoding.paged_kv_attention`): the class of the model decides
-    every difference, there is no switch."""
+    """The three classes whose attention writes and reads the page pool
+    (`decoding.paged_kv_attention`): `TransformerLM`, `LlamaLM` and
+    `NemotronHLM`, whose Mamba-2 layers keep a per-slot state beside
+    the pool (`state_layers`). The class of the model decides every
+    difference, there is no switch."""
     from cloud_tpu.models.llama import LlamaLM
+    from cloud_tpu.models.nemotron_h import NemotronHLM
     from cloud_tpu.models.transformer import TransformerLM
     from cloud_tpu.parallel import SEQUENCE_PARALLEL_IMPLS
 
-    if not isinstance(model, (TransformerLM, LlamaLM)):
+    if not isinstance(model, (TransformerLM, LlamaLM, NemotronHLM)):
         raise NotImplementedError(
-            "graftserve serves TransformerLM and LlamaLM (their "
-            "attention reads the page pool); got {} as the {}."
+            "graftserve serves TransformerLM, LlamaLM and NemotronHLM "
+            "(their attention reads the page pool); got {} as the {}."
             .format(type(model).__name__, role))
     if isinstance(model, LlamaLM) and (
             model.attn_logit_softcap
@@ -513,6 +538,18 @@ class DecodeEngine:
         self._params = params
         self.spec_k = int(spec_k)
         self.spec_on = draft_model is not None and self.spec_k > 0
+        #: Layers that keep a recurrent state a slot (the model's class
+        #: says so; 0 for the classes that have none). Pages cannot
+        #: give such a state back, so a model with any gets no prefix
+        #: reuse, no host tier and no speculation until state
+        #: snapshots exist (ROADMAP 2.5).
+        self.state_layers = getattr(model, "state_layers", 0)
+        if self.state_layers and self.spec_on:
+            raise NotImplementedError(
+                "spec_k > 0 is not served for a model with recurrent "
+                "layers ({} has {}): a rejected draft token would have "
+                "to roll the state back, and no snapshot of it exists."
+                .format(type(model).__name__, self.state_layers))
         # "" = pages in compute_dtype; "int8" = graftpack quantized
         # pages (per-page per-head f32 scale sidecars in the same
         # cache subtrees — models/transformer.py).
@@ -595,6 +632,7 @@ class DecodeEngine:
             jit, donate_argnums=(0,))(self._gather_impl))
 
         def gather(dense_cache, pool_cache, page_vec, prefix_len):
+            self._refuse_recurrent("a prefix hit")
             # The view strips slot-count-bound leaves so the gather
             # signature is identical at every geometry rung.
             return gather_exec(dense_cache, _pool_pages_view(pool_cache),
@@ -607,11 +645,19 @@ class DecodeEngine:
         self._promote = best_effort_donation(functools.partial(
             jit, donate_argnums=(0,))(self._promote_impl))
         self._warm_stats = None
-        #: The last tick's expert-layer counters, still on the device
-        #: (`_moe_counters`; {} for a model with no expert layer and
-        #: under speculation): the scheduler fetches them with the
-        #: tick's tokens, in the one read-back a tick makes.
+        #: The last tick's counters, still on the device: an expert
+        #: model's (`_moe_counters`) and a recurrent model's
+        #: `ssm_slot_steps`; {} for a model with neither and under
+        #: speculation. The scheduler fetches them with the tick's
+        #: tokens, in the one read-back a tick makes.
         self.tick_counters = {}
+
+    def _refuse_recurrent(self, what):
+        if self.state_layers:
+            raise NotImplementedError(
+                "{} is not served for a model with recurrent layers: "
+                "pages hold keys and values, and the state the layers "
+                "had after the prefix is kept nowhere.".format(what))
 
     # -- prefill ------------------------------------------------------
 
@@ -1020,11 +1066,11 @@ class DecodeEngine:
                 patt["slot_valid"][0])
             return out
 
-        new_cache = _map_attention(cache, scatter, pcache)
-        if "pos_count" in cache:
-            new_cache["pos_count"] = cache["pos_count"].at[slot].set(
-                pcache["pos_count"][0])
-        return new_cache
+        # The slot's own state: the whole row, so a slot that is used
+        # again never sees what the last request left.
+        return _map_slot_state(
+            _map_attention(cache, scatter, pcache),
+            lambda leaf, row: leaf.at[slot].set(row[0]), pcache)
 
     def _arm_ctl(self, ctl, slot, step_keys_row, max_steps, first_tok,
                  temperature, top_k, top_p, eos, has_eos):
@@ -1103,8 +1149,11 @@ class DecodeEngine:
         out_ctl["steps_done"] = steps
         out = jnp.stack([jnp.where(active, nxt, -1),
                          finished.astype(jnp.int32)])
-        return (_plain(vars_["cache"]), out_ctl, out,
-                _moe_counters(_plain(vars_.get(MOE_STATS, {}))))
+        counters = _moe_counters(_plain(vars_.get(MOE_STATS, {})))
+        if self.state_layers:
+            counters["ssm_slot_steps"] = self.state_layers * jnp.sum(
+                active.astype(jnp.int32))
+        return _plain(vars_["cache"]), out_ctl, out, counters
 
     def _spec_tick_impl(self, params, draft_params, cache, dcache, ctl):
         """Draft/verify speculation, one executable per tick:
@@ -1282,6 +1331,7 @@ class DecodeEngine:
         holding `[n, P, H*D]` K/V blocks (+ `[n, H]` scales in int8
         mode) with n == len(page_ids), rows in logical page order.
         Tick thread only — reads the tick-donated cache."""
+        self._refuse_recurrent("the host tier")
         n = len(page_ids)
         vec = jnp.asarray(self.pool_page_vec(page_ids), jnp.int32)
         tree = jax.device_get(self._snapshot(self.cache, vec))
@@ -1293,6 +1343,7 @@ class DecodeEngine:
         `page_ids[i]`, except the first `n_skip` logical pages (already
         resident via the prefix trie) and any `page_ids` entry of 0,
         which collapse onto scratch. Tick thread only."""
+        self._refuse_recurrent("the host tier")
         vec = self.pool_page_vec(page_ids)
         vec[:n_skip] = 0
         n = len(page_ids)
@@ -1329,12 +1380,27 @@ class DecodeEngine:
             per_layer = 2 * self.page_size * kv_heads * head_dim * item
             if self.page_dtype == "int8":
                 per_layer += 2 * kv_heads * 4
-            return per_layer * m.num_layers
+            # Layers that hold pages: all of them, but for a class
+            # that says otherwise.
+            return per_layer * getattr(m, "attention_layers",
+                                       m.num_layers)
 
         total = per_model(self.model)
         if self.spec_on:
             total += per_model(self._paged_draft)
         return int(total)
+
+    def state_hbm_bytes(self):
+        """HBM bytes of the recurrent state resident beside the pool:
+        every slot's row of every `_map_slot_state` leaf of a model
+        with recurrent layers (0 for the others; it is allocated whole
+        whether or not a slot is occupied)."""
+        if not self.state_layers:
+            return 0
+        sizes = []
+        _map_slot_state(self.cache, lambda leaf: sizes.append(
+            leaf.size * leaf.dtype.itemsize))
+        return int(sum(sizes))
 
     def _clear_slots(self, cache, keep):
         def clear(att):
@@ -1345,11 +1411,8 @@ class DecodeEngine:
             out["slot_valid"] = att["slot_valid"] & keep[:, None]
             return out
 
-        new_cache = _map_attention(cache, clear)
-        if "pos_count" in cache:
-            new_cache["pos_count"] = jnp.where(keep, cache["pos_count"],
-                                               0)
-        return new_cache
+        return _map_slot_state(_map_attention(cache, clear),
+                               lambda leaf: _rows_where(keep, leaf))
 
     def _evict_impl(self, cache, ctl, evict_mask):
         keep = ~evict_mask
@@ -1385,11 +1448,9 @@ class DecodeEngine:
             out["slot_valid"] = att["slot_valid"][src] & mask[:, None]
             return out
 
-        new_cache = _map_attention(cache, rs)
-        if "pos_count" in cache:
-            new_cache["pos_count"] = jnp.where(
-                mask, cache["pos_count"][src], 0)
-        return new_cache
+        return _map_slot_state(
+            _map_attention(cache, rs),
+            lambda leaf: _rows_where(mask, leaf[src]))
 
     def _resize_ctl(self, ctl, perm):
         """Control rows under the same perm. The masked leaves mirror

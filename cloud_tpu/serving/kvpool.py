@@ -44,7 +44,7 @@ class PagePool:
     """
 
     def __init__(self, num_pages, page_size, pages_per_slot,
-                 page_dtype="", page_bytes=0):
+                 page_dtype="", page_bytes=0, state_bytes=0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "scratch page); got {}.".format(num_pages))
@@ -62,6 +62,11 @@ class PagePool:
         # wire it (pool used standalone in tests).
         self.page_dtype = str(page_dtype)
         self.page_bytes = int(page_bytes)
+        # What a model with recurrent layers keeps beside the pages: a
+        # fixed-size state a slot and layer, resident for every slot
+        # whether occupied or not (engine.state_hbm_bytes). No page
+        # accounts for it; the byte totals below add it.
+        self.state_bytes = int(state_bytes)
         self._cond = threading.Condition()
         # LIFO free list: recently-freed pages are re-handed first
         # (warm in whatever cache hierarchy the backend keeps).
@@ -240,6 +245,9 @@ class PagePool:
                 "page_dtype": self.page_dtype,
                 "kv_bytes_held": len(self._refs) * self.page_bytes,
                 "kv_bytes_total": self.capacity * self.page_bytes,
+                "state_bytes": self.state_bytes,
+                "cache_bytes_total": (self.capacity * self.page_bytes
+                                      + self.state_bytes),
             }
 
     def leak_report(self):

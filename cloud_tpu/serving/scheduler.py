@@ -389,6 +389,19 @@ class Scheduler:
             env = os.environ.get("CLOUD_TPU_SERVE_HOST_TIER",
                                  "").strip().lower()
             host_tier = env not in _OFF_VALUES
+        if getattr(model, "state_layers", 0):
+            # A model with recurrent layers (its class says so): a
+            # page holds keys and values, not the state those layers
+            # had after a prefix, so nothing can be reused from pages
+            # until state snapshots exist. No trie: no request is
+            # probed, registered or counted as a hit.
+            prefix_cache = False
+            if host_tier:
+                raise NotImplementedError(
+                    "host_tier is not served for a model with "
+                    "recurrent layers ({}): a demoted page cannot "
+                    "give their state back.".format(
+                        type(model).__name__))
         if host_tier:
             if draft_model is not None and spec_k > 0:
                 raise ValueError(
@@ -409,7 +422,8 @@ class Scheduler:
         self.pool = PagePool(num_pages, page_size,
                              self.engine.pages_per_slot,
                              page_dtype=kv_dtype,
-                             page_bytes=self.engine.page_hbm_bytes())
+                             page_bytes=self.engine.page_hbm_bytes(),
+                             state_bytes=self.engine.state_hbm_bytes())
         self.host_tier = None
         if host_tier:
             if host_tier_pages is None:
@@ -485,6 +499,9 @@ class Scheduler:
         self._moe_pairs_held = 0
         self._moe_experts_touched = 0
         self._moe_expert_load = None
+        # A recurrent model's counter, summed over ticks: slots
+        # advanced x state layers (each is one state read and written).
+        self._ssm_slot_steps = 0
         from cloud_tpu.monitoring.telemetry import Histogram
         self._ttft_hist = Histogram("ttft")
         self._ttft_hit_hist = Histogram("ttft_hit")
@@ -1763,7 +1780,7 @@ class Scheduler:
         with spans.span("tick_commit"):
             self._distribute(live, fetched, t_commit - t_from, t_commit)
             if counters:
-                self._count_moe(counters)
+                self._count_tick(counters)
         if self.strict_no_retrace:
             self.engine.check_no_retrace()
 
@@ -2253,7 +2270,10 @@ class Scheduler:
                                ticks=self._ticks,
                                slots=self.engine.slots)
 
-    def _count_moe(self, counters):
+    def _count_tick(self, counters):
+        self._ssm_slot_steps += int(counters.get("ssm_slot_steps", 0))
+        if "pairs_routed" not in counters:
+            return
         self._moe_pairs_routed += int(counters["pairs_routed"])
         self._moe_pairs_held += int(counters["pairs_held"])
         self._moe_experts_touched += int(counters["experts_touched"])
@@ -2611,6 +2631,7 @@ class Scheduler:
         self._moe_pairs_held = 0
         self._moe_experts_touched = 0
         self._moe_expert_load = None
+        self._ssm_slot_steps = 0
         self._t_last_commit = None
         self._completed = 0
         self._tokens_out = 0
@@ -2726,6 +2747,8 @@ class Scheduler:
             "moe_experts_touched": self._moe_experts_touched,
             "moe_expert_load": ([] if self._moe_expert_load is None
                                 else self._moe_expert_load.tolist()),
+            "ssm_state_bytes": self.pool.state_bytes,
+            "ssm_slot_steps": self._ssm_slot_steps,
             "elapsed_seconds": wall,
             "requests_per_sec": self._completed / wall,
             "tokens_per_sec": self._tokens_out / wall,

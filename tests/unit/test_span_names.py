@@ -20,9 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from cloud_tpu.models import mamba2 as mamba_lib
 from cloud_tpu.models import moe as moe_lib
 from cloud_tpu.monitoring import spans
 from cloud_tpu.ops import fused_mlp, fused_norm
+from cloud_tpu.ops import ssm as ssm_ops
 from cloud_tpu.serving import engine as engine_lib
 from cloud_tpu.training import trainer as trainer_lib
 
@@ -32,6 +34,11 @@ paged_ops = importlib.import_module("cloud_tpu.ops.paged_attention")
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 F32 = jnp.float32
+
+
+EXPERT_SCOPES = (moe_lib.MOE_ROUTER, moe_lib.MOE_ROUTED_EXPERTS,
+                 moe_lib.MOE_SHARED_EXPERT)
+LATENT_SCOPES = (moe_lib.MOE_LATENT_DOWN, moe_lib.MOE_LATENT_UP)
 
 
 @pytest.fixture(autouse=True)
@@ -79,10 +86,11 @@ def test_kernel_and_program_tables_equal_the_constants():
         attention_ops.FLASH_FWD, attention_ops.FLASH_BWD_DQ,
         attention_ops.FLASH_BWD_DKV, fused_mlp.FUSED_SWIGLU_FWD,
         fused_norm.FUSED_RMSNORM, fused_norm.FUSED_RMSNORM_RESIDUAL,
-        paged_ops.PAGED_DECODE, paged_ops.PAGED_DECODE_WINDOW)
-    assert spans.names("Scopes") == (
-        moe_lib.MOE_ROUTER, moe_lib.MOE_ROUTED_EXPERTS,
-        moe_lib.MOE_SHARED_EXPERT)
+        paged_ops.PAGED_DECODE, paged_ops.PAGED_DECODE_WINDOW,
+        ssm_ops.SSM_DECODE_UPDATE)
+    assert spans.names("Scopes") == EXPERT_SCOPES + LATENT_SCOPES + (
+        mamba_lib.SSM_IN_PROJ, mamba_lib.SSM_CONV, mamba_lib.SSM_SCAN,
+        mamba_lib.SSM_GATE_NORM, mamba_lib.SSM_OUT_PROJ)
     assert spans.names("Programs") == (
         trainer_lib.TRAIN_STEP, engine_lib.SERVE_TICK,
         engine_lib.SERVE_PREFILL, engine_lib.SLOT_INSERT,
@@ -155,6 +163,14 @@ def _kernel_cases():
                jnp.ones((2, 1, 32), bool), interpret=True, window=8),
            (jnp.ones((2, 1, 4, 128), F32), pages, pages),
            [paged_ops.PAGED_DECODE_WINDOW])
+    # Two heads of 64 side by side: the lane-dense layout the kernel takes.
+    yield ("ssm_decode_update",
+           lambda s, x, dt, a, b: ssm_ops.ssm_decode_update(
+               s, x, dt, a, a, b, b, impl="kernel", interpret=True),
+           (jnp.ones((2, 2, 128, 128), F32), jnp.ones((2, 4, 64), F32),
+            jnp.ones((2, 4), F32), -jnp.ones((4,), F32),
+            jnp.ones((2, 2, 128), F32)),
+           [ssm_ops.SSM_DECODE_UPDATE])
 
 
 @pytest.mark.parametrize("case", list(_kernel_cases()),
@@ -401,7 +417,7 @@ def test_expert_layer_lowers_under_its_declared_scopes(toy_expert_engine,
     lowered = fn.__wrapped__.lower(*args)
     assert _module_name(lowered) == "jit_" + program
     text = lowered.as_text(debug_info=True)
-    for scope in spans.names("Scopes"):
+    for scope in EXPERT_SCOPES:
         assert re.search(r"jit\({}\)/[^\"]*/moe/{}/".format(program, scope),
                          text), scope
 
@@ -420,6 +436,61 @@ def test_window_and_full_reads_are_told_apart_by_name(toy_expert_engine,
     assert paged == [paged_ops.PAGED_DECODE, paged_ops.PAGED_DECODE_WINDOW]
 
 
+@pytest.fixture(scope="module")
+def toy_hybrid_engine():
+    from cloud_tpu.models import NemotronHLM
+    model = NemotronHLM(vocab_size=64, d_model=32, pattern="ME*",
+                        max_seq_len=32, num_heads=2, num_kv_heads=1,
+                        mamba_heads=4, mamba_head_dim=8, ssm_groups=2,
+                        ssm_state=16, chunk_size=8, moe_experts=8,
+                        moe_top_k=2, moe_d_ff=16, moe_latent=12,
+                        moe_shared_d_ff=24, moe_held_experts=(0, 1),
+                        compute_dtype=F32)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    return engine_lib.DecodeEngine(model, params, slots=2, page_size=8,
+                                   num_pages=9), params
+
+
+@pytest.mark.parametrize("program", [engine_lib.SERVE_TICK,
+                                     engine_lib.SERVE_PREFILL])
+def test_hybrid_layers_lower_under_their_declared_scopes(toy_hybrid_engine,
+                                                         program):
+    """A Mamba-2 layer's five parts and a latent expert layer's two
+    projections (beside its other three parts) carry their scopes in
+    the tick and in the prefill."""
+    fn, args = _serve_programs(toy_hybrid_engine)[program]
+    text = fn.__wrapped__.lower(*args).as_text(debug_info=True)
+    under = {"mamba": spans.names("Scopes")[len(EXPERT_SCOPES
+                                                + LATENT_SCOPES):],
+             "moe": EXPERT_SCOPES + LATENT_SCOPES}
+    for layer, scopes in under.items():
+        for scope in scopes:
+            assert re.search(r"jit\({}\)/[^\"]*/{}/{}/".format(
+                program, layer, scope), text), scope
+
+
+def test_tick_of_a_hybrid_model_holds_the_state_update_kernel(
+        toy_hybrid_engine, monkeypatch):
+    """Traced as on the chip at widths the kernel takes: one
+    `ssm_decode_update` a Mamba-2 layer, its state aliased in place."""
+    from cloud_tpu.models import NemotronHLM
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = NemotronHLM(vocab_size=64, d_model=32, pattern="MM*",
+                        max_seq_len=32, num_heads=2, num_kv_heads=1,
+                        head_dim=128, mamba_heads=4, mamba_head_dim=64,
+                        ssm_groups=2, ssm_state=128, compute_dtype=F32)
+    params = jax.eval_shape(
+        model.init, jax.random.PRNGKey(1),
+        jnp.zeros((1, 4), jnp.int32))["params"]
+    engine = engine_lib.DecodeEngine(model, params, slots=2, page_size=8,
+                                     num_pages=9)
+    names = _pallas_names(engine._tick_impl, params, engine.cache,
+                          engine.ctl)
+    assert sorted(n for n in names if "paged" not in n
+                  and "rmsnorm" not in n) == [ssm_ops.SSM_DECODE_UPDATE] * 2
+
+
 def test_counter_table_names_the_stats_an_expert_model_adds():
     from cloud_tpu.models import TransformerLM
     from cloud_tpu.serving import Scheduler
@@ -431,5 +502,6 @@ def test_counter_table_names_the_stats_an_expert_model_adds():
     with Scheduler(model, params, slots=2, page_size=8) as sched:
         stats = sched.stats()
     table = spans.names("Counters")
-    assert set(table) == {k for k in stats if k.startswith("moe_")}
-    assert [stats[k] for k in table] == [0, 0, 0, []]
+    assert set(table) == {k for k in stats
+                          if k.startswith(("moe_", "ssm_"))}
+    assert [stats[k] for k in table] == [0, 0, 0, [], 0, 0]

@@ -263,6 +263,36 @@ def test_paged_kernel_compiles_at_the_cells_geometry(as_tpu, page_dtype,
     assert "_paged_decode_attention.paged_decode" in calls[0]
 
 
+def test_ssm_decode_update_compiles_at_the_cells_geometry(as_tpu):
+    """The hybrid cell's call — 128 slots, 128 heads x 64 (two side by
+    side on the lanes), 8 groups, a state of 128 — compiles for a v5e with
+    no chip as one Mosaic custom call under its declared name, the 537 MB
+    of state aliased to the output and never copied."""
+    from cloud_tpu.ops import ssm
+
+    devices = _tpu_topology()
+    if devices is None:
+        pytest.skip("no libtpu to describe a v5e")
+    slots, heads, hd, n, groups = 128, 128, 64, 128, 8
+    state = S((slots,) + ssm.packed_shape(heads, groups, hd, n), F32)
+    assert state.shape == (128, 64, 128, 128)
+    assert ssm.kernel_fits(state.shape, groups)
+    specs = [state, S((slots, heads, hd), BF16), S((slots, heads), F32),
+             S((heads,), F32), S((heads,), F32), S((slots, groups, n), BF16),
+             S((slots, groups, n), BF16)]
+    one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
+    compiled = jax.jit(ssm.ssm_decode_update, in_shardings=one,
+                       donate_argnums=0).trace(*specs).lower(
+                           lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1 and ssm.SSM_DECODE_UPDATE in calls[0], calls
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "[128,64,128,128]" in line]
+    assert compiled.memory_analysis().alias_size_in_bytes >= 4 * state.size
+
+
 # -- values under a mesh (interpreted, CPU devices) ---------------------
 
 
