@@ -1,18 +1,38 @@
-"""Mixture-of-Experts MLP with expert parallelism.
+"""Mixture-of-Experts MLPs with expert parallelism: three forms of
+one computation, each for the shapes it suits.
 
 Expert parallelism is another axis the reference never had (SURVEY §2.3
-lists EP among the explicitly-absent strategies). The design is the
-GShard/Switch dense-dispatch pattern, which is the XLA-friendly way to
-do MoE on TPU: routing is expressed as dense one-hot dispatch/combine
-einsums (static shapes, MXU-tiled), expert weights carry a leading
-[num_experts] dim sharded over the mesh's "ep" axis, and XLA inserts the
-all-to-alls when the dispatch einsum crosses the expert axis — no manual
-collectives, the compiler schedules them on ICI.
+lists EP among the explicitly-absent strategies). In every form the
+expert weights carry a leading [experts] dim that
+`expert_parallel_rules` shards over the mesh's "ep" axis, and shapes
+are static.
 
-Capacity-based top-1 (Switch) routing: each expert processes at most
-`capacity = capacity_factor * tokens / num_experts` tokens; overflow
-tokens are dropped (contribute zero, standard Switch behavior) and the
-load-balancing auxiliary loss pushes the router toward uniform load.
+1. **One-hot dispatch** (`MoEMLP`, Switch top-1 with GELU experts): the
+   GShard/Switch pattern. Routing is dense one-hot dispatch/combine
+   einsums over a `[T, E, C]` tensor, and XLA inserts the all-to-alls
+   when the dispatch einsum crosses the expert axis — no manual
+   collectives. Each expert takes at most
+   `capacity = capacity_factor * tokens / num_experts` tokens; overflow
+   tokens are dropped (contribute zero, standard Switch behavior) and
+   the load-balancing auxiliary loss pushes the router toward uniform
+   load. The dispatch tensor grows as T squared: training at modest T.
+2. **Grouped** (`routed_expert_ffn`: `TopKMoEMLP`,
+   `models.deepseek.DeepseekMoE`): the chosen (token, expert) pairs are
+   sorted by expert and each projection is one `jax.lax.ragged_dot`
+   over them, a group a held expert. No token-by-expert array at any
+   T, and only the chosen pairs are computed: every prefill, every
+   training step, any call under a capacity, and a decode tick that
+   leaves held experts untouched.
+3. **Batched over the held experts** (`routed_expert_ffn`, the same
+   parameters): every held expert's projections are applied to all T
+   rows as batched products and the gates, zero for a pair that was
+   not chosen, select afterwards. It computes `held / chosen` times
+   the pairs and reads each expert's matrices exactly once, in order:
+   the form for a decode tick whose few rows touch every held expert
+   anyway, where the layer's time is its weights' bytes.
+
+`routed_expert_ffn` picks between 2 and 3 from the shapes of the call
+(`batched_over_held`); nothing else selects a form.
 """
 
 from typing import Optional
@@ -211,19 +231,60 @@ PLAIN_ACTIVATIONS = {
 #: a caller that makes it mutable (the serve tick): `pairs_routed`
 #: (token, choice) pairs of real tokens, `pairs_held` those whose
 #: expert is held here, `experts_touched` held experts with at least
-#: one pair, `expert_load` pairs a held expert.
+#: one pair, `expert_load` pairs a held expert, `pairs_dense` the
+#: (real token, held expert) products the batched form computed (0 on
+#: the grouped one: over `pairs_held` it is the padding that form pays).
 MOE_STATS = "moe_stats"
+
+#: Rows up to which a `[T, d] x [d, f]` product whose weights stream
+#: from HBM is bound by their bytes, not by the MXU: it does
+#: `2 T d f` FLOPs over `d f` elements, so the two times meet at
+#: `T = peak FLOP/s x bytes an element / (2 x HBM bytes/s)` =
+#: 197e12 x 2 / (2 x 819e9) = 240 rows for bfloat16 on the v5e (peak:
+#: `monitoring.telemetry.PEAK_TFLOPS`; HBM: Google Cloud documentation,
+#: "TPU v5e"). Under it, multiplying every row by every held expert
+#: costs no time the weights' read does not already take.
+DENSE_MAX_ROWS = 240
+#: Least expected share of the held experts that a call touches for
+#: the batched form: it reads every held expert, and each untouched
+#: one is bytes the grouped form would not have read.
+DENSE_MIN_TOUCHED = 0.95
+
+
+def batched_over_held(tokens, top_k, num_experts, capacity):
+    """Whether `routed_expert_ffn` runs a call of these (static) shapes
+    batched over the held experts rather than grouped. All three hold:
+
+    (a) nothing is shed (`capacity` None or at least `tokens`): the
+        slot-major shedding order is the grouped form's;
+    (b) `tokens <= DENSE_MAX_ROWS`: the products are bytes-bound, so
+        the rows the gates zero afterwards are free;
+    (c) under an even router a held expert is touched with probability
+        `1 - (1 - top_k / num_experts) ** tokens`, and that is at
+        least `DENSE_MIN_TOUCHED`: (nearly) every held expert's
+        weights are read whatever the form.
+
+    128 slots choosing 22 of 512 (99.6 % touched) run batched; 32
+    slots choosing 8 of 128 (87 %) and every prefill or training step
+    (rows past the ridge) run grouped.
+    """
+    if capacity is not None and capacity < tokens:
+        return False
+    if tokens > DENSE_MAX_ROWS:
+        return False
+    touched = 1.0 - (1.0 - top_k / num_experts) ** tokens
+    return touched >= DENSE_MIN_TOUCHED
 
 
 def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
                       capacity, act, compute_dtype, held_experts=None,
                       token_mask=None, param_dtype=jnp.float32,
                       gated=True):
-    """Grouped (sort/segment) top-k expert computation, shared by
-    `TopKMoEMLP` (Mixtral) and `models.deepseek.DeepseekMoE`: gated
-    experts `down(act(gate(x)) * up(x))`, three grouped products, or
-    with `gated=False` plain ones `down(act(up(x)))`, two (no
-    `expert_gate` parameter then).
+    """Top-k expert computation, shared by `TopKMoEMLP` (Mixtral) and
+    `models.deepseek.DeepseekMoE`: gated experts
+    `down(act(gate(x)) * up(x))`, three products, or with
+    `gated=False` plain ones `down(act(up(x)))`, two (no `expert_gate`
+    parameter then).
 
     x2d: [T, d] tokens; top_idx/gates: [T, k] selected experts and
     combine weights over all `num_experts` (any routing recipe).
@@ -237,14 +298,30 @@ def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
     computes, before its exchange). `token_mask` [T] marks real
     tokens: a pad's pairs go nowhere.
 
-    The pairs are sorted by expert and each projection is ONE grouped
-    product over them (`jax.lax.ragged_dot`: a group a held expert),
-    so no `[T, E, .]` dispatch mask exists at any T and nothing is
-    dropped unless `capacity` says so. Capacity (None = drop-free) is
-    slot-major: the sort is stable over the slot-major list of pairs,
-    so within an expert all slot-0 (highest-gate) assignments stand
-    before any slot-1 assignment, and when capacity binds the
-    lowest-priority routes past it are shed first.
+    Two forms of the same sum, picked from the call's static shapes by
+    `batched_over_held(T, k, num_experts, capacity)` and by nothing
+    else. **Grouped**: the pairs are sorted by expert and each
+    projection is ONE grouped product over them
+    (`jax.lax.ragged_dot`: a group a held expert), so no `[T, E, .]`
+    dispatch mask exists at any T and nothing is dropped unless
+    `capacity` says so. Capacity (None = drop-free) is slot-major: the
+    sort is stable over the slot-major list of pairs, so within an
+    expert all slot-0 (highest-gate) assignments stand before any
+    slot-1 assignment, and when capacity binds the lowest-priority
+    routes past it are shed first. **Batched**: where T is at most
+    `DENSE_MAX_ROWS` (the products are bound by the weights' bytes),
+    nothing is shed and a call of T rows is expected to touch at
+    least `DENSE_MIN_TOUCHED` of the held experts, every held
+    expert's weights are read anyway and the grouped kernel's many
+    small groups only slow that read: each projection is one batched
+    product of all T rows with every held expert, and a `[T, held]`
+    float32 matrix of gates — zero for an expert the token did not
+    choose, for a pad and for an expert not held — weights the sum.
+    The chosen pairs see the same operands in the same dtypes on both
+    forms; only the order of a token's float32 sum differs.
+
+    Both forms sow the same counters (`MOE_STATS`) from the router's
+    choice, not from what was multiplied.
     Returns [T, d] in compute_dtype.
     """
     tokens, d_model = x2d.shape
@@ -277,11 +354,75 @@ def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
         local = jnp.asarray(rows)[top_idx]
     if token_mask is not None:
         local = jnp.where(token_mask.reshape(tokens, 1), local, n_held)
+    local = local.astype(jnp.int32)                       # [T, k]
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[
+        local.reshape(-1)].add(1)[:n_held]
+    batched = batched_over_held(tokens, k, num_experts, capacity)
+    if not module.is_initializing():   # counters are no variables
+        module.sow(MOE_STATS, "pairs_held", jnp.sum(sizes))
+        module.sow(MOE_STATS, "experts_touched",
+                   jnp.sum((sizes > 0).astype(jnp.int32)))
+        module.sow(MOE_STATS, "expert_load", sizes)
+        real = (tokens if token_mask is None
+                else jnp.sum(token_mask.astype(jnp.int32)))
+        module.sow(MOE_STATS, "pairs_dense",
+                   jnp.asarray(real * n_held if batched else 0,
+                               jnp.int32))
+
+    weights = (w_gate, w_up, w_down)
+    if batched:
+        return _batched_experts(x2d, local, gates, weights, act,
+                                compute_dtype)
+    return _grouped_experts(x2d, local, gates, sizes, weights, act,
+                            compute_dtype, capacity)
+
+
+def _expert_mlp(product, xs, weights, act, compute_dtype):
+    """`down(act(gate(xs)) * up(xs))`, or `down(act(up(xs)))` where
+    there is no gate, each projection through `product(rows, w)`:
+    operands in `compute_dtype`, the result in the dtype it gives."""
+    w_gate, w_up, w_down = (
+        w if w is None else w.astype(compute_dtype) for w in weights)
+    if w_gate is not None:
+        hidden = act(product(xs, w_gate)) * product(xs, w_up)
+    else:
+        hidden = act(product(xs, w_up))
+    return product(hidden.astype(compute_dtype), w_down)
+
+
+def _batched_experts(x2d, local, gates, weights, act, compute_dtype):
+    """Every held expert over all T rows, then the gates select.
+    `local` [T, k]: a pair's row in the stacked weights, `n_held` for
+    a pair no one here computes."""
+    tokens, d_model = x2d.shape
+    n_held = weights[1].shape[0]
+    # The expert is a batch dimension of BOTH operands, so a weight is
+    # read where and as it lies: no transpose, no copy.
+    xs = jnp.broadcast_to(x2d.astype(compute_dtype)[None],
+                          (n_held, tokens, d_model))
+    out = _expert_mlp(lambda a, w: jax.lax.dot_general(
+        a, w, (((2,), (1,)), ((0,), (0,)))), xs, weights, act,
+        compute_dtype)                                    # [E, T, d]
+    # A token's gate for each held expert: its chosen pairs scattered
+    # (column `n_held` takes what no one here computes), 0 elsewhere.
+    gate_of = jnp.zeros((tokens, n_held + 1), jnp.float32).at[
+        jnp.arange(tokens)[:, None], local].add(
+            gates.astype(jnp.float32))[:, :n_held]
+    out = out.astype(jnp.float32) * jnp.transpose(gate_of)[:, :, None]
+    return out.sum(axis=0).astype(compute_dtype)
+
+
+def _grouped_experts(x2d, local, gates, sizes, weights, act,
+                     compute_dtype, capacity):
+    """The chosen pairs sorted by expert, one `ragged_dot` a
+    projection. `sizes` [n_held]: the pairs of each held expert."""
+    tokens, d_model = x2d.shape
+    k = local.shape[1]
+    n_held = sizes.shape[0]
     # Slot-major: pair j * T + t is token t's j-th choice.
-    local = jnp.transpose(local).reshape(k * tokens).astype(jnp.int32)
+    local = jnp.transpose(local).reshape(k * tokens)
     order = jnp.argsort(local, stable=True)
     group = local[order]
-    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[local].add(1)[:n_held]
     computed = group < n_held
     kept = computed
     if capacity is not None and capacity < tokens:
@@ -289,21 +430,11 @@ def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
         rank = jnp.arange(k * tokens) - starts[
             jnp.minimum(group, n_held - 1)]
         kept = computed & (rank < capacity)
-    if not module.is_initializing():   # counters are no variables
-        module.sow(MOE_STATS, "pairs_held", jnp.sum(sizes))
-        module.sow(MOE_STATS, "experts_touched",
-                   jnp.sum((sizes > 0).astype(jnp.int32)))
-        module.sow(MOE_STATS, "expert_load", sizes)
 
     token_of = order % tokens
     xs = x2d.astype(compute_dtype)[token_of]              # [kT, d]
-    grouped = lambda a, w: jax.lax.ragged_dot(
-        a, w.astype(compute_dtype), sizes)
-    if gated:
-        hidden = act(grouped(xs, w_gate)) * grouped(xs, w_up)
-    else:
-        hidden = act(grouped(xs, w_up))
-    out = grouped(hidden.astype(compute_dtype), w_down)   # [kT, d]
+    out = _expert_mlp(lambda a, w: jax.lax.ragged_dot(a, w, sizes), xs,
+                      weights, act, compute_dtype)        # [kT, d]
     # Rows past the held pairs belong to no group: whatever the
     # product left there is not read.
     weight = jnp.transpose(gates).reshape(k * tokens)[order]

@@ -107,15 +107,19 @@ layer's (models/mamba2.py) run under `jax.named_scope`s of these
 names, inside whatever program holds the layer (`jit_serve_tick`, `jit_serve_prefill`, `jit_train_step`): every
 op a part lowers to carries the name in its `op_name`, and the grouped
 products of the routed experts are XLA:TPU's own kernel, whose
-instructions the trace names `ragged-dot*`.
+instructions the trace names `ragged-dot*`. Where the routed experts
+run batched over the held experts (models/moe.py `batched_over_held`:
+a decode tick whose rows touch every held expert) there is no such
+kernel: the products are XLA's own fusions.
 
 Scopes:
 
     moe_router            scores over all experts, the top-k choice
                           and the gates (float32)
     moe_routed_experts    sort of the chosen pairs held here, the
-                          three grouped products, the weighted sum
-                          back to tokens
+                          grouped products, the weighted sum back to
+                          tokens; or the batched products over the
+                          held experts and the gates' sum
     moe_shared_expert     the always-on expert's MLP
     moe_latent_down       a latent expert layer: the projection to the
                           latent width, before the routed experts
@@ -137,6 +141,9 @@ Counters:
 
     moe_pairs_routed      (token, choice) pairs of the active slots
     moe_pairs_held        those whose expert is held here
+    moe_pairs_dense       (token, held expert) products of the ticks
+                          whose expert layers ran batched over the
+                          held experts; 0 where they ran grouped
     moe_experts_touched   held experts with at least one pair, summed
                           a layer and tick
     moe_expert_load       pairs a held expert (a vector)

@@ -493,10 +493,14 @@ class Scheduler:
         # An expert model's counters, summed over ticks and expert
         # layers (`moe.MOE_STATS`): (token, choice) pairs of active
         # slots, those whose expert is held here, held experts that
-        # got at least one pair (a layer and tick), and the pairs a
-        # held expert. They come back with each tick's tokens.
+        # got at least one pair (a layer and tick), the pairs a held
+        # expert, and the (token, held expert) products of the ticks
+        # whose expert layers ran batched over the held experts (0
+        # where they ran grouped: `moe.batched_over_held`). They come
+        # back with each tick's tokens.
         self._moe_pairs_routed = 0
         self._moe_pairs_held = 0
+        self._moe_pairs_dense = 0
         self._moe_experts_touched = 0
         self._moe_expert_load = None
         # A recurrent model's counter, summed over ticks: slots
@@ -2276,6 +2280,7 @@ class Scheduler:
             return
         self._moe_pairs_routed += int(counters["pairs_routed"])
         self._moe_pairs_held += int(counters["pairs_held"])
+        self._moe_pairs_dense += int(counters["pairs_dense"])
         self._moe_experts_touched += int(counters["experts_touched"])
         load = np.asarray(counters["expert_load"], np.int64)
         self._moe_expert_load = (load if self._moe_expert_load is None
@@ -2629,6 +2634,7 @@ class Scheduler:
         self._kv_walked_tokens = 0
         self._moe_pairs_routed = 0
         self._moe_pairs_held = 0
+        self._moe_pairs_dense = 0
         self._moe_experts_touched = 0
         self._moe_expert_load = None
         self._ssm_slot_steps = 0
@@ -2744,6 +2750,7 @@ class Scheduler:
             "kv_walked_tokens": self._kv_walked_tokens,
             "moe_pairs_routed": self._moe_pairs_routed,
             "moe_pairs_held": self._moe_pairs_held,
+            "moe_pairs_dense": self._moe_pairs_dense,
             "moe_experts_touched": self._moe_experts_touched,
             "moe_expert_load": ([] if self._moe_expert_load is None
                                 else self._moe_expert_load.tolist()),

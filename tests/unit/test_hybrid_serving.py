@@ -75,7 +75,36 @@ def test_prefill_and_decode_through_the_pool_are_the_reference(built, chunk):
         stats["pool"]["kv_bytes_total"] + state)
     # Pages of the one attention layer only.
     assert stats["kv"]["page_bytes"] == 2 * 8 * 2 * 16 * 4
-    assert stats["moe_pairs_routed"] > 0
+    assert stats["moe_pairs_routed"] > stats["moe_pairs_held"] > 0
+    # Two rows choosing 4 of 16 experts leave held experts untouched: the
+    # tick's expert layers run grouped.
+    assert stats["moe_pairs_dense"] == 0
+
+
+def test_a_tick_that_touches_every_held_expert_runs_batched(built):
+    """Twelve slots choosing 4 of 16 experts are expected to touch 97 % of
+    the held ones, so the tick's two expert layers run batched over the 4
+    held experts (`moe.batched_over_held`): `moe_pairs_dense` counts slots
+    advanced x held experts a layer, restarts with `moe_pairs_held` at
+    warm-up's end, and every served token is still the reference's."""
+    from cloud_tpu.models import moe
+    from cloud_tpu.serving import Scheduler
+
+    _, model, _, params = built
+    assert moe.batched_over_held(12, 4, 16, None)
+    prompts = prompts_of((5, 13, 30, 41, 9, 24, 17, 8, 33, 12, 21, 6, 27, 10))
+    with Scheduler(model, params, slots=12, page_size=8) as sched:
+        sched.warmup([8, 16, 32, 64])
+        warm = sched.stats()
+        assert warm["ticks"] == warm["moe_pairs_held"] == 0
+        assert warm["moe_pairs_dense"] == 0
+        served = serve(sched, prompts, [10] * len(prompts))
+    stats = sched.stats()
+    assert worst_gap(built, served, prompts, 10) < 1e-4
+    assert stats["moe_pairs_held"] > 0
+    # 4 held experts in each of 2 expert layers, for every slot a tick
+    # advanced (`ssm_slot_steps` counts those once a state layer, of 2).
+    assert stats["moe_pairs_dense"] == 4 * stats["ssm_slot_steps"] > 0
 
 
 def test_a_resize_that_moves_slots_keeps_every_request_right(built):

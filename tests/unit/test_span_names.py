@@ -504,4 +504,4 @@ def test_counter_table_names_the_stats_an_expert_model_adds():
     table = spans.names("Counters")
     assert set(table) == {k for k in stats
                           if k.startswith(("moe_", "ssm_"))}
-    assert [stats[k] for k in table] == [0, 0, 0, [], 0, 0]
+    assert [stats[k] for k in table] == [0, 0, 0, 0, [], 0, 0]
