@@ -21,3 +21,4 @@ from cloud_tpu.models.transformer import (TransformerEncoder,
 from cloud_tpu.models.vit import ViT, ViT_B16, ViT_L16, ViT_S16
 from cloud_tpu.models.mamba2 import Mamba2Mixer
 from cloud_tpu.models.nemotron_h import NemotronHLM
+from cloud_tpu.models.evabyte import EvaByteLM

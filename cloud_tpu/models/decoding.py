@@ -157,6 +157,11 @@ def paged_kv_attention(module, q, k, v, mask, *, cache_len, page_size,
     dense cache does. `window`: a window layer — query i attends keys
     in (i - window, i], the band the dense cache masks on logical
     positions; a slot's logical position is its cache index here.
+    (That holds for every layer that keeps a row a token. The one
+    exception is an EVA layer, `models/evabyte.py`, which does not come
+    through this function: its slot keeps a ring of window rows, token
+    t at row `t mod window`, and a table of summary rows, and its mask
+    follows from the slot's depth alone — `ops/eva.py` `EvaLayout`.)
 
     Per-slot math is EXACTLY the dense `_decode_attention`'s per-row
     math over the gathered logical view (same masking, same f32

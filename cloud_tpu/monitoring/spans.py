@@ -97,7 +97,10 @@ Kernels:
     paged_decode            ops/paged_attention.py decode walk, a full
                             layer's (every live page of a slot)
     paged_decode_window     the same walk over a window layer's band
-                            (from the slot's first live page)
+                            (from the slot's first live page), and
+                            over an EVA layer's one run of live rows:
+                            the summaries of the windows behind the
+                            query, then its own window's ring rows
     ssm_decode_update       ops/ssm.py, a Mamba-2 layer's decode step:
                             every slot's recurrent state read once and
                             written once in place, one call a layer
@@ -135,7 +138,11 @@ Scopes:
 An expert model's tick also returns counters, which `Scheduler.stats()`
 sums over ticks and expert layers (fetched with the tick's tokens, in
 the same read-back); a model with recurrent (Mamba-2) layers adds a
-counter of its own to the same read-back, and a gauge.
+counter of its own to the same read-back, and a gauge. A model whose
+slots keep a ring and summary rows (EVA attention, ops/eva.py) is
+counted on the host, from each occupied slot's depth (the rows a query
+attends follow from it alone); its prefill's window chunks run under
+the span `serve_prefill_chunk`.
 
 Counters:
 
@@ -152,6 +159,16 @@ Counters:
     ssm_slot_steps        slots advanced x recurrent layers, summed
                           over ticks (each is one state read and
                           written)
+    eva_rows_read         rows of both kinds the ticks' queries
+                          attended, summed over slots and ticks, a
+                          layer counted once (`kv_live_tokens` is the
+                          same sum for such a model, and
+                          `kv_walked_tokens` the rows the walk fetched)
+    eva_summary_rows_read the summary rows among them
+    eva_windows_closed    window ends met, by ticks and by prefill
+                          chunks (a dict of the two)
+    eva_cache_bytes       bytes of ring and summary pages held by
+                          requests (a gauge, not a sum)
 
 The hot loops' jitted functions are named so (a constant beside the
 jit), and the trace's program line reads `jit_<name>`.

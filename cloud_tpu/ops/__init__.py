@@ -3,6 +3,7 @@
 from cloud_tpu.ops.attention import attention
 from cloud_tpu.ops.attention import flash_attention
 from cloud_tpu.ops.attention import mha_reference
+from cloud_tpu.ops.eva import chunk_summaries
 from cloud_tpu.ops.fused_ce import lm_head_loss
 from cloud_tpu.ops.fused_ce import lm_head_loss_reference
 from cloud_tpu.ops.fused_mlp import fused_swiglu
@@ -16,6 +17,7 @@ from cloud_tpu.ops.ssm import ssm_decode_update
 from cloud_tpu.ops.ssm import ssm_decode_update_reference
 
 __all__ = ["attention", "flash_attention", "mha_reference",
+           "chunk_summaries",
            "lm_head_loss", "lm_head_loss_reference",
            "fused_swiglu", "swiglu_reference",
            "fused_rmsnorm", "rmsnorm_residual_reference",

@@ -169,21 +169,25 @@ def attention_shape(model):
 
 
 def _served_model(model, role):
-    """The three classes whose attention writes and reads the page pool
-    (`decoding.paged_kv_attention`): `TransformerLM`, `LlamaLM` and
-    `NemotronHLM`, whose Mamba-2 layers keep a per-slot state beside
-    the pool (`state_layers`). The class of the model decides every
-    difference, there is no switch."""
+    """The four classes whose attention writes and reads the page pool:
+    `TransformerLM`, `LlamaLM` and `NemotronHLM` a row a token
+    (`decoding.paged_kv_attention`; `NemotronHLM`'s Mamba-2 layers keep
+    a per-slot state beside the pool, `state_layers`), and `EvaByteLM`
+    a ring of window rows and a table of summary rows a slot
+    (`layout`). The class of the model decides every difference, there
+    is no switch."""
+    from cloud_tpu.models.evabyte import EvaByteLM
     from cloud_tpu.models.llama import LlamaLM
     from cloud_tpu.models.nemotron_h import NemotronHLM
     from cloud_tpu.models.transformer import TransformerLM
     from cloud_tpu.parallel import SEQUENCE_PARALLEL_IMPLS
 
-    if not isinstance(model, (TransformerLM, LlamaLM, NemotronHLM)):
+    if not isinstance(model, (TransformerLM, LlamaLM, NemotronHLM,
+                              EvaByteLM)):
         raise NotImplementedError(
-            "graftserve serves TransformerLM, LlamaLM and NemotronHLM "
-            "(their attention reads the page pool); got {} as the {}."
-            .format(type(model).__name__, role))
+            "graftserve serves TransformerLM, LlamaLM, NemotronHLM and "
+            "EvaByteLM (their attention reads the page pool); got {} as "
+            "the {}.".format(type(model).__name__, role))
     if isinstance(model, LlamaLM) and (
             model.attn_logit_softcap
             or model.attention_impl in SEQUENCE_PARALLEL_IMPLS):
@@ -300,7 +304,7 @@ def _cache_prefill_fn(decoder):
     return best_effort_donation(prefill)
 
 
-def chunk_plan(n_suffix, chunk_size, max_seq_len):
+def chunk_plan(n_suffix, chunk_size, max_seq_len, whole_tail=False):
     """Chunk layout for an `n_suffix`-token prefill at fixed chunk
     width `chunk_size`: `(n_full, tail, tail_bucket)` — `n_full` full
     chunks of `chunk_size` real tokens, then one tail chunk of `tail`
@@ -310,11 +314,14 @@ def chunk_plan(n_suffix, chunk_size, max_seq_len):
     `chunk_size` a power of two the written extent
     `n_full * chunk_size + tail_bucket` never exceeds
     `bucket_length(n_suffix)`, so the whole-prefill in-cache check
-    also bounds the chunked writes."""
+    also bounds the chunked writes. `whole_tail`: the tail runs at
+    `chunk_size` too (a model prefilled a window at a time: one
+    executable for every prompt length)."""
     from cloud_tpu.models.decoding import bucket_length
     n_full = (n_suffix - 1) // chunk_size
     tail = n_suffix - n_full * chunk_size
-    return n_full, tail, bucket_length(tail, max_seq_len)
+    return n_full, tail, (chunk_size if whole_tail
+                          else bucket_length(tail, max_seq_len))
 
 
 class ChunkedPrefill:
@@ -368,7 +375,8 @@ class ChunkedPrefill:
         self._sampling = dict(sampling)
         self._gather_vec = gather_vec
         n_full, tail, tail_bucket = chunk_plan(
-            n_suffix, self.chunk_size, engine.max_seq_len)
+            n_suffix, self.chunk_size, engine.max_seq_len,
+            whole_tail=engine.layout is not None)
         self.n_chunks = n_full + 1
         self.chunks_done = 0
         self._tail = tail
@@ -496,19 +504,32 @@ class DecodeEngine:
                  max_new_cap=None, draft_model=None, draft_params=None,
                  spec_k=0, page_dtype="", ladder=None):
         _served_model(model, "model")
-        if model.max_seq_len % page_size:
+        #: The rows a slot keeps where they are not one a token
+        #: (`ops.eva.EvaLayout`, from the model's class; None for the
+        #: classes that keep a row a token): a ring of the current
+        #: window's rows, overwritten in place, and a table of summary
+        #: rows. Such pages cannot be shared by a prefix, kept on the
+        #: host or rolled back after a rejected draft, and the model
+        #: is prefilled a window at a time.
+        self.layout = getattr(model, "layout", None)
+        cache_rows = (self.layout.rows if self.layout is not None
+                      else model.max_seq_len)
+        if cache_rows % page_size:
             raise ValueError(
-                "max_seq_len ({}) must be a multiple of page_size "
-                "({}).".format(model.max_seq_len, page_size))
+                "a slot's {} cache rows (max_seq_len {}) must be a "
+                "multiple of page_size ({}).".format(
+                    cache_rows, model.max_seq_len, page_size))
         if page_dtype not in ("", "int8"):
             raise ValueError(
                 "page_dtype must be '' or 'int8'; got {!r}.".format(
                     page_dtype))
+        if self.layout is not None:
+            self.layout.check(page_size)
         self.model = model
         self.slots = int(slots)
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
-        self.pages_per_slot = model.max_seq_len // page_size
+        self.pages_per_slot = cache_rows // page_size
         self.max_seq_len = model.max_seq_len
         self.max_new_cap = int(max_new_cap or model.max_seq_len)
         if self.max_new_cap < 2:
@@ -544,6 +565,14 @@ class DecodeEngine:
         #: reuse, no host tier and no speculation until state
         #: snapshots exist (ROADMAP 2.5).
         self.state_layers = getattr(model, "state_layers", 0)
+        if self.layout is not None and (self.spec_on or page_dtype):
+            raise NotImplementedError(
+                "spec_k > 0 and quantized pages are not served for a "
+                "model whose slots keep a ring and summary rows ({}): "
+                "a verify window would overwrite ring rows that no "
+                "rewind gives back, and a summary row is made from "
+                "the page's rows as computed.".format(
+                    type(model).__name__))
         if self.state_layers and self.spec_on:
             raise NotImplementedError(
                 "spec_k > 0 is not served for a model with recurrent "
@@ -632,7 +661,7 @@ class DecodeEngine:
             jit, donate_argnums=(0,))(self._gather_impl))
 
         def gather(dense_cache, pool_cache, page_vec, prefix_len):
-            self._refuse_recurrent("a prefix hit")
+            self._refuse_page_reuse("a prefix hit")
             # The view strips slot-count-bound leaves so the gather
             # signature is identical at every geometry rung.
             return gather_exec(dense_cache, _pool_pages_view(pool_cache),
@@ -652,7 +681,13 @@ class DecodeEngine:
         #: tokens, in the one read-back a tick makes.
         self.tick_counters = {}
 
-    def _refuse_recurrent(self, what):
+    def _refuse_page_reuse(self, what):
+        if self.layout is not None:
+            raise NotImplementedError(
+                "{} is not served for a model whose slots keep a ring "
+                "and summary rows: a ring page is overwritten in place "
+                "when the next window begins, and a summary row stands "
+                "for a chunk of one request's own keys.".format(what))
         if self.state_layers:
             raise NotImplementedError(
                 "{} is not served for a model with recurrent layers: "
@@ -690,6 +725,18 @@ class DecodeEngine:
         whole of it is `serve_prefill` (gather + dense prefill + the
         blocking first-token fetch, the device side of TTFT)."""
         with spans.span(SERVE_PREFILL, rid=rid):
+            if self.layout is not None:
+                # A window at a time, never a dense cache: the chunked
+                # path run to its end.
+                chunked = self.prefill_chunks(
+                    prompt, max_new_tokens, rng, sampling,
+                    self.layout.window, prefix_len=prefix_len,
+                    gather_vec=gather_vec, key_override=key_override,
+                    rid=rid)
+                while True:
+                    result = chunked.step()
+                    if result is not None:
+                        return result
             return self._prefill(prompt, max_new_tokens, rng, sampling,
                                  prefix_len, gather_vec, key_override,
                                  rid)
@@ -1331,7 +1378,7 @@ class DecodeEngine:
         holding `[n, P, H*D]` K/V blocks (+ `[n, H]` scales in int8
         mode) with n == len(page_ids), rows in logical page order.
         Tick thread only — reads the tick-donated cache."""
-        self._refuse_recurrent("the host tier")
+        self._refuse_page_reuse("the host tier")
         n = len(page_ids)
         vec = jnp.asarray(self.pool_page_vec(page_ids), jnp.int32)
         tree = jax.device_get(self._snapshot(self.cache, vec))
@@ -1343,7 +1390,7 @@ class DecodeEngine:
         `page_ids[i]`, except the first `n_skip` logical pages (already
         resident via the prefix trie) and any `page_ids` entry of 0,
         which collapse onto scratch. Tick thread only."""
-        self._refuse_recurrent("the host tier")
+        self._refuse_page_reuse("the host tier")
         vec = self.pool_page_vec(page_ids)
         vec[:n_skip] = 0
         n = len(page_ids)

@@ -27,6 +27,12 @@ to write past it makes a copy-on-write page first (the engine's insert
 scatter routes shared entries to scratch and reconstructs divergent
 content into fresh pages), and `note_cow` keeps the count for
 `pool_stats`.
+
+Two kinds of page for a model whose slots keep a ring and summary rows
+(`RingSummaryPagePool`, `ops/eva.py`): a request holds the pages of the
+current window's ring, which it overwrites in place window after
+window, and the summary pages of every window it begins. Both are pages
+of the one pool and count alike in every total; neither is ever shared.
 """
 
 import threading
@@ -272,6 +278,54 @@ class PagePool:
         return vec
 
 
+class RingSummaryPagePool(PagePool):
+    """The pool of a model whose slots keep a ring of the current
+    window's rows and one summary row per chunk behind it
+    (`ops.eva.EvaLayout`; `pages_per_slot` is the layout's). A request
+    holds ring pages for at most one window, however long it is, and
+    summary pages for every window it begins; where each goes in the
+    slot's page table is the layout's. Everything else (reservation,
+    backpressure, refcounts of 1, byte totals, the leak report) is the
+    base pool's: both kinds are pages like any other."""
+
+    def __init__(self, layout, num_pages, page_size, page_bytes=0):
+        super().__init__(num_pages, page_size, layout.rows // page_size,
+                         page_bytes=page_bytes)
+        self.layout = layout
+
+    def pages_needed(self, prompt_tokens, max_new_tokens, slack=0):
+        """Ring pages + summary pages a request holds for its life
+        (`prompt + max_new - 1` positions written):
+        `ceil(min(tokens, window) / page_size)` of the ring, and a
+        window's summary pages for each window begun."""
+        tokens = prompt_tokens + max(int(max_new_tokens) - 1, 0) + slack
+        if tokens > self.layout.max_seq_len:
+            ring, summaries = self.layout.pages(self.layout.max_seq_len,
+                                                self.page_size)
+            raise ValueError(
+                "request writes {} positions but a slot addresses {} "
+                "ring pages (a window of {} tokens) + {} summary pages "
+                "(one row per {} tokens of {}), page_size {}.".format(
+                    tokens, ring, self.layout.window, summaries,
+                    self.layout.chunk, self.layout.max_seq_len,
+                    self.page_size))
+        return sum(self.layout.pages(tokens, self.page_size))
+
+    def page_vec(self, page_ids):
+        """The slot's page-table row: ring pages at the ring's rows,
+        summary pages below them (the count says which are which),
+        scratch elsewhere."""
+        return self.layout.page_vec(page_ids, self.page_size)
+
+    def pool_stats(self):
+        out = super().pool_stats()
+        ring, summaries = self.layout.pages(self.layout.max_seq_len,
+                                            self.page_size)
+        out["page_kinds"] = {"ring_pages_per_slot": ring,
+                             "summary_pages_per_slot": summaries}
+        return out
+
+
 class HostPageTier:
     """Host-RAM second tier of the KV page hierarchy (graftpack).
 
@@ -419,4 +473,4 @@ class HostPageTier:
             }
 
 
-__all__ = ["PagePool", "HostPageTier"]
+__all__ = ["PagePool", "RingSummaryPagePool", "HostPageTier"]

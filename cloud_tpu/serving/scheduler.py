@@ -134,7 +134,8 @@ from cloud_tpu.serving.engine import DecodeEngine, attention_shape
 from cloud_tpu.serving.faults import (HostTierCorrupt, PoolSqueezed,
                                       PrefillFailed, ServeShed,
                                       SlotEvicted, SlotHang, fault_kind)
-from cloud_tpu.serving.kvpool import HostPageTier, PagePool
+from cloud_tpu.serving.kvpool import (HostPageTier, PagePool,
+                                      RingSummaryPagePool)
 from cloud_tpu.serving.prefixcache import PrefixCache
 
 #: pool_squeeze hold window: confiscated pages return after this many
@@ -367,13 +368,17 @@ class Scheduler:
                     rungs.add(w)
                 w *= 2
             ladder = tuple(sorted(rungs | {int(slots)}))
+        # The rows a slot keeps where they are not one a token (the
+        # model's class says so; `DecodeEngine.layout`).
+        layout = getattr(model, "layout", None)
         if num_pages is None:
             # Default: every slot of the WIDEST rung can hold a
             # full-length sequence, plus scratch — paging then bounds
             # fragmentation, not memory, and a grow never needs new
             # pages (the pool serves every geometry).
             widest = max(ladder) if ladder else slots
-            num_pages = widest * (model.max_seq_len // page_size) + 1
+            rows = layout.rows if layout is not None else model.max_seq_len
+            num_pages = widest * (rows // page_size) + 1
         # -- graftpack: KV page dtype + host page tier ----------------
         if kv_dtype is None:
             kv_dtype = os.environ.get("CLOUD_TPU_SERVE_KV_DTYPE",
@@ -402,6 +407,18 @@ class Scheduler:
                     "recurrent layers ({}): a demoted page cannot "
                     "give their state back.".format(
                         type(model).__name__))
+        if layout is not None:
+            # A model whose slots keep a ring and summary rows (its
+            # class says so): a ring page is overwritten when the next
+            # window begins and a summary row stands for one request's
+            # own chunk, so no page outlives or is shared beyond its
+            # request. No trie, as above.
+            prefix_cache = False
+            if host_tier:
+                raise NotImplementedError(
+                    "host_tier is not served for a model whose slots "
+                    "keep a ring and summary rows ({}): its pages are "
+                    "overwritten in place.".format(type(model).__name__))
         if host_tier:
             if draft_model is not None and spec_k > 0:
                 raise ValueError(
@@ -419,11 +436,16 @@ class Scheduler:
                                    draft_params=draft_params,
                                    spec_k=spec_k, page_dtype=kv_dtype,
                                    ladder=ladder)
-        self.pool = PagePool(num_pages, page_size,
-                             self.engine.pages_per_slot,
-                             page_dtype=kv_dtype,
-                             page_bytes=self.engine.page_hbm_bytes(),
-                             state_bytes=self.engine.state_hbm_bytes())
+        if layout is not None:
+            self.pool = RingSummaryPagePool(
+                layout, num_pages, page_size,
+                page_bytes=self.engine.page_hbm_bytes())
+        else:
+            self.pool = PagePool(
+                num_pages, page_size, self.engine.pages_per_slot,
+                page_dtype=kv_dtype,
+                page_bytes=self.engine.page_hbm_bytes(),
+                state_bytes=self.engine.state_hbm_bytes())
         self.host_tier = None
         if host_tier:
             if host_tier_pages is None:
@@ -490,6 +512,14 @@ class Scheduler:
             self._kv_reach + 1, self.engine.pages_per_slot)
         self._kv_live_tokens = 0
         self._kv_walked_tokens = 0
+        # A ring-and-summaries model's counters, summed over ticks and
+        # occupied slots (layers counted once): the rows a tick
+        # attended and the summary rows among them (`kv_live_tokens`
+        # and `kv_walked_tokens` count rows for such a model), and the
+        # window ends met by ticks and by prefill chunks.
+        self._eva_rows_read = 0
+        self._eva_summary_rows_read = 0
+        self._eva_windows_closed = {"ticks": 0, "prefills": 0}
         # An expert model's counters, summed over ticks and expert
         # layers (`moe.MOE_STATS`): (token, choice) pairs of active
         # slots, those whose expert is held here, held experts that
@@ -552,6 +582,12 @@ class Scheduler:
                                  "").strip().lower()
             prefill_chunk = 0 if env in _OFF_VALUES else int(env)
         prefill_chunk = int(prefill_chunk)
+        if layout is not None:
+            # Prefilled a window at a time, whatever was asked: each
+            # chunk attends `[summaries so far | its own window]` and
+            # leaves summaries and a partial window, never a dense
+            # cache.
+            prefill_chunk = layout.window
         if prefill_chunk < 0:
             raise ValueError("prefill_chunk must be >= 0 (0 = off); "
                              "got {}.".format(prefill_chunk))
@@ -1375,6 +1411,11 @@ class Scheduler:
             raise
         dur = time.monotonic() - t0
         self._chunks_dispatched += 1
+        if self.engine.layout is not None:
+            # A chunk that fills its window ends it (the tail's may
+            # not).
+            self._eva_windows_closed["prefills"] += (
+                item.chunked.chunk_tokens(i) == self.engine.layout.window)
         self._observe_prefill_chunk(dur)
         self._trace_emit(item.rec.rid, "prefill_chunk", i=int(i),
                          n=int(item.chunked.n_chunks),
@@ -2236,9 +2277,23 @@ class Scheduler:
             # What this tick's attention read: the token it consumed
             # sits at prompt + emitted - 1, so that many keys and
             # itself.
+            layout = self.engine.layout
             for _, state in live:
                 depth = (len(state.request.prompt)
                          + len(state.emitted) + self._kv_reach)
+                if layout is not None:
+                    # The token consumed sits at depth - 1: the rows
+                    # of both kinds it attends, and the groups the
+                    # walk fetches from its first live row to its own.
+                    summaries, ring = layout.rows_read(depth - 1)
+                    self._eva_rows_read += summaries + ring
+                    self._eva_summary_rows_read += summaries
+                    self._eva_windows_closed["ticks"] += (
+                        depth % layout.window == 0)
+                    self._kv_live_tokens += summaries + ring
+                    self._kv_walked_tokens += layout.rows_walked(
+                        depth - 1, self._kv_group * self.pool.page_size)
+                    continue
                 self._kv_live_tokens += depth
                 self._kv_walked_tokens += walked_tokens(
                     depth, self.pool.page_size, self._kv_group)
@@ -2550,6 +2605,11 @@ class Scheduler:
             merged.update(dict(cfg))
             configs.append(merged)
         widths = set(buckets)
+        if self.engine.layout is not None:
+            # One chunk executable and one tail executable serve every
+            # prompt length (the chunk path below warms both): the
+            # widths asked for name no program of such a model.
+            widths = set()
         if self.trie is not None and buckets:
             w = 1
             while w <= max(buckets):
@@ -2565,7 +2625,12 @@ class Scheduler:
         # the intended width.
         cap = self.engine.max_seq_len - max_new - self._spec_slack()
         chunk_lengths = []
-        if self._prefill_chunk is not None:
+        if self.engine.layout is not None:
+            # A whole window and a one-token tail where a request may
+            # be that long, else the tail alone (no prompt then
+            # reaches a second chunk).
+            chunk_lengths = [min(self._prefill_chunk + 1, cap)]
+        elif self._prefill_chunk is not None:
             # Drive the chunk + tail-bucket surface: length C + t has
             # exactly one full chunk and a t-token tail, so the set
             # {C + t : t pow2 <= C} compiles the fixed-chunk executable
@@ -2632,6 +2697,9 @@ class Scheduler:
         self._ticks_overlapped = 0
         self._kv_live_tokens = 0
         self._kv_walked_tokens = 0
+        self._eva_rows_read = 0
+        self._eva_summary_rows_read = 0
+        self._eva_windows_closed = {"ticks": 0, "prefills": 0}
         self._moe_pairs_routed = 0
         self._moe_pairs_held = 0
         self._moe_pairs_dense = 0
@@ -2740,6 +2808,7 @@ class Scheduler:
                                        time.monotonic()), 1e-9)
         lookups = self._hits + self._misses
         proposed = self._proposed_draft_tokens
+        pool = self.pool.pool_stats()
         out = {
             "requests_completed": self._completed,
             "tokens_emitted": self._tokens_out,
@@ -2756,6 +2825,11 @@ class Scheduler:
                                 else self._moe_expert_load.tolist()),
             "ssm_state_bytes": self.pool.state_bytes,
             "ssm_slot_steps": self._ssm_slot_steps,
+            "eva_rows_read": self._eva_rows_read,
+            "eva_summary_rows_read": self._eva_summary_rows_read,
+            "eva_windows_closed": dict(self._eva_windows_closed),
+            "eva_cache_bytes": (pool["kv_bytes_held"]
+                                if self.engine.layout is not None else 0),
             "elapsed_seconds": wall,
             "requests_per_sec": self._completed / wall,
             "tokens_per_sec": self._tokens_out / wall,
@@ -2781,7 +2855,7 @@ class Scheduler:
             "prefix_misses": self._misses,
             "prefix_hit_rate": self._hits / lookups if lookups else 0.0,
             "prefix_tokens_served": self._prefix_tokens_served,
-            "pool": self.pool.pool_stats(),
+            "pool": pool,
             "spec_accept_rate": (self._accepted_draft_tokens / proposed
                                  if proposed else 0.0),
             "spec_accepted_tokens": self._accepted_draft_tokens,

@@ -44,16 +44,23 @@ import pytest  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
-def toy_sizes_nemotron_h(monkeypatch):
+def toy_sizes_of_families_with_a_file(monkeypatch):
     """`tests/cellbench/conftest.py` keeps the toy widths and limits of the
     families later PRs add in two tables, and may not be edited (it lies
-    under the benchmark's `paths`). The `nemotron_h` family's are in
-    `tests/cellbench/toy_sizes_nemotron_h.py`, and this fixture, which runs
-    before that conftest's own, puts them into the tables for the test."""
+    under the benchmark's `paths`). A later family's are in a file of its
+    own, `tests/cellbench/toy_sizes_<family>.py`, and this fixture, which runs
+    before that conftest's own, puts every such file's into the tables for
+    the test."""
     tables = sys.modules.get("tests.cellbench.conftest")
     if tables is not None:
-        from tests.cellbench import toy_sizes_nemotron_h as sizes
+        import glob
+        import importlib
 
-        monkeypatch.setitem(tables.TOY_LIMITS, sizes.FAMILY, sizes.LIMITS)
-        monkeypatch.setitem(tables.SHRINK, sizes.FAMILY, sizes.shrink)
+        here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "cellbench", "toy_sizes_*.py")
+        for path in sorted(glob.glob(here)):
+            sizes = importlib.import_module(
+                "tests.cellbench." + os.path.basename(path)[:-3])
+            monkeypatch.setitem(tables.TOY_LIMITS, sizes.FAMILY, sizes.LIMITS)
+            monkeypatch.setitem(tables.SHRINK, sizes.FAMILY, sizes.shrink)
     yield

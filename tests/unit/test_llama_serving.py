@@ -104,7 +104,7 @@ def test_engine_refuses_what_the_pool_cannot_serve():
     from cloud_tpu.models import DeepseekLM
     model = DeepseekLM(vocab_size=64, num_layers=1, num_heads=2, d_model=32,
                        max_seq_len=32)
-    with pytest.raises(NotImplementedError, match="TransformerLM, LlamaLM and NemotronHLM"):
+    with pytest.raises(NotImplementedError, match="TransformerLM, LlamaLM, NemotronHLM and EvaByteLM"):
         DecodeEngine(model, None, slots=2, page_size=8, num_pages=9)
     capped = MODELS["qwen_shaped"]().clone(attn_logit_softcap=30.0)
     with pytest.raises(NotImplementedError, match="softcap"):

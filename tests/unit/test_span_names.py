@@ -503,5 +503,6 @@ def test_counter_table_names_the_stats_an_expert_model_adds():
         stats = sched.stats()
     table = spans.names("Counters")
     assert set(table) == {k for k in stats
-                          if k.startswith(("moe_", "ssm_"))}
-    assert [stats[k] for k in table] == [0, 0, 0, 0, [], 0, 0]
+                          if k.startswith(("moe_", "ssm_", "eva_"))}
+    assert [stats[k] for k in table] == [
+        0, 0, 0, 0, [], 0, 0, 0, 0, {"ticks": 0, "prefills": 0}, 0]

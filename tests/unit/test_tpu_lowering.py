@@ -263,6 +263,56 @@ def test_paged_kernel_compiles_at_the_cells_geometry(as_tpu, page_dtype,
     assert "_paged_decode_attention.paged_decode" in calls[0]
 
 
+def test_eva_tick_compiles_at_the_cells_geometry(as_tpu):
+    """The EVA cell's tick, one layer of it: 16 slots, a table of 256 pages
+    of 16 rows a slot (2048 summary rows, then the 2048-row ring), rows of
+    32 heads x 128 = 4096 lanes. It compiles for a v5e with no chip, the read
+    one Mosaic custom call under `paged_decode_window`; the two 537 MB pools
+    are aliased to the output and never copied, and no dense view of a
+    slot's rows or of its positions (`[16, 4096, ...]`, `[16, 32768, ...]`)
+    exists."""
+    import re
+
+    from cloud_tpu.models import EvaByteLM
+
+    devices = _tpu_topology()
+    if devices is None:
+        pytest.skip("no libtpu to describe a v5e")
+    slots, page = 16, 16
+    model = EvaByteLM(
+        vocab_size=320, num_layers=1, num_heads=32, d_model=4096, d_ff=11008,
+        max_seq_len=32768, window_size=2048, chunk_size=16, num_pred_heads=8,
+        compute_dtype=BF16, param_dtype=BF16, decode=True, kv_page_size=page,
+        kv_num_pages=slots * 256 + 1)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                               jnp.zeros((slots, 1), jnp.int32))
+    pool = variables["cache"]["block_0"]["attention"]["key_pages"]
+    assert pool.shape == (4097, 16, 4096)
+
+    def tick(params, cache, tokens, active):
+        return model.apply({"params": params, "cache": cache}, tokens,
+                           active, mutable=["cache"])
+
+    one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
+    compiled = jax.jit(tick, in_shardings=one, donate_argnums=1).trace(
+        variables["params"], variables["cache"], S((slots, 1), jnp.int32),
+        S((slots, 1), jnp.bool_)).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    reads = [c for c in calls if "paged_decode" in c]
+    assert len(reads) == 1 and reads[0].split(".")[-2] == (
+        "paged_decode_window"), calls
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "[4097,16,4096]" in line]
+    assert not re.search(r"\[16,(4096|32768),(32,128|4096)\]", text)
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * pool.size * 2
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+
+
 def test_ssm_decode_update_compiles_at_the_cells_geometry(as_tpu):
     """The hybrid cell's call — 128 slots, 128 heads x 64 (two side by
     side on the lanes), 8 groups, a state of 128 — compiles for a v5e with
