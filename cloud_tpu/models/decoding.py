@@ -502,13 +502,28 @@ def _cache_shapes(decoder, batch):
                              jnp.zeros((batch, 1), jnp.int32)))["cache"]
 
 
+@functools.lru_cache(maxsize=256)
+def _empty_cache_fn(decoder, batch):
+    """The one program that makes (decoder, batch)'s zeroed cache. Leaf
+    by leaf a 24-layer cache is a hundred eager dispatches: 100-140 ms
+    on a serving host beside a running tick, 11 ms as one program
+    (PERF.md section 6, PR 35)."""
+    from cloud_tpu.parallel import runtime
+
+    shapes = _cache_shapes(decoder, batch)
+
+    @runtime.instrumented_jit
+    def empty_cache():
+        return jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    return empty_cache
+
+
 def empty_cache(decoder, batch):
     """Zero-initialized decode-cache pytree for a decode-mode module
     (shared by `generate` and `generate_speculative`): built from the
     abstract init so no second params copy is ever materialized."""
-    shapes = _cache_shapes(decoder, batch)
-    return jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    return _empty_cache_fn(decoder, batch)()
 
 
 # --------------------------------------------------------------------------
@@ -551,7 +566,11 @@ def acquire_cache(decoder, batch):
     when one is parked, a fresh `empty_cache` otherwise."""
     with _pool_lock():
         parked = _CACHE_POOL.get((decoder, batch))
-        cache = parked.pop() if parked else None
+        # The one parked longest: what consumed a cache parked just now
+        # (a slot insert, queued behind a tick) may still be reading
+        # it, and donating it to the zero program then waits on the
+        # host for that read (10 ms a prefill; PERF.md section 6, PR 35).
+        cache = parked.pop(0) if parked else None
     if cache is None:
         return empty_cache(decoder, batch)
     return _zero_in_place()(cache)

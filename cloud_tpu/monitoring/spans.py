@@ -65,19 +65,32 @@ Spans:
                           completions, eviction (while the next tick
                           is on the device, unless drained)
     admit                 one request's turn in its admission window:
-                          probe, decision, reservation, prefill (rid)
+                          probe, decision, reservation, the dispatch
+                          of its prefill and, where one was in flight,
+                          the fetch of the prefill BEFORE it (rid)
     admit_reserve         inside admit: the page-reservation rounds
                           (rid)
-    serve_prefill         one serving prefill: gather + dense prefill
-                          + first-token fetch, the device side of
-                          TTFT (rid)
+    serve_prefill         one serving prefill (rid). On the admission
+                          thread, which keeps one prefill in flight:
+                          as far as the dispatch (prefill_host,
+                          prefill_dispatch). On the tick thread (a
+                          prefix hit, a requeue): gather + dense
+                          prefill + first-token fetch
     serve_prefill_chunk   one chunk of a chunked prefill (rid)
-    prefill_host          inside a prefill: array prep and the eager
-                          key split (rid)
-    prefill_dispatch      inside a prefill: the jitted prefill call
-                          (rid)
-    prefill_fetch         inside a prefill: the blocking fetch of the
-                          first token (rid)
+    prefill_host          inside serve_prefill, once: array prep, the
+                          key schedule (host arithmetic), the dense
+                          cache, a hit's gather (rid)
+    prefill_dispatch      inside serve_prefill: the jitted prefill
+                          call and the start of the first token's
+                          copy to the host (rid)
+    prefill_fetch         the blocking fetch of a prefill's first
+                          token, the TTFT point (rid of the prefill
+                          fetched): inside the NEXT request's admit,
+                          outside its serve_prefill; on its own where
+                          the prefill in flight is collected (an empty
+                          queue, a reservation that waits, a hit
+                          handed over, close); inside serve_prefill
+                          on the tick thread
 
 Each `pl.pallas_call` passes one of these as `name=` (a constant
 beside the call), and the trace's op text carries it.

@@ -80,6 +80,63 @@ def _program(name, impl, donate):
         program, donate_argnums=donate))
 
 
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def host_prng_key(seed):
+    """`jax.random.PRNGKey(seed)`'s two words for an int seed, as a
+    host array: the seed's low word behind its high word, which is 0
+    unless 64-bit mode is on."""
+    seed = int(seed)
+    high = seed >> 32 if jax.config.jax_enable_x64 else 0
+    return np.array([high & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
+
+
+def host_split(key, num):
+    """`jax.random.split(key, num)` of a raw threefry key, computed on
+    the host: row i is the Threefry-2x32 block of the counter (0, i)
+    under `key`, the layout `jax_threefry_partitionable` gives (the
+    engine refuses to start without it). No device program, no
+    read-back; `key` may be a host array or, at the cost of the read,
+    a device one. tests/unit/test_serving.py holds it to
+    `jax.random.split` row for row."""
+    k0, k1 = (np.uint32(word) for word in np.asarray(key).reshape(2))
+    ks = (k0, k1, k0 ^ k1 ^ np.uint32(0x1BD11BDA))
+    with np.errstate(over="ignore"):
+        x0 = np.zeros((num,), np.uint32) + ks[0]
+        x1 = np.arange(num, dtype=np.uint32) + ks[1]
+        for block in range(5):
+            for rot in _THREEFRY_ROTATIONS[block % 2]:
+                x0 = x0 + x1
+                x1 = (x1 << np.uint32(rot)) | (x1 >> np.uint32(32 - rot))
+                x1 = x1 ^ x0
+            x0 = x0 + ks[(block + 1) % 3]
+            x1 = x1 + ks[(block + 2) % 3] + np.uint32(block + 1)
+    return np.stack([x0, x1], axis=1)
+
+
+def _key_schedule(rng, key_override, sampling, n_steps, width):
+    """A request's keys, on the host: the key its prefill samples the
+    first token with, and `generate()`'s split schedule for the ticks
+    as `[width, 2]` rows (row i samples token i + 2; rows from
+    `n_steps - 1` on stay zero). A greedy request reads no key, so
+    `rng` is not touched for one and every row is zero. Under
+    `key_override` both come from the rows handed in."""
+    step_keys = np.zeros((width, 2), np.uint32)
+    if key_override is not None:
+        prefill_key = np.asarray(key_override[0], np.uint32).reshape(2)
+        rest = np.asarray(key_override[1], np.uint32).reshape(-1, 2)
+        if n_steps > 1:
+            step_keys[:n_steps - 1] = rest[:n_steps - 1]
+    elif sampling["temperature"]:
+        key, prefill_key = host_split(rng, 2)
+        if n_steps > 1:
+            step_keys[:n_steps - 1] = host_split(key, n_steps - 1)
+    else:
+        prefill_key = np.zeros((2,), np.uint32)
+    return prefill_key, step_keys
+
+
 @dataclasses.dataclass
 class PrefillResult:
     """A prefilled request waiting for slot insertion."""
@@ -90,6 +147,14 @@ class PrefillResult:
     bucket: int             # pow2 SUFFIX bucket the prefill compiled at
     n_steps: int            # max_new_tokens for this request
     prompt_len: int         # full prompt length (prefix + suffix)
+
+
+@dataclasses.dataclass
+class PrefillFlight:
+    """A whole-prompt prefill on the device whose first token the host
+    has not read (`DecodeEngine.prefill_dispatch`)."""
+    first: object           # device [1] int32, its copy to the host begun
+    result: PrefillResult   # `first_token` None until `prefill_finish`
 
 
 def _plain(tree):
@@ -339,7 +404,8 @@ class ChunkedPrefill:
     untouched: only the tail chunk draws, with the same split the
     whole prefill uses.
 
-    Construction is host-side only (the chunk PLAN); the first
+    Construction is host-side only (the chunk PLAN and the key
+    schedule, `_key_schedule`); the first
     `step()` acquires the dense cache(s) and runs the optional prefix
     gather. Every device dispatch therefore happens on the stepping
     thread — the scheduler steps chunks on the tick thread, whose
@@ -381,13 +447,9 @@ class ChunkedPrefill:
         self.chunks_done = 0
         self._tail = tail
         self._tail_bucket = tail_bucket
-        if key_override is None:
-            self._key, self._prefill_rng = jax.random.split(rng)
-            self._override_rest = None
-        else:
-            self._prefill_rng = jnp.asarray(key_override[0], jnp.uint32)
-            self._key = None
-            self._override_rest = key_override[1]
+        self._prefill_rng, self._step_keys = _key_schedule(
+            rng, key_override, self._sampling, self.max_new_tokens,
+            engine.max_new_cap - 1)
         self._cache = None
         self._dcache = None
         self._closed = False
@@ -458,22 +520,12 @@ class ChunkedPrefill:
                 engine._draft_params, self._dcache,
                 jnp.asarray(tokens), jnp.asarray(mask))
             self._dcache = None
-        n_steps = self.max_new_tokens
-        step_keys = np.zeros((engine.max_new_cap - 1, 2), np.uint32)
-        if self._override_rest is not None:
-            rest = np.asarray(self._override_rest,
-                              np.uint32).reshape(-1, 2)
-            if n_steps > 1:
-                step_keys[:n_steps - 1] = rest[:n_steps - 1]
-        elif n_steps > 1:
-            step_keys[:n_steps - 1] = np.asarray(
-                jax.random.split(self._key, n_steps - 1))
         first_host = int(runtime.device_fetch(first)[0])
         self.chunks_done = i + 1
         self._closed = True
         return PrefillResult(first_token=first_host, pcache=pcache,
-                             dpcache=dpcache, step_keys=step_keys,
-                             bucket=bucket, n_steps=n_steps,
+                             dpcache=dpcache, step_keys=self._step_keys,
+                             bucket=bucket, n_steps=self.max_new_tokens,
                              prompt_len=self.prompt_len)
 
     def abandon(self):
@@ -504,6 +556,13 @@ class DecodeEngine:
                  max_new_cap=None, draft_model=None, draft_params=None,
                  spec_k=0, page_dtype="", ladder=None):
         _served_model(model, "model")
+        if (jax.config.jax_default_prng_impl != "threefry2x32"
+                or not jax.config.jax_threefry_partitionable):
+            raise NotImplementedError(
+                "the engine derives a request's keys on the host as "
+                "threefry2x32 under jax_threefry_partitionable splits "
+                "them (`host_split`); under another generator a served "
+                "request would no longer match `generate()`.")
         #: The rows a slot keeps where they are not one a token
         #: (`ops.eva.EvaLayout`, from the model's class; None for the
         #: classes that keep a row a token): a ring of the current
@@ -723,7 +782,10 @@ class DecodeEngine:
         Returns a `PrefillResult`; blocks until the first token is on
         host (the TTFT point). `rid` labels the call's spans: the
         whole of it is `serve_prefill` (gather + dense prefill + the
-        blocking first-token fetch, the device side of TTFT)."""
+        blocking first-token fetch, the device side of TTFT). It is
+        `prefill_dispatch` and `prefill_finish` back to back, for the
+        callers that may not run ahead: the tick thread's, whose
+        hit-path gather reads the pool cache the next tick donates."""
         with spans.span(SERVE_PREFILL, rid=rid):
             if self.layout is not None:
                 # A window at a time, never a dense cache: the chunked
@@ -737,12 +799,41 @@ class DecodeEngine:
                     result = chunked.step()
                     if result is not None:
                         return result
-            return self._prefill(prompt, max_new_tokens, rng, sampling,
-                                 prefix_len, gather_vec, key_override,
-                                 rid)
+            return self.prefill_finish(
+                self._dispatch_prefill(prompt, max_new_tokens, rng,
+                                       sampling, prefix_len, gather_vec,
+                                       key_override, rid), rid=rid)
 
-    def _prefill(self, prompt, max_new_tokens, rng, sampling,
-                 prefix_len, gather_vec, key_override, rid):
+    def prefill_dispatch(self, prompt, max_new_tokens, rng, sampling,
+                         prefix_len=0, gather_vec=None,
+                         key_override=None, rid=None):
+        """The first half of `prefill()` for a model prefilled whole:
+        everything up to and including the jitted call, and the start
+        of the first token's copy to the host. Returns a
+        `PrefillFlight` without waiting for the device, so the caller
+        can prepare and dispatch the next request's prefill before it
+        reads this one's token (`prefill_finish`). The span is
+        `serve_prefill`, as far as the dispatch."""
+        if self.layout is not None:
+            raise NotImplementedError(
+                "a model whose slots keep a ring and summary rows is "
+                "prefilled a window at a time (`prefill()` or "
+                "`prefill_chunks()`), never as one dispatch.")
+        with spans.span(SERVE_PREFILL, rid=rid):
+            return self._dispatch_prefill(prompt, max_new_tokens, rng,
+                                          sampling, prefix_len,
+                                          gather_vec, key_override, rid)
+
+    def prefill_finish(self, flight, rid=None):
+        """The second half: blocks until `flight`'s first token is on
+        the host (the TTFT point) and returns its `PrefillResult`."""
+        with spans.span("prefill_fetch", rid=rid):
+            flight.result.first_token = int(
+                runtime.device_fetch(flight.first)[0])
+        return flight.result
+
+    def _dispatch_prefill(self, prompt, max_new_tokens, rng, sampling,
+                          prefix_len, gather_vec, key_override, rid):
         from cloud_tpu.models.decoding import (acquire_cache,
                                                bucket_length)
 
@@ -766,15 +857,12 @@ class DecodeEngine:
             tokens[0, :n_suffix] = prompt[prefix_len:]
             mask = np.zeros((1, bucket), bool)
             mask[0, :n_suffix] = True
-            if key_override is None:
-                key, prefill_rng = jax.random.split(rng)
-            else:
-                # Same aval as a split key row (uint32[2], the legacy raw
-                # key layout categorical accepts), so the override path
-                # reuses the warmed prefill executable — no retrace.
-                prefill_rng = jnp.asarray(key_override[0], jnp.uint32)
-                key = None
-
+            # Host arrays: a split row and an override row are one
+            # aval (uint32[2], the legacy raw key layout categorical
+            # accepts), so both reuse the warmed prefill executable.
+            prefill_rng, step_keys = _key_schedule(
+                rng, key_override, sampling, int(max_new_tokens),
+                self.max_new_cap - 1)
             cache = _plain(acquire_cache(self._dense, 1))
             gvec = None
             if prefix_len:
@@ -797,25 +885,12 @@ class DecodeEngine:
                 dpcache = _cache_prefill_fn(self._dense_draft)(
                     self._draft_params, dcache, jnp.asarray(tokens),
                     jnp.asarray(mask))
-        # The eager split of the tick schedule queues behind the
-        # prefill just dispatched, and reading it back waits for both.
-        with spans.span("prefill_host", rid=rid):
-            step_keys = np.zeros((self.max_new_cap - 1, 2), np.uint32)
-            if key_override is not None:
-                rest = np.asarray(key_override[1],
-                                  np.uint32).reshape(-1, 2)
-                if max_new_tokens > 1:
-                    step_keys[:max_new_tokens - 1] = \
-                        rest[:max_new_tokens - 1]
-            elif max_new_tokens > 1:
-                step_keys[:max_new_tokens - 1] = np.asarray(
-                    jax.random.split(key, max_new_tokens - 1))
-        with spans.span("prefill_fetch", rid=rid):
-            first_host = int(runtime.device_fetch(first)[0])
-        return PrefillResult(first_token=first_host, pcache=pcache,
-                             dpcache=dpcache, step_keys=step_keys,
-                             bucket=bucket, n_steps=int(max_new_tokens),
-                             prompt_len=prompt_len)
+            # Queued ahead of whatever program is dispatched next.
+            first.copy_to_host_async()
+        return PrefillFlight(first, PrefillResult(
+            first_token=None, pcache=pcache, dpcache=dpcache,
+            step_keys=step_keys, bucket=bucket,
+            n_steps=int(max_new_tokens), prompt_len=prompt_len))
 
     def prefill_chunks(self, prompt, max_new_tokens, rng, sampling,
                        chunk_size, prefix_len=0, gather_vec=None,

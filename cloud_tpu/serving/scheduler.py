@@ -9,7 +9,12 @@ Two threads around a `DecodeEngine`:
   longest-prefill-first), reserves KV pages for cache MISSES (BLOCKING
   when the pool is exhausted — backpressure, never OOM; a blocked
   reservation applies LRU eviction pressure to the prefix cache), and
-  runs miss prefills off the tick's critical path;
+  runs miss prefills off the tick's critical path. It keeps ONE
+  prefill in flight: request n+1 is marked, reserved and dispatched
+  before prefill n's first token is fetched (`_prefill_loop`), and
+  whatever it waits for that is not the device (an empty queue, pages
+  only ticks can free, a hit handed over, close) first fetches the
+  prefill in flight;
 - the TICK thread owns the engine's device state: it admits prefix-
   cache HITS (the hit prefill gathers from the engine's live pool
   cache, which every tick donates — only the tick thread may read it),
@@ -130,7 +135,8 @@ from cloud_tpu.monitoring import spans
 from cloud_tpu.ops.paged_attention import group_pages, walked_tokens
 from cloud_tpu.parallel import runtime
 from cloud_tpu.serving import reqtrace
-from cloud_tpu.serving.engine import DecodeEngine, attention_shape
+from cloud_tpu.serving.engine import (DecodeEngine, attention_shape,
+                                      host_prng_key)
 from cloud_tpu.serving.faults import (HostTierCorrupt, PoolSqueezed,
                                       PrefillFailed, ServeShed,
                                       SlotEvicted, SlotHang, fault_kind)
@@ -223,6 +229,20 @@ class _ReadyItem:
     def __init__(self, request, result, pages, future, rec):
         self.request = request
         self.result = result
+        self.pages = pages
+        self.future = future
+        self.rec = rec
+
+
+class _MissFlight:
+    """A miss whose whole-prompt prefill is on the device and whose
+    first token the host has not read (admission thread only): the
+    twin of `_Flight`, one thread over."""
+    __slots__ = ("request", "flight", "pages", "future", "rec")
+
+    def __init__(self, request, flight, pages, future, rec):
+        self.request = request
+        self.flight = flight        # `engine.PrefillFlight`
         self.pages = pages
         self.future = future
         self.rec = rec
@@ -496,6 +516,12 @@ class Scheduler:
         # which the device did not wait for the host.
         self._flight = None
         self._ticks_overlapped = 0
+        # The same one thread over: the miss whose prefill is on the
+        # device with its first token unread (admission thread only),
+        # and the prefills dispatched while the one before them was
+        # still unfetched.
+        self._miss_flight = None
+        self._prefills_overlapped = 0
         # Running sums over ticks and occupied slots: the keys a slot
         # attends to, and the keys the paged kernel's walk fetches for
         # that depth (whole groups of `_kv_group` pages; the kernel's
@@ -990,30 +1016,46 @@ class Scheduler:
     # -- admission/prefill thread -------------------------------------
 
     def _prefill_loop(self):
+        """The admission thread: a pipeline of depth one between the
+        host and the device, the twin of `_tick_loop`'s. A whole-prompt
+        miss is marked, decided, reserved and DISPATCHED, and only then
+        is the prefill before it fetched, marked `first` and handed to
+        the tick thread (`_admit_one`): the next request's host work
+        runs while a prefill is on the device, which needs none of it.
+        The prefill in flight is collected (`_collect_prefill`) before
+        the thread waits for anything but the device: an empty queue,
+        a reservation only ticks can satisfy, a hit handed to the tick
+        thread, the end of the loop. So a first token never waits for
+        an arrival. (With `prefill_chunk` set every miss is the tick
+        thread's, a chunk at a time, and nothing is ever in flight
+        here.)"""
         runtime.set_phase("serve_prefill")
-        while not self._stop.is_set():
-            window = self._next_window()
-            if not window:
-                continue
-            # Longest-radix-match-first within the FCFS window, then
-            # longest-prefill-first (stable sort: ties stay FCFS). Hits
-            # admit cheapest and re-touch their prefix before LRU
-            # pressure can evict it; among misses, big prefills hold
-            # their slot longest, so starting them earliest minimizes
-            # tail latency.
-            window.sort(key=lambda item: (-self._probe(item[0]),
-                                          -self._bucket(item[0])))
-            admitted = 0
-            for request, future, rec, meta in window:
-                if self._stop.is_set():
-                    return
-                # Its own turn begins: the window's requests are taken
-                # one after another, so the time since `dequeued` is
-                # the wait for the prefills ahead of it.
-                self._mark(rec, "admit")
-                with spans.span("admit", rid=rec.rid):
-                    admitted += self._admit_turn(request, future, rec,
-                                                 meta, admitted)
+        try:
+            while not self._stop.is_set():
+                self._admit_window(self._next_window())
+        finally:
+            self._collect_prefill()
+
+    def _admit_window(self, window):
+        # Longest-radix-match-first within the FCFS window, then
+        # longest-prefill-first (stable sort: ties stay FCFS). Hits
+        # admit cheapest and re-touch their prefix before LRU
+        # pressure can evict it; among misses, big prefills hold
+        # their slot longest, so starting them earliest minimizes
+        # tail latency.
+        window.sort(key=lambda item: (-self._probe(item[0]),
+                                      -self._bucket(item[0])))
+        admitted = 0
+        for request, future, rec, meta in window:
+            if self._stop.is_set():
+                return
+            # Its own turn begins: the window's requests are taken
+            # one after another, so the time since `dequeued` is
+            # the wait for the turns ahead of it.
+            self._mark(rec, "admit")
+            with spans.span("admit", rid=rec.rid):
+                admitted += self._admit_turn(request, future, rec,
+                                             meta, admitted)
 
     def _admit_turn(self, request, future, rec, meta, admitted):
         """One request's turn in its window: decision, then admission.
@@ -1034,18 +1076,27 @@ class Scheduler:
         try:
             self._admit_one(request, future, rec)
         except BaseException as exc:  # noqa: BLE001
-            if request.max_new_tokens > 1:
-                self._pending_inserts -= 1
-            self._trace_fail(rec.rid, exc)
-            future.set_exception(exc)
+            self._fail_admission(request, future, rec, exc)
         return 1
+
+    def _fail_admission(self, request, future, rec, exc):
+        if request.max_new_tokens > 1:
+            self._pending_inserts -= 1
+        self._trace_fail(rec.rid, exc)
+        future.set_exception(exc)
 
     def _next_window(self):
         window = []
         try:
-            window.append(self._admit_q.get(timeout=0.05))
+            window.append(self._admit_q.get_nowait())
         except queue.Empty:
-            return window
+            # Nothing to run ahead with: the prefill in flight is
+            # fetched before the thread waits for an arrival.
+            self._collect_prefill()
+            try:
+                window.append(self._admit_q.get(timeout=0.05))
+            except queue.Empty:
+                return window
         while len(window) < self._admission_window:
             try:
                 window.append(self._admit_q.get_nowait())
@@ -1079,9 +1130,16 @@ class Scheduler:
         need = self.pool.pages_needed(len(request.prompt),
                                       request.max_new_tokens,
                                       slack=self._spec_slack())
-        pages = None
         t_reserve0 = time.monotonic()
         with spans.span("admit_reserve", rid=rec.rid):
+            # What is free now, after LRU pressure where that is
+            # short; beyond it only ticks free pages, and the prefill
+            # in flight is not left waiting for them.
+            pages = self._reserve_with_pressure(need, timeout=0)
+            if pages is None and self.trie is not None:
+                pages = self.pool.reserve(need, timeout=0)
+            if pages is None:
+                self._collect_prefill()
             while pages is None and not self._stop.is_set():
                 pages = self._reserve_with_pressure(need, timeout=0.2)
         if pages is None:
@@ -1222,7 +1280,9 @@ class Scheduler:
             # Prefix-cache hit: hand the whole admission to the tick
             # thread — the gather-prefill reads the engine's live pool
             # cache, which every tick donates, so no other thread may
-            # read it concurrently.
+            # read it concurrently. The miss in flight goes first, as
+            # its turn came first.
+            self._collect_prefill()
             rec.path = "hit"
             with self._ready_lock:
                 self._ready.append(_HitTicket(request, future, rec))
@@ -1240,10 +1300,11 @@ class Scheduler:
                 self._fail_closed(future, rec)
                 return
             try:
-                result = self._engine_prefill(
+                self._chaos_prefill()
+                flight = self.engine.prefill_dispatch(
                     np.asarray(request.prompt, np.int32),
                     request.max_new_tokens,
-                    jax.random.PRNGKey(request.rng_seed), sampling,
+                    host_prng_key(request.rng_seed), sampling,
                     rid=rec.rid)
             except PrefillFailed as exc:
                 if pages:
@@ -1256,6 +1317,33 @@ class Scheduler:
                     self.pool.free(pages)
                 raise
             break
+        # This prefill is on the device; now the one before it.
+        behind, self._miss_flight = self._miss_flight, _MissFlight(
+            request, flight, pages, future, rec)
+        if behind is not None:
+            self._prefills_overlapped += 1
+            self._finish_miss(behind)
+
+    def _collect_prefill(self):
+        """Fetches the prefill in flight, if there is one (admission
+        thread only), and hands its request on."""
+        behind, self._miss_flight = self._miss_flight, None
+        if behind is not None:
+            self._finish_miss(behind)
+
+    def _finish_miss(self, item):
+        """The fetch of a dispatched miss's first token (the TTFT
+        point), then the hand-over to the tick thread. A failure that
+        surfaces here is the failure of `item`'s request, not of the
+        one whose turn it is."""
+        request, future, rec = item.request, item.future, item.rec
+        try:
+            result = self.engine.prefill_finish(item.flight, rid=rec.rid)
+        except BaseException as exc:  # noqa: BLE001
+            if item.pages:
+                self.pool.free(item.pages)
+            self._fail_admission(request, future, rec, exc)
+            return
         self._first_token(rec, result, hit=False)
         if request.max_new_tokens == 1:
             # Completes at prefill: no slot, no pages, no tick.
@@ -1265,7 +1353,7 @@ class Scheduler:
                            prefix_len=0)
             return
         with self._ready_lock:
-            self._ready.append(_ReadyItem(request, result, pages,
+            self._ready.append(_ReadyItem(request, result, item.pages,
                                           future, rec))
         self._wake.set()
 
@@ -1324,7 +1412,7 @@ class Scheduler:
             return
         chunked = self.engine.prefill_chunks(
             np.asarray(request.prompt, np.int32),
-            request.max_new_tokens, jax.random.PRNGKey(request.rng_seed),
+            request.max_new_tokens, host_prng_key(request.rng_seed),
             sampling, self._prefill_chunk, rid=rec.rid)
         self._enqueue_chunk_item(_ChunkItem(
             "miss", request, chunked, future, rec, pages=pages))
@@ -1519,16 +1607,22 @@ class Scheduler:
 
     # -- graftstorm: chaos + slot fault recovery ----------------------
 
-    def _engine_prefill(self, *args, **kwargs):
-        """Every prefill dispatch funnels here so an armed chaos
+    def _chaos_prefill(self):
+        """Every prefill dispatch passes here first, so an armed chaos
         `prefill_fail` hits whichever thread prefills next (admission
-        thread for misses, tick thread for hits/requeues)."""
+        thread for misses, tick thread for hits/requeues), before
+        anything of it is on the device."""
         with self._chaos_lock:
             armed = self._prefill_fail_armed > 0
             if armed:
                 self._prefill_fail_armed -= 1
         if armed:
             raise PrefillFailed("graftchaos: injected prefill_fail")
+
+    def _engine_prefill(self, *args, **kwargs):
+        """The tick thread's prefill: dispatch and fetch back to back
+        (it reads the pool cache the next tick donates)."""
+        self._chaos_prefill()
         return self.engine.prefill(*args, **kwargs)
 
     def _chaos_pre_tick(self):
@@ -1924,7 +2018,7 @@ class Scheduler:
             chunked = self.engine.prefill_chunks(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
-                jax.random.PRNGKey(request.rng_seed),
+                host_prng_key(request.rng_seed),
                 self._sampling(request), self._prefill_chunk,
                 key_override=key_override, rid=rec.rid)
             self._enqueue_chunk_item(_ChunkItem(
@@ -1937,7 +2031,7 @@ class Scheduler:
             result = self._engine_prefill(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
-                jax.random.PRNGKey(request.rng_seed),
+                host_prng_key(request.rng_seed),
                 self._sampling(request), key_override=key_override,
                 rid=rec.rid)
         except PrefillFailed as exc:
@@ -2033,7 +2127,7 @@ class Scheduler:
             # content live until then.
             chunked = self.engine.prefill_chunks(
                 np.asarray(prompt, np.int32), request.max_new_tokens,
-                jax.random.PRNGKey(request.rng_seed),
+                host_prng_key(request.rng_seed),
                 self._sampling(request), self._prefill_chunk,
                 prefix_len=prefix_len,
                 gather_vec=self.pool.page_vec(held), rid=rec.rid)
@@ -2046,7 +2140,7 @@ class Scheduler:
         try:
             result = self._engine_prefill(
                 np.asarray(prompt, np.int32), request.max_new_tokens,
-                jax.random.PRNGKey(request.rng_seed),
+                host_prng_key(request.rng_seed),
                 self._sampling(request), prefix_len=prefix_len,
                 gather_vec=self.pool.page_vec(held), rid=rec.rid)
         except PrefillFailed as exc:
@@ -2110,7 +2204,7 @@ class Scheduler:
             chunked = self.engine.prefill_chunks(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
-                jax.random.PRNGKey(request.rng_seed),
+                host_prng_key(request.rng_seed),
                 self._sampling(request), self._prefill_chunk,
                 rid=rec.rid)
             self._enqueue_chunk_item(_ChunkItem(
@@ -2121,7 +2215,7 @@ class Scheduler:
             result = self._engine_prefill(
                 np.asarray(request.prompt, np.int32),
                 request.max_new_tokens,
-                jax.random.PRNGKey(request.rng_seed),
+                host_prng_key(request.rng_seed),
                 self._sampling(request), rid=rec.rid)
         except PrefillFailed as exc:
             self.pool.free(pages)
@@ -2695,6 +2789,7 @@ class Scheduler:
         self._chunks_dispatched = 0
         self._tick_paces = 0
         self._ticks_overlapped = 0
+        self._prefills_overlapped = 0
         self._kv_live_tokens = 0
         self._kv_walked_tokens = 0
         self._eva_rows_read = 0
@@ -2815,6 +2910,7 @@ class Scheduler:
             "ticks": self._ticks,
             "tick_paces": self._tick_paces,
             "ticks_overlapped": self._ticks_overlapped,
+            "prefills_overlapped": self._prefills_overlapped,
             "kv_live_tokens": self._kv_live_tokens,
             "kv_walked_tokens": self._kv_walked_tokens,
             "moe_pairs_routed": self._moe_pairs_routed,

@@ -231,8 +231,9 @@ class TestDecodeBucketing:
 
     def test_varied_prompt_lengths_share_executables(self):
         """The bucket census cap: three prompt lengths in one bucket
-        compile ONE prefill (+ one decode scan, + the cache pool's
-        one-time re-zero executable), never a per-length prefill."""
+        compile ONE prefill (+ one decode scan, + the cache pool's two
+        one-time executables: the fresh cache and the re-zero), never
+        a per-length prefill."""
         from cloud_tpu.models import TransformerLM, generate
 
         model = TransformerLM(vocab_size=17, num_layers=1, num_heads=2,
@@ -249,11 +250,12 @@ class TestDecodeBucketing:
             outs[length] = generate(model, params, p, 4,
                                     temperature=0.0)
             assert outs[length].shape == (1, length + 4)
-        # Call 1: prefill + decode scan. Call 2: +1 for the in-place
+        # Call 1: the fresh cache (one program, not a dispatch a
+        # leaf) + prefill + decode scan. Call 2: +1 for the in-place
         # zero of the reacquired pool cache (the executable that
         # replaced per-call HBM allocation) — and nothing else.
         stats = runtime.compile_stats()
-        assert stats["n_traces"] == 3, stats
+        assert stats["n_traces"] == 4, stats
         # Every further length in the bucket rides entirely warm.
         runtime.reset_compile_stats()
         outs[7] = generate(model, params, prompt, 4, temperature=0.0)
@@ -267,6 +269,56 @@ class TestDecodeBucketing:
                               temperature=0.0, bucket_prompts=False)
         np.testing.assert_array_equal(np.asarray(outs[5]),
                                       np.asarray(unbucketed))
+
+
+class TestDecodeCachePool:
+
+    @staticmethod
+    def _decoder():
+        from cloud_tpu.models import TransformerLM
+        return TransformerLM(vocab_size=17, num_layers=2, num_heads=2,
+                             d_model=16, d_ff=32, max_seq_len=32,
+                             compute_dtype=jnp.float32).clone(
+                                 decode=True, dropout_rate=0.0)
+
+    def test_fresh_cache_is_one_program(self):
+        """A cache the pool cannot supply is made by ONE program,
+        traced once a (decoder, batch), not a dispatch a leaf."""
+        from cloud_tpu.models import decoding
+        decoder = self._decoder()
+        runtime.reset_compile_stats()
+        cache = decoding.empty_cache(decoder, 1)
+        assert runtime.compile_stats()["n_traces"] == 1
+        again = decoding.empty_cache(decoder, 1)
+        assert runtime.compile_stats()["n_traces"] == 1
+        shapes = decoding._cache_shapes(decoder, 1)
+        leaves, want = (jax.tree_util.tree_leaves(t)
+                        for t in (cache, shapes))
+        assert len(leaves) == len(want) > 4
+        for leaf, shape in zip(leaves, want):
+            assert (leaf.shape, leaf.dtype) == (shape.shape, shape.dtype)
+            assert not np.asarray(leaf).any()
+        # Two caches, not one handed out twice.
+        assert all(a is not b for a, b in zip(
+            leaves, jax.tree_util.tree_leaves(again)))
+
+    def test_acquire_takes_the_cache_parked_longest(self, monkeypatch):
+        """What consumed the cache parked last may still be reading it
+        on the device; the one parked first is the one re-zeroed."""
+        from cloud_tpu.models import decoding
+        decoder = self._decoder()
+        decoding.clear_cache_pool()
+        monkeypatch.setattr(decoding, "_zero_in_place",
+                            lambda: (lambda cache: cache))
+        first, second = {"parked": 1}, {"parked": 2}
+        decoding.release_cache(decoder, 1, first)
+        decoding.release_cache(decoder, 1, second)
+        decoding.release_cache(decoder, 1, {"parked": 3})  # pool full
+        assert decoding.acquire_cache(decoder, 1) is first
+        assert decoding.acquire_cache(decoder, 1) is second
+        fresh = decoding.acquire_cache(decoder, 1)
+        assert "parked" not in fresh
+        decoding.clear_cache_pool()
 
 
 class TestPersistentCache:

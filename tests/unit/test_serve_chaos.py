@@ -472,3 +472,103 @@ class TestFaultDrainsThePipeline:
         for req, res in zip(requests, results):
             np.testing.assert_array_equal(res.tokens,
                                           _oracle(model, params, req))
+
+
+class TestPrefillFaultsWithOneInFlight:
+    """The admission thread keeps one prefill in flight; a fault goes
+    to the request it belongs to, and the other one is unharmed (fast
+    tier)."""
+
+    @staticmethod
+    def _pair():
+        from cloud_tpu.serving import ServeRequest
+        return [
+            ServeRequest(prompt=[5, 6, 7, 8, 9], max_new_tokens=6,
+                         temperature=0.0, rng_seed=91),
+            ServeRequest(prompt=[9, 8, 7], max_new_tokens=6,
+                         temperature=0.9, top_p=0.9, rng_seed=92),
+        ]
+
+    def test_prefill_fail_armed_behind_a_dispatch(self, model, params,
+                                                  monkeypatch):
+        """Armed while the first prefill is on the device: the hook
+        fires before anything of the SECOND is dispatched, so the
+        second frees its pages, is requeued and retried; the first is
+        fetched behind the retry like any other."""
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import PrefillLog, check_prefill_order
+        requests = self._pair()
+        sched = Scheduler(model, params, slots=2)
+        log = PrefillLog(sched, monkeypatch)
+
+        def arm(n):
+            if n == 0:
+                with sched._chaos_lock:
+                    sched._prefill_fail_armed += 1
+        log.on_dispatch = arm
+        seen = []
+        note = sched._note_fault
+
+        def logged_note(fault, rid=None, slot=None):
+            seen.append((rid, sched.pool.available()))
+            return note(fault, rid=rid, slot=slot)
+        monkeypatch.setattr(sched, "_note_fault", logged_note)
+        with sched:
+            with log.hold():
+                futures = [sched.submit(r, timeout=30) for r in requests]
+            results = [f.result(timeout=300) for f in futures]
+            stats = sched.stats()
+            sched.assert_drained(clear_prefix=True)
+            assert sched.pool.leak_report() == {}
+            held_by_first = sched.pool.pages_needed(5, 6)
+            capacity = sched.pool.capacity
+        entries = [e for e in log.since() if e[0] != "wait"]
+        assert entries == [("dispatch", 0), ("dispatch", 1), ("fetch", 0),
+                           ("fetch", 1)], entries
+        check_prefill_order(log.since())
+        first, second = (r.trace.rid for r in results)
+        assert log.rids == [first, second]
+        # The fault is the second's, and its pages were back in the
+        # pool when it was noted: only the first's were out.
+        assert seen == [(second, capacity - held_by_first)]
+        assert stats["faults"] == {"prefill_fail": 1}
+        assert stats["requeues"] == 1
+        assert stats["prefills_overlapped"] == 1
+        for req, res in zip(requests, results):
+            np.testing.assert_array_equal(res.tokens,
+                                          _oracle(model, params, req))
+
+    def test_failure_at_the_fetch_is_its_own_requests(self, model, params,
+                                                      monkeypatch):
+        """A failure that only surfaces when the first token is
+        fetched, inside the NEXT request's turn: the request in flight
+        fails and frees its pages, the one whose turn it is goes on."""
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import PrefillLog, check_prefill_order
+        requests = self._pair()
+        sched = Scheduler(model, params, slots=2)
+        log = PrefillLog(sched, monkeypatch)
+        finish = sched.engine.prefill_finish
+        first_flight = []
+
+        def failing_finish(flight, rid=None):
+            if not first_flight:
+                first_flight.append(flight)
+                raise RuntimeError("device lost behind the dispatch")
+            return finish(flight, rid=rid)
+        monkeypatch.setattr(sched.engine, "prefill_finish", failing_finish)
+        with sched:
+            with log.hold():
+                futures = [sched.submit(r, timeout=30) for r in requests]
+            with pytest.raises(RuntimeError, match="device lost"):
+                futures[0].result(timeout=300)
+            survivor = futures[1].result(timeout=300)
+            stats = sched.stats()
+            sched.assert_drained(clear_prefix=True)
+            assert sched.pool.leak_report() == {}
+        assert [e for e in log.since() if e[0] == "dispatch"] == [
+            ("dispatch", 0), ("dispatch", 1)]
+        assert stats["requests_completed"] == 1
+        assert stats["prefills_overlapped"] == 1
+        np.testing.assert_array_equal(
+            survivor.tokens, _oracle(model, params, requests[1]))

@@ -626,3 +626,357 @@ def test_finished_slot_retires_itself(model, params, engine):
                 valid = old["slot_valid"][0][:flat_now.shape[0]]
                 np.testing.assert_array_equal(flat_now[valid],
                                               flat_old[valid])
+
+
+# -- a request's key schedule, on the host (fast tier) ----------------
+
+KEY_CAP = 160  # the engines below: step_keys of KEY_CAP - 1 rows
+
+
+@pytest.mark.parametrize("seed", [0, 1, 7, 2**31 - 1, 2**31 + 5,
+                                  2**32 - 1, 2**40 + 3, -1, -5])
+def test_host_key_is_prngkey(seed):
+    import jax
+
+    from cloud_tpu.serving.engine import host_prng_key
+    key = host_prng_key(seed)
+    assert key.dtype == np.uint32 and key.shape == (2,)
+    np.testing.assert_array_equal(key, np.asarray(jax.random.PRNGKey(seed)))
+
+
+@pytest.mark.parametrize("n", [1, 2, 31, 127, KEY_CAP - 1])
+def test_host_split_is_jax_split_row_for_row(n):
+    import jax
+
+    from cloud_tpu.serving.engine import host_split
+    for seed in (0, 3, 12345, 2**31 + 7):
+        key = jax.random.PRNGKey(seed)
+        got = host_split(np.asarray(key), n)
+        assert got.dtype == np.uint32 and got.shape == (n, 2)
+        np.testing.assert_array_equal(got,
+                                      np.asarray(jax.random.split(key, n)))
+        # A device key reads back to the same rows.
+        np.testing.assert_array_equal(host_split(key, n), got)
+
+
+@pytest.fixture(scope="module")
+def key_engine(model, params):
+    from cloud_tpu.serving.engine import DecodeEngine
+    return DecodeEngine(model, params, slots=2, page_size=4, num_pages=17,
+                        max_new_cap=KEY_CAP)
+
+
+def _run_prefill(engine, form, prompt, max_new, rng, sampling, **kwargs):
+    if form == "whole":
+        return engine.prefill(prompt, max_new, rng, sampling, **kwargs)
+    chunked = engine.prefill_chunks(prompt, max_new, rng, sampling, 2,
+                                    **kwargs)
+    while True:
+        result = chunked.step()
+        if result is not None:
+            return result
+
+
+@pytest.mark.parametrize("form", ["whole", "chunked"])
+@pytest.mark.parametrize("n", [1, 2, 31, 127, KEY_CAP - 1])
+def test_sampled_prefill_arms_generates_schedule(key_engine, form, n):
+    """`step_keys` of a sampled request: `generate()`'s schedule, the
+    rows `jax.random.split(key, max_new_tokens - 1)` gives, from a host
+    key and from a device key alike; and the prefill samples with the
+    key `generate()` would."""
+    import jax
+
+    from cloud_tpu.serving.engine import host_prng_key
+    sampling = dict(temperature=0.8, top_k=None, top_p=None,
+                    eos_token=None)
+    prompt = np.asarray([5, 9, 3], np.int32)
+    firsts = []
+    for seed in (4, 2**31 + 9):
+        rng = jax.random.PRNGKey(seed)
+        key, _ = jax.random.split(rng)
+        want = np.asarray(jax.random.split(key, n))
+        for given in (host_prng_key(seed), rng):
+            result = _run_prefill(key_engine, form, prompt, n + 1, given,
+                                  sampling)
+            keys = result.step_keys
+            assert keys.dtype == np.uint32
+            assert keys.shape == (KEY_CAP - 1, 2)
+            np.testing.assert_array_equal(keys[:n], want)
+            assert not keys[n:].any()
+            firsts.append(result.first_token)
+            key_engine.release_prefill(result)
+    assert firsts[0] == firsts[1] and firsts[2] == firsts[3]
+
+
+@pytest.mark.parametrize("form", ["whole", "chunked"])
+def test_greedy_prefill_reads_no_key_and_one_token(key_engine, form,
+                                                   monkeypatch):
+    """A request with temperature 0 reads no key: no split is
+    dispatched, its `rng` is never looked at, its rows stay zero, and
+    the one thing read back from the device is its first token."""
+    import jax
+
+    from cloud_tpu.parallel import runtime
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("a greedy admission split a key")
+
+    class Untouchable:
+        def __array__(self, *args, **kwargs):
+            raise AssertionError("a greedy admission read its rng")
+
+    fetched = []
+    fetch = runtime.device_fetch
+
+    def logged_fetch(tree):
+        fetched.append(tree)
+        return fetch(tree)
+
+    monkeypatch.setattr(jax.random, "split", refuse)
+    monkeypatch.setattr(runtime, "device_fetch", logged_fetch)
+    sampling = dict(temperature=0.0, top_k=None, top_p=None,
+                    eos_token=None)
+    result = _run_prefill(key_engine, form, np.asarray([5, 9, 3], np.int32),
+                          40, Untouchable(), sampling)
+    assert result.step_keys.shape == (KEY_CAP - 1, 2)
+    assert not result.step_keys.any()
+    (first,) = fetched
+    assert first.shape == (1,) and int(first[0]) == result.first_token
+    key_engine.release_prefill(result)
+
+
+def test_key_override_rows_reach_the_schedule(key_engine):
+    """The requeue hook: the prefill key and the rest of the ORIGINAL
+    schedule are taken as handed in, whatever the seed would give."""
+    import jax
+
+    sampling = dict(temperature=0.8, top_k=None, top_p=None,
+                    eos_token=None)
+    rng = jax.random.PRNGKey(11)
+    key, _ = jax.random.split(rng)
+    rows = np.asarray(jax.random.split(key, 9))
+    prompt = np.asarray([5, 9, 3], np.int32)
+    whole = key_engine.prefill(prompt, 10, rng, sampling)
+    # After three tokens: row 2 samples the continuation's first
+    # token, rows 3.. its ticks.
+    cont = np.concatenate([prompt, [whole.first_token, 1, 2]]).astype(
+        np.int32)
+    for form in ("whole", "chunked"):
+        result = _run_prefill(key_engine, form, cont, 7, None, sampling,
+                              key_override=(rows[2], rows[3:]))
+        np.testing.assert_array_equal(result.step_keys[:6], rows[3:])
+        assert not result.step_keys[6:].any()
+        key_engine.release_prefill(result)
+    key_engine.release_prefill(whole)
+
+
+# -- the one-deep prefill pipeline (fast tier) ------------------------
+
+
+def _miss(i, max_new=6, **kwargs):
+    """Distinct first tokens: no request is another's prefix."""
+    return _greedy([10 + i, 5, 7], max_new, seed=i, **kwargs)
+
+
+class TestPrefillPipeline:
+    """Prefill n+1 is on the device before prefill n's first token is
+    fetched, and the prefill in flight is collected before the
+    admission thread waits for anything but the device."""
+
+    def test_dispatch_runs_one_ahead_within_a_window(self, model, params,
+                                                     monkeypatch):
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import PrefillLog, check_prefill_order
+        sched = Scheduler(model, params, slots=4, page_size=16)
+        log = PrefillLog(sched, monkeypatch)
+        with sched:
+            sched.warmup([4], sampling_configs=[(("temperature",
+                                                  0.0),)])
+            # Warm-up's own overlaps are not left for the traffic's
+            # count, and nothing of it is still in flight.
+            assert sched.stats()["prefills_overlapped"] == 0
+            assert sched._miss_flight is None
+            check_prefill_order(log.since())
+            mark = log.mark()
+            requests = [_miss(i) for i in range(4)]
+            with log.hold():
+                futures = [sched.submit(r, timeout=30) for r in requests]
+            results = [f.result(timeout=300) for f in futures]
+            sched.assert_drained()
+            stats = sched.stats()
+        entries = [e for e in log.since(mark) if e[0] != "wait"]
+        # One window of four misses: each is dispatched before the one
+        # ahead of it is fetched; the last is fetched when the queue is
+        # found empty, not at the next arrival.
+        n0 = entries[0][1]
+        assert entries == [
+            ("dispatch", n0), ("dispatch", n0 + 1), ("fetch", n0),
+            ("dispatch", n0 + 2), ("fetch", n0 + 1),
+            ("dispatch", n0 + 3), ("fetch", n0 + 2), ("fetch", n0 + 3)]
+        prefills, overlapped = check_prefill_order(log.since(mark))
+        assert (prefills, overlapped) == (4, 3)
+        assert stats["prefills_overlapped"] == overlapped
+        assert stats["prefix_misses"] == prefills
+        # close() left nothing in flight either.
+        check_prefill_order(log.since())
+        assert sched._miss_flight is None
+        for req, res in zip(requests, results):
+            np.testing.assert_array_equal(res.tokens,
+                                          _oracle(model, params, req))
+            # The record's phases still tile TTFT.
+            phases = res.trace.phases()
+            assert sum(phases[k] for k in (
+                "queue", "window", "reserve", "prefill")) == \
+                pytest.approx(res.ttft_s, abs=1e-9)
+
+    def test_lone_request_is_fetched_without_an_arrival(self, model,
+                                                        params,
+                                                        monkeypatch):
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import PrefillLog, check_prefill_order
+        sched = Scheduler(model, params, slots=2, page_size=16)
+        log = PrefillLog(sched, monkeypatch)
+        with sched:
+            request = _miss(0)
+            result = sched.submit(request, timeout=30).result(timeout=300)
+            entries = log.since()
+            stats = sched.stats()
+        at = entries.index(("dispatch", 0))
+        # Straight after its dispatch, before the thread looks at its
+        # queue again.
+        assert entries[at + 1] == ("fetch", 0), entries
+        check_prefill_order(entries)
+        assert stats["prefills_overlapped"] == 0
+        np.testing.assert_array_equal(result.tokens,
+                                      _oracle(model, params, request))
+
+    def test_collected_before_a_reservation_that_waits(self, model,
+                                                       params,
+                                                       monkeypatch):
+        """Two requests that each need most of the pool: the second's
+        pages are freed only by ticks of the first, so the first is
+        fetched and handed over before the thread waits for them."""
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import PrefillLog, check_prefill_order
+        sched = Scheduler(model, params, slots=2, page_size=4,
+                          num_pages=8, prefix_cache=False)
+        log = PrefillLog(sched, monkeypatch)
+        requests = [_miss(i, max_new=14) for i in range(2)]
+        with sched:
+            with log.hold():
+                futures = [sched.submit(r, timeout=30) for r in requests]
+            results = [f.result(timeout=300) for f in futures]
+            sched.assert_drained()
+            stats = sched.stats()
+            assert sched.pool.leak_report() == {}
+        entries = log.since()
+        check_prefill_order(entries)
+        at = entries.index(("dispatch", 0))
+        assert entries[at + 1:at + 3] == [("fetch", 0),
+                                          ("wait", "pages")], entries
+        assert stats["prefills_overlapped"] == 0
+        assert stats["reserve_wait"]["count"] == 2
+        for req, res in zip(requests, results):
+            np.testing.assert_array_equal(res.tokens,
+                                          _oracle(model, params, req))
+
+    def test_collected_before_a_hit_is_handed_over(self, model, params,
+                                                   monkeypatch):
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import PrefillLog, check_prefill_order
+        # Windows of one: the hit's turn comes with the miss in flight.
+        sched = Scheduler(model, params, slots=4, page_size=4,
+                          admission_window=1)
+        log = PrefillLog(sched, monkeypatch)
+        shared = [9, 8, 7, 6, 5, 4, 3, 2]
+        first = _greedy(shared + [1], 4, seed=1)
+        miss = _miss(0)
+        hit = _greedy(shared + [11, 12], 4, seed=2)
+        with sched:
+            sched.submit(first, timeout=30).result(timeout=300)
+            mark = log.mark()
+            with log.hold():
+                futures = [sched.submit(r, timeout=30)
+                           for r in (miss, hit)]
+            results = [f.result(timeout=300) for f in futures]
+            sched.assert_drained()
+        entries = [e for e in log.since(mark) if e != ("wait", "queue")]
+        n = entries[0][1]
+        assert entries == [("dispatch", n), ("fetch", n),
+                           ("wait", "hit")], entries
+        check_prefill_order(log.since())
+        assert results[1].prefix_len == 8 and results[0].prefix_len == 0
+        for req, res in zip((miss, hit), results):
+            np.testing.assert_array_equal(res.tokens,
+                                          _oracle(model, params, req))
+
+    def test_collected_at_close(self, model, params, monkeypatch):
+        """A stop that finds a prefill in flight: its token is fetched,
+        its request fails like every pending one, its pages go back."""
+        from cloud_tpu.serving import Scheduler
+        from tests.unit.tick_log import PrefillLog, check_prefill_order
+        sched = Scheduler(model, params, slots=2, page_size=16)
+        log = PrefillLog(sched, monkeypatch)
+        log.on_dispatch = lambda n: sched._stop.set()
+        sched.start()
+        try:
+            future = sched.submit(_miss(0), timeout=30)
+            sched._prefill_thread.join(timeout=60)
+            assert not sched._prefill_thread.is_alive()
+        finally:
+            sched.close()
+        entries = [e for e in log.since() if e[0] != "wait"]
+        assert entries == [("dispatch", 0), ("fetch", 0)]
+        check_prefill_order(log.since())
+        assert sched._miss_flight is None
+        with pytest.raises(RuntimeError, match="scheduler closed"):
+            future.result(timeout=30)
+        assert sched.pool.leak_report() == {}
+
+
+@pytest.mark.parametrize("engine", ["plain", "speculative"])
+def test_overlapping_prefills_stay_bit_identical(model, params, engine,
+                                                 monkeypatch):
+    """Sampled and greedy requests admitted in overlapping prefills,
+    with no eager key program anywhere in their admission: every
+    result is its solo `generate()`, and the pool ends leak-free."""
+    import jax
+
+    from cloud_tpu.serving import Scheduler, ServeRequest
+    from tests.unit.tick_log import PrefillLog, check_prefill_order
+    target, extra = params, {}
+    if engine == "speculative":
+        target, extra = _spec_pair(model, params)
+    configs = [dict(temperature=0.0), dict(temperature=1.0),
+               dict(temperature=0.7, top_k=8)]
+    rng = np.random.default_rng(35)
+    requests = [ServeRequest(
+        prompt=[20 + i] + rng.integers(1, 64, (2 + i % 2,)).tolist(),
+        max_new_tokens=int(rng.integers(3, 8)), rng_seed=300 + i,
+        **configs[i % 3]) for i in range(8)]
+    refs = [_oracle(model, target, r) for r in requests]
+    sched = Scheduler(model, target, slots=4, page_size=16, **extra)
+    log = PrefillLog(sched, monkeypatch)
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("an admission made a key on the device")
+
+    with sched:
+        # Every program is traced here, before the names are taken away.
+        sched.warmup([4], sampling_configs=[tuple(c.items())
+                                            for c in configs])
+        mark = log.mark()
+        monkeypatch.setattr(jax.random, "split", refuse)
+        monkeypatch.setattr(jax.random, "PRNGKey", refuse)
+        with log.hold():
+            futures = [sched.submit(r, timeout=30) for r in requests]
+        results = [f.result(timeout=300) for f in futures]
+        sched.assert_drained(clear_prefix=True)
+        stats = sched.stats()
+        assert sched.pool.leak_report() == {}
+    prefills, overlapped = check_prefill_order(log.since(mark))
+    assert prefills == 8 and overlapped == 7
+    assert stats["prefills_overlapped"] == overlapped
+    for i, (ref, res) in enumerate(zip(refs, results)):
+        np.testing.assert_array_equal(
+            res.tokens, ref, err_msg="request {}".format(i))

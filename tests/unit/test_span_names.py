@@ -390,6 +390,75 @@ def test_tick_spans_nest_as_the_table_says():
         assert any(inside(e, [fetch]) for e in by_name["d2h_fetch"])
 
 
+def test_admission_spans_nest_as_the_table_says(monkeypatch):
+    """The admission thread's spans under the one-deep prefill
+    pipeline: a `serve_prefill` inside an `admit` goes as far as the
+    dispatch (one `prefill_host`, one `prefill_dispatch`); a
+    `prefill_fetch` inside an `admit` stands outside that `serve_prefill`
+    and is the fetch of the prefill BEFORE; the last of a window is
+    fetched outside every `admit`. `engine.prefill()`, the tick
+    thread's form, holds all three."""
+    from cloud_tpu.models import TransformerLM
+    from cloud_tpu.serving import Scheduler, ServeRequest
+    from tests.unit.tick_log import PrefillLog
+
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=32, d_ff=64, max_seq_len=32,
+                          compute_dtype=F32)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+
+    def spans_of(tracer):
+        by_name = {}
+        for name, _, start, dur in tracer.events():
+            by_name.setdefault(name, []).append((start, start + dur))
+        return by_name
+
+    def inside(inner, outers):
+        return any(lo <= inner[0] and inner[1] <= hi for lo, hi in outers)
+
+    sched = Scheduler(model, params, slots=4, page_size=8)
+    tracer = spans.install()
+    sampling = dict(temperature=0.0, top_k=None, top_p=None,
+                    eos_token=None)
+    sched.engine.release_prefill(sched.engine.prefill(
+        np.asarray([3, 5, 7], np.int32), 4, None, sampling))
+    whole = spans_of(tracer)
+    (outer,) = whole["serve_prefill"]
+    for name in ("prefill_host", "prefill_dispatch", "prefill_fetch"):
+        (event,) = whole[name]
+        assert inside(event, [outer]), name
+    spans.uninstall()
+
+    tracer = spans.install()
+    log = PrefillLog(sched, monkeypatch)
+    with sched:
+        with log.hold():
+            futures = [sched.submit(ServeRequest(
+                prompt=[10 + i, 5, 7], max_new_tokens=4, temperature=0.0))
+                for i in range(3)]
+        for future in futures:
+            future.result(timeout=300)
+        sched.assert_drained()
+        assert sched.stats()["prefills_overlapped"] == 2
+    by_name = spans_of(tracer)
+    admits, prefills = by_name["admit"], by_name["serve_prefill"]
+    assert len(admits) == len(prefills) == 3
+    assert all(inside(e, admits) for e in prefills)
+    for name in ("prefill_host", "prefill_dispatch"):
+        # Once a prefill: the second `prefill_host` (the eager key
+        # split and its read-back) is gone.
+        assert len(by_name[name]) == 3, name
+        for outer in prefills:
+            assert sum(inside(e, [outer]) for e in by_name[name]) == 1
+    fetches = by_name["prefill_fetch"]
+    assert len(fetches) == 3
+    assert not any(inside(e, prefills) for e in fetches)
+    assert sum(inside(e, admits) for e in fetches) == 2
+    for fetch in fetches:
+        assert any(inside(e, [fetch]) for e in by_name["d2h_fetch"])
+
+
 # ------------------------------------------- an expert model's scopes
 
 @pytest.fixture(scope="module")

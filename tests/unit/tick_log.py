@@ -1,6 +1,8 @@
 """What a Scheduler's tick thread does to the device, in the order it
 does it: a log for the tests of the one-deep tick pipeline
-(test_serving.py, test_autoscale.py, test_serve_chaos.py).
+(test_serving.py, test_autoscale.py, test_serve_chaos.py). Below it,
+`PrefillLog`: the same for the admission thread's one-deep prefill
+pipeline.
 
 Entries, appended by wrappers round the calls themselves:
 
@@ -14,6 +16,7 @@ Entries, appended by wrappers round the calls themselves:
     ("chaos", kind)      `_apply_chaos(event)`
 """
 
+import contextlib
 import threading
 
 
@@ -121,3 +124,138 @@ def check_order(entries):
         last = entry
     assert not flying, flying
     return ticks, overlapped
+
+
+class PrefillLog:
+    """What a Scheduler's admission thread does to the device, and what
+    it waits for, in order: a log for the tests of the one-deep
+    prefill pipeline (test_serving.py, test_serve_chaos.py).
+
+        ("dispatch", n)      `engine.prefill_dispatch` returned prefill n
+        ("fetch", n)         `engine.prefill_finish` of prefill n: the
+                             blocking fetch of its first token
+        ("wait", "queue")    a blocking `_admit_q.get`
+        ("wait", "pages")    a `pool.reserve` that may wait (timeout > 0)
+                             on the admission thread
+        ("wait", "hit")      a hit ticket made for the tick thread
+
+    `hold()` parks the admission thread at its next blocking `get`
+    until the block ends, so that what a test submits meanwhile is
+    taken off the queue as one run of windows."""
+
+    THREAD = "graftserve-prefill"
+
+    def __init__(self, sched, monkeypatch):
+        from cloud_tpu.serving import scheduler as scheduler_lib
+        self.sched = sched
+        self.entries = []
+        self.rids = []           # prefill n's request id
+        self._lock = threading.Lock()
+        self._flights = {}       # id(flight) -> n
+        self._keep = []
+        self._open = threading.Event()
+        self._open.set()
+        self._parked = threading.Event()
+        self.on_dispatch = None  # called with n on the admission thread
+        engine, pool, admit_q = sched.engine, sched.pool, sched._admit_q
+        dispatch, finish = engine.prefill_dispatch, engine.prefill_finish
+        reserve, get = pool.reserve, admit_q.get
+        ticket = scheduler_lib._HitTicket
+
+        def logged_dispatch(*args, **kwargs):
+            flight = dispatch(*args, **kwargs)
+            n = len(self._keep)
+            self._keep.append(flight)
+            self._flights[id(flight)] = n
+            self.rids.append(kwargs.get("rid"))
+            self._add("dispatch", n)
+            if self.on_dispatch is not None:
+                self.on_dispatch(n)
+            return flight
+
+        def logged_finish(flight, rid=None):
+            # `engine.prefill()` finishes its own dispatch, unlogged.
+            if id(flight) in self._flights:
+                self._add("fetch", self._flights.pop(id(flight)))
+            return finish(flight, rid=rid)
+
+        def logged_reserve(n, timeout=None):
+            if (threading.current_thread().name == self.THREAD
+                    and (timeout is None or timeout > 0)):
+                self._add("wait", "pages")
+            return reserve(n, timeout=timeout)
+
+        def logged_get(block=True, timeout=None):
+            if block and threading.current_thread().name == self.THREAD:
+                self._add("wait", "queue")
+                if not self._open.is_set():
+                    self._parked.set()
+                    self._open.wait(timeout=60)
+            return get(block=block, timeout=timeout)
+
+        class LoggedTicket(ticket):
+            # A class, not a wrapper: the tick thread tells its ready
+            # items apart with `isinstance`.
+            __slots__ = ()
+
+            def __init__(item, *args, **kwargs):
+                if threading.current_thread().name == self.THREAD:
+                    self._add("wait", "hit")
+                super().__init__(*args, **kwargs)
+
+        monkeypatch.setattr(engine, "prefill_dispatch", logged_dispatch)
+        monkeypatch.setattr(engine, "prefill_finish", logged_finish)
+        monkeypatch.setattr(pool, "reserve", logged_reserve)
+        monkeypatch.setattr(admit_q, "get", logged_get)
+        monkeypatch.setattr(scheduler_lib, "_HitTicket", LoggedTicket)
+
+    _add = TickLog._add
+    mark = TickLog.mark
+    since = TickLog.since
+
+    @contextlib.contextmanager
+    def hold(self):
+        self._parked.clear()
+        self._open.clear()
+        try:
+            assert self._parked.wait(timeout=60), \
+                "the admission thread never came back to its queue"
+            yield
+        finally:
+            self._open.set()
+
+
+def check_prefill_order(entries):
+    """Holds a `PrefillLog` (one that starts and ends with nothing in
+    flight) to the pipeline's rules and returns `(prefills,
+    overlapped)`: how many were dispatched, and how many of them while
+    the one before was still unfetched.
+
+    - prefills are fetched in the order dispatched, each once;
+    - at most one is unfetched when another is dispatched, and then
+      the older one is fetched next: dispatch n+1, fetch n, nothing
+      between;
+    - whatever the thread waits for that is not the device (an empty
+      queue, pages, the tick thread taking a hit) finds nothing in
+      flight."""
+    flying = []
+    prefills = overlapped = 0
+    last = None
+    for entry in entries:
+        kind = entry[0]
+        if kind == "dispatch":
+            assert len(flying) <= 1, (entry, flying)
+            if flying:
+                overlapped += 1
+            flying.append(entry[1])
+            prefills += 1
+        elif kind == "fetch":
+            assert flying and flying[0] == entry[1], (entry, flying)
+            flying.pop(0)
+        elif kind == "wait":
+            assert not flying, (entry, flying)
+        if len(flying) == 2:
+            assert kind == "dispatch", (last, entry)
+        last = entry
+    assert not flying, flying
+    return prefills, overlapped
