@@ -513,10 +513,10 @@ def _empty_cache_fn(decoder, batch):
     shapes = _cache_shapes(decoder, batch)
 
     @runtime.instrumented_jit
-    def empty_cache():
+    def cache_zero():
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-    return empty_cache
+    return cache_zero
 
 
 def empty_cache(decoder, batch):
@@ -538,6 +538,11 @@ def empty_cache(decoder, batch):
 # that fixes every leaf shape. Bounded per key so a burst can't pin
 # unbounded HBM; thread-safe for concurrent generate() callers.
 
+#: The two programs that make a zeroed cache, a fresh one and a parked
+#: one zeroed in place, are both jitted under this name (the trace's
+#: program line reads `jit_cache_zero`; table in monitoring/spans.py).
+CACHE_ZERO = "cache_zero"
+
 _CACHE_POOL = {}
 _CACHE_POOL_LOCK = None
 _CACHE_POOL_DEPTH = 2  # parked caches per (decoder, batch) key
@@ -556,9 +561,9 @@ def _zero_in_place():
     from cloud_tpu.parallel import runtime
 
     @functools.partial(runtime.instrumented_jit, donate_argnums=0)
-    def zero(cache):
+    def cache_zero(cache):
         return jax.tree_util.tree_map(jnp.zeros_like, cache)
-    return best_effort_donation(zero)
+    return best_effort_donation(cache_zero)
 
 
 def acquire_cache(decoder, batch):

@@ -26,6 +26,12 @@ serving/README.md, PERF.md section 3), the telemetry histograms
 tests/unit/test_span_names.py holds the code to them. `names(section)`
 parses them.
 
+A span that belongs to one request carries its `rid=`, one that
+belongs to one decode tick its `tick=` (the tick's `seq`): the table
+says which, in brackets, and in a capture the id joins the span, on
+the profiler's clock, to the record of the same request or tick
+("Records", below).
+
 Spans:
 
     step                  one epoch's step-loop section
@@ -52,18 +58,21 @@ Spans:
                           dispatch of tick n+1, then the d2h fetch of
                           tick n where one was in flight (the first
                           tick after a drain: the dispatch alone)
+                          (tick of the one dispatched)
     tick_dispatch         inside serve_tick: the `engine.tick()` call
                           and the start of its tokens' copy to the
-                          host
+                          host (tick)
     tick_fetch            the blocking fetch of a tick's tokens: of
                           the tick BEFORE the one just dispatched,
                           inside serve_tick; of a tick that is
                           drained (before a nap, an idle wait, a
                           resize, a chaos event, close), on its own
+                          (tick of the one fetched)
     tick_commit           after a tick_fetch, outside serve_tick: the
                           fetched tick's tokens to their requests,
                           completions, eviction (while the next tick
-                          is on the device, unless drained)
+                          is on the device, unless drained) (tick of
+                          the one committed)
     admit                 one request's turn in its admission window:
                           probe, decision, reservation, the dispatch
                           of its prefill and, where one was in flight,
@@ -184,19 +193,53 @@ Counters:
                           requests (a gauge, not a sum)
 
 The hot loops' jitted functions are named so (a constant beside the
-jit), and the trace's program line reads `jit_<name>`.
+jit), and the trace's program line reads `jit_<name>`. The serving
+engine notes each of its dispatches under the same name in its
+dispatch log (`DecodeEngine.take_dispatched()`), which holds no other
+names.
 
 Programs:
 
-    train_step      training/trainer.py, one optimizer step
-    serve_tick      serving/engine.py, one decode tick over all slots
-    serve_prefill   serving/engine.py, one dense prefill + first token
-    slot_insert     serving/engine.py, a prefill scattered into a slot
-    slot_evict      serving/engine.py, finished slots' rows zeroed
+    train_step           training/trainer.py, one optimizer step
+    serve_tick           serving/engine.py, one decode tick over all
+                         slots
+    serve_prefill        serving/engine.py, one dense prefill + first
+                         token: a whole prompt, a hit's suffix, or
+                         the last chunk of a chunked prefill
+    slot_insert          serving/engine.py, a prefill scattered into
+                         a slot
+    slot_evict           serving/engine.py, finished slots' rows
+                         zeroed
+    serve_prefill_chunk  serving/engine.py, a prefill that keeps the
+                         cache and samples nothing: a chunk of a
+                         chunked prefill but the last, and a draft
+                         model's prefill. The dispatch log notes
+                         EVERY chunk under this name, the last too
+    prefix_gather        serving/engine.py, a prefix hit's pages
+                         copied from the pool into its dense cache
+    slot_resize          serving/engine.py, the slots' rows moved to
+                         another rung of the ladder
+    page_snapshot        serving/engine.py, a finished request's
+                         pages gathered for the host tier
+    page_promote         serving/engine.py, host-tier pages written
+                         back into the pool
+    cache_zero           models/decoding.py, a zeroed dense cache for
+                         a prefill: a parked one zeroed in place, or
+                         a fresh one (two programs, one name)
 
-Request-scoped serving observability (one record a request, whose
-phases tile its latency) lives in serving/reqtrace.py; its JSONL export
-merges into the same Perfetto view via `monitoring/collect.py --serve`.
+Records. Two always-on records in memory, both in serving/reqtrace.py,
+both on `time.monotonic()`, neither written anywhere: one
+`RequestRecord` a request (its boundaries, whose phases tile its
+latency; `reqtrace.recent()`; its JSONL export merges into the same
+Perfetto view via `monitoring/collect.py --serve`), and one
+`TickRecord` a decode tick (`reqtrace.recent_ticks()`): its dispatch,
+fetch and commit times, what it advanced, and `dispatched`, the
+engine's dispatch log since the tick before: the programs above that
+shared the device with it, whichever thread sent them, with the naps
+and idle waits the tick thread took meanwhile. The benchmark's
+`window_*_share_pct.serve`, `tick_period_clean_ms.serve`,
+`prefill_cost_ms.serve`, `*_overlap_share_pct.serve` and
+`kv_walk_live_share_pct.serve` are read from it.
 """
 
 import json

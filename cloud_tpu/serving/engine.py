@@ -41,8 +41,12 @@ tests/unit/test_serving.py and tests/unit/test_prefix_cache.py for the
 enforced oracles.
 """
 
+import collections
 import dataclasses
 import functools
+import threading
+import time
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +68,26 @@ SERVE_TICK = "serve_tick"
 SERVE_PREFILL = "serve_prefill"
 SLOT_INSERT = "slot_insert"
 SLOT_EVICT = "slot_evict"
+SERVE_PREFILL_CHUNK = "serve_prefill_chunk"
+PREFIX_GATHER = "prefix_gather"
+SLOT_RESIZE = "slot_resize"
+PAGE_SNAPSHOT = "page_snapshot"
+PAGE_PROMOTE = "page_promote"
+
+#: The dispatch log keeps this many notes where nobody takes them (an
+#: engine driven without a Scheduler); a Scheduler takes them a tick.
+DISPATCH_LOG_CAP = 4096
+
+
+class Dispatched(typing.NamedTuple):
+    """One note of the engine's dispatch log: a device program the
+    serving path dispatched, written when the dispatch had returned."""
+    name: str                 # from the "Programs" table of spans.py
+    t: float                  # time.monotonic() after the dispatch
+    rows: int = 0             # a prefill's bucket, a chunk's length
+    rid: object = None        # where the caller passed one
+    overlapped: bool = False  # a whole prefill dispatched while the
+    #                           one before it was unfetched
 
 
 def _program(name, impl, donate):
@@ -359,14 +383,12 @@ def _cache_prefill_fn(decoder):
     proposes inside the tick) and the INTERMEDIATE chunks of a chunked
     prefill, which only advance the cache — the tail chunk samples."""
 
-    @functools.partial(runtime.instrumented_jit, donate_argnums=1)
     def prefill(params, cache, tokens, mask):
         _, vars_ = decoder.apply({"params": params, "cache": cache},
                                  tokens, mask, mutable=["cache"])
         return vars_["cache"]
 
-    from cloud_tpu.models.decoding import best_effort_donation
-    return best_effort_donation(prefill)
+    return _program(SERVE_PREFILL_CHUNK, prefill, 1)
 
 
 def chunk_plan(n_suffix, chunk_size, max_seq_len, whole_tail=False):
@@ -459,20 +481,24 @@ class ChunkedPrefill:
         return self.chunk_size if i < self.n_chunks - 1 else self._tail
 
     def _acquire(self):
-        from cloud_tpu.models.decoding import acquire_cache
+        from cloud_tpu.models.decoding import CACHE_ZERO, acquire_cache
         engine = self.engine
         cache = _plain(acquire_cache(engine._dense, 1))
+        engine._note(CACHE_ZERO, rid=self.rid)
         gvec = None
         if self.prefix_len:
             gvec = jnp.asarray(self._gather_vec, jnp.int32)
             cache = engine._gather(cache, engine.cache, gvec,
-                                   np.int32(self.prefix_len))
+                                   np.int32(self.prefix_len),
+                                   rid=self.rid)
         self._cache = cache
         if engine.spec_on:
             dcache = _plain(acquire_cache(engine._dense_draft, 1))
+            engine._note(CACHE_ZERO, rid=self.rid)
             if self.prefix_len:
                 dcache = engine._gather(dcache, engine.draft_cache,
-                                        gvec, np.int32(self.prefix_len))
+                                        gvec, np.int32(self.prefix_len),
+                                        rid=self.rid)
             self._dcache = dcache
 
     def step(self):
@@ -500,6 +526,7 @@ class ChunkedPrefill:
             if engine.spec_on:
                 self._dcache = _cache_prefill_fn(engine._dense_draft)(
                     engine._draft_params, self._dcache, tokens, mask)
+            engine._note(SERVE_PREFILL_CHUNK, rows=C, rid=self.rid)
             self.chunks_done = i + 1
             return None
         tail, bucket = self._tail, self._tail_bucket
@@ -520,6 +547,7 @@ class ChunkedPrefill:
                 engine._draft_params, self._dcache,
                 jnp.asarray(tokens), jnp.asarray(mask))
             self._dcache = None
+        engine._note(SERVE_PREFILL_CHUNK, rows=bucket, rid=self.rid)
         first_host = int(runtime.device_fetch(first)[0])
         self.chunks_done = i + 1
         self._closed = True
@@ -651,8 +679,7 @@ class DecodeEngine:
                                   kv_num_pages=num_pages,
                                   kv_page_dtype=self.page_dtype)
 
-        from cloud_tpu.models.decoding import (best_effort_donation,
-                                               empty_cache)
+        from cloud_tpu.models.decoding import empty_cache
         self.cache = _plain(empty_cache(self._paged, self.slots))
 
         if self.spec_on:
@@ -699,7 +726,6 @@ class DecodeEngine:
             "has_eos": jnp.zeros((slots,), jnp.bool_),
             "step_keys": jnp.zeros((slots, key_width, 2), jnp.uint32),
         }
-        jit = runtime.instrumented_jit
         if self.spec_on:
             self._tick = _program(SERVE_TICK, self._spec_tick_impl,
                                   (2, 3, 4))
@@ -707,38 +733,64 @@ class DecodeEngine:
                                     (0, 1, 2))
             self._evict = _program(SLOT_EVICT, self._evict_spec_impl,
                                    (0, 1, 2))
-            self._resize = best_effort_donation(functools.partial(
-                jit, donate_argnums=(0, 1, 2))(self._resize_spec_impl))
+            self._resize = _program(SLOT_RESIZE, self._resize_spec_impl,
+                                    (0, 1, 2))
         else:
             self._tick = _program(SERVE_TICK, self._tick_impl, (1, 2))
             self._insert = _program(SLOT_INSERT, self._insert_impl,
                                     (0, 1))
             self._evict = _program(SLOT_EVICT, self._evict_impl, (0, 1))
-            self._resize = best_effort_donation(functools.partial(
-                jit, donate_argnums=(0, 1))(self._resize_impl))
-        gather_exec = best_effort_donation(functools.partial(
-            jit, donate_argnums=(0,))(self._gather_impl))
-
-        def gather(dense_cache, pool_cache, page_vec, prefix_len):
-            self._refuse_page_reuse("a prefix hit")
-            # The view strips slot-count-bound leaves so the gather
-            # signature is identical at every geometry rung.
-            return gather_exec(dense_cache, _pool_pages_view(pool_cache),
-                               page_vec, prefix_len)
-
-        self._gather = gather
+            self._resize = _program(SLOT_RESIZE, self._resize_impl,
+                                    (0, 1))
+        self._gather_pages = _program(PREFIX_GATHER, self._gather_impl,
+                                      (0,))
         # Host-tier executables: snapshot READS the pool cache (no
         # donation — the tick keeps it); promote replaces it.
-        self._snapshot = jit(self._snapshot_impl)
-        self._promote = best_effort_donation(functools.partial(
-            jit, donate_argnums=(0,))(self._promote_impl))
+        self._snapshot = _program(PAGE_SNAPSHOT, self._snapshot_impl, ())
+        self._promote = _program(PAGE_PROMOTE, self._promote_impl, (0,))
         self._warm_stats = None
+        # The dispatch log: every device program the serving path
+        # dispatches, noted once its dispatch has returned, by
+        # whichever thread dispatched it. The lock is held for the
+        # append and the swap alone, never across a dispatch.
+        self._dispatched = collections.deque(maxlen=DISPATCH_LOG_CAP)
+        self._dispatched_lock = threading.Lock()
         #: The last tick's counters, still on the device: an expert
         #: model's (`_moe_counters`) and a recurrent model's
         #: `ssm_slot_steps`; {} for a model with neither and under
         #: speculation. The scheduler fetches them with the tick's
         #: tokens, in the one read-back a tick makes.
         self.tick_counters = {}
+
+    def _gather(self, dense_cache, pool_cache, page_vec, prefix_len,
+                rid=None):
+        self._refuse_page_reuse("a prefix hit")
+        # The view strips slot-count-bound leaves so the gather
+        # signature is identical at every geometry rung.
+        out = self._gather_pages(dense_cache, _pool_pages_view(pool_cache),
+                                 page_vec, prefix_len)
+        self._note(PREFIX_GATHER, rid=rid)
+        return out
+
+    def _note(self, name, rows=0, rid=None, overlapped=False):
+        # The clock is read under the lock: the log is in time order.
+        with self._dispatched_lock:
+            self._dispatched.append(Dispatched(
+                name, time.monotonic(), rows, rid, overlapped))
+
+    def take_dispatched(self):
+        """The notes since the last call, oldest first (`Dispatched`:
+        program, time, rows, rid), and an empty log behind them. A
+        draft model's twin of a program rides in its target's note,
+        and every chunk of a chunked prefill is noted
+        `serve_prefill_chunk`, the tail too, which runs
+        `serve_prefill` to sample. A note is written after its
+        dispatch returned, so one thread's may land behind a program
+        another thread dispatched later."""
+        with self._dispatched_lock:
+            taken = list(self._dispatched)
+            self._dispatched.clear()
+        return taken
 
     def _refuse_page_reuse(self, what):
         if self.layout is not None:
@@ -806,14 +858,16 @@ class DecodeEngine:
 
     def prefill_dispatch(self, prompt, max_new_tokens, rng, sampling,
                          prefix_len=0, gather_vec=None,
-                         key_override=None, rid=None):
+                         key_override=None, rid=None, overlapped=False):
         """The first half of `prefill()` for a model prefilled whole:
         everything up to and including the jitted call, and the start
         of the first token's copy to the host. Returns a
         `PrefillFlight` without waiting for the device, so the caller
         can prepare and dispatch the next request's prefill before it
         reads this one's token (`prefill_finish`). The span is
-        `serve_prefill`, as far as the dispatch."""
+        `serve_prefill`, as far as the dispatch. `overlapped`: the
+        caller's prefill before this one is still unfetched; it goes
+        into the dispatch log's note."""
         if self.layout is not None:
             raise NotImplementedError(
                 "a model whose slots keep a ring and summary rows is "
@@ -822,7 +876,8 @@ class DecodeEngine:
         with spans.span(SERVE_PREFILL, rid=rid):
             return self._dispatch_prefill(prompt, max_new_tokens, rng,
                                           sampling, prefix_len,
-                                          gather_vec, key_override, rid)
+                                          gather_vec, key_override, rid,
+                                          overlapped)
 
     def prefill_finish(self, flight, rid=None):
         """The second half: blocks until `flight`'s first token is on
@@ -833,8 +888,9 @@ class DecodeEngine:
         return flight.result
 
     def _dispatch_prefill(self, prompt, max_new_tokens, rng, sampling,
-                          prefix_len, gather_vec, key_override, rid):
-        from cloud_tpu.models.decoding import (acquire_cache,
+                          prefix_len, gather_vec, key_override, rid,
+                          overlapped=False):
+        from cloud_tpu.models.decoding import (CACHE_ZERO, acquire_cache,
                                                bucket_length)
 
         with spans.span("prefill_host", rid=rid):
@@ -864,11 +920,12 @@ class DecodeEngine:
                 rng, key_override, sampling, int(max_new_tokens),
                 self.max_new_cap - 1)
             cache = _plain(acquire_cache(self._dense, 1))
+            self._note(CACHE_ZERO, rid=rid)
             gvec = None
             if prefix_len:
                 gvec = jnp.asarray(gather_vec, jnp.int32)
                 cache = self._gather(cache, self.cache, gvec,
-                                     np.int32(prefix_len))
+                                     np.int32(prefix_len), rid=rid)
             fn = _serve_prefill_fns(
                 self._dense, float(sampling["temperature"]),
                 sampling["top_k"], sampling["top_p"])
@@ -879,14 +936,18 @@ class DecodeEngine:
             dpcache = None
             if self.spec_on:
                 dcache = _plain(acquire_cache(self._dense_draft, 1))
+                self._note(CACHE_ZERO, rid=rid)
                 if prefix_len:
                     dcache = self._gather(dcache, self.draft_cache,
-                                          gvec, np.int32(prefix_len))
+                                          gvec, np.int32(prefix_len),
+                                          rid=rid)
                 dpcache = _cache_prefill_fn(self._dense_draft)(
                     self._draft_params, dcache, jnp.asarray(tokens),
                     jnp.asarray(mask))
             # Queued ahead of whatever program is dispatched next.
             first.copy_to_host_async()
+            self._note(SERVE_PREFILL, rows=bucket, rid=rid,
+                       overlapped=overlapped)
         return PrefillFlight(first, PrefillResult(
             first_token=None, pcache=pcache, dpcache=dpcache,
             step_keys=step_keys, bucket=bucket,
@@ -964,6 +1025,7 @@ class DecodeEngine:
         else:
             self.cache, self.ctl = self._insert(
                 self.cache, self.ctl, _plain(result.pcache), *args)
+        self._note(SLOT_INSERT)
         self.release_prefill(result)
 
     def tick(self):
@@ -1001,6 +1063,7 @@ class DecodeEngine:
         else:
             self.cache, self.ctl, out, self.tick_counters = self._tick(
                 self._params, self.cache, self.ctl)
+        self._note(SERVE_TICK)
         return out
 
     def evict(self, evict_mask):
@@ -1015,6 +1078,7 @@ class DecodeEngine:
         else:
             self.cache, self.ctl = self._evict(
                 self.cache, self.ctl, jnp.asarray(evict_mask, bool))
+        self._note(SLOT_EVICT)
 
     def resize(self, new_slots, perm):
         """Moves the engine to ladder rung `new_slots` at a tick
@@ -1051,6 +1115,7 @@ class DecodeEngine:
         else:
             self.cache, self.ctl = self._resize(self.cache, self.ctl,
                                                 pv)
+        self._note(SLOT_RESIZE)
         self.slots = new_slots
 
     # -- retrace sentinel ---------------------------------------------
@@ -1456,7 +1521,9 @@ class DecodeEngine:
         self._refuse_page_reuse("the host tier")
         n = len(page_ids)
         vec = jnp.asarray(self.pool_page_vec(page_ids), jnp.int32)
-        tree = jax.device_get(self._snapshot(self.cache, vec))
+        snapshot = self._snapshot(self.cache, vec)
+        self._note(PAGE_SNAPSHOT)
+        tree = jax.device_get(snapshot)
         return jax.tree_util.tree_map(lambda a: a[:n], tree)
 
     def promote_pages(self, host_tree, page_ids, n_skip=0):
@@ -1480,6 +1547,7 @@ class DecodeEngine:
         padded = jax.tree_util.tree_map(pad, host_tree)
         self.cache = self._promote(self.cache, padded,
                                    jnp.asarray(vec, jnp.int32))
+        self._note(PAGE_PROMOTE)
 
     def pool_page_vec(self, page_ids):
         """Full-width scratch-padded page vector (kvpool.page_vec's

@@ -86,6 +86,38 @@ traffic stays out of `recent()` and of the JSONL. Nothing is written
 anywhere: the cost a request is some ten clock reads and one append,
 and a tick, one float a live slot.
 
+The tick record (always on). Every decode tick the Scheduler commits
+leaves one `TickRecord`, made by the tick thread where the tick is
+dispatched and published where it is committed, on the same clock:
+
+    seq         this server's tick ordinal (warm-up's ticks count)
+    slots       the geometry it ran at
+    t_dispatch  `engine.tick()` was about to be called
+    t_fetch0    the host began to wait for its tokens
+    t_fetched   they were on the host (each later token's commit time
+                in the request records)
+    t_committed they were handed to their requests
+    live        slots it advanced
+    overlapped  dispatched while the tick before was unfetched
+                (`drained`: it was not; nothing was in flight)
+    kv_live     this tick's term of `kv_live_tokens`, and
+    kv_walked   of `kv_walked_tokens`
+    dispatched  the engine's dispatch log (`engine.Dispatched` notes:
+                program, time, rows, rid) from the dispatch of the
+                tick before, whose `serve_tick` note comes first, to
+                this tick's: what the device was given to run ahead
+                of this tick, by either thread
+    naps        `tick_pace` naps since the tick before
+    idle_s      seconds in `tick_idle` waits since the tick before
+
+so that `t_fetched` of one tick to the next is the device's tick
+period where `dispatched` holds `serve_tick` alone, and holds a
+prefill, an insert, a nap where it names one. The last `TICKS_CAP` are
+`recent_ticks()`, in a ring of their own with `recent()`'s rule for
+`server`; `clear()` leaves it alone (`clear_ticks()` empties it).
+Warm-up's ticks are kept like any other: a reader cuts by time. A tick
+costs one swap of the engine's log, one small object and one append.
+
 The JSONL (opt-in) is an export of the same marks. When
 ``CLOUD_TPU_REQTRACE`` is unset no tracer is installed — ``get()``
 returns None, no events are built, and no file or thread is ever
@@ -116,9 +148,14 @@ DEFAULT_TICK_EVERY = 8
 #: How many finished requests' records `recent()` keeps.
 RECENT_CAP = 4096
 
+#: How many committed ticks' records `recent_ticks()` keeps (a 45 s
+#: window of 6 ms ticks is 7 500).
+TICKS_CAP = 16384
+
 _tracer = None
 _lock = threading.Lock()
 _recent = collections.deque(maxlen=RECENT_CAP)
+_recent_ticks = collections.deque(maxlen=TICKS_CAP)
 _recent_lock = threading.Lock()
 _rids = itertools.count()
 _servers = itertools.count(1)
@@ -179,6 +216,34 @@ class RequestRecord:
         return [b - a for a, b in zip(times, times[1:])]
 
 
+class TickRecord:
+    """One decode tick's times and what shared the device with it (see
+    the module docstring). Times are `time.monotonic()` seconds."""
+
+    __slots__ = ("seq", "server", "slots", "t_dispatch", "t_fetch0",
+                 "t_fetched", "t_committed", "live", "overlapped",
+                 "kv_live", "kv_walked", "dispatched", "naps", "idle_s")
+
+    def __init__(self, seq, server, slots, t_dispatch, overlapped,
+                 dispatched=(), naps=0, idle_s=0.0):
+        self.seq = seq
+        self.server = server
+        self.slots = slots
+        self.t_dispatch = t_dispatch
+        self.t_fetch0 = self.t_fetched = self.t_committed = None
+        self.live = 0
+        self.overlapped = overlapped
+        self.kv_live = self.kv_walked = 0
+        self.dispatched = dispatched
+        self.naps = naps
+        self.idle_s = idle_s
+
+    @property
+    def drained(self):
+        """Nothing was in flight when it was dispatched."""
+        return not self.overlapped
+
+
 def new_rid():
     """A process-unique request id ("r000042")."""
     return "r%06d" % next(_rids)
@@ -202,8 +267,12 @@ def publish(record):
 def recent(server=None):
     """The kept records, oldest first: of the Scheduler started last,
     of the one with ordinal `server`, or (`server=0`) of all."""
+    return _of_server(_recent, server)
+
+
+def _of_server(ring, server):
     with _recent_lock:
-        records = list(_recent)
+        records = list(ring)
         if server is None:
             server = _last_server
     if server == 0:
@@ -212,9 +281,28 @@ def recent(server=None):
 
 
 def clear():
-    """Empties `recent()`."""
+    """Empties `recent()`, and not `recent_ticks()`: a caller that
+    drops requests of its own from the first has no tick to drop."""
     with _recent_lock:
         _recent.clear()
+
+
+def publish_tick(record):
+    """Keeps a committed tick's record among the last TICKS_CAP."""
+    with _recent_lock:
+        _recent_ticks.append(record)
+
+
+def recent_ticks(server=None):
+    """The kept tick records, oldest first, with `recent()`'s rule for
+    `server`."""
+    return _of_server(_recent_ticks, server)
+
+
+def clear_ticks():
+    """Empties `recent_ticks()`."""
+    with _recent_lock:
+        _recent_ticks.clear()
 
 
 def env_enabled():
@@ -373,7 +461,10 @@ __all__ = [
     "RECENT_CAP",
     "RequestRecord",
     "RequestTracer",
+    "TICKS_CAP",
+    "TickRecord",
     "clear",
+    "clear_ticks",
     "default_path",
     "env_enabled",
     "get",
@@ -382,6 +473,8 @@ __all__ = [
     "new_rid",
     "new_server",
     "publish",
+    "publish_tick",
     "recent",
+    "recent_ticks",
     "uninstall",
 ]

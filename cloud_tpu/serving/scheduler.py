@@ -64,7 +64,15 @@ when the buffer fills and at close(); with the env unset no tracer is
 installed: no events, no file, no threads. The sections of both threads
 are graftscope spans (`monitoring/spans.py` has the table), which a
 profile capture shows beside the device's ops, those of one request
-under its rid. Queue-wait and page-reservation-wait histograms are
+under its rid, those of one tick under its `tick=`. Beside the record a
+request the tick thread keeps a record a TICK (`reqtrace.TickRecord`,
+made in `_dispatch_tick`, published in `_commit_tick`; the last 16 384
+are `reqtrace.recent_ticks()`): its dispatch, fetch and commit times,
+what it advanced, and what the engine dispatched to the device between
+the tick before and it (`DecodeEngine.take_dispatched()`), with the
+naps and idle waits taken meanwhile: what shared the device with each
+tick, over the whole of a window. Queue-wait and
+page-reservation-wait histograms are
 host-side and always on (warm-reset like TTFT), feeding `stats()` and
 ROADMAP item 4's predicted-TTFT admission.
 
@@ -212,12 +220,12 @@ class _Flight:
     its dispatch has to remember, because by the time it is committed
     the engine holds the next tick's counters and a slot that finished
     in the tick before may hold another request."""
-    __slots__ = ("out", "counters", "t_dispatch", "slots")
+    __slots__ = ("out", "counters", "record", "slots")
 
-    def __init__(self, out, counters, t_dispatch, slots):
+    def __init__(self, out, counters, record, slots):
         self.out = out              # device tokens (`engine.tick()`)
         self.counters = counters    # `engine.tick_counters`, this tick's
-        self.t_dispatch = t_dispatch
+        self.record = record        # its `reqtrace.TickRecord`
         self.slots = slots          # slot -> _Slot it ran with (a copy)
 
 
@@ -516,6 +524,12 @@ class Scheduler:
         # which the device did not wait for the host.
         self._flight = None
         self._ticks_overlapped = 0
+        # The tick record (`reqtrace.TickRecord`, one a tick): the next
+        # tick's ordinal, never reset, and what the tick thread did
+        # instead of dispatching since the last dispatch.
+        self._tick_seq = 0
+        self._naps_since = 0
+        self._idle_since = 0.0
         # The same one thread over: the miss whose prefill is on the
         # device with its first token unread (admission thread only),
         # and the prefills dispatched while the one before them was
@@ -674,10 +688,7 @@ class Scheduler:
         slots = int(self.engine.slots if slots is None else slots)
         g = self._geom_stats.get(slots)
         if g is None:
-            from cloud_tpu.monitoring.telemetry import Histogram
-            g = {"ticks": 0, "active_sum": 0,
-                 "tick_hist": Histogram("tick_latency_g%d" % slots),
-                 "decode_gap_hist": Histogram("decode_gap_g%d" % slots)}
+            g = {"ticks": 0, "active_sum": 0}
             self._geom_stats[slots] = g
         return g
 
@@ -1305,7 +1316,8 @@ class Scheduler:
                     np.asarray(request.prompt, np.int32),
                     request.max_new_tokens,
                     host_prng_key(request.rng_seed), sampling,
-                    rid=rec.rid)
+                    rid=rec.rid,
+                    overlapped=self._miss_flight is not None)
             except PrefillFailed as exc:
                 if pages:
                     self.pool.free(pages)
@@ -1595,9 +1607,6 @@ class Scheduler:
         if n_active <= 0:
             return
         self._decode_gap_hist.observe(gap, count=n_active)
-        # Geometry stamp: the same gap also lands in the current
-        # rung's histogram, so A/B reads never mix widths silently.
-        self._geom()["decode_gap_hist"].observe(gap, count=n_active)
         reg = _registry()
         if reg is not None:
             from cloud_tpu.monitoring import telemetry
@@ -1834,8 +1843,10 @@ class Scheduler:
                         # A continuation advanced and nothing decodes:
                         # drain chunks back-to-back, no idle sleep.
                         continue
+                    t_idle = time.monotonic()
                     with spans.span("tick_idle"):
                         woke = self._wake.wait(timeout=0.05)
+                    self._idle_since += time.monotonic() - t_idle
                     if woke:
                         self._wake.clear()
                     continue
@@ -1856,12 +1867,13 @@ class Scheduler:
                     self._drain_tick()
                     skips += 1
                     self._tick_paces += 1
+                    self._naps_since += 1
                     with spans.span("tick_pace"):
                         self._wake.wait(timeout=0.005)
                     self._wake.clear()
                     continue
                 skips = 0
-                with spans.span("serve_tick"):
+                with spans.span("serve_tick", tick=self._tick_seq):
                     behind, self._flight = (self._flight,
                                             self._dispatch_tick())
                     fetched = (None if behind is None
@@ -1880,20 +1892,31 @@ class Scheduler:
         """Puts one tick on the device and returns what its commit
         will need. The copy of its tokens (and of an expert model's
         counters) to the host is started here, so that the transfer is
-        queued ahead of whatever program is dispatched next."""
-        t_dispatch = time.monotonic()
-        with spans.span("tick_dispatch"):
+        queued ahead of whatever program is dispatched next. The
+        tick's record starts here: it takes the engine's dispatch log
+        (what went to the device since the tick before, this tick's
+        own note not yet among it) and the naps and idle time since."""
+        dispatched = self.engine.take_dispatched()
+        record = reqtrace.TickRecord(
+            self._tick_seq, self._server, self.engine.slots,
+            time.monotonic(), overlapped=self._flight is not None,
+            dispatched=dispatched, naps=self._naps_since,
+            idle_s=self._idle_since)
+        self._tick_seq += 1
+        self._naps_since, self._idle_since = 0, 0.0
+        with spans.span("tick_dispatch", tick=record.seq):
             out = self.engine.tick()
             # The next tick overwrites the attribute.
             counters = self.engine.tick_counters
             for leaf in jax.tree_util.tree_leaves((out, counters)):
                 leaf.copy_to_host_async()
-        return _Flight(out, counters, t_dispatch, list(self._slots))
+        return _Flight(out, counters, record, list(self._slots))
 
     def _fetch_tick(self, flight):
         """Blocks until `flight`'s tokens are on the host: the serving
         loop's one counted read-back a tick."""
-        with spans.span("tick_fetch"):
+        flight.record.t_fetch0 = time.monotonic()
+        with spans.span("tick_fetch", tick=flight.record.seq):
             fetched, counters = runtime.device_fetch(
                 (flight.out, flight.counters))
         return fetched, counters, time.monotonic()
@@ -1910,16 +1933,24 @@ class Scheduler:
         # left slot s nor to the one inserted there since).
         live = [(slot, state) for slot, state in enumerate(flight.slots)
                 if state is not None and self._slots[slot] is state]
-        t_from = flight.t_dispatch
+        record = flight.record
+        t_from = record.t_dispatch
         if self._t_last_commit is not None:
             t_from = max(t_from, self._t_last_commit)
             self._observe_decode_gap(t_commit - self._t_last_commit,
                                      len(live))
         self._t_last_commit = t_commit
-        with spans.span("tick_commit"):
+        kv_live, kv_walked = self._kv_live_tokens, self._kv_walked_tokens
+        with spans.span("tick_commit", tick=record.seq):
             self._distribute(live, fetched, t_commit - t_from, t_commit)
             if counters:
                 self._count_tick(counters)
+        record.t_fetched = t_commit
+        record.live = len(live)
+        record.kv_live = self._kv_live_tokens - kv_live
+        record.kv_walked = self._kv_walked_tokens - kv_walked
+        record.t_committed = time.monotonic()
+        reqtrace.publish_tick(record)
         if self.strict_no_retrace:
             self.engine.check_no_retrace()
 
@@ -2362,12 +2393,12 @@ class Scheduler:
         n_active = len(live)
         if n_active:
             self._token_hist.observe(elapsed, count=n_active)
-            # Geometry stamp: tick latency and occupancy roll up under
-            # the rung this tick RAN at, never a mixed aggregate.
+            # Geometry stamp: occupancy rolls up under the rung this
+            # tick RAN at, never a mixed aggregate (a tick's times by
+            # rung: its `reqtrace.TickRecord` carries `slots`).
             g = self._geom()
             g["ticks"] += 1
             g["active_sum"] += n_active
-            g["tick_hist"].observe(elapsed)
             # What this tick's attention read: the token it consumed
             # sits at prompt + emitted - 1, so that many keys and
             # itself.
@@ -2958,17 +2989,15 @@ class Scheduler:
             "spec_proposed_tokens": proposed,
         }
         # graftflex geometry rollup: the current rung, the ladder, the
-        # resize census, and every per-tick stat split by the geometry
-        # it ran under — the aggregate histograms above stay for
-        # back-compat, but cross-width comparisons must read this.
+        # resize census, and ticks and occupancy split by the geometry
+        # they ran under (a tick's times by rung: `reqtrace.
+        # recent_ticks()`, whose records carry `slots`).
         geoms = {}
         for s, g in sorted(self._geom_stats.items()):
             geoms[str(s)] = {
                 "ticks": g["ticks"],
                 "occupancy_mean": (g["active_sum"] / g["ticks"]
                                    if g["ticks"] else 0.0),
-                "tick_latency": g["tick_hist"].snapshot(),
-                "decode_gap": g["decode_gap_hist"].snapshot(),
             }
         out["geometry"] = {
             "slots": self.engine.slots,

@@ -353,11 +353,14 @@ class TestGeometryStats:
             sched.request_resize(4, reason="test", timeout=120)
             [f.result(timeout=300) for f in futures]
             geometry = sched.stats()["geometry"]
+        from cloud_tpu.serving import reqtrace
+        ticks = [t for t in reqtrace.recent_ticks() if t.live]
         per_geom = geometry["per_geometry"]
         assert set(per_geom) <= {"2", "4"}
         assert sum(g["ticks"] for g in per_geom.values()) > 0
-        for g in per_geom.values():
-            assert g["ticks"] == g["tick_latency"]["count"]
+        for slots, g in per_geom.items():
+            # A tick's record carries the rung it ran at.
+            assert g["ticks"] == sum(t.slots == int(slots) for t in ticks)
             assert 0.0 <= g["occupancy_mean"] <= 4.0
 
 

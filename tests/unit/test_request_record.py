@@ -129,11 +129,19 @@ def test_hit_record_names_its_prefix(served):
 
 
 def test_stats_count_paces_and_carry_no_kernel_costs(served):
-    stats = served["miss"][3]
+    results, _, _, stats = served["miss"]
     assert stats["tick_paces"] >= 0
-    for geometry in stats["geometry"]["per_geometry"].values():
-        assert set(geometry) == {"ticks", "occupancy_mean", "tick_latency",
-                                 "decode_gap"}
+    # A rung's tick times are its tick records' (they carry `slots`),
+    # cut to the served traffic by time: warm-up's ticks are kept too.
+    t0 = min(r.trace.t_submit for r in results)
+    ticks = [t for t in reqtrace.recent_ticks(results[0].trace.server)
+             if t.t_fetched >= t0 and t.live]
+    for slots, geometry in stats["geometry"]["per_geometry"].items():
+        assert set(geometry) == {"ticks", "occupancy_mean"}
+        of_rung = [t for t in ticks if t.slots == int(slots)]
+        assert len(of_rung) == geometry["ticks"] > 0
+        assert sum(t.live for t in of_rung) == pytest.approx(
+            geometry["ticks"] * geometry["occupancy_mean"])
 
 
 def test_zero_token_request_gets_an_empty_record(model, params):

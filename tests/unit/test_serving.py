@@ -468,8 +468,12 @@ class TestTickPipeline:
         row is dropped; the rows of slots still running are kept."""
         import types
 
-        from cloud_tpu.serving import Scheduler
+        from cloud_tpu.serving import Scheduler, reqtrace
         from cloud_tpu.serving.scheduler import _Flight
+
+        def flight(t_dispatch, slots):
+            return _Flight(None, {}, reqtrace.TickRecord(
+                0, 0, 4, t_dispatch, overlapped=False), slots)
 
         def state(prompt_len):
             return types.SimpleNamespace(
@@ -479,13 +483,15 @@ class TestTickPipeline:
 
         sched = Scheduler(model, params, slots=4, page_size=4)
         gone, stays, newcomer = state(3), state(5), state(7)
-        flight = _Flight(None, {}, 1.0, [gone, stays, None, None])
+        first = flight(1.0, [gone, stays, None, None])
         # Since the dispatch: slot 0's request finished (tick before)
         # and another was inserted there; slot 2 was filled too.
         sched._slots = [newcomer, stays, state(2), None]
         fetched = (np.array([-1, 9, -1, -1], np.int32),
                    np.zeros(4, np.int32))
-        sched._commit_tick(flight, fetched, {}, 2.0)
+        sched._commit_tick(first, fetched, {}, 2.0)
+        assert (first.record.t_fetched, first.record.live,
+                first.record.kv_live) == (2.0, 1, 5 + 1)
         assert stays.emitted == [2, 9]
         assert stays.rec.token_times == [2.0]
         assert gone.emitted == newcomer.emitted == [2]
@@ -497,7 +503,7 @@ class TestTickPipeline:
         assert stats["token_latency"]["sum"] == pytest.approx(1.0)
         # ... and commit to commit while the pipeline is full.
         sched._commit_tick(
-            _Flight(None, {}, 1.5, [newcomer, stays, None, None]),
+            flight(1.5, [newcomer, stays, None, None]),
             (np.array([4, 8, -1, -1], np.int32), np.zeros(4, np.int32)),
             {}, 2.5)
         stats = sched.stats()

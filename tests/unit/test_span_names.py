@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from cloud_tpu.models import decoding as decoding_lib
 from cloud_tpu.models import mamba2 as mamba_lib
 from cloud_tpu.models import moe as moe_lib
 from cloud_tpu.monitoring import spans
@@ -76,6 +77,40 @@ def test_span_table_and_code_name_the_same_spans():
     assert found == set(table)
 
 
+def _span_ids_in_the_table():
+    """span -> the id the "Spans" table gives it in brackets (`rid`,
+    `tick`), for the spans it gives one."""
+    ids, name, inside = {}, None, False
+    for line in spans.__doc__.splitlines():
+        if not line.startswith(" "):
+            if line:
+                inside = line == "Spans:"
+            continue
+        if not inside:
+            continue
+        if line[4] != " ":
+            name = line.split()[0]
+        for marked in re.findall(r"\((rid|tick)\b", line):
+            ids[name] = marked
+    return ids
+
+
+def test_span_table_and_code_give_the_same_spans_an_id():
+    """A span of one request carries `rid=`, a span of one tick
+    `tick=`, and the table says so of exactly those."""
+    call = re.compile(
+        r'spans\.span\(\s*(?:"([a-z_0-9]+)"|([A-Z_]+)),\s*(rid|tick)=')
+    found = {}
+    for path in glob.glob(os.path.join(ROOT, "cloud_tpu", "serving", "*.py")):
+        with open(path, encoding="utf-8") as f:
+            for literal, constant, key in call.findall(f.read()):
+                name = literal or getattr(engine_lib, constant)
+                assert found.setdefault(name, key) == key, name
+    assert found == _span_ids_in_the_table()
+    assert {found[n] for n in ("serve_tick", "tick_dispatch", "tick_fetch",
+                               "tick_commit")} == {"tick"}
+
+
 def test_histogram_spans_stand_in_the_table():
     from cloud_tpu.monitoring import telemetry
     assert set(telemetry.SPAN_HISTOGRAMS) <= set(spans.names("Spans"))
@@ -94,7 +129,21 @@ def test_kernel_and_program_tables_equal_the_constants():
     assert spans.names("Programs") == (
         trainer_lib.TRAIN_STEP, engine_lib.SERVE_TICK,
         engine_lib.SERVE_PREFILL, engine_lib.SLOT_INSERT,
-        engine_lib.SLOT_EVICT)
+        engine_lib.SLOT_EVICT, engine_lib.SERVE_PREFILL_CHUNK,
+        engine_lib.PREFIX_GATHER, engine_lib.SLOT_RESIZE,
+        engine_lib.PAGE_SNAPSHOT, engine_lib.PAGE_PROMOTE,
+        decoding_lib.CACHE_ZERO)
+
+
+def test_dispatch_log_holds_no_name_the_program_table_lacks():
+    """Every note the engine writes goes under a constant, and every
+    serving program of the table has a note."""
+    with open(engine_lib.__file__, encoding="utf-8") as f:
+        noted = set(re.findall(r"\b_note\(\s*([A-Z_]+)", f.read()))
+    values = {getattr(engine_lib, name, None)
+              or getattr(decoding_lib, name) for name in noted}
+    assert values == set(spans.names("Programs")) - {
+        trainer_lib.TRAIN_STEP}
 
 
 # --------------------------------------------------------------- kernels
@@ -232,7 +281,22 @@ def _serve_programs(toy):
                False)
     prefill = engine_lib._serve_prefill_fns(engine._dense, 0.0, None, None)
     tokens = jnp.zeros((1, 8), jnp.int32)
+    host_pages = jax.eval_shape(engine._snapshot_impl, engine.cache, vec)
     return {
+        engine_lib.SERVE_PREFILL_CHUNK: (
+            engine_lib._cache_prefill_fn(engine._dense),
+            (params, dense, tokens, jnp.ones((1, 8), bool))),
+        engine_lib.PREFIX_GATHER: (
+            engine._gather_pages,
+            (dense, engine_lib._pool_pages_view(engine.cache), vec,
+             np.int32(8))),
+        engine_lib.SLOT_RESIZE: (engine._resize,
+                                 (engine.cache, engine.ctl,
+                                  jnp.zeros((2,), jnp.int32))),
+        engine_lib.PAGE_SNAPSHOT: (engine._snapshot, (engine.cache, vec)),
+        engine_lib.PAGE_PROMOTE: (engine._promote,
+                                  (engine.cache, host_pages, vec)),
+        decoding_lib.CACHE_ZERO: (decoding_lib._zero_in_place(), (dense,)),
         engine_lib.SERVE_TICK: (engine._tick,
                                 (params, engine.cache, engine.ctl)),
         engine_lib.SLOT_INSERT: (engine._insert,
@@ -247,13 +311,17 @@ def _serve_programs(toy):
     }
 
 
-@pytest.mark.parametrize("program", [
-    engine_lib.SERVE_TICK, engine_lib.SERVE_PREFILL, engine_lib.SLOT_INSERT,
-    engine_lib.SLOT_EVICT])
+@pytest.mark.parametrize("program", spans.names("Programs")[1:])
 def test_serving_program_lowers_under_its_declared_name(toy_engine, program):
     fn, args = _serve_programs(toy_engine)[program]
     # best_effort_donation wraps the InstrumentedJit it was given.
     assert _module_name(fn.__wrapped__.lower(*args)) == "jit_" + program
+
+
+def test_fresh_dense_cache_lowers_under_the_zero_programs_name(toy_engine):
+    engine, _ = toy_engine
+    fresh = decoding_lib._empty_cache_fn(engine._dense, 1)
+    assert _module_name(fresh.lower()) == "jit_" + decoding_lib.CACHE_ZERO
 
 
 def test_train_step_lowers_under_its_declared_name():
@@ -332,7 +400,7 @@ def test_profile_capture_holds_the_spans_and_a_rid(tmp_path):
     _, _, host, _ = tracing.load_xplane(path)
     assert {"serve_tick", "tick_dispatch", "tick_fetch", "serve_prefill",
             "tick_commit", "admit"} <= set(host.names)
-    rids = {}
+    rids, ticks = {}, {}
     for plane in jax.profiler.ProfileData.from_file(path).planes:
         for line in plane.lines:
             for event in line.events:
@@ -340,6 +408,16 @@ def test_profile_capture_holds_the_spans_and_a_rid(tmp_path):
                                   "prefill_dispatch"):
                     rids.setdefault(event.name, set()).add(
                         dict(event.stats).get("rid"))
+                if event.name in ("serve_tick", "tick_dispatch",
+                                  "tick_fetch", "tick_commit"):
+                    ticks.setdefault(event.name, set()).add(
+                        int(dict(event.stats).get("tick")))
+    # Spans of one tick share its `seq`, which is its record's.
+    from cloud_tpu.serving import reqtrace
+    recorded = {t.seq for t in reqtrace.recent_ticks()}
+    # (The capture may begin or end between two spans of one tick.)
+    assert all(seqs <= recorded for seqs in ticks.values())
+    assert len(ticks) == 4 and set.intersection(*ticks.values())
     wanted = {r.trace.rid for r in results}
     assert None not in wanted
     # Spans of one request share its rid.
