@@ -264,6 +264,22 @@ class TransformerLM(nn.Module):
                           dtype=self.compute_dtype, name="lm_head")(x)
         return logits.astype(jnp.float32)
 
+    def promoted_at_use(self, path):
+        """Whether the module that owns the parameter at `path` (its
+        keys below "params", e.g. `("block_0", "mlp_in", "kernel")`)
+        casts it to `compute_dtype` at every use, so that a holder who
+        only applies the model may cast it once and keep that: the
+        `nn.Dense` / `nn.DenseGeneral` kernels and biases and the
+        `nn.Embed` tables (flax's `promote_dtype`), and `MoEMLP`'s
+        expert stacks (its own `astype`). `nn.LayerNorm` multiplies by
+        its scale and adds its bias in float32 and `MoEMLP` routes in
+        float32, so those are used as given. The serving engine asks
+        (`serving/engine.py` `held_params`); `__call__`, `generate()`
+        and `Trainer` keep the tree they are handed.
+        tests/unit/test_served_params.py holds the rule to the logits,
+        bit for bit."""
+        return not (path[-2].startswith("ln_") or path[-1] == "router")
+
 
 class TransformerEncoder(nn.Module):
     """BERT-style bidirectional encoder.

@@ -285,6 +285,49 @@ def _served_model(model, role):
             "sequence-parallel attention_impl.")
 
 
+def weights_to_compute_dtype(leaves, dtype):
+    # A function of its own for its name: a trace's program line reads
+    # `jit_weights_to_compute_dtype`.
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+_cast_weights = jax.jit(weights_to_compute_dtype, static_argnums=1)
+
+
+def held_params(model, params):
+    """The tree the engine's programs read for `model`, given `params`:
+    a leaf that the model's modules cast to `compute_dtype` at every
+    use (the class says which: `promoted_at_use(path)`) is cast once,
+    here, all of them in one program; every other leaf is the leaf
+    given. The programs then compute from the operands they made for
+    themselves before, and a tick no longer converts the weights it
+    reads. A class that declares nothing, and a tree that is in the
+    compute type already, get `params` itself back. A leaf the cast
+    would widen stays as given: it would cost the bytes every tick
+    streams."""
+    promoted = getattr(model, "promoted_at_use", None)
+    if promoted is None:
+        return params
+    dtype = jnp.dtype(model.compute_dtype)
+    paths, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [leaf for _, leaf in paths]
+    cast = [i for i, (path, leaf) in enumerate(paths)
+            if leaf.dtype.itemsize > dtype.itemsize
+            and promoted(tuple(str(getattr(k, "key", k)) for k in path))]
+    if not cast:
+        return params
+    for i, leaf in zip(cast, _cast_weights([leaves[i] for i in cast],
+                                           dtype)):
+        leaves[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _tree_bytes(tree):
+    """Bytes of a tree's leaves (arrays, or shapes alone)."""
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
 def _moe_counters(stats):
     """An expert model's sown counters of one apply (`moe.MOE_STATS`:
     a tuple a call under each expert layer's path), summed over the
@@ -643,7 +686,12 @@ class DecodeEngine:
                 "initial slots ({}) must be a ladder rung; got "
                 "{}.".format(self.slots, ladder))
         self.ladder = ladder
-        self._params = params
+        #: THE tree the programs read (each takes it as an argument, so
+        #: it may be assigned to between calls): `params` with the
+        #: leaves the model casts at use cast once (`held_params`). The
+        #: tree given is not kept; what it weighed is (`stats()`).
+        self._params = held_params(model, params)
+        self.weight_bytes_given = _tree_bytes(params)
         self.spec_k = int(spec_k)
         self.spec_on = draft_model is not None and self.spec_k > 0
         #: Layers that keep a recurrent state a slot (the model's class
@@ -694,7 +742,8 @@ class DecodeEngine:
                     "draft max_seq_len ({}) must match target ({}) — "
                     "both caches share the page geometry.".format(
                         draft_model.max_seq_len, model.max_seq_len))
-            self._draft_params = draft_params
+            self._draft_params = held_params(draft_model, draft_params)
+            self.weight_bytes_given += _tree_bytes(draft_params)
             self._dense_draft = draft_model.clone(decode=True,
                                                   dropout_rate=0.0)
             # Same page_size/num_pages: page id i means the same token
@@ -777,6 +826,14 @@ class DecodeEngine:
         with self._dispatched_lock:
             self._dispatched.append(Dispatched(
                 name, time.monotonic(), rows, rid, overlapped))
+
+    @property
+    def weight_bytes_served(self):
+        """Bytes of the parameters the programs read now, the draft
+        model's among them: what a tick streams of weights, beside
+        `weight_bytes_given`, what the trees handed in weighed."""
+        return (_tree_bytes(self._params)
+                + _tree_bytes(self._draft_params))
 
     def take_dispatched(self):
         """The notes since the last call, oldest first (`Dispatched`:

@@ -2957,6 +2957,8 @@ class Scheduler:
             "eva_windows_closed": dict(self._eva_windows_closed),
             "eva_cache_bytes": (pool["kv_bytes_held"]
                                 if self.engine.layout is not None else 0),
+            "weight_bytes_given": self.engine.weight_bytes_given,
+            "weight_bytes_served": self.engine.weight_bytes_served,
             "elapsed_seconds": wall,
             "requests_per_sec": self._completed / wall,
             "tokens_per_sec": self._tokens_out / wall,
