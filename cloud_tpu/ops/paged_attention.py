@@ -858,9 +858,15 @@ def paged_attention(q, key_pages, value_pages, page_table, allowed,
 #: The latent walk's declared name (table in monitoring/spans.py).
 PAGED_MLA_DECODE = "paged_mla_decode"
 
-#: Keys a grid step of the latent walk serves: 1024 at 16-token pages
-#: (G = 64 pages, 1.3 MB of 640-wide bf16 rows a step).
-_MLA_STEP_KEYS = 1024
+#: Keys a grid step of the latent walk serves: 2048 at 16-token pages
+#: (G = 128 pages, 2.6 MB of 640-wide bf16 rows a step). Timed alone at
+#: `axk1_docqa_32k`'s geometry on the v5e: 2.80 ms a call at 1024, 2.64
+#: at 2048, 2.68 at 4096, 3.19 at 8192 (a slot's last group half dead).
+_MLA_STEP_KEYS = 2048
+
+#: VMEM the latent walk may take (`vmem_limit_bytes`; the v5e has 128
+#: MiB): two `[keys, W]` row buffers and the step's f32 score tiles.
+_MLA_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 class _MlaConfig(NamedTuple):
@@ -870,7 +876,6 @@ class _MlaConfig(NamedTuple):
     page_size: int
     group: int
     value_width: int  # the row's leading lanes that are the value
-    interpret: bool
 
 
 def mla_group_pages(page_size, pages_per_slot):
@@ -956,21 +961,58 @@ def _paged_mla_walk_lax(q, pages, page_table, allowed, sm_scale,
 
 
 def _mla_kernel(slot_ref, group_ref, pt_ref, live_ref, q_ref, a_ref,
-                *rest, config):
+                pool_ref, o_ref, rows_ref, sems, acc_ref, m_ref, l_ref, *,
+                config):
     """One step of the latent walk: every head of one slot against one
-    group of G logical pages. The G row blocks are joined once into
-    `[keys, W]`; the scores are `q @ rows.T` and the output takes the
-    same rows' first `value_width` lanes, so a row is fetched once for
-    both."""
-    del pt_ref  # consumed by the BlockSpec index maps
+    group of G logical pages, read in place from `rows_ref[t % 2]`
+    (`[keys, W]`). The pool stays in HBM: the step before started the
+    copies of this step's G pages, each straight into its `page_size`
+    rows of that buffer, and this step starts the next step's into the
+    other before it computes (the call's first step starts its own).
+    The scores are `q @ rows.T` and the output takes the same rows'
+    first `value_width` lanes, so a row is fetched once for both."""
     group, seq, hp = config.group, config.seq, config.heads
-    row_refs = rest[:group]
-    o_ref, acc_ref, m_ref, l_ref = rest[group:]
+    page = config.page_size
     ti = pl.program_id(0)
     ji = group_ref[ti]
     steps = live_groups(live_ref[slot_ref[ti]], group)
-    keys = group * config.page_size
-    qk_dtype, pv_dtype = _operand_dtypes(q_ref.dtype, row_refs[0].dtype)
+    keys = group * page
+    qk_dtype, pv_dtype = _operand_dtypes(q_ref.dtype, pool_ref.dtype)
+
+    def computes(t):
+        """False on an evicted slot's step and past the grid's end (the
+        schedule's entry there reads as the last slot's first group)."""
+        return ((t < pl.num_programs(0))
+                & (group_ref[t] < live_groups(live_ref[slot_ref[t]],
+                                              group)))
+
+    def copy_pages(t, start):
+        """Starts (or waits for) the copies of step t's G pages into
+        buffer t % 2, page g into rows `[g * P, (g + 1) * P)`."""
+        buf = t % 2
+        slot, first = slot_ref[t], group_ref[t] * group
+
+        def one(g, carry):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[pt_ref[slot, first + g]],
+                rows_ref.at[buf, pl.ds(pl.multiple_of(g * page, page),
+                                       page)],
+                sems.at[buf])
+            if start:
+                copy.start()
+            else:
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, group, one, 0, unroll=True)
+
+    @pl.when((ti == 0) & computes(ti))
+    def _first_copies():
+        copy_pages(ti, start=True)
+
+    @pl.when(computes(ti + 1))
+    def _next_copies():
+        copy_pages(ti + 1, start=True)
 
     @pl.when(ji == 0)
     def _init():
@@ -980,8 +1022,8 @@ def _mla_kernel(slot_ref, group_ref, pt_ref, live_ref, q_ref, a_ref,
 
     @pl.when(ji < steps)        # false only on an evicted slot's step
     def _step():
-        rows = jnp.concatenate([r[0].astype(qk_dtype) for r in row_refs],
-                               axis=0)                    # [keys, W]
+        copy_pages(ti, start=False)
+        rows = rows_ref[ti % 2].astype(qk_dtype)          # [keys, W]
         s = _dot(q_ref[0].astype(qk_dtype), rows, 1) * config.sm_scale
         tiles = [jnp.broadcast_to(a_ref[0, i:i + 1, :], (hp, keys))
                  for i in range(seq)]
@@ -1013,34 +1055,33 @@ def _paged_mla_call(q, pages, page_table, allowed, *, sm_scale,
                     value_width, interpret):
     """The latent walk over one device's call: the schedule of
     `_schedule` (live groups only, a dynamic grid bound), the page
-    table and the live bounds as scalar prefetch, step t's G row blocks
-    indexed straight out of the pool."""
+    table and the live bounds as scalar prefetch, the pool passed once
+    and left in HBM for the kernel's own copies of step t's G pages."""
     slots, seq, heads, width = q.shape
     page_size = pages.shape[1]
     group = mla_group_pages(page_size, page_table.shape[1])
     hp = _round_up(heads, _SUBLANES)
     rows = seq * hp
+    keys = group * page_size
     config = _MlaConfig(sm_scale=sm_scale, seq=seq, heads=hp,
                         page_size=page_size, group=group,
-                        value_width=value_width, interpret=interpret)
+                        value_width=value_width)
     table, mask, live_pages = _grouped(page_table, allowed, page_size,
                                        group)
+    # One entry past the most steps a call can take: the kernel looks
+    # at step t + 1's entry to start its copies.
     slot_of, group_of, total = _schedule(
-        live_pages, group, slots * (table.shape[1] // group))
+        live_pages, group, slots * (table.shape[1] // group) + 1)
     q_rows = jnp.pad(q, ((0, 0), (0, 0), (0, hp - heads), (0, 0))
                      ).reshape(slots, rows, width)
     scalars = [slot_of, group_of, table, live_pages]
-    operands = scalars + [q_rows, mask.astype(jnp.int32)] + [pages] * group
-    row_blocks = [
-        pl.BlockSpec((1, page_size, width),
-                     lambda t, slot, grp, pt, *_, g=g: (
-                         pt[slot[t], grp[t] * group + g], 0, 0))
-        for g in range(group)]
+    operands = scalars + [q_rows, mask.astype(jnp.int32), pages]
     in_specs = [
         pl.BlockSpec((1, rows, width), lambda t, slot, *_: (slot[t], 0, 0)),
-        pl.BlockSpec((1, seq, group * page_size),
+        pl.BlockSpec((1, seq, keys),
                      lambda t, slot, grp, *_: (slot[t], 0, grp[t])),
-    ] + row_blocks
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
     out = pl.pallas_call(
         functools.partial(_mla_kernel, config=config),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1049,11 +1090,17 @@ def _paged_mla_call(q, pages, page_table, allowed, *, sm_scale,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, rows, value_width),
                                    lambda t, slot, *_: (slot[t], 0, 0)),
-            scratch_shapes=[pltpu.VMEM((rows, value_width), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2, keys, width), pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((rows, value_width), jnp.float32),
                             pltpu.VMEM((rows, _LANES), jnp.float32),
                             pltpu.VMEM((rows, _LANES), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((slots, rows, value_width),
                                        pages.dtype),
+        # A step's copies are started by the step before: in order.
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MLA_VMEM_LIMIT),
         interpret=interpret,
         name=PAGED_MLA_DECODE,
     )(*operands)

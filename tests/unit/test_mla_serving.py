@@ -205,32 +205,81 @@ def test_prefill_kernel_against_the_dense_softmax(dtype, seq, cache_len,
         atol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_walk_kernel_and_reference_agree(dtype):
+@pytest.mark.parametrize("dtype,depth,per_slot,step_keys", [
+    # A step larger than a slot's table: one step a slot.
+    (jnp.float32, (37, 150, 0), 40, None),
+    (jnp.bfloat16, (37, 150, 0), 40, None),
+    # 128-key steps of 32 pages: 2, 1 and 3 steps (an odd count puts the
+    # next slot's first step in the other buffer), each slot's last
+    # group partial, and every step's copies started by a step of the
+    # slot before or of its own.
+    (jnp.float32, (150, 37, 300), 100, 128),
+    (jnp.bfloat16, (150, 37, 300), 100, 128),
+    # An evicted slot between two live ones: its step copies nothing.
+    (jnp.float32, (300, 0, 150), 100, 128),
+    # A whole table (4 steps, 100 pages padded to 128 with the scratch
+    # page), then a slot one key into its second group, then evicted.
+    (jnp.float32, (400, 129, 0), 100, 128),
+], ids=["float32", "bfloat16", "float32-steps", "bfloat16-steps",
+        "float32-evicted-between", "float32-full-table"])
+def test_paged_walk_kernel_and_reference_agree(dtype, depth, per_slot,
+                                               step_keys, capfd,
+                                               monkeypatch, request):
     """One row a token read once: the interpreted Pallas walk, its lax walk
     (the same online-softmax order) and the gathered reference agree to
-    rounding, on slots at different depths, one
-    evicted (zeros), with a row wider than its 576 useful values."""
+    rounding, on slots at different depths, an evicted one (zeros), with a
+    row wider than its useful values. Over several steps a slot the walk
+    runs in TPU interpret mode, which moves the kernel's own page copies as
+    the chip would: a copy that lands only when waited for (a step that
+    did not wait for its pages reads stale rows), and one that lands when
+    started, with every semaphore counted back to zero at the kernel's
+    exit (a copy started and never waited for) and no copy landing in
+    rows a step still reads."""
+    from jax.experimental.pallas import tpu as pltpu
+
     rng = np.random.default_rng(7)
-    slots, heads, width, value, page, per_slot = 3, 8, 256, 128, 4, 40
+    slots, heads, width, value, page = 3, 8, 256, 128, 4
     pages = jnp.asarray(rng.normal(size=(slots * per_slot + 1, page, width)),
                         dtype)
     q = jnp.asarray(rng.normal(size=(slots, 1, heads, width)), dtype)
     table = jnp.asarray(1 + rng.permutation(slots * per_slot).reshape(
         slots, per_slot), jnp.int32)
-    depth = np.array([37, 150, 0])
+    depth = np.array(depth)
     allowed = jnp.asarray(np.arange(per_slot * page)[None, None, :]
                           < depth[:, None, None])
     args = (q, pages, table, allowed, 0.1, value)
-    kernel = paged.paged_mla_attention(*args, impl="paged", interpret=True)
+    if step_keys is None:
+        kernels = [paged.paged_mla_attention(*args, impl="paged",
+                                             interpret=True)]
+    else:
+        # The step is a constant of the module, read when the call is
+        # traced: set it here, and trace afresh on both sides of the test.
+        monkeypatch.setattr(paged, "_MLA_STEP_KEYS", step_keys)
+        paged._paged_mla_call.clear_cache()
+        request.addfinalizer(paged._paged_mla_call.clear_cache)
+        assert paged.mla_group_pages(page, per_slot) == 32
+        kernels = [paged._paged_mla_call(
+            q, pages, table, allowed, sm_scale=0.1, value_width=value,
+            interpret=pltpu.InterpretParams(dma_execution_mode=mode,
+                                            detect_races=True))
+                   for mode in ("on_wait", "eager")]
+        printed = capfd.readouterr().out
+        assert "non-zero count" not in printed, printed
+        assert "RACE DETECTED" not in printed, printed
     walk = paged._paged_mla_walk_lax(*args)
     want = paged.paged_mla_reference(*args)
-    tol = 2e-6 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(kernel, np.float32),
-                               np.asarray(walk, np.float32), atol=tol)
-    np.testing.assert_allclose(np.asarray(kernel[:2], np.float32),
-                               np.asarray(want[:2], np.float32), atol=tol)
-    assert not np.asarray(kernel[2], np.float32).any()
+    # float32: the rounding of the online softmax's rescales grows with
+    # the steps a slot takes (3e-6 over three).
+    tol = ((2e-6 if step_keys is None else 5e-6) if dtype == jnp.float32
+           else 2e-2)
+    live = depth > 0
+    for kernel in kernels:
+        np.testing.assert_allclose(np.asarray(kernel, np.float32),
+                                   np.asarray(walk, np.float32), atol=tol)
+        np.testing.assert_allclose(np.asarray(kernel[live], np.float32),
+                                   np.asarray(want[live], np.float32),
+                                   atol=tol)
+        assert not np.asarray(kernel[~live], np.float32).any()
 
 
 def test_expert_shares_add_up_to_the_uncut_grouped_layer():

@@ -377,6 +377,52 @@ def test_latent_prefill_compiles_at_the_cells_geometry(as_tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def test_latent_decode_compiles_at_the_cells_geometry(as_tpu):
+    """`axk1_docqa_32k`'s tick, one latent attention layer at A.X K1's
+    widths: 32 slots, a table of 2176 pages of 16 rows a slot, a pool of
+    16 385 pages of 640 lanes. Under `auto` on a TPU the read compiles
+    with no chip as ONE Mosaic custom call under `paged_mla_decode`, the
+    pool among its operands once (the kernel copies a step's pages
+    itself), never copied and no dense view of a slot's rows."""
+    from cloud_tpu.models.deepseek import MLAttention
+    from cloud_tpu.ops.paged_attention import PAGED_MLA_DECODE
+
+    devices = _tpu_topology()
+    if devices is None:
+        pytest.skip("no libtpu to describe a v5e")
+    slots, page, cache_len, num_pages = 32, 16, 34816, 16385
+    layer = MLAttention(num_heads=64, kv_lora_rank=512, q_lora_rank=1536,
+                        qk_nope_head_dim=128, qk_rope_head_dim=64,
+                        v_head_dim=128, decode=True, cache_len=cache_len,
+                        page_size=page, num_pages=num_pages,
+                        param_dtype=BF16)
+    x = S((slots, 1, 7168), BF16)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    pool = variables["cache"]["latent_pages"]
+    assert pool.shape == (num_pages, page, 640)
+
+    def tick(params, cache, x, active):
+        return layer.apply({"params": params, "cache": cache}, x, active,
+                           mutable=["cache"])
+
+    one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
+    text = jax.jit(tick, in_shardings=one, donate_argnums=1).trace(
+        variables["params"], variables["cache"], x,
+        S((slots, 1), jnp.bool_)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1 and PAGED_MLA_DECODE in calls[0].split(
+        " = ")[0], calls
+    # The call's line lists its operands' types (`operand_layout_
+    # constraints`): the pool's once, where a BlockSpec a page gave G.
+    pool_type = "bf16[{},{},640]".format(num_pages, page)
+    assert calls[0].count(pool_type) == 1, calls[0]
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and pool_type in line]
+    assert "[32,34816,640]" not in text
+
+
 def test_batched_experts_compile_copy_free_at_the_cells_geometry():
     """The hybrid cell's tick — 128 rows choosing 22 of 512 experts, 128
     held, relu^2 experts 1024 -> 2688 -> 1024 in bfloat16 — takes the form
