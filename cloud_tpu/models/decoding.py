@@ -265,6 +265,72 @@ def paged_kv_attention(module, q, k, v, mask, *, cache_len, page_size,
         **scales_kw)
 
 
+def paged_block_attention(module, q, k, v, mask, *, block, cache_len,
+                          page_size, num_pages, store_dtype, sm_scale,
+                          impl, rotate=None):
+    """Block-causal attention over the paged pool, for a block-diffusion
+    model (models/sdar.py): a block tick's B rows a slot, or a prefill's
+    whole blocks.
+
+    q: [slots, T, H, D]; k, v: [slots, T, H_kv, D]. Row j of slot s sits
+    at position `slot_steps[s] + j`: `slot_steps` is the slot's first
+    position that is not final yet, the first row of its open block.
+    The rows are rotated at those positions and their keys and values
+    written there — over what an earlier step of the same block wrote,
+    so the open block's rows are rewritten at every step — and row j
+    attends every key j' with j' // block <= (its position) // block:
+    the earlier blocks, and its own block both ways. Nothing here moves
+    the pointer: the caller moves it past rows that are final
+    (`paged_slot_advance`, when a block's clean pass has run).
+
+    mask: [slots, T] (None: every row), a live slot's rows. A dead
+    slot's rows write the scratch page and attend nothing. The cache
+    variables are `paged_kv_attention`'s, so the engine inserts,
+    evicts and resizes such a pool as any other."""
+    slots, seq = q.shape[:2]
+    kv_heads, head_dim = k.shape[2:]
+    if not cache_len or cache_len % page_size:
+        raise ValueError(
+            "cache_len ({}) must be a positive multiple of "
+            "page_size ({}).".format(cache_len, page_size))
+    pool_shape = (num_pages, page_size, kv_heads * head_dim)
+    key_pages = module.variable("cache", "key_pages", jnp.zeros,
+                                pool_shape, store_dtype)
+    value_pages = module.variable("cache", "value_pages", jnp.zeros,
+                                  pool_shape, store_dtype)
+    page_table = module.variable(
+        "cache", "page_table", jnp.zeros,
+        (slots, cache_len // page_size), jnp.int32)
+    slot_steps = module.variable(
+        "cache", "slot_steps", jnp.zeros, (slots,), jnp.int32)
+    slot_valid = module.variable(
+        "cache", "slot_valid", jnp.zeros, (slots, cache_len), jnp.bool_)
+
+    live = (jnp.ones((slots, seq), jnp.bool_) if mask is None
+            else mask.reshape(slots, seq).astype(jnp.bool_))
+    pos = slot_steps.value[:, None] + jnp.arange(seq, dtype=jnp.int32)
+    if rotate is not None:
+        q, k = rotate(q, pos), rotate(k, pos)
+    at = jnp.clip(pos, 0, cache_len - 1)
+    phys = jnp.where(live, jnp.take_along_axis(
+        page_table.value, at // page_size, 1), 0)
+    off = at % page_size
+    rows = lambda x: x.astype(store_dtype).reshape(
+        slots, seq, kv_heads * head_dim)
+    key_pages.value = key_pages.value.at[phys, off].set(rows(k))
+    value_pages.value = value_pages.value.at[phys, off].set(rows(v))
+    slot_valid.value = slot_valid.value.at[
+        jnp.arange(slots)[:, None], at].max(live)
+    allowed = live[:, :, None] & (
+        jnp.arange(cache_len)[None, None, :] // block
+        <= pos[:, :, None] // block)
+
+    from cloud_tpu.ops import paged_attention
+    return paged_attention(
+        q, key_pages.value, value_pages.value, page_table.value, allowed,
+        sm_scale=sm_scale, impl=impl, block=block)
+
+
 def _quantized_page_write(pages, scales, x, phys, off):
     """Write [slots, seq, H, D] decode K/V into int8 pages with
     per-page per-head amax rescale.
@@ -337,6 +403,22 @@ def paged_slot_rewind(cache_tree, delta, cache_len):
         if isinstance(tree, dict):
             if "key_pages" in tree or "latent_pages" in tree:
                 return rewind(tree)
+            return {k: walk(v) for k, v in tree.items()}
+        return tree
+
+    return walk(cache_tree)
+
+
+def paged_slot_advance(cache_tree, rows):
+    """Moves every layer's slot pointers `rows[s]` positions forward:
+    the rows behind them are final. A block-diffusion model's paged
+    attention (`paged_block_attention`) never moves them itself; its
+    caller does, after a prefill and after a block's clean pass.
+    `cache_tree` as `paged_slot_rewind`'s."""
+    def walk(tree):
+        if isinstance(tree, dict):
+            if "key_pages" in tree:
+                return dict(tree, slot_steps=tree["slot_steps"] + rows)
             return {k: walk(v) for k, v in tree.items()}
         return tree
 
@@ -647,5 +729,6 @@ def decode_latency_finish(start, n_tokens, result=None):
 __all__ = ["acquire_cache", "best_effort_donation", "bucket_length",
            "clear_cache_pool", "decode_latency_finish",
            "decode_latency_start", "decode_slot_update", "empty_cache",
+           "paged_block_attention", "paged_slot_advance",
            "paged_slot_rewind", "paged_slot_update", "release_cache",
            "validate_prompt_mask", "warp_logits"]

@@ -240,6 +240,12 @@ class GQAttention(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     page_dtype: str = ""
+    # Block-causal attention (a block-diffusion model, models/sdar.py):
+    # key j is visible to the token at i iff j // block <= i // block.
+    # 0 = causal. Over the paged pool the call is a block tick's: a
+    # slot's `block` rows of its open block (`decoding.
+    # paged_block_attention`).
+    attn_block: int = 0
 
     def _rope(self, x, positions):
         if not self.use_rope:
@@ -309,7 +315,8 @@ class GQAttention(nn.Module):
                                     sm_scale=self.attn_scale,
                                     window=self.sliding_window,
                                     logit_softcap=self.logit_softcap,
-                                    impl=self.attention_impl)
+                                    impl=self.attention_impl,
+                                    block=self.attn_block)
         out = out.astype(self.compute_dtype)
         return nn.DenseGeneral(d_model, axis=(-2, -1), use_bias=False,
                                dtype=self.compute_dtype,
@@ -321,10 +328,18 @@ class GQAttention(nn.Module):
         `CausalSelfAttention` makes, at H_kv-wide rows, with RoPE at
         each slot's own depth and a window layer's band
         (`decoding.paged_kv_attention` holds the contract)."""
-        from cloud_tpu.models.decoding import paged_kv_attention
+        from cloud_tpu.models.decoding import (paged_block_attention,
+                                               paged_kv_attention)
         if self.logit_softcap:
             raise NotImplementedError(
                 "logit_softcap is not supported over the paged pool.")
+        if self.attn_block:
+            return paged_block_attention(
+                self, q, k, v, mask, block=self.attn_block,
+                cache_len=self.cache_len, page_size=self.page_size,
+                num_pages=self.num_pages, store_dtype=self.compute_dtype,
+                sm_scale=self.attn_scale or 1.0 / np.sqrt(q.shape[-1]),
+                impl=self.attention_impl, rotate=self._rope)
         return paged_kv_attention(
             self, q, k, v, mask, cache_len=self.cache_len,
             page_size=self.page_size, num_pages=self.num_pages,
@@ -371,6 +386,14 @@ class GQAttention(nn.Module):
 
         idx, positions, allowed = decode_slot_update(
             self, mask, batch, seq, self.cache_len)
+        if self.attn_block:
+            # Block-causal in cache order, which is the order of
+            # positions (a prompt's real tokens are contiguous from
+            # slot 0): a row sees its whole block.
+            allowed = self.get_variable("cache", "slot_valid")[
+                :, None, :] & (
+                jnp.arange(self.cache_len)[None, None, :] // self.attn_block
+                <= (idx + jnp.arange(seq))[None, :, None] // self.attn_block)
         q = self._rope(q, positions)
         k = self._rope(k, positions)
 
@@ -400,7 +423,8 @@ class GQAttention(nn.Module):
                 sm_scale=self.attn_scale,
                 mask=self.get_variable("cache", "slot_valid"),
                 window=self.sliding_window,
-                logit_softcap=self.logit_softcap)
+                logit_softcap=self.logit_softcap,
+                block=self.attn_block)
             return lax.dynamic_slice(
                 out, (0, idx, 0, 0), (batch, seq) + q.shape[2:])
 
@@ -558,6 +582,7 @@ class LlamaBlock(nn.Module):
     page_size: int = 0  # paged-pool decode (serving); see attention
     num_pages: int = 0
     page_dtype: str = ""
+    attn_block: int = 0  # block-causal attention; see GQAttention
 
     @nn.compact
     def __call__(self, x, mask=None, deterministic=True):
@@ -585,6 +610,7 @@ class LlamaBlock(nn.Module):
                         page_size=self.page_size,
                         num_pages=self.num_pages,
                         page_dtype=self.page_dtype,
+                        attn_block=self.attn_block,
                         name="attention")(y, mask)
         if self.post_norms:
             # Gemma2/3 sandwich norms: each sublayer's OUTPUT is
@@ -633,8 +659,10 @@ class LlamaBlock(nn.Module):
                 capacity_factor=self.moe_capacity_factor,
                 compute_dtype=self.compute_dtype,
                 activation=self.mlp_activation,
-                norm_topk=self.moe_norm_topk, name="moe")(
-                    y, deterministic)
+                norm_topk=self.moe_norm_topk,
+                param_dtype=self.param_dtype, name="moe")(
+                    y, deterministic,
+                    token_mask=mask if self.decode else None)
             # Surfaced via mutable=["losses"] and summed into the
             # training loss by Trainer, same as TransformerBlock's
             # Switch-MoE path.
@@ -746,6 +774,11 @@ class LlamaLM(nn.Module):
                            jnp.dtype(self.param_dtype))
         super().__post_init__()
 
+    def attn_block(self):
+        """The attention mask's block length: 0, causal. A block-
+        diffusion class (models/sdar.py) says otherwise."""
+        return 0
+
     def _layer_attn(self, i):
         """(window, theta, scaling, rotated) for layer i under
         attn_kinds."""
@@ -818,6 +851,7 @@ class LlamaLM(nn.Module):
                            page_size=self.kv_page_size,
                            num_pages=self.kv_num_pages,
                            page_dtype=self.kv_page_dtype,
+                           attn_block=self.attn_block(),
                            name="block_%d" % i)(x, mask, deterministic)
         x = FusedRMSNorm(epsilon=self.norm_eps,
                          dtype=self.compute_dtype,

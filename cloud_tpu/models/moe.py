@@ -148,7 +148,12 @@ class TopKMoEMLP(nn.Module):
     batch, and let the aux loss balance load).
 
     Call returns (output, aux_loss); `LlamaBlock` sows the aux loss
-    into the "losses" collection like `TransformerBlock` does.
+    into the "losses" collection like `TransformerBlock` does. The
+    experts are stored in `param_dtype` (a served model's dtype; the
+    router stays float32) and, as `models.deepseek.DeepseekMoE`'s, the
+    router runs under the `MOE_ROUTER` scope and the experts under
+    `MOE_ROUTED_EXPERTS`, and a call sows `MOE_STATS` (the (token,
+    choice) pairs of real tokens among them: `token_mask` marks them).
     """
 
     num_experts: int = 8
@@ -158,10 +163,13 @@ class TopKMoEMLP(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16
     activation: str = "silu"
     norm_topk: bool = True  # Qwen3-MoE checkpoints may set False
+    param_dtype: jnp.dtype = jnp.float32  # the experts' stored dtype
 
     @nn.compact
-    def __call__(self, x, deterministic=True):
-        """x: [batch, seq, d_model] -> ([batch, seq, d_model], scalar)."""
+    def __call__(self, x, deterministic=True, token_mask=None):
+        """x: [batch, seq, d_model] -> ([batch, seq, d_model], scalar);
+        token_mask: optional [batch, seq], real tokens (a pad is routed
+        nowhere and counted nowhere)."""
         del deterministic  # no router noise in the Mixtral recipe
         from cloud_tpu.models.llama import _GATE_ACTIVATIONS
 
@@ -182,15 +190,22 @@ class TopKMoEMLP(nn.Module):
         router_kernel = self.param(
             "router", nn.initializers.lecun_normal(),
             (d_model, self.num_experts), jnp.float32)
-        logits = jnp.asarray(x, jnp.float32).reshape(
-            tokens, d_model) @ router_kernel              # [T, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_probs, top_idx = jax.lax.top_k(probs, k)      # [T, k]
-        if self.norm_topk:
-            gates = top_probs / jnp.sum(top_probs, axis=-1,
-                                        keepdims=True)
-        else:  # Qwen3-MoE norm_topk_prob=False: raw softmax mass
-            gates = top_probs
+        with jax.named_scope(MOE_ROUTER):
+            logits = jnp.asarray(x, jnp.float32).reshape(
+                tokens, d_model) @ router_kernel          # [T, E]
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_probs, top_idx = jax.lax.top_k(probs, k)  # [T, k]
+            if self.norm_topk:
+                gates = top_probs / jnp.sum(top_probs, axis=-1,
+                                            keepdims=True)
+            else:  # Qwen3-MoE norm_topk_prob=False: raw softmax mass
+                gates = top_probs
+        if token_mask is not None:
+            token_mask = token_mask.reshape(tokens)
+        if not self.is_initializing():
+            real = (tokens if token_mask is None
+                    else jnp.sum(token_mask.astype(jnp.int32)))
+            self.sow(MOE_STATS, "pairs_routed", real * k)
 
         # Load-balancing aux loss at HF Mixtral's scale
         # (load_balancing_loss_func): per-expert assignment counts are
@@ -202,10 +217,13 @@ class TopKMoEMLP(nn.Module):
         aux_loss = self.num_experts * jnp.sum(
             counts / tokens * probs.mean(axis=0))
 
-        out = routed_expert_ffn(self, x.reshape(tokens, d_model),
-                                top_idx, gates, self.num_experts,
-                                self.d_ff, capacity, act,
-                                self.compute_dtype)
+        with jax.named_scope(MOE_ROUTED_EXPERTS):
+            out = routed_expert_ffn(self, x.reshape(tokens, d_model),
+                                    top_idx, gates, self.num_experts,
+                                    self.d_ff, capacity, act,
+                                    self.compute_dtype,
+                                    token_mask=token_mask,
+                                    param_dtype=self.param_dtype)
         return out.reshape(batch, seq, d_model).astype(x.dtype), aux_loss
 
 

@@ -123,6 +123,12 @@ Kernels:
                             over an EVA layer's one run of live rows:
                             the summaries of the windows behind the
                             query, then its own window's ring rows
+    paged_block_decode      the paged_decode walk for a block-diffusion
+                            tick (`models.sdar.SdarLM`): a slot's B rows
+                            of its open block over every live page of
+                            the earlier blocks and the block's own,
+                            a key/value head's page against B x its
+                            query heads' rows, one call a layer
     paged_mla_decode        ops/paged_attention.py latent walk
                             (`DeepseekLM`): every live page of a slot,
                             each latent row read once for the scores of
@@ -209,6 +215,16 @@ Counters:
                           expanded (`DeepseekLM.latent_absorbed` of
                           the prefill's bucket is false)
     mla_prefill_tokens_absorbed  and of those that read it absorbed
+    diffusion_slot_steps  a block-diffusion model's denoising steps of
+                          slots, summed over ticks (a clean pass is
+                          none)
+    diffusion_tokens_committed  tokens those steps committed; a tick
+                          record's `committed` is its term
+    diffusion_blocks_closed  blocks whose clean pass ran (their rows
+                          made final in the pool)
+    diffusion_rows_computed  query rows the ticks ran: B a live slot,
+                          masked, committed and clean rows alike; a
+                          tick record's `rows` is its term
 
 The hot loops' jitted functions are named so (a constant beside the
 jit), and the trace's program line reads `jit_<name>`. The serving
@@ -220,10 +236,14 @@ Programs:
 
     train_step           training/trainer.py, one optimizer step
     serve_tick           serving/engine.py, one decode tick over all
-                         slots
+                         slots (a block-diffusion model's: a block
+                         tick, one denoising step or clean pass of
+                         each slot's open block)
     serve_prefill        serving/engine.py, one dense prefill + first
                          token: a whole prompt, a hit's suffix, or
-                         the last chunk of a chunked prefill
+                         the last chunk of a chunked prefill (a
+                         block-diffusion model's: the prompt's whole
+                         blocks, no token)
     slot_insert          serving/engine.py, a prefill scattered into
                          a slot
     slot_evict           serving/engine.py, finished slots' rows

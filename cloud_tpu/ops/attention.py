@@ -23,6 +23,12 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   ops/paged_attention.py), and the in-kernel walk runs between the
   causal / band bounds of its rows (`_live_blocks`, the same arithmetic
   `flash_plan` counts with).
+- Block-causal attention (`block=B`, the block-diffusion models'
+  mask): key j is visible to query i iff j // B <= i // B, causal
+  between blocks and bidirectional inside one. It is the causal walk
+  with each row's edge moved to the end of its block (`_row_edge`), so
+  the tiles, the schedule and the masks count it with the same
+  arithmetic; B <= 1 is the causal mask and builds what it always did.
 - The online-softmax state (acc, m, l) is carried as values along a
   row block's k walk and parked in VMEM scratch between span pairs; m/l
   scratch is (group, span, 128) lane-broadcast.
@@ -90,6 +96,7 @@ class _Config(NamedTuple):
     window: int = 0  # sliding-window width; 0 = full causal
     softcap: float = 0.0  # Gemma2-style tanh logit cap; 0 = off
     backward: Optional["FlashPlan"] = None  # the backward's own tiles
+    block: int = 0  # block-causal: key j seen by row i iff j//B <= i//B
 
     block_q = property(lambda self: self.tiles.block_q)
     block_k = property(lambda self: self.tiles.block_k)
@@ -125,7 +132,7 @@ def repeat_kv(k, num_heads):
 
 
 def mha_reference(q, k, v, causal=True, sm_scale=None, mask=None,
-                  window=None, logit_softcap=None):
+                  window=None, logit_softcap=None, block=None):
     """Pure-jnp multi-head attention, layout [B, S, H, D].
 
     The correctness oracle for the kernel and the fallback path for
@@ -135,7 +142,9 @@ def mha_reference(q, k, v, causal=True, sm_scale=None, mask=None,
     (Mistral-style) attention — row i attends keys (i-window, i];
     requires causal=True. logit_softcap: Gemma2-style tanh capping,
     logits -> cap * tanh(logits / cap), applied after the softmax scale
-    and before any masking (the HF Gemma2 order).
+    and before any masking (the HF Gemma2 order). block: block-causal
+    attention — row i attends keys j with j // block <= i // block;
+    requires causal=True and no window.
     """
     head_dim = q.shape[-1]
     if sm_scale is None:
@@ -143,9 +152,7 @@ def mha_reference(q, k, v, causal=True, sm_scale=None, mask=None,
     if v.shape != k.shape:
         raise ValueError("k and v must have identical shapes; got "
                          "{} vs {}.".format(k.shape, v.shape))
-    if window is not None and not causal:
-        raise ValueError("window (sliding-window attention) requires "
-                         "causal=True.")
+    _check_mask_kind(causal, window, block)
     if k.shape[2] != q.shape[2]:
         heads, h_kv = q.shape[2], k.shape[2]
         if heads % h_kv:
@@ -160,7 +167,12 @@ def mha_reference(q, k, v, causal=True, sm_scale=None, mask=None,
         cap = float(logit_softcap)
         logits = cap * jnp.tanh(logits / cap)
     seq_q, seq_k = q.shape[1], k.shape[1]
-    if causal:
+    if causal and (block or 0) > 1:
+        row = jnp.arange(seq_q)[:, None]
+        col = jnp.arange(seq_k)[None, :]
+        logits = jnp.where(col // int(block) <= row // int(block), logits,
+                           _NEG_INF)
+    elif causal:
         allowed = jnp.tril(jnp.ones((seq_q, seq_k), dtype=bool))
         if window is not None:
             # Band: col in (row - window, row]. HF Mistral's convention
@@ -209,7 +221,7 @@ class FlashPlan(NamedTuple):
         return {FLASH_FWD: steps, FLASH_BWD_DQ: steps,
                 FLASH_BWD_DKV: steps}
 
-    def tiles(self, causal=True, window=0):
+    def tiles(self, causal=True, window=0, block=0):
         """Score tiles a q head's walk visits in one pass: `walked` by
         the bounds the kernels loop between, `live` by asking every
         tile of the square whether it holds a visible entry, `dense`
@@ -223,14 +235,14 @@ class FlashPlan(NamedTuple):
                 lo, hi = _live_blocks(
                     qi * self.span_q + s * self.block_q, self.block_q,
                     self.block_k, kj * per_k, (kj + 1) * per_k, causal,
-                    window)
+                    window, attn_block=block)
                 walked += max(hi - lo, 0)
         rows = range(0, self.seq_pad, self.block_q)
         cols = range(0, self.seq_pad, self.block_k)
         live = sum(_live(r0, self.block_q, c0, self.block_k, causal,
-                         window) for r0 in rows for c0 in cols)
+                         window, block) for r0 in rows for c0 in cols)
         pairs_live = sum(
-            _live(r0, self.span_q, c0, self.span_k, causal, window)
+            _live(r0, self.span_q, c0, self.span_k, causal, window, block)
             for r0 in range(0, self.seq_pad, self.span_q)
             for c0 in range(0, self.seq_pad, self.span_k))
         return {"walked": walked, "live": live,
@@ -238,35 +250,58 @@ class FlashPlan(NamedTuple):
                 "pairs": len(self.pairs), "pairs_live": pairs_live}
 
 
-def _live(r0, rows, c0, cols, causal, window):
+def _check_mask_kind(causal, window, block):
+    if window is not None and not causal:
+        raise ValueError("window (sliding-window attention) requires "
+                         "causal=True.")
+    if (block or 0) > 1 and (not causal or window is not None):
+        raise ValueError("block (block-causal attention) requires "
+                         "causal=True and no window.")
+
+
+def _row_edge(row, block):
+    """The last column row `row` sees: itself, or under block-causal
+    attention the last column of its block."""
+    if block > 1:
+        return row // block * block + block - 1
+    return row
+
+
+def _live(r0, rows, c0, cols, causal, window, block=0):
     """Whether rows [r0, r0 + rows) see any of columns [c0, c0 + cols):
-    at or below the diagonal and, under a window, not wholly left of
-    the band (col > row - window)."""
+    at or below the diagonal (under `block`, the end of the last row's
+    block) and, under a window, not wholly left of the band
+    (col > row - window)."""
     if not causal:
         return True
-    seen = c0 <= r0 + rows - 1
+    seen = c0 <= _row_edge(r0 + rows - 1, block)
     if window:
         seen = seen and c0 + cols - 1 > r0 - window
     return seen
 
 
 def _live_blocks(start, size, block, lo, hi, causal, window,
-                 transposed=False):
+                 transposed=False, attn_block=0):
     """[lo', hi') within [lo, hi): the blocks of `block` columns that
     rows [start, start + size) see — or, `transposed`, the blocks of
     `block` rows that see columns [start, start + size). Python ints
-    (`flash_plan`) or traced scalars (the kernels' loop bounds)."""
+    (`flash_plan`) or traced scalars (the kernels' loop bounds).
+    `attn_block`: block-causal attention's block length (0 = causal)."""
     if not causal:
         return lo, hi
     traced = not isinstance(start, int)
     most = jnp.maximum if traced else max
     least = jnp.minimum if traced else min
     if transposed:
-        lo = most(lo, start // block)
+        # The first row that sees column `start`: the first of the
+        # column's attention block.
+        first = (start // attn_block * attn_block if attn_block > 1
+                 else start)
+        lo = most(lo, first // block)
         if window:
             hi = least(hi, (start + size - 2 + window) // block + 1)
         return lo, hi
-    hi = least(hi, (start + size - 1) // block + 1)
+    hi = least(hi, _row_edge(start + size - 1, attn_block) // block + 1)
     if window:
         lo = most(lo, most(start - window + 1, 0) // block)
     return lo, hi
@@ -299,7 +334,7 @@ def _pow2_tile(target, cap):
 
 
 def flash_plan(seq, head_dim, group=1, itemsize=2, causal=True, window=0,
-               block_q=None, block_k=None, backward=False):
+               block_q=None, block_k=None, backward=False, block=0):
     """Tiles, spans and schedule for `seq` tokens at `head_dim`, `group`
     q heads a kv head, operands of `itemsize` bytes.
 
@@ -322,7 +357,9 @@ def flash_plan(seq, head_dim, group=1, itemsize=2, causal=True, window=0,
     f32 accumulator and the lane-broadcast m and l. A side that does
     not fit whole gets the most whole tiles that do and that divide the
     padded sequence evenly, and the grid walks the span pairs that hold
-    a visible entry.
+    a visible entry. `block`: block-causal attention's block length
+    (0 = causal), whose live tiles are counted by the kernels' own
+    arithmetic (`_live`).
     """
     window = int(window or 0)
     lane_seq = -(-seq // _LANES) * _LANES
@@ -359,6 +396,7 @@ def flash_plan(seq, head_dim, group=1, itemsize=2, causal=True, window=0,
     per_k_row = 8 * lanes * itemsize + 8 * lanes
     per_q_row = group * (6 * lanes * itemsize + 4 * lanes
                          + 2 * 4 * _LANES)
+    block = int(block or 0)
     share = _VMEM_BUDGET // 2
 
     def span(tile, fit):
@@ -374,7 +412,7 @@ def flash_plan(seq, head_dim, group=1, itemsize=2, causal=True, window=0,
         (qi, kj) for qi in range(seq_pad // span_q)
         for kj in range(seq_pad // span_k)
         if _live(qi * span_q, span_q, kj * span_k, span_k, causal,
-                 window))
+                 window, block))
     return FlashPlan(block_q, block_k, span_q, span_k, seq_pad, pairs)
 
 
@@ -395,7 +433,8 @@ def _pair_ends(config, at, transposed=False):
     if transposed:
         size, block = block, size
     lo, hi = _live_blocks(at, size, block, 0, config.seq_pad // block,
-                          config.causal, config.window, transposed)
+                          config.causal, config.window, transposed,
+                          config.block)
     return lo, hi - 1
 
 
@@ -444,7 +483,11 @@ def _tile_mask(config, r0, c0, key_valid, transposed=False):
     terms = []
     if config.kv_len < config.seq_pad:
         terms.append(key < config.kv_len - c0)
-    if config.causal:
+    if config.causal and config.block > 1:
+        # col // B <= row // B: col up to the end of the row's block.
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - key_axis)
+        terms.append(c0 + key <= _row_edge(r0 + row, config.block))
+    elif config.causal:
         # col <= row, as an offset between the two iotas.
         ahead = key - jax.lax.broadcasted_iota(jnp.int32, shape,
                                                1 - key_axis)
@@ -532,7 +575,8 @@ def _fwd_kernel(qi_ref, kj_ref, *refs, config):
 
         lo, hi = _live_blocks(r0, block_q, block_k, k_lo,
                               k_lo + config.span_k // block_k,
-                              config.causal, config.window)
+                              config.causal, config.window,
+                              attn_block=config.block)
         state = jax.lax.fori_loop(lo, hi, k_walk, tuple(
             (m_ref[g, rows, :1], l_ref[g, rows, :1], acc_ref[g, rows, :])
             for g in heads))
@@ -690,7 +734,8 @@ def _dq_kernel(qi_ref, kj_ref, *refs, config):
 
         lo, hi = _live_blocks(r0, block_q, block_k, k_lo,
                               k_lo + config.span_k // block_k,
-                              config.causal, config.window)
+                              config.causal, config.window,
+                              attn_block=config.block)
         dq = jax.lax.fori_loop(
             lo, hi, k_walk, tuple(dq_acc[g, rows, :] for g in heads))
         for g in heads:
@@ -764,7 +809,7 @@ def _dkdv_kernel(qi_ref, kj_ref, *refs, config):
         lo, hi = _live_blocks(c0, block_k, block_q, q_lo,
                               q_lo + config.span_q // block_q,
                               config.causal, config.window,
-                              transposed=True)
+                              transposed=True, attn_block=config.block)
         dk, dv = jax.lax.fori_loop(
             lo, hi, q_walk, (dk_acc[cols, :], dv_acc[cols, :]))
         dk_acc[cols, :] = dk
@@ -890,7 +935,8 @@ _flash_attention_masked.defvjp(_flash_attention_masked_fwd,
 
 def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
                     window=None, logit_softcap=None, block_q=None,
-                    block_k=None, interpret: Optional[bool] = None):
+                    block_k=None, interpret: Optional[bool] = None,
+                    block=None):
     """Blockwise flash attention, layout [batch, seq, heads, head_dim].
 
     Args:
@@ -924,6 +970,10 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
             internally.
         interpret: Force Pallas interpret mode. Default: interpret
             everywhere except on real TPU backends.
+        block: Block-causal attention (block-diffusion models): row i
+            attends keys j with j // block <= i // block — causal
+            between blocks, bidirectional inside one. Requires
+            causal=True and no window; None/0/1 = the causal mask.
 
     Returns:
         [B, S, H, D] attention output, differentiable w.r.t. q/k/v.
@@ -939,16 +989,15 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
                 heads, h_kv))
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
-    if window is not None and not causal:
-        raise ValueError("window (sliding-window attention) requires "
-                         "causal=True.")
+    _check_mask_kind(causal, window, block)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     shape = (head_dim, heads // h_kv, q.dtype.itemsize, causal, window)
-    tiles = flash_plan(seq, *shape, block_q, block_k)
+    extra = {"block": int(block)} if (block or 0) > 1 else {}
+    tiles = flash_plan(seq, *shape, block_q, block_k, **extra)
     seq_pad = tiles.seq_pad
     backward = flash_plan(seq_pad, *shape, tiles.block_q, block_k,
-                          backward=True)
+                          backward=True, **extra)
 
     if mask is not None and mask.shape != (batch, seq):
         raise ValueError(
@@ -964,7 +1013,7 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
                          kv_group=heads // k.shape[2],
                          window=int(window or 0),
                          softcap=float(logit_softcap or 0.0),
-                         backward=backward)
+                         backward=backward, **extra)
 
         def fold(x):
             n_heads = x.shape[2]
@@ -1006,16 +1055,19 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, mask=None,
 
 
 def attention(q, k, v, causal=True, sm_scale=None, mask=None,
-              window=None, logit_softcap=None, impl="auto"):
+              window=None, logit_softcap=None, impl="auto", block=None):
     """Dispatching attention: pallas flash kernel or jnp reference.
 
     impl: "auto" picks the flash kernel on TPU (with or without a key
     mask — padded batches stay on the fast path), the jnp reference
     elsewhere; "flash"/"reference" force a path. window: sliding-window
-    width; logit_softcap: Gemma2 tanh capping (both paths honor both).
+    width; logit_softcap: Gemma2 tanh capping; block: block-causal
+    attention's block length (every path honors all three).
     """
     kwargs = dict(causal=causal, sm_scale=sm_scale, mask=mask,
                   window=window, logit_softcap=logit_softcap)
+    if (block or 0) > 1:
+        kwargs["block"] = int(block)
     if impl == "flash":
         return flash_attention(q, k, v, **kwargs)
     if impl == "reference":

@@ -72,7 +72,11 @@ Grid and masking contract (see /opt/skills/guides/pallas_guide.md):
   average over pool garbage. Holes inside the live range are the
   mask's, as before: the live bound only ends the walk.
 - `seq` (1 for the plain tick, spec_k + 1 for the speculative verify
-  window) rides in the rows of the one product a step.
+  window, B for a block-diffusion tick) rides in the rows of the one
+  product a step. A block tick's B rows of a slot read the same keys
+  (bidirectional inside the open block, every earlier block before
+  it): the call goes under `PAGED_BLOCK_DECODE`, and under grouped
+  queries each key/value head's page meets B x (H / H_kv) rows.
 
 The gathered-lax reference below is bitwise the math
 `models/transformer.py::_paged_decode_attention` shipped before this
@@ -129,6 +133,9 @@ PAGED_DECODE = "paged_decode"
 #: The same walk over a window layer's band (`window=`): it starts at
 #: the slot's first live page as well as ending at its last.
 PAGED_DECODE_WINDOW = "paged_decode_window"
+#: The same walk for a block-diffusion tick (`block=`): a slot's B rows
+#: of its open block, over every earlier block and the block itself.
+PAGED_BLOCK_DECODE = "paged_block_decode"
 
 #: `pl.pallas_call(name=)` is the innermost scope, and XLA:TPU names
 #: the custom call by it (`%<name>.N`). The benchmark's accepted
@@ -159,6 +166,7 @@ class _PagedConfig(NamedTuple):
     quantized: bool = False
     kv_heads: int = 0     # 0 = heads (MHA); fewer = grouped queries
     banded: bool = False  # the mask has a lower edge (a window layer)
+    block: bool = False   # a block-diffusion tick's rows: the call's name
 
     @property
     def q_group(self):
@@ -577,7 +585,8 @@ def _paged_forward(config, q, key_pages, value_pages, page_table,
         out_shape=jax.ShapeDtypeStruct(
             q.shape, out_dtype, vma=partition.vma_of(*operands)),
         interpret=config.interpret,
-        name=_CALL_PREFIX + (PAGED_DECODE_WINDOW if config.banded
+        name=_CALL_PREFIX + (PAGED_BLOCK_DECODE if config.block
+                             else PAGED_DECODE_WINDOW if config.banded
                              else PAGED_DECODE),
     )(*operands)
 
@@ -676,9 +685,10 @@ def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "banded"))
+                   static_argnames=("sm_scale", "interpret", "banded",
+                                    "block"))
 def _paged_call(q, key_pages, value_pages, page_table, allowed, *scales,
-                sm_scale, interpret, banded=False):
+                sm_scale, interpret, banded=False, block=False):
     """One device's call, `[slots, seq, H', D]` over its H' heads: the
     walk's geometry from the shapes, then the kernel. Jitted because a
     model's layers all make this call with the same shapes, and a
@@ -696,7 +706,7 @@ def _paged_call(q, key_pages, value_pages, page_table, allowed, *scales,
     config = _PagedConfig(sm_scale=sm_scale, heads=local_heads, seq=seq,
                           page_size=page_size, group=group,
                           interpret=interpret, quantized=bool(scales),
-                          kv_heads=kv_heads, banded=banded)
+                          kv_heads=kv_heads, banded=banded, block=block)
     table, mask, live_pages = _grouped(page_table, allowed, page_size,
                                        group)
     first = (_first_groups(allowed, page_size, group) if banded
@@ -731,7 +741,8 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
                            allowed, sm_scale=None,
                            interpret: Optional[bool] = None,
                            key_scales=None, value_scales=None,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None,
+                           block: Optional[int] = None):
     """Pallas paged decode attention; layouts as the reference.
 
     Handles both the seq=1 plain tick and the seq=spec_k+1 speculative
@@ -744,7 +755,10 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
     already carries the band) the walk starts at the slot's first
     live page, so a tick reads at most
     `ceil(window / page_size) + 1` pages a slot, rounded out to
-    groups, and the call goes under `PAGED_DECODE_WINDOW`.
+    groups, and the call goes under `PAGED_DECODE_WINDOW`. With `block`
+    (a block-diffusion tick: `seq` is the block's B rows and `allowed`
+    already carries the block-causal bound) the call goes under
+    `PAGED_BLOCK_DECODE`.
 
     interpret: None (default) compiles the kernel on TPU and runs the
     lax page-walk form of the same math elsewhere; True forces Pallas
@@ -793,9 +807,11 @@ def paged_decode_attention(q, key_pages, value_pages, page_table,
             specs += [P(None, tp)] * 2
         return tuple(specs), by_head, None
 
+    if window and block:
+        raise ValueError("a block tick reads no window band.")
     kernel = functools.partial(_paged_call, sm_scale=float(sm_scale),
                                interpret=bool(interpret),
-                               banded=bool(window))
+                               banded=bool(window), block=bool(block))
     return partition.per_shard(kernel, args, plan, interpret)
 
 
@@ -808,7 +824,8 @@ def paged_attention(q, key_pages, value_pages, page_table, allowed,
                     sm_scale=None, impl="auto",
                     interpret: Optional[bool] = None,
                     key_scales=None, value_scales=None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    block: Optional[int] = None):
     """Dispatching paged decode attention: Pallas kernel or gathered lax.
 
     impl: "paged" forces the kernel, "reference" forces the gathered
@@ -822,7 +839,9 @@ def paged_attention(q, key_pages, value_pages, page_table, allowed,
     picked (the dequant contract in the module docstring). `window`
     says that `allowed` is a window layer's band (the mask itself
     decides every weight): the kernel's walk then starts at the first
-    live page.
+    live page. `block` says that the rows are a block-diffusion tick's
+    block (the mask decides every weight here too): the kernel is
+    declared `PAGED_BLOCK_DECODE`.
     """
     env = os.environ.get("CLOUD_TPU_PAGED_KERNEL", "").strip()
     if env == "1":
@@ -842,7 +861,7 @@ def paged_attention(q, key_pages, value_pages, page_table, allowed,
                                       interpret=interpret,
                                       key_scales=key_scales,
                                       value_scales=value_scales,
-                                      window=window)
+                                      window=window, block=block)
     return paged_attention_reference(q, key_pages, value_pages,
                                      page_table, allowed,
                                      sm_scale=sm_scale,

@@ -30,6 +30,19 @@ copy happens here, device-side, fixed shape) or to the scratch page
 (shared content already resident; the slot's page table still points
 at the shared page).
 
+Block diffusion (a model whose class generates by denoising blocks,
+`models.sdar.SdarLM`): the tick is a block tick. Every slot runs the B
+rows of its open block: a denoising step, which commits 1 to B of the
+block's masked rows, or the block's clean pass once none is left, which
+leaves the block's final keys and values in the pool and opens the next
+block. Slots at different steps of their blocks, with different
+`denoising_steps`, closing and opening, ride one executable; the
+out-array is the speculative tick's form (committed tokens, their
+offsets in the block, the count). The prefill writes the prompt's whole
+blocks and samples nothing. No prefix reuse, host tier or speculation:
+a prompt's last partial block is re-run with the tokens generated
+beside it, so no page of it is final when the prefill ends.
+
 Bit-identical contract: a request decoded through the engine produces
 exactly the tokens `models.transformer.generate()` would produce for it
 solo (same rng, same sampling config) — with or without prefix sharing
@@ -171,6 +184,9 @@ class PrefillResult:
     bucket: int             # pow2 SUFFIX bucket the prefill compiled at
     n_steps: int            # max_new_tokens for this request
     prompt_len: int         # full prompt length (prefix + suffix)
+    # A block-diffusion request (no first token): its first open block,
+    # (tokens [B], masked rows [B]), from `models.sdar.open_block`.
+    open_block: tuple = None
 
 
 @dataclasses.dataclass
@@ -718,6 +734,18 @@ class DecodeEngine:
         #: reuse, no host tier and no speculation until state
         #: snapshots exist (ROADMAP 2.5).
         self.state_layers = getattr(model, "state_layers", 0)
+        #: The block length of a model that generates by denoising
+        #: blocks (its class carries one: `models.sdar.SdarLM`); 0 for
+        #: the classes that decode a token a slot and tick. Such a model
+        #: ticks a block a slot (`_block_tick_impl`).
+        self.block = getattr(model, "block_length", 0)
+        if self.block and (self.spec_on or page_dtype or len(ladder) > 1):
+            raise NotImplementedError(
+                "spec_k > 0, quantized pages and a geometry ladder are "
+                "not served for a block-diffusion model ({}): its tick "
+                "rewrites the open block's rows in place, which no "
+                "draft, page scale or resize walk expects.".format(
+                    type(model).__name__))
         if self.layout is not None and (self.spec_on or page_dtype):
             raise NotImplementedError(
                 "spec_k > 0 and quantized pages are not served for a "
@@ -793,6 +821,24 @@ class DecodeEngine:
             "has_eos": jnp.zeros((slots,), jnp.bool_),
             "step_keys": jnp.zeros((slots, key_width, 2), jnp.uint32),
         }
+        if self.block:
+            # A slot's open block: its rows' tokens (the mask token on a
+            # masked row), which rows are masked, its first position,
+            # the steps it has had, the masked rows it opened with, the
+            # request's denoising steps a block, and where its new
+            # tokens end.
+            b = self.block
+            self.ctl.update({
+                "block_tokens": jnp.zeros((slots, b), jnp.int32),
+                "block_masked": jnp.zeros((slots, b), jnp.bool_),
+                "block_start": jnp.zeros((slots,), jnp.int32),
+                "block_step": jnp.zeros((slots,), jnp.int32),
+                "block_masks0": jnp.zeros((slots,), jnp.int32),
+                "denoise_steps": jnp.ones((slots,), jnp.int32),
+                "gen_end": jnp.zeros((slots,), jnp.int32),
+            })
+            self._block_prefill = _program(
+                SERVE_PREFILL, self._block_prefill_impl, (1,))
         if self.spec_on:
             self._tick = _program(SERVE_TICK, self._spec_tick_impl,
                                   (2, 3, 4))
@@ -803,9 +849,12 @@ class DecodeEngine:
             self._resize = _program(SLOT_RESIZE, self._resize_spec_impl,
                                     (0, 1, 2))
         else:
-            self._tick = _program(SERVE_TICK, self._tick_impl, (1, 2))
-            self._insert = _program(SLOT_INSERT, self._insert_impl,
-                                    (0, 1))
+            self._tick = _program(SERVE_TICK, (
+                self._block_tick_impl if self.block else self._tick_impl),
+                (1, 2))
+            self._insert = _program(SLOT_INSERT, (
+                self._insert_block_impl if self.block
+                else self._insert_impl), (0, 1))
             self._evict = _program(SLOT_EVICT, self._evict_impl, (0, 1))
             self._resize = _program(SLOT_RESIZE, self._resize_impl,
                                     (0, 1))
@@ -868,6 +917,12 @@ class DecodeEngine:
         return taken
 
     def _refuse_page_reuse(self, what):
+        if self.block:
+            raise NotImplementedError(
+                "{} is not served for a block-diffusion model: a "
+                "prompt's last partial block is run again with the "
+                "tokens generated beside it, so its page is not the "
+                "prompt's alone.".format(what))
         if self.layout is not None:
             raise NotImplementedError(
                 "{} is not served for a model whose slots keep a ring "
@@ -958,8 +1013,9 @@ class DecodeEngine:
         """The second half: blocks until `flight`'s first token is on
         the host (the TTFT point) and returns its `PrefillResult`."""
         with spans.span("prefill_fetch", rid=rid):
-            flight.result.first_token = int(
-                runtime.device_fetch(flight.first)[0])
+            first = int(runtime.device_fetch(flight.first)[0])
+        if not self.block:   # a block-diffusion prefill samples nothing
+            flight.result.first_token = first
         return flight.result
 
     def _dispatch_prefill(self, prompt, max_new_tokens, rng, sampling,
@@ -968,6 +1024,9 @@ class DecodeEngine:
         from cloud_tpu.models.decoding import (CACHE_ZERO, acquire_cache,
                                                bucket_length)
 
+        if self.block:
+            return self._dispatch_block_prefill(prompt, max_new_tokens,
+                                                prefix_len, rid, overlapped)
         with spans.span("prefill_host", rid=rid):
             prompt = np.asarray(prompt, np.int32).reshape(-1)
             prompt_len = int(prompt.shape[0])
@@ -1028,6 +1087,47 @@ class DecodeEngine:
             step_keys=step_keys, bucket=bucket,
             n_steps=int(max_new_tokens), prompt_len=prompt_len))
 
+    def _dispatch_block_prefill(self, prompt, max_new_tokens, prefix_len,
+                                rid, overlapped):
+        """A block-diffusion request's prefill: the prompt's whole
+        blocks, block-causally, into a dense cache; what remains of the
+        prompt opens the first block (`PrefillResult.open_block`). The
+        flight's `first` is the cache's token count, fetched only to
+        know the prefill done: no token is sampled."""
+        from cloud_tpu.models.decoding import (CACHE_ZERO, acquire_cache,
+                                               bucket_length)
+        from cloud_tpu.models.sdar import open_block
+
+        with spans.span("prefill_host", rid=rid):
+            if prefix_len:
+                self._refuse_page_reuse("a prefix hit")
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            opened, masked, start = open_block(
+                prompt, self.block, self.model.mask_token,
+                end=len(prompt) + int(max_new_tokens))
+            if not start:
+                raise ValueError(
+                    "a block-diffusion prompt needs at least one whole "
+                    "block ({} tokens); got {}.".format(self.block,
+                                                        len(prompt)))
+            bucket = bucket_length(start, self.max_seq_len)
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :start] = prompt[:start]
+            mask = np.zeros((1, bucket), bool)
+            mask[0, :start] = True
+            cache = _plain(acquire_cache(self._dense, 1))
+            self._note(CACHE_ZERO, rid=rid)
+        with spans.span("prefill_dispatch", rid=rid):
+            pcache, done = self._block_prefill(
+                self._params, cache, jnp.asarray(tokens), jnp.asarray(mask))
+            done.copy_to_host_async()
+            self._note(SERVE_PREFILL, rows=bucket, rid=rid,
+                       overlapped=overlapped)
+        return PrefillFlight(done, PrefillResult(
+            first_token=None, pcache=pcache, dpcache=None, step_keys=None,
+            bucket=bucket, n_steps=int(max_new_tokens),
+            prompt_len=int(prompt.shape[0]), open_block=(opened, masked)))
+
     def prefill_chunks(self, prompt, max_new_tokens, rng, sampling,
                        chunk_size, prefix_len=0, gather_vec=None,
                        key_override=None, rid=None):
@@ -1042,6 +1142,11 @@ class DecodeEngine:
         first `step()` (which must run on the tick thread when
         `prefix_len > 0` — the gather reads the tick-donated pool
         cache)."""
+        if self.block:
+            raise NotImplementedError(
+                "a block-diffusion model is prefilled whole (its blocks' "
+                "mask is block-causal, and no chunk plan keeps to the "
+                "blocks).")
         chunk_size = int(chunk_size)
         if chunk_size < 1 or chunk_size & (chunk_size - 1):
             raise ValueError(
@@ -1080,6 +1185,10 @@ class DecodeEngine:
         already resident and shared. One fixed-shape executable for
         every bucket — the prefill cache is always full-length dense.
         """
+        if self.block:
+            self._insert_block(slot, result, page_vec, scatter_vec,
+                               sampling)
+            return
         vocab = self.model.vocab_size
         top_k = sampling["top_k"]
         top_p = sampling["top_p"]
@@ -1103,6 +1212,23 @@ class DecodeEngine:
         self._note(SLOT_INSERT)
         self.release_prefill(result)
 
+    def _insert_block(self, slot, result, page_vec, scatter_vec, sampling):
+        """`insert` of a block-diffusion request: the prompt's whole
+        blocks into the slot's pages, its first block opened in `ctl`
+        with the request's denoising steps (`sampling`'s
+        `denoising_steps`, B where it gives none)."""
+        opened, masked = result.open_block
+        steps = sampling.get("denoising_steps") or self.block
+        start = result.prompt_len // self.block * self.block
+        self.cache, self.ctl = self._insert(
+            self.cache, self.ctl, _plain(result.pcache), np.int32(slot),
+            jnp.asarray(page_vec, jnp.int32),
+            jnp.asarray(scatter_vec, jnp.int32), jnp.asarray(opened),
+            jnp.asarray(masked), np.int32(masked.sum()), np.int32(steps),
+            np.int32(start), np.int32(result.prompt_len + result.n_steps))
+        self._note(SLOT_INSERT)
+        self.release_prefill(result)
+
     def tick(self):
         """Advances every active slot. Plain engine: one token per
         call, device out-array `[2, S]` (row 0: sampled token, row 1:
@@ -1110,8 +1236,13 @@ class DecodeEngine:
         call, out-array `[spec_k + 4, S]` — rows 0..spec_k committed
         tokens (-1 on inactive slots), row spec_k + 1 the commit count,
         row spec_k + 2 the finished flag, row spec_k + 3 the accepted
-        draft count (-1 on non-speculating slots). The scheduler
-        fetches it with `runtime.device_fetch`.
+        draft count (-1 on non-speculating slots). Block engine
+        (`block` B): a denoising step or a clean pass of each slot's
+        open block, out-array `[2B + 3, S]` — rows 0..B-1 the tokens the
+        step committed (-1 past the count), rows B..2B-1 their offsets
+        in the block, row 2B the count (0 on a clean pass), row 2B + 1
+        the finished flag, row 2B + 2 the block's first position. The
+        scheduler fetches it with `runtime.device_fetch`.
 
         A finished slot retires itself: the tick that reports a slot
         `finished` clears its `ctl["active"]`, so the scheduler may
@@ -1408,6 +1539,95 @@ class DecodeEngine:
             counters["ssm_slot_steps"] = self.state_layers * jnp.sum(
                 active.astype(jnp.int32))
         return _plain(vars_["cache"]), out_ctl, out, counters
+
+    def _block_prefill_impl(self, params, cache, tokens, mask):
+        _, vars_ = self._dense.apply({"params": params, "cache": cache},
+                                     tokens, mask, mutable=["cache"])
+        cache = _plain(vars_["cache"])
+        # A layer's token count: what the host fetches to know it done.
+        count = next(leaf for path, leaf in
+                     jax.tree_util.tree_flatten_with_path(cache)[0]
+                     if path[-1].key == "token_count")
+        return cache, count
+
+    def _insert_block_impl(self, cache, ctl, pcache, slot, page_vec,
+                           scatter_vec, tokens, masked, masks0, steps,
+                           start, gen_end):
+        new_cache = self._scatter_request(cache, pcache, slot, page_vec,
+                                          scatter_vec)
+        out = dict(ctl)
+        for key, value in (("active", True), ("block_tokens", tokens),
+                           ("block_masked", masked),
+                           ("block_start", start), ("block_step", 0),
+                           ("block_masks0", masks0),
+                           ("denoise_steps", steps), ("gen_end", gen_end)):
+            out[key] = ctl[key].at[slot].set(value)
+        return new_cache, out
+
+    def _block_tick_impl(self, params, cache, ctl):
+        """One block tick (`tick`'s docstring has the out-array): every
+        active slot runs its open block's B rows over the pool. A block
+        with masked rows takes a denoising step (`models.sdar.
+        denoise_step`: the step's `transfer_count` most confident masked
+        rows take their argmax); one without runs clean, and its rows
+        are then final: the slot's pointer moves past them, the next
+        block opens masked (`models.sdar.masked_rows`), and a slot
+        whose new tokens all lie
+        behind the pointer has finished (and retires itself, as a
+        finished slot of the plain tick does)."""
+        from cloud_tpu.models.decoding import paged_slot_advance
+        from cloud_tpu.models.moe import MOE_STATS
+        from cloud_tpu.models.sdar import (denoise_step, masked_rows,
+                                           transfer_count)
+
+        b = self.block
+        active = ctl["active"]
+        tokens, masked = ctl["block_tokens"], ctl["block_masked"]
+        closing = active & ~jnp.any(masked, axis=1)
+        stepping = active & ~closing
+        logits, vars_ = self._paged.apply(
+            {"params": params, "cache": cache}, tokens,
+            jnp.broadcast_to(active[:, None], tokens.shape),
+            mutable=["cache", MOE_STATS])
+        count = jnp.where(stepping, transfer_count(
+            ctl["block_masks0"], ctl["denoise_steps"], ctl["block_step"]),
+            0)
+        tokens, masked, commit = denoise_step(logits, tokens, masked, count)
+        n = jnp.sum(commit.astype(jnp.int32), axis=1)
+        # The committed rows first, in the block's order.
+        order = jnp.argsort(~commit, axis=1, stable=True).astype(jnp.int32)
+        got = jnp.arange(b)[None, :] < n[:, None]
+        committed = jnp.where(got, jnp.take_along_axis(tokens, order, 1),
+                              -1)
+        offsets = jnp.where(got, order, -1)
+        moved = jnp.where(closing, b, 0)
+        cache = paged_slot_advance(_plain(vars_["cache"]), moved)
+        start = ctl["block_start"] + moved
+        finished = closing & (start >= ctl["gen_end"])
+        out_ctl = dict(ctl)
+        out_ctl["active"] = active & ~finished
+        out_ctl["block_tokens"] = jnp.where(
+            closing[:, None], self.model.mask_token, tokens)
+        opened = masked_rows(start, ctl["gen_end"], b)
+        out_ctl["block_masked"] = jnp.where(closing[:, None], opened, masked)
+        out_ctl["block_start"] = start
+        out_ctl["block_step"] = jnp.where(
+            closing, 0, ctl["block_step"] + stepping.astype(jnp.int32))
+        out_ctl["block_masks0"] = jnp.where(
+            closing, jnp.sum(opened.astype(jnp.int32), axis=1),
+            ctl["block_masks0"])
+        out = jnp.concatenate([
+            jnp.transpose(committed), jnp.transpose(offsets), n[None],
+            finished.astype(jnp.int32)[None], ctl["block_start"][None],
+        ], axis=0)                                        # [2B + 3, S]
+        counters = _moe_counters(_plain(vars_.get(MOE_STATS, {})))
+        counted = lambda x: jnp.sum(x.astype(jnp.int32))
+        counters.update(
+            diffusion_slot_steps=counted(stepping),
+            diffusion_tokens_committed=jnp.sum(n),
+            diffusion_blocks_closed=counted(closing),
+            diffusion_rows_computed=b * counted(active))
+        return cache, out_ctl, out, counters
 
     def _spec_tick_impl(self, params, draft_params, cache, dcache, ctl):
         """Draft/verify speculation, one executable per tick:

@@ -70,7 +70,10 @@ each boundary it passes, on `time.monotonic()`:
                 it in the window are prefilled first)
     t_reserved  its KV pages were reserved
     t_first     its first token was on the host (the TTFT point)
-    t_insert    it was written into a decode slot
+    t_insert    it was written into a decode slot (a block-diffusion
+                request: marked at `t_first`, since its first token is
+                out only when the ticks have committed it and every
+                position before it; its `prefill` phase holds them)
     token_times the tick's commit time of each later token
     t_done      the result was handed to the caller
 
@@ -104,6 +107,9 @@ dispatched and published where it is committed, on the same clock:
     kv_walked   of `kv_walked_tokens`
     mla_rows    of `mla_rows_read` (latent rows, over the layers; 0
                 for a model without latent attention)
+    committed   a block-diffusion model's: tokens the tick committed
+                (`diffusion_tokens_committed`; 0 for the others), and
+    rows        the query rows it ran (`diffusion_rows_computed`)
     dispatched  the engine's dispatch log (`engine.Dispatched` notes:
                 program, time, rows, rid) from the dispatch of the
                 tick before, whose `serve_tick` note comes first, to
@@ -224,8 +230,8 @@ class TickRecord:
 
     __slots__ = ("seq", "server", "slots", "t_dispatch", "t_fetch0",
                  "t_fetched", "t_committed", "live", "overlapped",
-                 "kv_live", "kv_walked", "mla_rows", "dispatched",
-                 "naps", "idle_s")
+                 "kv_live", "kv_walked", "mla_rows", "committed", "rows",
+                 "dispatched", "naps", "idle_s")
 
     def __init__(self, seq, server, slots, t_dispatch, overlapped,
                  dispatched=(), naps=0, idle_s=0.0):
@@ -237,6 +243,7 @@ class TickRecord:
         self.live = 0
         self.overlapped = overlapped
         self.kv_live = self.kv_walked = self.mla_rows = 0
+        self.committed = self.rows = 0
         self.dispatched = dispatched
         self.naps = naps
         self.idle_s = idle_s

@@ -162,6 +162,11 @@ SQUEEZE_HOLD_S = 2.0
 
 _OFF_VALUES = ("", "0", "off", "false", "none")
 
+#: A block-diffusion model's counters in `stats()` (table in
+#: monitoring/spans.py), each summed over the ticks.
+DIFFUSION_COUNTERS = ("diffusion_slot_steps", "diffusion_tokens_committed",
+                      "diffusion_blocks_closed", "diffusion_rows_computed")
+
 
 @dataclasses.dataclass
 class ServeRequest:
@@ -176,6 +181,10 @@ class ServeRequest:
     top_p: Optional[float] = None
     eos_token: Optional[int] = None
     rng_seed: int = 0
+    # A block-diffusion model's (`models.sdar.SdarLM`): the denoising
+    # steps a block of this request takes, 1 to B (None = B). Fewer
+    # steps commit more tokens a step: speed against quality.
+    denoising_steps: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -184,18 +193,22 @@ class ServeResult:
     `generate()` row contract. `prefix_len` is the token count served
     from the prefix cache (0 = cold prefill). `trace` is the request's
     `reqtrace.RequestRecord`: `ttft_s` and `latency_s` are computed
-    from it, and its `phases()` add up to them."""
+    from it, and its `phases()` add up to them. `commit_steps` (a
+    block-diffusion model's request; None otherwise): for each new
+    token the step of its block at which it was committed, from 0, as
+    `models.sdar.generate` returns them."""
     tokens: np.ndarray
     ttft_s: float
     latency_s: float
     prefix_len: int = 0
     trace: Optional[reqtrace.RequestRecord] = None
+    commit_steps: Optional[np.ndarray] = None
 
 
 class _Slot:
     __slots__ = ("request", "pages", "emitted", "future", "rec",
                  "prefix_len", "trace_ticks", "step_keys",
-                 "result_prefix_len")
+                 "result_prefix_len", "committed", "block_steps")
 
     def __init__(self, request, pages, future, rec, prefix_len):
         self.request = request
@@ -214,6 +227,12 @@ class _Slot:
         # (the continuation cold-prefills, but the REQUEST's cache-hit
         # status is a property of its original admission).
         self.result_prefix_len = prefix_len
+        # A block-diffusion request's new tokens as they are committed,
+        # out of order inside a block: position -> (token, the step of
+        # its block), and the steps its open block has had. `emitted`
+        # takes a token when it and every one before it are committed.
+        self.committed = {}
+        self.block_steps = 0
 
 
 class _Flight:
@@ -423,6 +442,23 @@ class Scheduler:
             env = os.environ.get("CLOUD_TPU_SERVE_HOST_TIER",
                                  "").strip().lower()
             host_tier = env not in _OFF_VALUES
+        if getattr(model, "block_length", 0):
+            # A model that generates by denoising blocks (its class
+            # says so): a prompt's last partial block is run again with
+            # the tokens generated beside it, so no page of a request
+            # is final when its prefill ends, and one tick commits a
+            # block's rows out of order. No trie; no chunks.
+            prefix_cache = False
+            if host_tier:
+                raise NotImplementedError(
+                    "host_tier is not served for a block-diffusion "
+                    "model ({}).".format(type(model).__name__))
+            if prefill_chunk:
+                raise NotImplementedError(
+                    "prefill_chunk is not served for a block-diffusion "
+                    "model ({}): it is prefilled whole.".format(
+                        type(model).__name__))
+            prefill_chunk = 0
         if getattr(model, "state_layers", 0):
             # A model with recurrent layers (its class says so): a
             # page holds keys and values, not the state those layers
@@ -546,6 +582,9 @@ class Scheduler:
         m = self.engine.model
         heads, kv_heads, head_dim = attention_shape(m)
         self._kv_reach = self.engine.spec_k if self.engine.spec_on else 0
+        if self.engine.block:
+            # A block tick's rows reach to the end of the open block.
+            self._kv_reach = self.engine.block - 1
         if getattr(m, "latent_row", 0):
             # One latent row a token, walked by `paged_mla_decode`.
             self._kv_group = mla_group_pages(page_size,
@@ -591,6 +630,12 @@ class Scheduler:
         # A recurrent model's counter, summed over ticks: slots
         # advanced x state layers (each is one state read and written).
         self._ssm_slot_steps = 0
+        # A block-diffusion model's counters, summed over ticks
+        # (`DecodeEngine._block_tick_impl`): denoising steps of slots,
+        # tokens they committed, blocks closed by a clean pass, and the
+        # query rows the ticks ran (B a live slot: masked, committed
+        # and clean rows alike).
+        self._diffusion = dict.fromkeys(DIFFUSION_COUNTERS, 0)
         from cloud_tpu.monitoring.telemetry import Histogram
         self._ttft_hist = Histogram("ttft")
         self._ttft_hit_hist = Histogram("ttft_hit")
@@ -946,13 +991,13 @@ class Scheduler:
                 tokens=np.asarray(request.prompt, np.int32),
                 ttft_s=0.0, latency_s=0.0, trace=rec))
             return future
-        if request.max_new_tokens > 1:
+        if self._slotted(request):
             self._pending_inserts += 1
         try:
             self._admit_q.put((request, future, rec, {"defers": 0}),
                               timeout=timeout)
         except queue.Full:
-            if request.max_new_tokens > 1:
+            if self._slotted(request):
                 self._pending_inserts -= 1
             self._trace_emit(rid, "fail", error="queue.Full: admission "
                              "queue full (load shed)")
@@ -960,7 +1005,21 @@ class Scheduler:
         self._observe_queue()
         return future
 
+    def _slotted(self, request):
+        """Whether the request takes pages and a decode slot: every
+        request of a block-diffusion model (its prefill samples
+        nothing), else one of more than one new token (the prefill
+        samples the first)."""
+        if self.engine.block:
+            return request.max_new_tokens > 0
+        return request.max_new_tokens > 1
+
     def _spec_slack(self):
+        """Positions a request may write past its last new token: a
+        verify window's drafts, or the rest of a block-diffusion
+        request's last block (pages are reserved in whole blocks)."""
+        if self.engine.block:
+            return self.engine.block - 1
         return self.engine.spec_k if self.engine.spec_on else 0
 
     def _validate(self, request):
@@ -991,6 +1050,25 @@ class Scheduler:
                 "max_new_tokens ({}) exceeds the engine's max_new_cap "
                 "({}).".format(request.max_new_tokens,
                                self.engine.max_new_cap))
+        block = self.engine.block
+        if block:
+            steps = request.denoising_steps or block
+            end = -(-(prompt_len + request.max_new_tokens) // block) * block
+            if (prompt_len < block or not 1 <= steps <= block
+                    or end > model.max_seq_len or request.temperature
+                    or request.eos_token is not None
+                    or request.max_new_tokens < 1):
+                raise ValueError(
+                    "a block-diffusion request has a prompt of at least "
+                    "one block ({} tokens), 1 <= denoising_steps <= {}, "
+                    "at least one new token, its last block inside "
+                    "max_seq_len {}, temperature 0 and no eos_token (the "
+                    "block tick is greedy and stops at its length).".format(
+                        block, block, model.max_seq_len))
+        elif request.denoising_steps is not None:
+            raise ValueError(
+                "denoising_steps is a block-diffusion model's; {} "
+                "decodes a token a tick.".format(type(model).__name__))
         if request.top_k is not None and not (
                 1 <= request.top_k <= model.vocab_size):
             raise ValueError("top_k must be in [1, vocab_size={}]; got "
@@ -1000,7 +1078,7 @@ class Scheduler:
                 0.0 < request.top_p <= 1.0):
             raise ValueError("top_p must be in (0, 1]; got {}.".format(
                 request.top_p))
-        if request.max_new_tokens > 1:
+        if self._slotted(request):
             # Raises when no reservation could EVER satisfy it.
             need = self.pool.pages_needed(prompt_len,
                                           request.max_new_tokens,
@@ -1037,6 +1115,7 @@ class Scheduler:
             else float(request.top_p),
             "eos_token": None if request.eos_token is None
             else int(request.eos_token),
+            "denoising_steps": request.denoising_steps,
         }
 
     # -- admission/prefill thread -------------------------------------
@@ -1106,7 +1185,7 @@ class Scheduler:
         return 1
 
     def _fail_admission(self, request, future, rec, exc):
-        if request.max_new_tokens > 1:
+        if self._slotted(request):
             self._pending_inserts -= 1
         self._trace_fail(rec.rid, exc)
         future.set_exception(exc)
@@ -1150,7 +1229,7 @@ class Scheduler:
         (blocking is the backpressure). Marks `reserved`; a request
         that needs no pages passes straight through. Returns the pages,
         or None when the scheduler closed meanwhile."""
-        if request.max_new_tokens <= 1:
+        if not self._slotted(request):
             self._mark(rec, "reserved", event=False)
             return []
         need = self.pool.pages_needed(len(request.prompt),
@@ -1212,7 +1291,7 @@ class Scheduler:
         model = self._admission_model
         if model is not None:
             pool_short = False
-            if request.max_new_tokens > 1:
+            if self._slotted(request):
                 need = self.pool.pages_needed(
                     len(request.prompt), request.max_new_tokens,
                     slack=self._spec_slack())
@@ -1242,7 +1321,7 @@ class Scheduler:
         else:
             prefill_p50 = self._prefill_hist.percentile(50)
             predicted = accrued + (position + 1) * prefill_p50
-        if request.max_new_tokens > 1:
+        if self._slotted(request):
             need = self.pool.pages_needed(len(request.prompt),
                                           request.max_new_tokens,
                                           slack=self._spec_slack())
@@ -1281,7 +1360,7 @@ class Scheduler:
     def _shed(self, request, future, rid, reason, predicted):
         """Refuses one candidate by policy: typed ServeShed to the
         caller, `shed` terminal trace event, census counters."""
-        if request.max_new_tokens > 1:
+        if self._slotted(request):
             self._pending_inserts -= 1
         self._shed_counts[reason] = self._shed_counts.get(reason, 0) + 1
         reg = _registry()
@@ -1370,6 +1449,16 @@ class Scheduler:
             if item.pages:
                 self.pool.free(item.pages)
             self._fail_admission(request, future, rec, exc)
+            return
+        if self.engine.block:
+            # No token yet: the first is out when the ticks have
+            # committed it (`_distribute_block`).
+            rec.bucket = int(result.bucket)
+            self._observe_prefill(time.monotonic() - rec.t_reserved)
+            with self._ready_lock:
+                self._ready.append(_ReadyItem(request, result, item.pages,
+                                              future, rec))
+            self._wake.set()
             return
         self._first_token(rec, result, hit=False)
         if request.max_new_tokens == 1:
@@ -1737,7 +1826,13 @@ class Scheduler:
         self._slots[slot] = None
         self._free_slots.append(slot)
         self.pool.free(state.pages)
-        self._requeue_slot(state)
+        if self.engine.block:
+            # No continuation re-enters a block mid-way: the request
+            # fails with the fault.
+            self._trace_fail(state.rec.rid, fault)
+            state.future.set_exception(fault)
+        else:
+            self._requeue_slot(state)
         self._observe_gauges()
 
     def _requeue_slot(self, state):
@@ -1964,14 +2059,19 @@ class Scheduler:
         kv_live, kv_walked = self._kv_live_tokens, self._kv_walked_tokens
         mla_rows = self._mla_rows_read
         with spans.span("tick_commit", tick=record.seq):
-            self._distribute(live, fetched, t_commit - t_from, t_commit)
+            # The counters first: a request this tick completes sees
+            # them in `stats()`.
             if counters:
                 self._count_tick(counters)
+            self._distribute(live, fetched, t_commit - t_from, t_commit)
         record.t_fetched = t_commit
         record.live = len(live)
         record.kv_live = self._kv_live_tokens - kv_live
         record.kv_walked = self._kv_walked_tokens - kv_walked
         record.mla_rows = self._mla_rows_read - mla_rows
+        if self.engine.block:
+            record.committed = int(counters["diffusion_tokens_committed"])
+            record.rows = int(counters["diffusion_rows_computed"])
         record.t_committed = time.monotonic()
         reqtrace.publish_tick(record)
         if self.strict_no_retrace:
@@ -2031,13 +2131,18 @@ class Scheduler:
         slot = self._free_slots.pop()
         state = _Slot(item.request, item.pages, item.future, item.rec,
                       prefix_len=0)
-        state.emitted.append(item.result.first_token)
+        if item.result.first_token is not None:
+            state.emitted.append(item.result.first_token)
         state.step_keys = item.result.step_keys
         self._slots[slot] = state
         vec = self.pool.page_vec(item.pages)
         self.engine.insert(slot, item.result, vec, vec,
                            self._sampling(item.request))
-        self._inserted(item.rec, slot)
+        if not self.engine.block:
+            # A block-diffusion request is marked inserted with its
+            # first token (`_release`): its prefill phase holds the
+            # ticks that committed it.
+            self._inserted(item.rec, slot)
         self._register(item.request, item.pages)
         self._pending_inserts -= 1
         self._observe_gauges()
@@ -2426,9 +2531,13 @@ class Scheduler:
             # sits at prompt + emitted - 1, so that many keys and
             # itself.
             layout = self.engine.layout
-            for _, state in live:
+            for slot, state in live:
                 depth = (len(state.request.prompt)
                          + len(state.emitted) + self._kv_reach)
+                if self.engine.block:
+                    # The open block's end: its first position and B.
+                    depth = int(fetched[2 * self.engine.block + 2][slot]
+                                ) + self._kv_reach + 1
                 if layout is not None:
                     # The token consumed sits at depth - 1: the rows
                     # of both kinds it attends, and the groups the
@@ -2454,7 +2563,9 @@ class Scheduler:
                 reg.histogram(
                     telemetry.SERVE_TICK_SECONDS
                     % self.engine.slots).observe(elapsed)
-        if self.engine.spec_on:
+        if self.engine.block:
+            self._distribute_block(live, fetched, t_commit)
+        elif self.engine.spec_on:
             self._distribute_spec(live, fetched, t_commit)
         else:
             self._distribute_plain(live, fetched, t_commit)
@@ -2480,6 +2591,8 @@ class Scheduler:
 
     def _count_tick(self, counters):
         self._ssm_slot_steps += int(counters.get("ssm_slot_steps", 0))
+        for name in DIFFUSION_COUNTERS:
+            self._diffusion[name] += int(counters.get(name, 0))
         if "pairs_routed" not in counters:
             return
         self._moe_pairs_routed += int(counters["pairs_routed"])
@@ -2526,17 +2639,70 @@ class Scheduler:
             self.engine.evict(evict_mask)
             self._observe_gauges()
 
+    def _distribute_block(self, live, fetched, t_commit):
+        """A block tick's commits (`DecodeEngine.tick`'s out-array):
+        each slot's committed rows go to their positions, and a token
+        is released to its request once it and every position before
+        it are committed (`_release`)."""
+        b = self.engine.block
+        count_row, finished_row, start_row = fetched[2 * b:2 * b + 3]
+        evict_mask = np.zeros((self.engine.slots,), bool)
+        for slot, state in live:
+            c = int(count_row[slot])
+            if c == 0:
+                state.block_steps = 0       # a clean pass: the block closed
+            else:
+                start = int(start_row[slot])
+                for j in range(c):
+                    state.committed[start + int(fetched[b + j][slot])] = (
+                        int(fetched[j][slot]), state.block_steps)
+                state.block_steps += 1
+                self._release(slot, state, t_commit)
+            if finished_row[slot]:
+                self._finish_slot(slot, state, evict_mask)
+        if evict_mask.any():
+            self.engine.evict(evict_mask)
+            self._observe_gauges()
+
+    def _release(self, slot, state, t_commit):
+        """Hands a block-diffusion request the tokens that are now
+        committed in order: its first is the TTFT point (and its
+        insertion is marked there too), each later one joins
+        `token_times`."""
+        request, rec = state.request, state.rec
+        at = len(request.prompt) + len(state.emitted)
+        while (len(state.emitted) < request.max_new_tokens
+               and at in state.committed):
+            state.emitted.append(state.committed[at][0])
+            if len(state.emitted) == 1:
+                self._mark(rec, "first", t_commit, bucket=rec.bucket,
+                           prefix_len=0)
+                self._mark(rec, "insert", t_commit, slot=slot)
+                self._record_ttft(rec.ttft_s, hit=False)
+            else:
+                rec.token_times.append(t_commit)
+            at += 1
+
     def _finish_slot(self, slot, state, evict_mask):
         evict_mask[slot] = True
         self._slots[slot] = None
         self._free_slots.append(slot)
         self._maybe_demote(state)
         self.pool.free(state.pages)
+        steps = None
+        if self.engine.block:
+            first = len(state.request.prompt)
+            steps = np.asarray(
+                [state.committed[p][1] for p in range(
+                    first, first + state.request.max_new_tokens)],
+                np.int32)
         self._complete(state.request, state.future, state.rec,
                        state.emitted,
-                       prefix_len=state.result_prefix_len)
+                       prefix_len=state.result_prefix_len,
+                       commit_steps=steps)
 
-    def _complete(self, request, future, rec, emitted, prefix_len):
+    def _complete(self, request, future, rec, emitted, prefix_len,
+                  commit_steps=None):
         # A speculative tick can overshoot max_new_tokens by up to
         # spec_k accepted tokens — the greedy chain is identical, so
         # truncation is exact.
@@ -2575,7 +2741,8 @@ class Scheduler:
             reqtrace.publish(rec)
         future.set_result(ServeResult(tokens=tokens, ttft_s=ttft,
                                       latency_s=latency,
-                                      prefix_len=prefix_len, trace=rec))
+                                      prefix_len=prefix_len, trace=rec,
+                                      commit_steps=commit_steps))
 
     # -- shared helpers -----------------------------------------------
 
@@ -2754,6 +2921,12 @@ class Scheduler:
             merged.update(dict(cfg))
             configs.append(merged)
         widths = set(buckets)
+        block = self.engine.block
+        if block:
+            # A block-diffusion prefill runs at the bucket of the
+            # prompt's whole blocks: a prompt whose last block is
+            # partial may fall in the bucket below its own.
+            widths = {w for b in widths for w in (b, b // 2) if w >= block}
         if self.engine.layout is not None:
             # One chunk executable and one tail executable serve every
             # prompt length (the chunk path below warms both): the
@@ -2795,10 +2968,13 @@ class Scheduler:
         for _ in range(2):
             futures = []
             for bucket in sorted(widths):
-                for length in sorted({min(bucket, cap),
-                                      min(max(bucket - 1, 1), cap)}):
-                    if length < 1 or bucket_length(
-                            length, self.engine.max_seq_len) != bucket:
+                lengths = {min(bucket, cap), min(max(bucket - 1, 1), cap)}
+                if block:
+                    lengths = {min(bucket, cap)}
+                for length in sorted(lengths):
+                    whole = length // block * block if block else length
+                    if whole < 1 or bucket_length(
+                            whole, self.engine.max_seq_len) != bucket:
                         continue
                     for cfg in configs:
                         first = 2 + combo % max(vocab - 2, 1)
@@ -2858,6 +3034,7 @@ class Scheduler:
         self._moe_experts_touched = 0
         self._moe_expert_load = None
         self._ssm_slot_steps = 0
+        self._diffusion = dict.fromkeys(DIFFUSION_COUNTERS, 0)
         self._t_last_commit = None
         self._completed = 0
         self._tokens_out = 0
@@ -2978,6 +3155,7 @@ class Scheduler:
                                 else self._moe_expert_load.tolist()),
             "ssm_state_bytes": self.pool.state_bytes,
             "ssm_slot_steps": self._ssm_slot_steps,
+            **self._diffusion,
             "eva_rows_read": self._eva_rows_read,
             "eva_summary_rows_read": self._eva_summary_rows_read,
             "eva_windows_closed": dict(self._eva_windows_closed),

@@ -122,7 +122,8 @@ def test_kernel_and_program_tables_equal_the_constants():
         attention_ops.FLASH_BWD_DKV, fused_mlp.FUSED_SWIGLU_FWD,
         fused_norm.FUSED_RMSNORM, fused_norm.FUSED_RMSNORM_RESIDUAL,
         paged_ops.PAGED_DECODE, paged_ops.PAGED_DECODE_WINDOW,
-        paged_ops.PAGED_MLA_DECODE, attention_ops.MLA_PREFILL,
+        paged_ops.PAGED_BLOCK_DECODE, paged_ops.PAGED_MLA_DECODE,
+        attention_ops.MLA_PREFILL,
         ssm_ops.SSM_DECODE_UPDATE)
     assert spans.names("Scopes") == EXPERT_SCOPES + LATENT_SCOPES + (
         mamba_lib.SSM_IN_PROJ, mamba_lib.SSM_CONV, mamba_lib.SSM_SCAN,
@@ -667,7 +668,8 @@ def test_counter_table_names_the_stats_an_expert_model_adds():
         stats = sched.stats()
     table = spans.names("Counters")
     assert set(table) == {k for k in stats
-                          if k.startswith(("moe_", "ssm_", "eva_", "mla_"))}
+                          if k.startswith(("moe_", "ssm_", "eva_", "mla_",
+                                           "diffusion_"))}
     assert [stats[k] for k in table] == [
         0, 0, 0, 0, [], 0, 0, 0, 0, {"ticks": 0, "prefills": 0}, 0, 0, 0,
-        0]
+        0, 0, 0, 0, 0]

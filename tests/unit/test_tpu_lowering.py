@@ -648,3 +648,49 @@ def test_typed_map_sums_partial_products_and_gradients(cpu_mesh,
                    (P("dp"), P(None, "tp"), P(None, "tp"),
                     P("tp", None)), (x, wg, wu, wd))
     _assert_close(got, run("reference")(x, wg, wu, wd), tol=2e-4)
+
+
+def test_block_read_compiles_at_the_cells_geometry(as_tpu):
+    """`sdar_blockdiff_gen`'s tick, one attention layer at SDAR's widths: 64
+    slots of 4 rows (one open block each), 32 query heads over 4 key/value
+    heads of 128, a table of 176 pages of 16 rows a slot. The paged block
+    read compiles with no chip as ONE Mosaic custom call under
+    `paged_block_decode`, the 11 265-page pools aliased to the output and
+    never copied, and no dense view of a slot's rows."""
+    from cloud_tpu.models.llama import GQAttention
+    from cloud_tpu.ops.paged_attention import PAGED_BLOCK_DECODE
+
+    devices = _tpu_topology()
+    if devices is None:
+        pytest.skip("no libtpu to describe a v5e")
+    slots, block, page, cache_len = 64, 4, 16, 2816
+    num_pages = slots * cache_len // page + 1
+    layer = GQAttention(num_heads=32, num_kv_heads=4, head_dim=128,
+                        compute_dtype=BF16, param_dtype=BF16, qk_norm=True,
+                        rope_theta=1e6, rope_style="rotate_half",
+                        decode=True, cache_len=cache_len, page_size=page,
+                        num_pages=num_pages, attn_block=block)
+    x = S((slots, block, 2048), BF16)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    pool = variables["cache"]["key_pages"]
+    assert pool.shape == (num_pages, page, 512)
+
+    def tick(params, cache, x, live):
+        return layer.apply({"params": params, "cache": cache}, x, live,
+                           mutable=["cache"])
+
+    one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
+    compiled = jax.jit(tick, in_shardings=one, donate_argnums=1).trace(
+        variables["params"], variables["cache"], x,
+        S((slots, block), jnp.bool_)).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1 and PAGED_BLOCK_DECODE in calls[0], calls
+    pool_type = "bf16[{},{},512]".format(num_pages, page)
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and pool_type in line]
+    assert "[64,2816," not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * (
+        num_pages * page * 512 * 2)
