@@ -28,8 +28,14 @@ are static.
    rows as batched products and the gates, zero for a pair that was
    not chosen, select afterwards. It computes `held / chosen` times
    the pairs and reads each expert's matrices exactly once, in order:
-   the form for a decode tick whose few rows touch every held expert
-   anyway, where the layer's time is its weights' bytes.
+   the form for a call whose rows touch every held expert anyway and
+   are few enough that the products' arithmetic takes no longer than
+   the grouped kernel's read of the same weights (at most 410 rows on
+   the v5e): a decode tick of 128 slots choosing 22 of 512, a block
+   tick of 64 slots x 4 rows choosing 8 of 128, a prefill of a
+   256-token bucket. Prefills and training steps of 512 rows and more,
+   a call under a capacity and a tick that leaves held experts
+   untouched (32 slots choosing 8 of 128) run grouped.
 
 `routed_expert_ffn` picks between 2 and 3 from the shapes of the call
 (`batched_over_held`); nothing else selects a form.
@@ -254,15 +260,24 @@ PLAIN_ACTIVATIONS = {
 #: the grouped one: over `pairs_held` it is the padding that form pays).
 MOE_STATS = "moe_stats"
 
-#: Rows up to which a `[T, d] x [d, f]` product whose weights stream
-#: from HBM is bound by their bytes, not by the MXU: it does
-#: `2 T d f` FLOPs over `d f` elements, so the two times meet at
-#: `T = peak FLOP/s x bytes an element / (2 x HBM bytes/s)` =
-#: 197e12 x 2 / (2 x 819e9) = 240 rows for bfloat16 on the v5e (peak:
-#: `monitoring.telemetry.PEAK_TFLOPS`; HBM: Google Cloud documentation,
-#: "TPU v5e"). Under it, multiplying every row by every held expert
-#: costs no time the weights' read does not already take.
-DENSE_MAX_ROWS = 240
+#: Rows up to which multiplying every row by every held expert takes
+#: no longer than the grouped kernel's read of the same weights: a
+#: `[T, d] x [d, f]` product does `2 T d f` FLOPs over `d f` elements,
+#: so the two times meet at `T = peak FLOP/s x bytes an element /
+#: (2 x GROUPED_BEST_BYTES_PER_S)` = 197e12 x 2 / (2 x 480e9) = 410
+#: rows for bfloat16 on the v5e (peak: `monitoring.telemetry.
+#: PEAK_TFLOPS`). Up to the chip's ridge, the same with
+#: `HBM_BYTES_PER_S` (240 rows), the batched products cost nothing
+#: beyond their weights' read; past it they cost more, but less than
+#: any grouped call measured on the chip takes for the same weights.
+DENSE_MAX_ROWS = 410
+#: The v5e's HBM bandwidth (Google Cloud documentation, "TPU v5e").
+HBM_BYTES_PER_S = 819e9
+#: The fastest the grouped-product kernel (XLA:TPU's `ragged-dot`) has
+#: read expert weights on the v5e: 32 rows over 16 held experts of 8
+#: chosen from 128, 62 % of `HBM_BYTES_PER_S` (a traced
+#: `kexaone_decode_long` tick; at 256 rows, 16 a group, it read 280 GB/s).
+GROUPED_BEST_BYTES_PER_S = 480e9
 #: Least expected share of the held experts that a call touches for
 #: the batched form: it reads every held expert, and each untouched
 #: one is bytes the grouped form would not have read.
@@ -275,16 +290,19 @@ def batched_over_held(tokens, top_k, num_experts, capacity):
 
     (a) nothing is shed (`capacity` None or at least `tokens`): the
         slot-major shedding order is the grouped form's;
-    (b) `tokens <= DENSE_MAX_ROWS`: the products are bytes-bound, so
-        the rows the gates zero afterwards are free;
+    (b) `tokens <= DENSE_MAX_ROWS`: the products' arithmetic takes no
+        longer than the grouped kernel would take to read the weights,
+        so the rows the gates zero afterwards cost less than the
+        grouped kernel's many small groups do;
     (c) under an even router a held expert is touched with probability
         `1 - (1 - top_k / num_experts) ** tokens`, and that is at
         least `DENSE_MIN_TOUCHED`: (nearly) every held expert's
         weights are read whatever the form.
 
-    128 slots choosing 22 of 512 (99.6 % touched) run batched; 32
-    slots choosing 8 of 128 (87 %) and every prefill or training step
-    (rows past the ridge) run grouped.
+    128 slots choosing 22 of 512 (99.6 % touched), 64 slots x 4 rows
+    choosing 8 of 128 and a 256-row prefill run batched; 32 slots
+    choosing 8 of 128 (87 %) and every prefill or training step of
+    512 rows and more run grouped.
     """
     if capacity is not None and capacity < tokens:
         return False
@@ -327,14 +345,15 @@ def routed_expert_ffn(module, x2d, top_idx, gates, num_experts, d_ff,
     expert all slot-0 (highest-gate) assignments stand before any
     slot-1 assignment, and when capacity binds the lowest-priority
     routes past it are shed first. **Batched**: where T is at most
-    `DENSE_MAX_ROWS` (the products are bound by the weights' bytes),
-    nothing is shed and a call of T rows is expected to touch at
-    least `DENSE_MIN_TOUCHED` of the held experts, every held
-    expert's weights are read anyway and the grouped kernel's many
-    small groups only slow that read: each projection is one batched
-    product of all T rows with every held expert, and a `[T, held]`
-    float32 matrix of gates — zero for an expert the token did not
-    choose, for a pad and for an expert not held — weights the sum.
+    `DENSE_MAX_ROWS` (the products' arithmetic takes no longer than
+    the grouped kernel's read of the weights), nothing is shed and a
+    call of T rows is expected to touch at least `DENSE_MIN_TOUCHED`
+    of the held experts, every held expert's weights are read anyway
+    and the grouped kernel's many small groups only slow that read:
+    each projection is one batched product of all T rows with every
+    held expert, and a `[T, held]` float32 matrix of gates — zero for
+    an expert the token did not choose, for a pad and for an expert
+    not held — weights the sum.
     The chosen pairs see the same operands in the same dtypes on both
     forms; only the order of a token's float32 sum differs.
 

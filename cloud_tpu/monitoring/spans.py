@@ -150,8 +150,9 @@ op a part lowers to carries the name in its `op_name`, and the grouped
 products of the routed experts are XLA:TPU's own kernel, whose
 instructions the trace names `ragged-dot*`. Where the routed experts
 run batched over the held experts (models/moe.py `batched_over_held`:
-a decode tick whose rows touch every held expert) there is no such
-kernel: the products are XLA's own fusions.
+a call of at most 410 rows that touches every held expert, such as a
+decode or block tick or a 256-row prefill) there is no such kernel:
+the products are XLA's own fusions.
 
 Scopes:
 

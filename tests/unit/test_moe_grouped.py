@@ -131,7 +131,7 @@ def test_no_token_by_expert_mask_is_built():
     shape, at a T where the one-hot held [T, E, T] (and past the rows up to
     which the batched form, which does hold a [T, E] matrix of gates, is
     taken)."""
-    tokens, experts = 256, 12
+    tokens, experts = 512, 12
     assert not moe.batched_over_held(tokens, 3, experts, None)
     x, top_idx, gates = _routing(experts, 3, tokens=tokens)
     model = Routed(experts)
@@ -240,18 +240,23 @@ def test_batched_grouped_and_one_hot_agree(monkeypatch, gated, held, pads,
             "pairs_dense": dense}, form
 
 
-# The rule at the cells' own shapes (ISSUE 33's table): rows, choices of
-# experts, held, widths, gated, capacity -> batched?
+# The rule at the cells' own shapes: rows, choices of experts, held,
+# widths, gated, capacity -> batched?
 RULE_CASES = {
     "nemotron_tick": (128, 22, 512, 128, 1024, 2688, False, None, True),
     "nemotron_prefill_256": (256, 22, 512, 128, 1024, 2688, False, None,
-                             False),
+                             True),
     "nemotron_prefill_1024": (1024, 22, 512, 128, 1024, 2688, False, None,
                               False),
     "kexaone_tick": (32, 8, 128, 16, 6144, 2048, True, None, False),
     "kexaone_prefill_512": (512, 8, 128, 16, 6144, 2048, True, None, False),
     # A capacity factor of 2 at the nemotron tick's rows: 2 x 128 x 22 / 512.
     "capacity_factor": (128, 22, 512, 128, 1024, 2688, False, 11, False),
+    # The block tick: 64 slots x 4 rows choosing 8 of 128, all held.
+    "sdar_tick": (256, 8, 128, 128, 2048, 768, True, None, True),
+    "sdar_prefill_512": (512, 8, 128, 128, 2048, 768, True, None, False),
+    # 32 slots choosing 8 of 192, 12 held: 74 % touched.
+    "axk1_tick": (32, 8, 192, 12, 7168, 2048, True, None, False),
 }
 
 
@@ -300,12 +305,19 @@ def test_the_form_follows_the_shapes(case):
 
 def test_the_ridge_is_the_chips_own():
     """`DENSE_MAX_ROWS` is the derivation beside it, from the peak the MFU
-    gauge divides by and the v5e's published 819 GB/s."""
+    gauge divides by and the fastest read the grouped kernel has shown on
+    the chip; the ridge, from the v5e's published 819 GB/s, lies below
+    it."""
     from cloud_tpu.monitoring.telemetry import PEAK_TFLOPS
-    ridge = PEAK_TFLOPS["TPU v5 lite"] * 1e12 * 2 / (2 * 819e9)
-    assert moe.DENSE_MAX_ROWS == int(ridge) == 240
-    assert moe.batched_over_held(240, 22, 512, None)
-    assert not moe.batched_over_held(241, 22, 512, None)
+    peak = PEAK_TFLOPS["TPU v5 lite"] * 1e12
+    assert moe.HBM_BYTES_PER_S == 819e9
+    assert int(peak * 2 / (2 * moe.HBM_BYTES_PER_S)) == 240
+    assert moe.GROUPED_BEST_BYTES_PER_S == 480e9
+    limit = peak * 2 / (2 * moe.GROUPED_BEST_BYTES_PER_S)
+    assert moe.DENSE_MAX_ROWS == int(limit) == 410
+    assert moe.batched_over_held(410, 22, 512, None)
+    assert not moe.batched_over_held(411, 22, 512, None)
+    assert moe.batched_over_held(241, 22, 512, None)      # past the ridge
     # (c): 0.95 of the held experts expected touched.
     assert moe.batched_over_held(64, 8, 128, None)        # 98.4 %
     assert not moe.batched_over_held(32, 8, 128, None)    # 87.3 %

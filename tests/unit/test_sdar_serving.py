@@ -198,6 +198,44 @@ def test_engine_tokens_equal_a_solo_generate_bit_for_bit(toy):
     assert stats["prefix_hits"] == 0
 
 
+def test_a_block_tick_batched_over_the_held_experts_serves_as_grouped(
+        toy, monkeypatch):
+    """16 slots x 4 rows choosing 2 of 16 experts touch (nearly) every
+    one, so the block tick runs its expert layers batched over the held
+    experts (`moe.batched_over_held`). In float32 it serves the tokens
+    and commit steps of the same ticks forced grouped, and counts a
+    product a live row, held expert and expert layer, where the grouped
+    run counts none."""
+    from cloud_tpu.models import moe
+
+    model, params = toy
+    slots = 16
+    assert moe.batched_over_held(slots * BLOCK, TOY["moe_top_k"],
+                                 TOY["moe_experts"], None)
+    requests = _requests()
+    served = {}
+    for form in ("batched", "grouped"):
+        if form == "grouped":
+            monkeypatch.setattr(moe, "batched_over_held", lambda *a: False)
+        with Scheduler(model, params, slots=slots, page_size=4) as sched:
+            results = [f.result(timeout=600) for f in [
+                sched.submit(ServeRequest(
+                    prompt=p.tolist(), max_new_tokens=n, temperature=0.0,
+                    denoising_steps=s)) for p, n, s in requests]]
+            served[form] = (results, sched.stats())
+    for batched, grouped in zip(served["batched"][0], served["grouped"][0]):
+        assert np.array_equal(batched.tokens, grouped.tokens)
+        assert np.array_equal(batched.commit_steps, grouped.commit_steps)
+    stats = served["batched"][1]
+    assert stats["diffusion_rows_computed"] > 0
+    assert stats["moe_pairs_dense"] == (
+        stats["diffusion_rows_computed"] * TOY["moe_experts"]
+        * TOY["num_layers"])
+    assert served["grouped"][1]["moe_pairs_dense"] == 0
+    assert served["grouped"][1]["diffusion_rows_computed"] == stats[
+        "diffusion_rows_computed"]
+
+
 def test_requests_the_block_tick_cannot_serve_are_refused(toy):
     model, params = toy
     with Scheduler(model, params, slots=2, page_size=4) as sched:

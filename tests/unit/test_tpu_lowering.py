@@ -423,13 +423,11 @@ def test_latent_decode_compiles_at_the_cells_geometry(as_tpu):
     assert "[32,34816,640]" not in text
 
 
-def test_batched_experts_compile_copy_free_at_the_cells_geometry():
-    """The hybrid cell's tick — 128 rows choosing 22 of 512 experts, 128
-    held, relu^2 experts 1024 -> 2688 -> 1024 in bfloat16 — takes the form
-    batched over the held experts, and XLA:TPU reads both weight stacks
-    where and as they lie: no copy or transpose of a stack, and no
-    temporary of the hidden rows' size in HBM (a copy of a stack would be
-    1.4 GB a layer and tick, the layer's whole budget)."""
+def _batched_expert_layer(rows, top_k, experts, held, d_model, d_ff,
+                          gated):
+    """One expert layer at these widths in bfloat16, `held` of `experts`
+    held, which the rule runs batched, compiled for one deviceless v5e
+    chip: the compiled program and the two weight stacks' HLO shapes."""
     import flax.linen as nn
 
     from cloud_tpu.models import moe
@@ -437,7 +435,6 @@ def test_batched_experts_compile_copy_free_at_the_cells_geometry():
     devices = _tpu_topology()
     if devices is None:
         pytest.skip("no libtpu to describe a v5e")
-    rows, top_k, experts, held, latent, d_ff = 128, 22, 512, 128, 1024, 2688
     assert moe.batched_over_held(rows, top_k, experts, None)
 
     class Layer(nn.Module):
@@ -445,27 +442,69 @@ def test_batched_experts_compile_copy_free_at_the_cells_geometry():
         def __call__(self, x2d, top_idx, gates):
             return moe.routed_expert_ffn(
                 self, x2d, top_idx, gates, experts, d_ff, None,
-                moe.PLAIN_ACTIVATIONS["relu2"], BF16,
-                held_experts=tuple(range(held)), param_dtype=BF16,
-                gated=False)
+                jax.nn.silu if gated else moe.PLAIN_ACTIVATIONS["relu2"],
+                BF16, held_experts=tuple(range(held)), param_dtype=BF16,
+                gated=gated)
 
     layer = Layer()
-    specs = [S((rows, latent), BF16), S((rows, top_k), jnp.int32),
+    specs = [S((rows, d_model), BF16), S((rows, top_k), jnp.int32),
              S((rows, top_k), F32)]
     params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), *specs)
     one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
     compiled = jax.jit(layer.apply, in_shardings=one).trace(
         params, *specs).lower(lowering_platforms=("tpu",)).compile()
-    stacks = ("[{},{},{}]".format(held, latent, d_ff),
-              "[{},{},{}]".format(held, d_ff, latent))
-    moved = [line.strip()[:160] for line in compiled.as_text().splitlines()
-             if (" copy(" in line or " transpose(" in line)
-             and any(shape in line.split(" = ")[1].split("(")[0]
-                     for shape in stacks)]
+    stacks = ("[{},{},{}]".format(held, d_model, d_ff),
+              "[{},{},{}]".format(held, d_ff, d_model))
+    return compiled, stacks
+
+
+def _stacks_moved(text, stacks):
+    """Lines of `text` that copy or transpose a weight stack."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if (" copy(" in line or " transpose(" in line)
+            and any(shape in line.split(" = ")[1].split("(")[0]
+                    for shape in stacks)]
+
+
+def test_batched_experts_compile_copy_free_at_the_cells_geometry():
+    """The hybrid cell's tick — 128 rows choosing 22 of 512 experts, 128
+    held, relu^2 experts 1024 -> 2688 -> 1024 in bfloat16 — takes the form
+    batched over the held experts, and XLA:TPU reads both weight stacks
+    where and as they lie: no copy or transpose of a stack, and no
+    temporary of the hidden rows' size in HBM (a copy of a stack would be
+    1.4 GB a layer and tick, the layer's whole budget)."""
+    rows, held, d_ff = 128, 128, 2688
+    compiled, stacks = _batched_expert_layer(rows, 22, 512, held, 1024,
+                                             d_ff, gated=False)
+    moved = _stacks_moved(compiled.as_text(), stacks)
     assert not moved, moved
     assert "ragged" not in compiled.as_text()
     hidden_bytes = held * rows * d_ff * 2
     assert compiled.memory_analysis().temp_size_in_bytes < hidden_bytes
+
+
+def test_block_tick_experts_compile_batched_at_the_cells_geometry():
+    """The block-diffusion cell's tick — 64 slots x 4 rows choosing 8 of
+    128 experts, all held, gated experts 2048 -> 768 -> 2048 in bfloat16
+    — takes the batched form too (256 rows, under `DENSE_MAX_ROWS`), with
+    no grouped kernel, no copy or transpose of a weight stack, and no
+    temporary as large as every held expert's output rows
+    `[held, rows, d_model]`: the gates and the sum over experts fuse into
+    the down product."""
+    rows, held, d_model = 256, 128, 2048
+    compiled, stacks = _batched_expert_layer(rows, 8, 128, held, d_model,
+                                             768, gated=True)
+    text = compiled.as_text()
+    assert "ragged" not in text
+    moved = _stacks_moved(text, stacks)
+    assert not moved, moved
+    entry = text[text.index("ENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    outputs = "[{},{},{}]".format(held, rows, d_model)
+    assert not [line for line in entry.splitlines()
+                if outputs in line.split(" = ", 1)[-1].split("(")[0]]
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        held * rows * d_model * 2)
 
 
 # -- values under a mesh (interpreted, CPU devices) ---------------------
