@@ -12,9 +12,9 @@ row-major before every call — at H*D wide the device layout is the
 kernel's own. The lax path materializes a dense
 `[slots, cache_len, H, D]` view by gathering the pool through the page
 table every tick; this kernel never does — the page table rides as a
-scalar-prefetch operand, so each grid step's K/V blocks are *indexed*
-straight out of the pool in HBM (the gather becomes block addressing)
-and streamed through VMEM with FlashAttention-style online softmax.
+scalar-prefetch operand, so each grid step's K/V pages are copied (or
+*indexed* as blocks) straight out of the pool in HBM and streamed
+through VMEM with FlashAttention-style online softmax.
 
 Grid and masking contract (see /opt/skills/guides/pallas_guide.md):
 - The walk goes a *group* of G logical pages at a time and ends at
@@ -33,20 +33,36 @@ Grid and masking contract (see /opt/skills/guides/pallas_guide.md):
   dead step on the v5e, most of a chat tick; so the dead steps are not
   in the grid. An evicted slot keeps one step, which computes nothing
   and writes its zeros.
-- Step t's K (and V) arrive as G blocks, each the whole physical page
-  `page_table[slot[t], group[t] * G + g]` (`[P, H*D]`, the array's
-  full trailing dims, which is what Mosaic's block rule asks of a
-  16-row page): the pool is passed G times and Pallas's own pipeline
-  double-buffers the 2G copies of the next step behind this one. The
-  table is padded to whole groups with the scratch page and the mask
-  with False, so `pages_per_slot` need not be a multiple of G. (The
-  other form, the pool left in HBM and the kernel issuing its own
-  `make_async_copy`s for live pages only, does not compile for a
-  1600-wide pool: this Mosaic refuses an HBM slice whose minor extent
-  is not a multiple of 128. PERF.md section 6, PR 27.)
-- G comes from the shapes (`group_pages`): the most pages whose
-  blocks fit `_VMEM_BUDGET`, a whole number of 128-key lane tiles — 8
-  for the serve cells' 16-token bf16 pages 1600 wide, 16 for int8
+- Step t reads the physical pages `page_table[slot[t], group[t] * G
+  + g]` in one of two ways, chosen from the call's shapes and dtype
+  alone (`copies_pages`):
+  * copied: where a page row is a whole number of 128-lane tiles and
+    a page a whole number of the dtype's sublane tiles (16 rows of
+    bf16, 8 of f32), the pools are passed once each and stay in HBM,
+    and the kernel copies the step's live pages itself
+    (`make_async_copy`, `_copy_pages`), each straight into its P rows
+    of a contiguous `[G * P, H*D]` VMEM tile, one of two a pool: step
+    t starts step t+1's copies into the other tile, then waits for its
+    own and computes on its tile in place. A Pallas pipeline block
+    costs some 80 ns on the v5e whatever its bytes, four times a
+    16 KB page's read (PERF.md section 6). Only the step's live pages
+    are copied (from the band's first page on a window layer, to the
+    slot's last); the rows of the others keep what an earlier step
+    left there, finite (the tiles are zeroed at the call's first step)
+    and masked.
+  * blocks: otherwise each page is a `[P, H*D]` block of its own (the
+    array's full trailing dims, which is what Mosaic's block rule
+    asks of a 16-row page), the pool passed G times and Pallas's own
+    pipeline double-buffering the 2G blocks of the next step behind
+    this one. This Mosaic refuses a copy from an HBM slice whose
+    minor extent is not a multiple of 128 (gpt2-xl's 1600 lanes;
+    PERF.md section 6), and a 16-row int8 page is half the
+    int8 sublane tile.
+  The table is padded to whole groups with the scratch page and the
+  mask with False, so `pages_per_slot` need not be a multiple of G.
+- G comes from the shapes (`group_pages`): the most pages whose VMEM
+  fits `_VMEM_BUDGET`, a whole number of 128-key lane tiles — 8 for
+  the serve cells' 16-token bf16 blocks 1600 wide, 16 for int8
   pages, more under a tp mesh where the local width is a share.
 - Heads stay folded in lanes and keys go on lanes too. The query
   is laid block-diagonal once a slot, `qbd[(i, h), c] = q[i, c]` on
@@ -151,8 +167,10 @@ _LANES = 128
 _SUBLANES = 8
 
 #: VMEM a grid step's group of pages may take (`group_pages`). Fixed
-#: on the v5e at the serve cells' shape (16-token bf16 pages 1600
-#: wide, seq 1), where it gives G = 8: PERF.md section 6, PR 27.
+#: on the v5e at gpt2-xl's blocks (16-token bf16 pages 1600 wide, seq
+#: 1), where it gives G = 8; copied, at SDAR's block tick (512 lanes,
+#: 4 rows) it gives 64, and G = 40, 64 and 128 timed 0.61, 0.61 and
+#: 0.64 ms a call there (PERF.md section 6).
 _VMEM_BUDGET = 6 * 1024 * 1024
 
 
@@ -167,6 +185,7 @@ class _PagedConfig(NamedTuple):
     kv_heads: int = 0     # 0 = heads (MHA); fewer = grouped queries
     banded: bool = False  # the mask has a lower edge (a window layer)
     block: bool = False   # a block-diffusion tick's rows: the call's name
+    copies: bool = False  # the kernel copies its pages (`copies_pages`)
 
     @property
     def q_group(self):
@@ -282,21 +301,36 @@ def _round_up(x, multiple):
     return -(-x // multiple) * multiple
 
 
+def copies_pages(page_size, width, itemsize):
+    """Whether the walk copies its pages itself into contiguous VMEM
+    tiles (else: a pipeline block a page). A copy from the pool in HBM
+    must take whole tiles of its `[page_size, width]` slice: a row of
+    whole 128-lane tiles, a page of whole sublane tiles of its dtype
+    (8 rows of 32 bits: 16 of bf16, 32 of int8)."""
+    return (width % _LANES == 0
+            and page_size % (_SUBLANES * 4 // itemsize) == 0)
+
+
 def group_pages(page_size, heads, width, itemsize, seq, pages_per_slot):
     """G, the logical pages one grid step serves.
 
-    The most pages whose VMEM fits `_VMEM_BUDGET`: per page a K and a
-    V block, each double-buffered by the pipeline at the page itemsize
-    and joined once into the step's f32-or-narrower operand; per call
-    the `[seq * H8, width]` block-diagonal query, accumulator and
-    step product. Where a slot has more pages than that, G is a whole
-    number of 128-key lane tiles (the scores are `[rows, G * page_size]`
-    with keys on lanes, and the mask block must be lane-aligned), and
-    never less than one.
+    The most pages whose VMEM fits `_VMEM_BUDGET`. Per page: where
+    the kernel copies its pages (`copies_pages`), a K and a V page in
+    each of its two tiles and the step's f32 scores and probabilities
+    over the page's keys; else a K and a V block, each double-buffered
+    by the pipeline at the page itemsize and joined once into the
+    step's f32-or-narrower operand. Per call the `[seq * H8, width]`
+    block-diagonal query, accumulator and step product. Where a slot
+    has more pages than that, G is a whole number of 128-key lane
+    tiles (the scores are `[rows, G * page_size]` with keys on lanes,
+    and the mask block must be lane-aligned), and never less than one.
     """
     lanes = _round_up(width, _LANES)
     rows = seq * _round_up(heads, _SUBLANES)
-    per_page = 2 * page_size * lanes * (2 * itemsize + 4)
+    if copies_pages(page_size, width, itemsize):
+        per_page = 4 * page_size * (lanes * itemsize + 2 * rows)
+    else:
+        per_page = 2 * page_size * lanes * (2 * itemsize + 4)
     fixed = 3 * rows * lanes * 4
     tile = math.lcm(page_size, _LANES) // page_size
     fit = max(_VMEM_BUDGET - fixed, 0) // per_page
@@ -347,25 +381,64 @@ def _dot(a, b, contract_b):
         preferred_element_type=jnp.float32)
 
 
+def _copy_pages(pools, tiles, sems, buf, page_of, page, lo, hi, start):
+    """Starts (or, `start` False, waits for) the copies of a step's
+    pages g in [lo, hi): physical page `page_of(g)` of each pool, left
+    in HBM, straight into rows `[g * page, (g + 1) * page)` of buffer
+    `buf` of its VMEM tiles (`[2, keys, W]`), every copy signalling
+    `sems[buf]`. Unrolled where the bounds are Python ints. Both walks
+    fetch their steps through it."""
+
+    def one(g, carry):
+        src = page_of(g)
+        rows = pl.ds(pl.multiple_of(g * page, page), page)
+        for pool, tile in zip(pools, tiles):
+            copy = pltpu.make_async_copy(pool.at[src], tile.at[buf, rows],
+                                         sems.at[buf])
+            if start:
+                copy.start()
+            else:
+                copy.wait()
+        return carry
+
+    static = isinstance(lo, int) and isinstance(hi, int)
+    jax.lax.fori_loop(lo, hi, one, 0, unroll=True if static else None)
+
+
+def _computes(slot_ref, group_ref, live_ref, group, t):
+    """Whether step t of a walk computes (and so copies its pages):
+    False on an evicted slot's step and past the grid's end (the
+    schedule's entry there reads as the last slot's first group)."""
+    return ((t < pl.num_programs(0))
+            & (group_ref[t] < live_groups(live_ref[slot_ref[t]], group)))
+
+
 def _paged_kernel(slot_ref, group_ref, pt_ref, live_ref, *rest, config):
     """One step of the walk: every head of one slot against one group
     of G logical pages. The grid runs over the live groups alone;
     step t serves group `group_ref[t]` of slot `slot_ref[t]`.
 
-    rest: (a window layer: the slots' first live group), the query
-    block, the mask block, `segt`, G key-page blocks, G value-page
-    blocks, (int8: the group's `[H8, G]` K and V scale blocks), the
+    rest: (a window layer: the slots' first live page), the query
+    block, the mask block, `segt`, the K and V pages (copied: the two
+    pools, left in HBM; blocks: G key-page blocks, G value-page
+    blocks), (int8: the group's `[H8, G]` K and V scale blocks), the
     output block, then scratch: the block-diagonal query
     `[seq * H8, width]`, acc (same shape, f32), m and l
-    `[seq * H8, 128]`, and for full multi-head attention the
-    `[seq, width]` f32 output rows."""
-    del pt_ref  # consumed by the BlockSpec index maps
+    `[seq * H8, 128]`, for full multi-head attention the `[seq, width]`
+    f32 output rows, and where the kernel copies its pages the K and V
+    tiles `[2, G * P, width]` and their two DMA semaphores."""
     group, seq, page = config.group, config.seq, config.page_size
     if config.banded:
         first_ref, *rest = rest
     q_ref, a_ref, segt_ref, *rest = rest
-    k_refs, v_refs, rest = rest[:group], rest[group:2 * group], rest[
-        2 * group:]
+    if config.copies:
+        k_pool, v_pool, *rest, k_tiles, v_tiles, sems = rest
+        page_dtype = k_tiles.dtype
+    else:
+        del pt_ref  # consumed by the BlockSpec index maps
+        k_refs, v_refs, rest = rest[:group], rest[group:2 * group], rest[
+            2 * group:]
+        page_dtype = k_refs[0].dtype
     if config.quantized:
         ks_ref, vs_ref, *rest = rest
     grouped = config.q_group > 1
@@ -375,13 +448,44 @@ def _paged_kernel(slot_ref, group_ref, pt_ref, live_ref, *rest, config):
         o_ref, qbd_ref, acc_ref, m_ref, l_ref, rows_ref = rest
     ti = pl.program_id(0)
     ji = group_ref[ti]
-    first = first_ref[slot_ref[ti]] if config.banded else 0
+    first = first_ref[slot_ref[ti]] // group if config.banded else 0
     steps = live_groups(live_ref[slot_ref[ti]], group)
     hp = qbd_ref.shape[0] // seq          # heads, padded to sublanes
     keys = group * page
     kv_heads = config.kv_heads or config.heads
     depth = qbd_ref.shape[1] // kv_heads  # head size
-    qk_dtype, pv_dtype = _operand_dtypes(q_ref.dtype, k_refs[0].dtype)
+    qk_dtype, pv_dtype = _operand_dtypes(q_ref.dtype, page_dtype)
+
+    if config.copies:
+        computes = functools.partial(_computes, slot_ref, group_ref,
+                                     live_ref, group)
+
+        def copy_pages(t, start):
+            """Step t's live pages into tile t % 2 of each pool: from
+            the band's first page (a window layer) to the slot's last."""
+            slot, base = slot_ref[t], group_ref[t] * group
+            lo = (jnp.maximum(first_ref[slot] - base, 0) if config.banded
+                  else 0)
+            hi = jnp.minimum(live_ref[slot] - base, group)
+            _copy_pages((k_pool, v_pool), (k_tiles, v_tiles), sems, t % 2,
+                        lambda g: pt_ref[slot, base + g], page, lo, hi,
+                        start)
+
+        own = computes(ti)
+
+        @pl.when(ti == 0)
+        def _first_copies():
+            # A row no step copies keeps what its tile held: finite.
+            k_tiles[...] = jnp.zeros_like(k_tiles)
+            v_tiles[...] = jnp.zeros_like(v_tiles)
+
+            @pl.when(own)
+            def _own():
+                copy_pages(ti, start=True)
+
+        @pl.when(computes(ti + 1))
+        def _next_copies():
+            copy_pages(ti + 1, start=True)
 
     @pl.when(ji == first)
     def _init():
@@ -421,10 +525,15 @@ def _paged_kernel(slot_ref, group_ref, pt_ref, live_ref, *rest, config):
 
     @pl.when(ji < steps)        # false only on an evicted slot's step
     def _step():
-        join = lambda refs, dtype: jnp.concatenate(
-            [r[0].astype(dtype) for r in refs], axis=0)
-        k = join(k_refs, qk_dtype)                   # [keys, width]
-        v = join(v_refs, pv_dtype)
+        if config.copies:
+            copy_pages(ti, start=False)
+            k = k_tiles[ti % 2].astype(qk_dtype)     # [keys, width]
+            v = v_tiles[ti % 2].astype(pv_dtype)
+        else:
+            join = lambda refs, dtype: jnp.concatenate(
+                [r[0].astype(dtype) for r in refs], axis=0)
+            k = join(k_refs, qk_dtype)
+            v = join(v_refs, pv_dtype)
         s = _dot(qbd_ref[...], k, 1) * config.sm_scale  # [rows, keys]
         if config.quantized:
             lane_page = jax.lax.broadcasted_iota(
@@ -500,19 +609,21 @@ def _schedule(live_pages, group, most, first_groups=None):
 
 
 def _paged_forward(config, q, key_pages, value_pages, page_table,
-                   live_pages, allowed, first_groups=None,
+                   live_pages, allowed, first_pages=None,
                    key_scales=None, value_scales=None):
     """q: [S, seq, H*D] (grouped queries: [S, seq * H8, D], heads
     padded to sublanes); page_table: [S, groups * G] (padded with the
     scratch page); live_pages: [S]; allowed: [S, seq, groups * G * P]
-    int32; first_groups (a window layer): [S]; scales (int8 mode):
+    int32; first_pages (a window layer): [S]; scales (int8 mode):
     [S, groups, H8, G] -> out, shaped as q.
 
     The schedule (`_schedule`), the page table and the live bounds
     are the scalar-prefetch operands and the grid's one dimension is
-    the schedule's length, a value of the call: block g of step t's K
-    (and V) is physical page `pt[slot[t], group[t] * G + g]`, so the
-    pool is only ever touched at the pages of groups a slot has live.
+    the schedule's length, a value of the call: page g of step t's K
+    (and V) is physical page `pt[slot[t], group[t] * G + g]`, copied
+    by the kernel from the pools passed once (`config.copies`) or a
+    block of a pool passed G times, so the pool is only ever touched at
+    the pages of groups a slot has live.
     """
     slots = q.shape[0]
     width = key_pages.shape[2]
@@ -530,35 +641,45 @@ def _paged_forward(config, q, key_pages, value_pages, page_table,
     segt = ((row_head[:, None]
              == jnp.arange(width)[None, :] // (width // kv_heads))
             & (jnp.arange(hp) < heads)[:, None]).astype(jnp.float32)
+    # Where the kernel copies its pages, one entry past the most steps
+    # a call can take: it looks at step t + 1's entry to start them.
     slot_of, group_of, total = _schedule(
-        live_pages, group, slots * (page_table.shape[1] // group),
-        first_groups)
+        live_pages, group,
+        slots * (page_table.shape[1] // group) + config.copies,
+        None if first_pages is None else first_pages // group)
     scalars = [slot_of, group_of, page_table, live_pages]
     if config.banded:
-        scalars.append(first_groups)
+        scalars.append(first_pages)
     operands = scalars + [q, allowed, segt]
-    operands += [key_pages] * group + [value_pages] * group
+    if config.copies:
+        operands += [key_pages, value_pages]
+    else:
+        operands += [key_pages] * group + [value_pages] * group
     if config.quantized:
         operands += [key_scales, value_scales]
     operands = partition.common_vma(*operands)
 
     slot_block = pl.BlockSpec(
         (1,) + q.shape[1:], lambda t, slot, *_: (slot[t], 0, 0))
-    # K/V blocks are single physical pages, gathered by block
-    # *indexing* through the prefetched schedule — never an HBM
-    # materialization of the dense [S, cache_len, H, D] view.
-    page_blocks = [
-        pl.BlockSpec(
-            (1, page_size, width),
-            lambda t, slot, grp, pt, *_, g=g: (
-                pt[slot[t], grp[t] * group + g], 0, 0))
-        for g in range(group)]
+    # K/V pages are single physical pages, gathered through the
+    # prefetched schedule by the kernel's copies or by block
+    # *indexing* — never an HBM materialization of the dense
+    # [S, cache_len, H, D] view.
+    if config.copies:
+        page_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    else:
+        page_specs = [
+            pl.BlockSpec(
+                (1, page_size, width),
+                lambda t, slot, grp, pt, *_, g=g: (
+                    pt[slot[t], grp[t] * group + g], 0, 0))
+            for g in range(group)] * 2
     in_specs = [
         slot_block,
         pl.BlockSpec((1, seq, group * page_size),
                      lambda t, slot, grp, *_: (slot[t], 0, grp[t])),
         pl.BlockSpec((hp, width), lambda t, *_: (0, 0)),
-    ] + page_blocks * 2
+    ] + page_specs
     if config.quantized:
         in_specs += [pl.BlockSpec(
             (1, 1, hp, group), lambda t, slot, grp, *_: (
@@ -571,6 +692,11 @@ def _paged_forward(config, q, key_pages, value_pages, page_table,
     ]
     if config.q_group == 1:
         scratch_shapes.append(pltpu.VMEM((seq, width), jnp.float32))
+    if config.copies:
+        scratch_shapes += [
+            pltpu.VMEM((2, group * page_size, width), key_pages.dtype),
+            pltpu.VMEM((2, group * page_size, width), value_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(total,),
@@ -584,6 +710,10 @@ def _paged_forward(config, q, key_pages, value_pages, page_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             q.shape, out_dtype, vma=partition.vma_of(*operands)),
+        # A step's copies are started by the step before: in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+        if config.copies and not config.interpret else None,
         interpret=config.interpret,
         name=_CALL_PREFIX + (PAGED_BLOCK_DECODE if config.block
                              else PAGED_DECODE_WINDOW if config.banded
@@ -607,16 +737,17 @@ def _grouped(page_table, allowed, page_size, group):
     return table, mask, live_pages
 
 
-def _first_groups(allowed, page_size, group):
-    """Per slot the group that holds the first key any query row may
-    attend (0 for an evicted slot): where a window layer's walk
-    starts. Keys before the band inside that group are the mask's."""
+def _first_pages(allowed, page_size):
+    """Per slot the page that holds the first key any query row may
+    attend (0 for an evicted slot): a window layer's walk starts at
+    its group, and copies from it. Keys before the band inside that
+    page are the mask's."""
     cache_len = allowed.shape[2]
     first_key = jnp.min(
         jnp.where(allowed, jnp.arange(cache_len), cache_len),
         axis=(1, 2))
     first_key = jnp.where(first_key == cache_len, 0, first_key)
-    return (first_key // (page_size * group)).astype(jnp.int32)
+    return (first_key // page_size).astype(jnp.int32)
 
 
 def _paged_walk_lax(q, key_pages, value_pages, page_table, allowed,
@@ -706,11 +837,12 @@ def _paged_call(q, key_pages, value_pages, page_table, allowed, *scales,
     config = _PagedConfig(sm_scale=sm_scale, heads=local_heads, seq=seq,
                           page_size=page_size, group=group,
                           interpret=interpret, quantized=bool(scales),
-                          kv_heads=kv_heads, banded=banded, block=block)
+                          kv_heads=kv_heads, banded=banded, block=block,
+                          copies=copies_pages(page_size, width,
+                                              key_pages.dtype.itemsize))
     table, mask, live_pages = _grouped(page_table, allowed, page_size,
                                        group)
-    first = (_first_groups(allowed, page_size, group) if banded
-             else None)
+    first = _first_pages(allowed, page_size) if banded else None
     hp = _round_up(local_heads, _SUBLANES)
 
     def by_group(sc):
@@ -997,33 +1129,16 @@ def _mla_kernel(slot_ref, group_ref, pt_ref, live_ref, q_ref, a_ref,
     steps = live_groups(live_ref[slot_ref[ti]], group)
     keys = group * page
     qk_dtype, pv_dtype = _operand_dtypes(q_ref.dtype, pool_ref.dtype)
-
-    def computes(t):
-        """False on an evicted slot's step and past the grid's end (the
-        schedule's entry there reads as the last slot's first group)."""
-        return ((t < pl.num_programs(0))
-                & (group_ref[t] < live_groups(live_ref[slot_ref[t]],
-                                              group)))
+    computes = functools.partial(_computes, slot_ref, group_ref, live_ref,
+                                 group)
 
     def copy_pages(t, start):
-        """Starts (or waits for) the copies of step t's G pages into
-        buffer t % 2, page g into rows `[g * P, (g + 1) * P)`."""
+        """Step t's G pages into buffer t % 2."""
         buf = t % 2
         slot, first = slot_ref[t], group_ref[t] * group
-
-        def one(g, carry):
-            copy = pltpu.make_async_copy(
-                pool_ref.at[pt_ref[slot, first + g]],
-                rows_ref.at[buf, pl.ds(pl.multiple_of(g * page, page),
-                                       page)],
-                sems.at[buf])
-            if start:
-                copy.start()
-            else:
-                copy.wait()
-            return carry
-
-        jax.lax.fori_loop(0, group, one, 0, unroll=True)
+        _copy_pages((pool_ref,), (rows_ref,), sems, buf,
+                    lambda g: pt_ref[slot, first + g], page, 0, group,
+                    start)
 
     @pl.when((ti == 0) & computes(ti))
     def _first_copies():

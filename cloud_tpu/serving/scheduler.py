@@ -140,8 +140,8 @@ import jax
 import numpy as np
 
 from cloud_tpu.monitoring import spans
-from cloud_tpu.ops.paged_attention import (group_pages, mla_group_pages,
-                                         walked_tokens)
+from cloud_tpu.ops.paged_attention import (copies_pages, group_pages,
+                                         mla_group_pages, walked_tokens)
 from cloud_tpu.parallel import runtime
 from cloud_tpu.serving import reqtrace
 from cloud_tpu.serving.engine import (DecodeEngine, attention_shape,
@@ -575,7 +575,8 @@ class Scheduler:
         self._prefills_overlapped = 0
         # Running sums over ticks and occupied slots: the keys a slot
         # attends to, and the keys the paged kernel's walk fetches for
-        # that depth (whole groups of `_kv_group` pages; the kernel's
+        # that depth (whole groups of `_kv_group` pages, or whole pages
+        # where the kernel copies its live pages itself; the kernel's
         # own arithmetic, at the unsharded width). live / walked is
         # the share of the walk that is not dead. A verify window
         # reaches `_kv_reach` keys past the plain tick's.
@@ -590,11 +591,14 @@ class Scheduler:
             self._kv_group = mla_group_pages(page_size,
                                              self.engine.pages_per_slot)
         else:
-            self._kv_group = group_pages(
-                page_size, heads, kv_heads * head_dim,
-                1 if kv_dtype == "int8"
-                else np.dtype(m.compute_dtype).itemsize,
-                self._kv_reach + 1, self.engine.pages_per_slot)
+            itemsize = (1 if kv_dtype == "int8"
+                        else np.dtype(m.compute_dtype).itemsize)
+            width = kv_heads * head_dim
+            self._kv_group = (
+                1 if copies_pages(page_size, width, itemsize)
+                else group_pages(page_size, heads, width, itemsize,
+                                 self._kv_reach + 1,
+                                 self.engine.pages_per_slot))
         self._kv_live_tokens = 0
         self._kv_walked_tokens = 0
         # A ring-and-summaries model's counters, summed over ticks and

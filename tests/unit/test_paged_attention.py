@@ -497,18 +497,20 @@ def test_group_pages_from_the_shapes():
 # ---------------------------------------------------------------------------
 
 
-def _grouped_case(kind, seq, window, depths=(250, 37, 0), heads=8,
-                  kv_heads=2, head_dim=128):
-    """3 slots over `EDGE_PAGES` 16-token pages whose rows hold
-    `kv_heads` heads; slot s is `depths[s]` tokens deep (0: evicted).
-    Returns (reference, walk, interpreted kernel, tolerance)."""
+def _pool_case(kind, seq, window, depths=(250, 37, 0), heads=8,
+               kv_heads=2, head_dim=128, page=16):
+    """3 slots over `EDGE_PAGES` pages of `page` tokens whose rows hold
+    `kv_heads` heads; slot s is `depths[s]` tokens deep (0: evicted),
+    its `seq` query rows the last positions. Returns (q, k, v, table,
+    allowed, scales): int8 pages with their scales, else f32 or bf16
+    pages and no scales."""
     rng = np.random.default_rng(11)
     slots = len(depths)
     num_pages = slots * EDGE_PAGES + 1
-    cache_len = EDGE_PAGES * 16
+    cache_len = EDGE_PAGES * page
     dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
     q = jnp.asarray(rng.normal(size=(slots, seq, heads, head_dim)), dtype)
-    rows = lambda: rng.normal(size=(num_pages, 16, kv_heads * head_dim))
+    rows = lambda: rng.normal(size=(num_pages, page, kv_heads * head_dim))
     k, v = rows(), rows()
     pt = jnp.asarray(1 + rng.permutation(num_pages - 1).reshape(
         slots, EDGE_PAGES), jnp.int32)
@@ -522,18 +524,26 @@ def _grouped_case(kind, seq, window, depths=(250, 37, 0), heads=8,
     scales = {}
     if kind == "int8":
         def quantize(x):
-            x = x.reshape(num_pages, 16, kv_heads, head_dim)
+            x = x.reshape(num_pages, page, kv_heads, head_dim)
             scale = np.abs(x).max(axis=(1, 3)) / 127.0
             quant = np.round(x / scale[:, None, :, None])
-            return (jnp.asarray(quant.reshape(num_pages, 16, -1), jnp.int8),
+            return (jnp.asarray(quant.reshape(num_pages, page, -1),
+                                jnp.int8),
                     jnp.asarray(scale, jnp.float32))
         (k, ks), (v, vs) = quantize(k), quantize(v)
         scales = dict(key_scales=ks, value_scales=vs)
     else:
         k, v = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+    return q, k, v, pt, allowed, scales
+
+
+def _grouped_case(kind, seq, window, **shape):
+    """`_pool_case`'s reference, walk and interpreted kernel, and the
+    tolerance of the page type."""
+    q, k, v, pt, allowed, scales = _pool_case(kind, seq, window, **shape)
     ref = pa.paged_attention_reference(q, k, v, pt, allowed, **scales)
     walk = pa._paged_walk_lax(q, k, v, pt, allowed,
-                              1.0 / np.sqrt(head_dim), **scales)
+                              1.0 / np.sqrt(q.shape[-1]), **scales)
     kern = pa.paged_decode_attention(q, k, v, pt, allowed, interpret=True,
                                      window=window, **scales)
     as_f32 = lambda x: np.asarray(x, np.float32)
@@ -568,7 +578,7 @@ def test_window_walk_starts_at_the_first_live_group(group_of_8):
 
     def steps(mask, banded):
         _, _, live = pa._grouped(table, mask, 16, group_of_8)
-        first = (pa._first_groups(mask, 16, group_of_8) if banded
+        first = (pa._first_pages(mask, 16) // group_of_8 if banded
                  else None)
         slot_of, group_of, total = pa._schedule(live, group_of_8, most,
                                                 first)
@@ -578,3 +588,102 @@ def test_window_walk_starts_at_the_first_live_group(group_of_8):
 
     assert steps(full, False) == [(0, 0), (0, 1), (1, 0), (2, 0)]
     assert steps(band, True) == [(0, 1), (1, 0), (2, 0)]
+
+
+# ---------------------------------------------------------------------------
+# The two ways a step's pages arrive: copied by the kernel, or a block each
+# ---------------------------------------------------------------------------
+
+
+def test_which_form_the_shapes_take():
+    """The kernel copies its pages where a page is whole tiles of its
+    dtype: rows a multiple of 128 lanes, 16-row pages of bf16 and 8 of
+    f32, 32 of int8. Every other geometry keeps a block a page."""
+    assert pa.copies_pages(16, 512, 2)       # SDAR
+    assert pa.copies_pages(16, 1024, 2)      # K-EXAONE
+    assert pa.copies_pages(16, 256, 2)       # Nemotron
+    assert pa.copies_pages(16, 4096, 2)      # EvaByte
+    assert pa.copies_pages(8, 128, 4)
+    assert pa.copies_pages(32, 128, 1)
+    assert not pa.copies_pages(16, 1600, 2)  # gpt2-xl
+    assert not pa.copies_pages(16, 832, 2)   # gpt2-xl's half under tp = 2
+    assert not pa.copies_pages(16, 1024, 1)  # int8: half a sublane tile
+    assert not pa.copies_pages(8, 512, 2)
+
+
+#: name: (page kind, query heads, key/value heads, head size, query rows,
+#: window, block rows, page rows).
+COPY_CASES = {
+    "mha_tick_f32": ("f32", 2, 2, 64, 1, None, None, 16),
+    "mha_verify_bf16": ("bf16", 2, 2, 64, 3, None, None, 16),
+    "gqa_tick_bf16": ("bf16", 8, 2, 128, 1, None, None, 16),
+    "gqa_block_bf16": ("bf16", 8, 2, 128, 4, None, 4, 16),
+    "gqa_block_f32": ("f32", 8, 2, 128, 4, None, 4, 16),
+    "gqa_window_f32": ("f32", 8, 2, 128, 1, 40, None, 16),
+    "gqa_window_bf16": ("bf16", 8, 2, 128, 3, 40, None, 16),
+    "int8_32_row_pages": ("int8", 2, 2, 64, 1, None, None, 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COPY_CASES))
+def test_copied_pages_match_blocks_walk_and_reference(group_of_8, case,
+                                                      capfd, monkeypatch):
+    """Slot 0 is 280 tokens deep at 16-row pages (three groups; under a
+    window its band starts at the last page of its second group), slot
+    1 is evicted and
+    slot 2, the last, is one page deep, so the schedule's entry past the
+    grid's end names a live slot. The kernel that copies its pages runs
+    in TPU interpret mode, which moves each copy as the chip would (when
+    waited for, and when started), reports a copy left unwaited at the
+    kernel's exit and a copy that lands in rows a step still reads; its
+    output is the block form's bit for bit (the same rows in the same
+    order), the walk's to rounding, and the reference's to the page
+    type's tolerance."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    kind, heads, kv_heads, head_dim, seq, window, block, page = (
+        COPY_CASES[case])
+    q, k, v, pt, allowed, scales = _pool_case(
+        kind, seq, window, depths=(280 * page // 16, 0, 16), heads=heads,
+        kv_heads=kv_heads, head_dim=head_dim, page=page)
+    assert pa.copies_pages(page, kv_heads * head_dim, k.dtype.itemsize)
+    sm_scale = 1.0 / np.sqrt(head_dim)
+    group = pa.group_pages(page, heads, kv_heads * head_dim,
+                           k.dtype.itemsize, seq, EDGE_PAGES)
+    assert 280 * page // 16 > group * page   # slot 0 takes several steps
+    args = (q, k, v, pt, allowed) + tuple(scales.values())
+
+    def kernel(interpret):
+        return np.asarray(pa._paged_call(
+            *args, sm_scale=sm_scale, interpret=interpret,
+            banded=bool(window), block=bool(block)), np.float32)
+
+    pa._paged_call.clear_cache()
+    copied = [kernel(pltpu.InterpretParams(dma_execution_mode=mode,
+                                           detect_races=True))
+              for mode in ("on_wait", "eager")]
+    printed = capfd.readouterr().out
+    assert "non-zero count" not in printed, printed
+    assert "RACE DETECTED" not in printed, printed
+    # The block form at the same G: the same rows in the same order.
+    copies_pages, group_pages = pa.copies_pages, pa.group_pages
+    monkeypatch.setattr(pa, "copies_pages", lambda *_: False)
+    monkeypatch.setattr(pa, "group_pages", lambda *_: group)
+    pa._paged_call.clear_cache()
+    blocks = kernel(True)
+    monkeypatch.setattr(pa, "copies_pages", copies_pages)
+    monkeypatch.setattr(pa, "group_pages", group_pages)
+    pa._paged_call.clear_cache()
+    ref = np.asarray(pa.paged_attention_reference(
+        q, k, v, pt, allowed, **scales), np.float32)
+    walk = np.asarray(pa._paged_walk_lax(q, k, v, pt, allowed, sm_scale,
+                                         **scales), np.float32)
+    tol = 2e-2 if kind == "bf16" else TOL
+    live = np.array([True, False, True])
+    for out in copied:
+        np.testing.assert_array_equal(out, blocks)
+        np.testing.assert_allclose(out, walk, atol=1e-6 if kind == "f32"
+                                   else tol, rtol=1e-6 if kind == "f32"
+                                   else tol)
+        np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+        assert not out[~live].any()

@@ -363,19 +363,37 @@ class TestSchedulerStats:
         assert stats["ttft_hit"]["count"] == 0
         assert stats["ttft_miss"]["count"] == 1
 
+    @pytest.mark.parametrize("copied", [False, True])
     @pytest.mark.parametrize("depths", [(1, 16, 17), (5, 31), (32,)])
-    def test_kv_walk_sums(self, model, params, depths):
+    def test_kv_walk_sums(self, model, params, depths, copied):
         """`kv_live_tokens` sums the occupied slots' depths a tick and
         `kv_walked_tokens` what the paged kernel's walk fetches for
-        them: the kernel's own helper, whole groups of pages."""
+        them: the kernel's own helper, whole groups of pages, or whole
+        pages where the kernel copies its live pages itself (rows of
+        128 lanes, 8-row float32 pages)."""
         import types
 
-        from cloud_tpu.ops.paged_attention import (group_pages,
+        import jax
+        import jax.numpy as jnp
+
+        from cloud_tpu.models import TransformerLM
+        from cloud_tpu.ops.paged_attention import (copies_pages,
+                                                   group_pages,
                                                    walked_tokens)
         from cloud_tpu.serving import Scheduler
-        sched = Scheduler(model, params, slots=4, page_size=4)
-        group = group_pages(4, model.num_heads, model.d_model, 4, 1,
-                            model.max_seq_len // 4)
+        page = 4
+        if copied:
+            model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                                  d_model=128, d_ff=64, max_seq_len=32,
+                                  compute_dtype=jnp.float32)
+            params = model.init(jax.random.PRNGKey(1),
+                                jnp.zeros((1, 4), jnp.int32))["params"]
+            page = 8
+        assert copies_pages(page, model.d_model, 4) == copied
+        sched = Scheduler(model, params, slots=4, page_size=page)
+        group = 1 if copied else group_pages(
+            page, model.num_heads, model.d_model, 4, 1,
+            model.max_seq_len // page)
         assert sched._kv_group == group
         for slot, depth in enumerate(depths):
             sched._slots[slot] = types.SimpleNamespace(
@@ -389,7 +407,7 @@ class TestSchedulerStats:
         stats = sched.stats()
         assert stats["kv_live_tokens"] == sum(depths)
         assert stats["kv_walked_tokens"] == sum(
-            walked_tokens(d, 4, group) for d in depths)
+            walked_tokens(d, page, group) for d in depths)
         assert stats["kv_live_tokens"] <= stats["kv_walked_tokens"]
         # The next tick sees every slot one token deeper.
         sched._distribute(live, fetched, 0.01, 0.0)

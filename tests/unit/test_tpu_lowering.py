@@ -24,6 +24,7 @@ import chip_smoke
 from cloud_tpu import ops
 from cloud_tpu.models import LlamaLM, TransformerLM
 from cloud_tpu.ops import fused_mlp, fused_norm
+from cloud_tpu.ops.paged_attention import copies_pages, group_pages
 
 BF16 = jnp.bfloat16
 F32 = jnp.float32
@@ -257,17 +258,27 @@ def test_paged_kernel_compiles_at_the_cells_geometry(as_tpu, page_dtype,
     one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
     text = jax.jit(fn, in_shardings=one).trace(*specs).lower(
         lowering_platforms=("tpu",)).compile().as_text()
-    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+    calls = [line for line in text.splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
     assert len(calls) == 1, calls
-    assert "_paged_decode_attention.paged_decode" in calls[0]
+    assert "_paged_decode_attention.paged_decode" in calls[0].split(
+        " = ")[0]
+    # 1600 lanes take a pipeline block a page: the pools are passed G
+    # times each (the call's line lists its operands' types).
+    itemsize = pool.dtype.itemsize
+    assert not copies_pages(page, heads * hd, itemsize)
+    group = group_pages(page, heads, heads * hd, itemsize, seq, ppn)
+    pool_type = "{}[{},{},1600]".format(
+        "bf16" if page_dtype == "bf16" else "s8", pages, page)
+    assert calls[0].count(pool_type) == 2 * group, calls[0]
 
 
 def test_eva_tick_compiles_at_the_cells_geometry(as_tpu):
     """The EVA cell's tick, one layer of it: 16 slots, a table of 256 pages
     of 16 rows a slot (2048 summary rows, then the 2048-row ring), rows of
     32 heads x 128 = 4096 lanes. It compiles for a v5e with no chip, the read
-    one Mosaic custom call under `paged_decode_window`; the two 537 MB pools
+    one Mosaic custom call under `paged_decode_window` that copies its pages
+    itself (each pool among its operands once); the two 537 MB pools
     are aliased to the output and never copied, and no dense view of a
     slot's rows or of its positions (`[16, 4096, ...]`, `[16, 32768, ...]`)
     exists."""
@@ -304,6 +315,11 @@ def test_eva_tick_compiles_at_the_cells_geometry(as_tpu):
     reads = [c for c in calls if "paged_decode" in c]
     assert len(reads) == 1 and reads[0].split(".")[-2] == (
         "paged_decode_window"), calls
+    # The read copies its 4096-lane pages itself: each pool is among
+    # its operands once.
+    read = [line for line in text.splitlines()
+            if line.strip().startswith(reads[0] + " ")]
+    assert len(read) == 1 and read[0].count("bf16[4097,16,4096]") == 2
     assert not [line for line in text.splitlines()
                 if " copy(" in line and "[4097,16,4096]" in line]
     assert not re.search(r"\[16,(4096|32768),(32,128|4096)\]", text)
@@ -689,47 +705,107 @@ def test_typed_map_sums_partial_products_and_gradients(cpu_mesh,
     _assert_close(got, run("reference")(x, wg, wu, wd), tol=2e-4)
 
 
+def _paged_read_compiled(slots, rows, d_model, cache_len, num_pages,
+                         page=16, **layer):
+    """One `GQAttention` layer's tick over the paged pool, the cache
+    donated, compiled for one v5e with no chip -> (compiled, its text,
+    its Mosaic custom-call lines, the key pool's shape)."""
+    from cloud_tpu.models.llama import GQAttention
+
+    devices = _tpu_topology()
+    if devices is None:
+        pytest.skip("no libtpu to describe a v5e")
+    attn = GQAttention(compute_dtype=BF16, param_dtype=BF16, decode=True,
+                       cache_len=cache_len, page_size=page,
+                       num_pages=num_pages, **layer)
+    x = S((slots, rows, d_model), BF16)
+    variables = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)
+    pool = variables["cache"]["key_pages"]
+
+    def tick(params, cache, x, live):
+        return attn.apply({"params": params, "cache": cache}, x, live,
+                          mutable=["cache"])
+
+    one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
+    compiled = jax.jit(tick, in_shardings=one, donate_argnums=1).trace(
+        variables["params"], variables["cache"], x,
+        S((slots, rows), jnp.bool_)).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    return compiled, text, calls, pool.shape
+
+
+def _assert_one_copying_read(compiled, text, calls, pool_shape, name,
+                             dense):
+    """ONE Mosaic custom call under the declared `name`; the kernel copies
+    its pages, so each pool is among its operands once (a block a page
+    passed it G times; the call's line lists its operands' types); the
+    pools aliased to the output and never copied; no `dense` view of a
+    slot's rows."""
+    assert len(calls) == 1, calls
+    assert calls[0].split(" = ")[0].split(".")[-2] == name, calls
+    num_pages, page, width = pool_shape
+    assert copies_pages(page, width, 2)
+    pool_type = "bf16[{},{},{}]".format(num_pages, page, width)
+    assert calls[0].count(pool_type) == 2, calls[0]
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and pool_type in line]
+    assert dense not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * (
+        num_pages * page * width * 2)
+
+
 def test_block_read_compiles_at_the_cells_geometry(as_tpu):
     """`sdar_blockdiff_gen`'s tick, one attention layer at SDAR's widths: 64
     slots of 4 rows (one open block each), 32 query heads over 4 key/value
     heads of 128, a table of 176 pages of 16 rows a slot. The paged block
     read compiles with no chip as ONE Mosaic custom call under
-    `paged_block_decode`, the 11 265-page pools aliased to the output and
+    `paged_block_decode` that copies its 512-lane pages itself, the
+    11 265-page pools among its operands once, aliased to the output and
     never copied, and no dense view of a slot's rows."""
-    from cloud_tpu.models.llama import GQAttention
     from cloud_tpu.ops.paged_attention import PAGED_BLOCK_DECODE
 
-    devices = _tpu_topology()
-    if devices is None:
-        pytest.skip("no libtpu to describe a v5e")
     slots, block, page, cache_len = 64, 4, 16, 2816
     num_pages = slots * cache_len // page + 1
-    layer = GQAttention(num_heads=32, num_kv_heads=4, head_dim=128,
-                        compute_dtype=BF16, param_dtype=BF16, qk_norm=True,
-                        rope_theta=1e6, rope_style="rotate_half",
-                        decode=True, cache_len=cache_len, page_size=page,
-                        num_pages=num_pages, attn_block=block)
-    x = S((slots, block, 2048), BF16)
-    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
-    pool = variables["cache"]["key_pages"]
-    assert pool.shape == (num_pages, page, 512)
+    compiled, text, calls, pool_shape = _paged_read_compiled(
+        slots, block, 2048, cache_len, num_pages, num_heads=32,
+        num_kv_heads=4, head_dim=128, qk_norm=True, rope_theta=1e6,
+        rope_style="rotate_half", attn_block=block)
+    assert pool_shape == (num_pages, page, 512)
+    _assert_one_copying_read(compiled, text, calls, pool_shape,
+                             PAGED_BLOCK_DECODE, "[64,2816,")
 
-    def tick(params, cache, x, live):
-        return layer.apply({"params": params, "cache": cache}, x, live,
-                           mutable=["cache"])
 
-    one = NamedSharding(Mesh(np.array(devices[:1]), ("one",)), P())
-    compiled = jax.jit(tick, in_shardings=one, donate_argnums=1).trace(
-        variables["params"], variables["cache"], x,
-        S((slots, block), jnp.bool_)).lower(
-            lowering_platforms=("tpu",)).compile()
-    text = compiled.as_text()
-    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
-             if " custom-call(" in line and "tpu_custom_call" in line]
-    assert len(calls) == 1 and PAGED_BLOCK_DECODE in calls[0], calls
-    pool_type = "bf16[{},{},512]".format(num_pages, page)
-    assert not [line for line in text.splitlines()
-                if " copy(" in line and pool_type in line]
-    assert "[64,2816," not in text
-    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * (
-        num_pages * page * 512 * 2)
+#: name: (slots, d_model, query heads, key/value heads, cache_len, pool
+#: pages, window).
+COPYING_READS = {
+    # K-EXAONE's full and window layers: 1024-lane pools of 131 072 tokens.
+    "kexaone_full": (32, 6144, 64, 8, 4096, 8193, None),
+    "kexaone_window": (32, 6144, 64, 8, 4096, 8193, 128),
+    # Nemotron's attention layer: 256-lane pools.
+    "nemotron": (128, 4096, 32, 2, 2048, 16385, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COPYING_READS))
+def test_paged_read_copies_its_pages_at_the_cells_geometry(as_tpu, case):
+    """A serve cell's tick, one attention layer at its widths: the paged
+    read compiles with no chip as ONE Mosaic custom call under its
+    declared name (`paged_decode_window` on a window layer) that copies
+    its pages itself, each pool among its operands once, aliased and
+    never copied, and no dense view of a slot's rows."""
+    from cloud_tpu.ops.paged_attention import (PAGED_DECODE,
+                                               PAGED_DECODE_WINDOW)
+
+    slots, d_model, heads, kv_heads, cache_len, num_pages, window = (
+        COPYING_READS[case])
+    compiled, text, calls, pool_shape = _paged_read_compiled(
+        slots, 1, d_model, cache_len, num_pages, num_heads=heads,
+        num_kv_heads=kv_heads, head_dim=128, sliding_window=window)
+    assert pool_shape == (num_pages, 16, kv_heads * 128)
+    _assert_one_copying_read(
+        compiled, text, calls, pool_shape,
+        PAGED_DECODE_WINDOW if window else PAGED_DECODE,
+        "[{},{},".format(slots, cache_len))
